@@ -7,7 +7,7 @@ in-memory with the standard ResNet-50 topology ([3,4,6,3] bottlenecks,
 the real checkpoint, which is what throughput measures.
 
 Prints ONE JSON line: {"metric", "value", "unit", "batch"}.
-Run: python bench_onnx.py [batch] [--cpu]
+Run: python bench_onnx.py [batch] (JAX_PLATFORMS=cpu for a CPU run)
 """
 
 import json
@@ -125,12 +125,9 @@ def _resnet50_proto(rng):
 
 
 def main():
-    if "--cpu" not in sys.argv:
-        from bench import wait_for_backend
-        wait_for_backend(metric="onnx_resnet50_scoring", unit="img/s")
+    from bench import device_stamp
+    stamp = device_stamp()
     import jax
-    if "--cpu" in sys.argv:
-        jax.config.update("jax_platforms", "cpu")
     from mmlspark_tpu.core.compile_cache import enable_persistent_cache
     enable_persistent_cache()
     from mmlspark_tpu.core.dataframe import DataFrame
@@ -159,6 +156,7 @@ def main():
         "unit": "images/s",
         "batch": batch,
         "backend": jax.default_backend(),
+        **stamp,
     }))
 
 

@@ -1,8 +1,7 @@
-"""Shared example preamble: honor MMLSPARK_TPU_PLATFORM before jax use.
+"""Shared example preamble: put the repo root on ``sys.path``.
 
-Env-var platform overrides (JAX_PLATFORMS) are read when jax registers
-backends — too late in images whose sitecustomize pre-imports a TPU
-plugin — so the override must go through jax.config first.
+The examples run on whatever backend JAX initialises (the TPU when one
+is attached); ``JAX_PLATFORMS=cpu`` in the environment forces the CPU.
 """
 
 import os
@@ -12,7 +11,3 @@ import sys
 def setup() -> None:
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
-    plat = os.environ.get("MMLSPARK_TPU_PLATFORM")
-    if plat:
-        import jax
-        jax.config.update("jax_platforms", plat)

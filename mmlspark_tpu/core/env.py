@@ -75,10 +75,6 @@ PALLAS_FORCE_COMPILE = register(
     "MMLSPARK_TPU_PALLAS_FORCE_COMPILE", "flag", False,
     "=1 compiles Pallas kernels through Mosaic even off-TPU (AOT "
     "lowering tests / TPU-day debugging) instead of interpret mode")
-SYNC_CPU_DISPATCH = register(
-    "MMLSPARK_TPU_SYNC_CPU_DISPATCH", "flag", True,
-    "=0 keeps XLA:CPU asynchronous dispatch (unsafe with pure_callback "
-    "histograms over >~1 MB operands)")
 ONEHOT_CHUNK = register(
     "MMLSPARK_TPU_ONEHOT_CHUNK", "int", 4096,
     "rows per MXU dot in the onehot formulation")
@@ -88,10 +84,6 @@ ONEHOT_BF16 = register(
 FLASH = register(
     "MMLSPARK_TPU_FLASH", "flag", False,
     "=1 opts into the Pallas flash-attention kernel on TPU")
-COMPILE_CACHE = register(
-    "MMLSPARK_TPU_COMPILE_CACHE", "str", None,
-    "persistent XLA compilation-cache directory (default: a per-machine "
-    "dir under ~/.cache)")
 DIST_INIT_RETRIES = register(
     "MMLSPARK_TPU_DIST_INIT_RETRIES", "int", 3,
     "total rendezvous attempts in distributed_init")
@@ -278,12 +270,6 @@ RETRY_BUDGET_PCT = register(
     "a percentage of request volume; once drained (fleet-wide "
     "brownout) further retries shed to the caller with attribution "
     "instead of amplifying the overload")
-BENCH_PROBE_TIMEOUT_S = register(
-    "MMLSPARK_TPU_BENCH_PROBE_TIMEOUT_S", "int", 90,
-    "bench.py: seconds per TPU backend probe attempt")
-BENCH_PROBE_ATTEMPTS = register(
-    "MMLSPARK_TPU_BENCH_PROBE_ATTEMPTS", "int", 6,
-    "bench.py: max TPU backend probe attempts before falling back")
 WATCHDOG_MULT = register(
     "MMLSPARK_TPU_WATCHDOG_MULT", "float", 0.0,
     "train-step watchdog: stall budget multiplier over the rolling p99 "
@@ -297,7 +283,7 @@ WATCHDOG_MIN_S = register(
 WATCHDOG_INIT_S = register(
     "MMLSPARK_TPU_WATCHDOG_INIT_S", "float", 0.0,
     "fixed stall budget in seconds for each distributed_init attempt "
-    "(the BENCH_r05 hang shape); expiry raises an attributed "
+    "(an init that never returns); expiry raises an attributed "
     "TrainStalled instead of hanging; 0 disables (default)")
 RECOVERY_MAX = register(
     "MMLSPARK_TPU_RECOVERY_MAX", "int", 2,

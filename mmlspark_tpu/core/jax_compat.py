@@ -1,126 +1,50 @@
-"""Version shims for the jax manual-sharding surface.
+"""The jax manual-sharding surface the package is written against.
 
-The codebase targets the vma-typed shard_map API (``jax.shard_map``
-with ``check_vma``, ``jax.lax.pcast``, ``jax.typeof``, ``vma=`` on
-``ShapeDtypeStruct``). Some images bake an older jax (0.4.x) whose
-equivalents are ``jax.experimental.shard_map.shard_map`` with
-``check_rep``, and no axis-varying *types* at all — there ``pcast`` is
-semantically an identity (the collectives still execute; only the
-static checker's bookkeeping is missing). Routing every use through
-this module keeps the call sites written against the current API while
-degrading gracefully on the older runtime.
+``pyproject.toml`` pins ``jax>=0.9``, which has the vma-typed
+shard_map API natively (``jax.shard_map`` with ``check_vma``,
+``jax.lax.pcast``, ``jax.typeof``, ``vma=`` on ``ShapeDtypeStruct``),
+so the four helpers below are direct calls. The names stay because
+graftlint's GL006/GL008 recognise manual-sharding code by them
+(collapsing them into the call sites is ROADMAP D2).
 """
 
 from __future__ import annotations
 
-from typing import Any, Optional
-
-_SYNC_CPU_DISPATCH: Optional[bool] = None
-
-
-def ensure_sync_cpu_dispatch() -> bool:
-    """Force synchronous XLA:CPU dispatch; returns whether it is
-    guaranteed for every execution in this process.
-
-    XLA:CPU's asynchronous dispatch deadlocks any execution containing
-    a ``jax.pure_callback`` over large operands (jax 0.4.37: a single
-    jitted pure_callback on a >~1 MB buffer never returns, even under
-    ``block_until_ready`` — reproduced in isolation; the threshold
-    sits between 100K and 500K f32 elements, far below GBDT bench
-    shape). The root cause is pure_callback_impl issuing jax
-    dispatches (device_put / np.asarray on jax arrays) on the callback
-    thread; the trainer's raw-callback primitive
-    (``trainer._native_hist_primitive``) sidesteps that entirely and
-    is safe either way (so 0.4.x never calls this) — this guard
-    protects the pure_callback paths that remain on newer jax. The
-    flag is baked into the CPU client at creation, so flipping it only
-    works before the first jax computation: the trainer probes this
-    lazily when resolving a pure_callback-backed native histogram and
-    refuses to *default* to one when it returns False (client already
-    created asynchronous). The cost is only lost CPU dispatch
-    pipelining, which a host callback would serialize anyway; the TPU
-    client never reads the flag. ``MMLSPARK_TPU_SYNC_CPU_DISPATCH=0``
-    opts out."""
-    global _SYNC_CPU_DISPATCH
-    if _SYNC_CPU_DISPATCH is not None:
-        return _SYNC_CPU_DISPATCH
-    from mmlspark_tpu.core.env import env_flag
-
-    if not env_flag("MMLSPARK_TPU_SYNC_CPU_DISPATCH", default=True):
-        _SYNC_CPU_DISPATCH = False
-        return False
-    import jax
-
-    try:
-        from jax._src import xla_bridge
-        holder = getattr(xla_bridge, "_CPU_ENABLE_ASYNC_DISPATCH", None)
-        if (xla_bridge.backends_are_initialized()
-                and holder is not None and holder.value):
-            # too late: the CPU client already exists with async
-            # dispatch compiled in, and updating the config now is a
-            # silent no-op (verified empirically)
-            _SYNC_CPU_DISPATCH = False
-            return False
-    except ImportError:
-        pass
-    try:
-        jax.config.update("jax_cpu_enable_async_dispatch", False)
-        _SYNC_CPU_DISPATCH = True
-    except AttributeError:
-        # jax without the knob also predates the async CPU runtime
-        _SYNC_CPU_DISPATCH = True
-    return _SYNC_CPU_DISPATCH
+from typing import Any
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` when present, else the experimental one with
-    ``check_vma`` mapped onto its ``check_rep`` parameter."""
+    """``jax.shard_map`` with keyword arguments."""
     import jax
 
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def pcast_varying(x: Any, axes):
-    """``jax.lax.pcast(x, axes, to='varying')`` where the typed API
-    exists; identity otherwise (on untyped jax there is nothing to
-    cast — values are not tracked as varying/invariant). ``x`` may be
-    a pytree, matching pcast."""
+    """``jax.lax.pcast(x, axes, to='varying')``; ``axes`` may be one
+    name or empty (identity), ``x`` may be a pytree."""
     if not axes:
         return x
     if isinstance(axes, str):
         axes = (axes,)
     import jax
 
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None:
-        return x
-    return pcast(x, tuple(axes), to="varying")
+    return jax.lax.pcast(x, tuple(axes), to="varying")
 
 
 def operand_vma(*operands) -> frozenset:
-    """Union of the operands' varying mesh axes; empty on jax versions
-    without vma-typed avals."""
+    """Union of the operands' varying mesh axes."""
     import jax
 
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return frozenset()
     vma: frozenset = frozenset()
     for operand in operands:
-        vma = vma | getattr(typeof(operand), "vma", frozenset())
+        vma = vma | getattr(jax.typeof(operand), "vma", frozenset())
     return vma
 
 
 def shape_dtype_struct(shape, dtype, vma: frozenset = frozenset()):
-    """``jax.ShapeDtypeStruct`` carrying ``vma`` when supported."""
+    """``jax.ShapeDtypeStruct`` carrying ``vma``."""
     import jax
 
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except TypeError:
-        return jax.ShapeDtypeStruct(shape, dtype)
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)

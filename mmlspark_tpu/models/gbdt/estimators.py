@@ -793,6 +793,10 @@ class _LightGBMModelBase(Model, _LightGBMParams):
     booster: Optional[BoosterArrays] = None
     bin_mapper = None                  # training BinMapper, persisted
     train_measures: Optional[InstrumentationMeasures] = None
+    # TrainResult.hist_stats of the fit that produced this model: the
+    # histogram formulation, tree mode and placement it resolved
+    # (fit-time provenance, not persisted by save/load)
+    hist_stats: Optional[Dict[str, Any]] = None
     evals_result: Optional[List[Dict[str, float]]] = None
     best_iteration: int = -1
     _mesh = None
@@ -1119,6 +1123,7 @@ class LightGBMClassifier(_LightGBMBase):
         model.num_classes = num_class
         model.classes_ = classes
         model.train_measures = measures
+        model.hist_stats = result.hist_stats
         model.evals_result = result.evals
         model.best_iteration = result.best_iteration
         return model
@@ -1207,6 +1212,7 @@ class LightGBMRegressor(_LightGBMBase):
         model.bin_mapper = mapper
         model._mesh = self._mesh
         model.train_measures = measures
+        model.hist_stats = result.hist_stats
         model.evals_result = result.evals
         model.best_iteration = result.best_iteration
         return model
@@ -1262,6 +1268,7 @@ class LightGBMRanker(_LightGBMBase):
         model.bin_mapper = mapper
         model._mesh = self._mesh
         model.train_measures = measures
+        model.hist_stats = result.hist_stats
         model.evals_result = result.evals
         model.best_iteration = result.best_iteration
         return model

@@ -30,7 +30,8 @@ bandwidth-bound on reading the binned matrix, which is the roofline.
 The kernel accumulates in float32 in block order; results match the
 XLA formulations exactly on integer-valued grad/hess (no rounding) and
 to float-sum tolerance otherwise. ``tests/gbdt/test_hist_pallas.py``
-pins both in interpret mode.
+pins both in interpret mode, and ``chip_smoke.py`` checks the second at
+bench dimensions on whatever Mosaic compiled.
 """
 
 from __future__ import annotations
@@ -57,6 +58,20 @@ def pallas_histogram_enabled() -> bool:
                     default=jax.default_backend() == "tpu")
 
 
+def resolve_pallas_interpret() -> bool:
+    """Whether the kernel runs through the Pallas interpreter: never on
+    the TPU backend (Mosaic compiles it), always elsewhere unless
+    MMLSPARK_TPU_PALLAS_FORCE_COMPILE takes the Mosaic path off-TPU
+    (the AOT lowering tests validate the exact on-TPU combination).
+    One resolution shared by the kernel entry point, the shard_map
+    checker policy and the fit's ``hist_stats`` provenance."""
+    import jax
+
+    from mmlspark_tpu.core.env import env_flag
+    return (jax.default_backend() != "tpu"
+            and not env_flag("MMLSPARK_TPU_PALLAS_FORCE_COMPILE"))
+
+
 def _hist_kernel(bn_ref, bins_ref, data_ref, out_ref, *, num_features: int,
                  bin_pad: int):
     """One row block (all rows belong to node ``bn_ref[i]``): add the
@@ -76,8 +91,15 @@ def _hist_kernel(bn_ref, bins_ref, data_ref, out_ref, *, num_features: int,
     for fi in range(num_features):
         col = bins_ref[:, fi:fi + 1].astype(jnp.int32)  # (R, 1)
         eq = (col == iota_b).astype(jnp.float32)        # (R, bin_pad)
+        # HIGHEST: at default precision the MXU rounds the f32 stats to
+        # bf16 (measured on the v5e, PR 22: counts stay exact, grad/hess
+        # sums come out up to 0.12 off at 2M rows), which breaks the
+        # float-sum parity contract with the XLA formulations; the
+        # one-hot operand is exact in bf16, so the multi-pass product
+        # is exact and only the accumulation order differs
         s = jax.lax.dot_general(
             data, eq, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)         # (SPAD, bin_pad)
 
         @pl.when(first)
@@ -186,12 +208,7 @@ def pallas_level_histogram(binned, grad, hess, live, local, width, f, b,
             f"pallas histogram kernel supports at most {_BIN_PAD} bins, "
             f"got {b}; use the XLA formulation for wider bin counts")
     if interpret is None:
-        # FORCE_COMPILE: take the Mosaic path even off-TPU — used by
-        # the AOT lowering tests to validate the exact on-TPU
-        # combination (and for debugging on TPU day)
-        from mmlspark_tpu.core.env import env_flag
-        interpret = (jax.default_backend() != "tpu"
-                     and not env_flag("MMLSPARK_TPU_PALLAS_FORCE_COMPILE"))
+        interpret = resolve_pallas_interpret()
     key = (int(width), int(f), int(b), int(block_rows), bool(interpret))
     if key not in _JIT_CACHE:
         w, nf, nb, br, it = key

@@ -649,6 +649,10 @@ def train_ooc(spill: SpillReader, labels, cfg: TrainConfig, *,
     stores = (carry_st, gq_st, hq_st, node_st)
     hist_stats: Dict[str, object] = {
         "grow_policy": "depthwise", "hist_quant": quant,
+        # the chunked loop only ever runs the native integer kernel
+        # (trainer._ooc_supported screens everything else out)
+        "hist_formulation": "native", "tree_mode": "serial",
+        "pallas_interpret": None,
         "hist_shard": "off", "grad_shard": "off",
         "efb_bundles": 0, "efb_bundled_features": 0,
         "ooc": True, "ooc_reason": None, "chunk_rows": chunk_rows,

@@ -79,33 +79,23 @@ def _check_vma(total_bins: int) -> bool:
     - the pallas kernel's INTERPRET-mode discharge creates constants
       inside the manual trace that the checker refuses to mix with
       dp-varying refs, so the builders turn it off exactly when that
-      kernel is opted in AND the backend will interpret it (non-TPU);
+      kernel is selected AND the backend will interpret it (non-TPU);
       on TPU the kernel lowers opaquely through Mosaic with its output
-      vma declared, so the checker stays on for the production path —
-      on vma-typed jax only: 0.4.x's check_rep has no replication rule
-      for pallas_call at all (compiled or interpreted), so there the
-      checker is off whenever the pallas kernel is selected;
+      vma declared, so the checker stays on for the production path;
     - the native CPU kernel is a host callback whose result the
       checker may treat as axis-invariant even though each shard
-      computes its own local histogram (and on 0.4.x the raw-callback
-      primitive has no replication rule either); the psum on the
-      returned histogram still executes either way.
+      computes its own local histogram; the psum on the returned
+      histogram still executes either way.
     """
-    import jax
-
-    from mmlspark_tpu.core.env import env_flag
+    from mmlspark_tpu.models.gbdt.hist_pallas import (
+        resolve_pallas_interpret)
     from mmlspark_tpu.models.gbdt.trainer import (
         resolve_histogram_formulation)
     choice = resolve_histogram_formulation(total_bins, in_shard_map=True,
                                            warn=False)
     if choice == "native":
         return False
-    if choice != "pallas":
-        return True
-    if not hasattr(jax, "typeof"):
-        return False
-    return not (jax.default_backend() != "tpu"
-                and not env_flag("MMLSPARK_TPU_PALLAS_FORCE_COMPILE"))
+    return not (choice == "pallas" and resolve_pallas_interpret())
 
 
 def _histogram(binned, grad, hess, live, local, width, f, b):

@@ -183,65 +183,6 @@ def distributed_init(coordinator_address: Optional[str] = None,
     if cpu_devices_per_process is not None:
         from mmlspark_tpu.core.virtual_devices import force_cpu_devices
         force_cpu_devices(cpu_devices_per_process)
-        try:
-            # newer jax defaults CPU cross-process collectives to gloo;
-            # 0.4.x needs the opt-in or device_put onto a
-            # process-spanning mesh raises "Multiprocess computations
-            # aren't implemented on the CPU backend"
-            jax.config.update("jax_cpu_collectives_implementation",
-                              "gloo")
-        except (AttributeError, ValueError):
-            pass
-    import inspect
-    accepted = inspect.signature(jax.distributed.initialize).parameters
-    hb = kwargs.get("heartbeat_timeout_seconds")
-    if hb is not None and "heartbeat_timeout_seconds" not in accepted:
-        # jax 0.4.x: the public wrapper predates the knob, but the
-        # underlying client takes heartbeat interval x max-missing —
-        # map the requested window onto those so failure detection
-        # stays bounded by ~hb seconds instead of the ~100 s default
-        kwargs = {k: v for k, v in kwargs.items()
-                  if k != "heartbeat_timeout_seconds"}
-        try:
-            from jax._src import distributed as _distributed
-            from jax._src import xla_bridge as _xla_bridge
-            inner = inspect.signature(
-                _distributed.global_state.initialize).parameters
-            if not {"client_heartbeat_interval_seconds",
-                    "client_max_missing_heartbeats"} <= inner.keys():
-                raise ImportError("heartbeat knobs not exposed")
-            if _xla_bridge.backends_are_initialized():
-                raise RuntimeError(
-                    "jax.distributed.initialize() must be called before "
-                    "any JAX computations are executed.")
-            interval = max(1, int(hb) // 5)
-            missing = max(2, -(-int(hb) // interval))
-            _init_with_retries(
-                lambda: _distributed.global_state.initialize(
-                    coordinator_address=coordinator_address,
-                    num_processes=num_processes,
-                    process_id=process_id,
-                    local_device_ids=local_device_ids,
-                    service_heartbeat_interval_seconds=interval,
-                    service_max_missing_heartbeats=missing,
-                    client_heartbeat_interval_seconds=interval,
-                    client_max_missing_heartbeats=missing,
-                    **{k: v for k, v in kwargs.items() if k in inner}),
-                fault_point)
-            return
-        except ImportError:
-            import warnings
-            warnings.warn(
-                "this jax exposes no heartbeat configuration; dropping "
-                "heartbeat_timeout_seconds — failure detection uses "
-                "the runtime's default window", stacklevel=2)
-    dropped = sorted(k for k in kwargs if k not in accepted)
-    if dropped:
-        import warnings
-        warnings.warn(
-            f"jax.distributed.initialize on jax {jax.__version__} does "
-            f"not accept {dropped}; dropping", stacklevel=2)
-        kwargs = {k: v for k, v in kwargs.items() if k in accepted}
     _init_with_retries(
         lambda: jax.distributed.initialize(
             coordinator_address=coordinator_address,
@@ -265,8 +206,8 @@ def _init_with_retries(init_fn, fault_point) -> None:
 
     def attempt():
         # MMLSPARK_TPU_WATCHDOG_INIT_S > 0 bounds each rendezvous
-        # attempt — the BENCH_r05 failure shape is an init that never
-        # returns, which no retry policy can see without this; a
+        # attempt — an init that never returns is a failure no retry
+        # policy can see without this; a
         # TrainStalled attempt retries like any transient failure and
         # the exhaustion annotation says why the init gave up
         with stall_guard("distributed.init"):

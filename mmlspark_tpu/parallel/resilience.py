@@ -1,11 +1,10 @@
 """Train-step watchdog, stall attribution, and elastic dp-shrink recovery.
 
-Mid-fit hangs are the one failure mode ``bench.py --preflight`` cannot
-attribute: a collective that never completes, a native host callback that
-wedges, or an input pipeline that starves all look identical from the
-outside — a process that stops making progress but never dies (the
-real-TPU flavor of this is the BENCH_r05 init hang, see BASELINE.md).
-This module turns that silence into an attributed, recoverable error:
+Mid-fit hangs are the failure mode no start-up check can attribute: a
+collective that never completes, a native host callback that wedges, or
+an input pipeline that starves all look identical from the outside — a
+process that stops making progress but never dies. This module turns
+that silence into an attributed, recoverable error:
 
 - :class:`TrainWatchdog` observes every train-step boundary (trainers call
   :func:`step_start` / :func:`step_end`, which are free when no watchdog
@@ -21,7 +20,7 @@ This module turns that silence into an attributed, recoverable error:
   hanging forever.
 
 - :func:`stall_guard` is the fixed-budget variant for single blocking
-  calls (``distributed_init`` attempts — the BENCH_r05 shape).
+  calls (``distributed_init`` attempts: an init that never returns).
 
 - :func:`fit_resilient` is the elastic recovery loop: on
   :class:`TrainStalled` / :class:`ParticipantLost` it re-forms the mesh

@@ -111,9 +111,10 @@ def test_registry_shape():
         assert var.name == name
         assert var.kind in ("flag", "int", "float", "str")
         assert var.description
-    # the 5 knobs PR 3's audit found undocumented must stay declared
-    for name in ("MMLSPARK_TPU_COMPILE_CACHE",
-                 "MMLSPARK_TPU_FABRIC_ENDPOINT",
+    # knobs PR 3's audit found undocumented must stay declared (the
+    # fifth, MMLSPARK_TPU_COMPILE_CACHE, went in PR 22: JAX's own
+    # JAX_COMPILATION_CACHE_DIR places the cache)
+    for name in ("MMLSPARK_TPU_FABRIC_ENDPOINT",
                  "MMLSPARK_TPU_FABRIC_TOKEN",
                  "MMLSPARK_TPU_FLASH",
                  "MMLSPARK_TPU_PALLAS_FORCE_COMPILE"):

@@ -25,7 +25,7 @@ def test_all_examples_are_covered():
 @pytest.mark.parametrize("script", SCRIPTS)
 def test_example_runs(script):
     env = dict(os.environ)
-    env["MMLSPARK_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     # examples must not inherit the test process's virtual-device
     # forcing; 05 spawns its own cluster, others run single-device
     env.pop("XLA_FLAGS", None)

@@ -54,8 +54,7 @@ _KW = dict(numIterations=3, numLeaves=4, maxBin=16)
 def test_injected_hist_nan_caught_at_named_boundary(monkeypatch):
     """SAN=1 + armed NaN corruption on the histogram callback must
     abort the fit with a diagnostic naming the jit boundary. jax wraps
-    callback exceptions (XlaRuntimeError in 0.4.x), so match on the
-    message, not the type."""
+    callback exceptions, so match on the message, not the type."""
     monkeypatch.setenv("MMLSPARK_TPU_HIST_FORMULATION", "native")
     san.enable()
     with faults.injected("gbdt.level_hist", "corrupt", count=None,

@@ -6,7 +6,7 @@ unsupported-primitive / layout failures. ``jax.jit(...).trace().lower``
 with a TPU lowering platform runs the full Pallas->Mosaic lowering on
 any host and embeds the serialized Mosaic module in a
 ``tpu_custom_call`` — only XLA:TPU's final compile and execution remain
-hardware-gated (tools/tpu_day.sh covers those).
+hardware-gated (``chip_smoke.py`` covers those for the histogram kernel).
 
 ``test_lowering_check_is_not_vacuous`` proves this catches real
 problems: a kernel using an unimplemented primitive must be rejected.
@@ -81,10 +81,8 @@ def test_voting_builder_with_pallas_lowers_to_mosaic(monkeypatch):
     )
     from mmlspark_tpu.parallel.mesh import MeshConfig, create_mesh
 
-    # the on-TPU configuration keeps the checker ON — on vma-typed jax;
-    # 0.4.x check_rep has no replication rule for pallas_call, so there
-    # the builders must turn it off to lower at all
-    assert _check_vma(64) == hasattr(jax, "typeof")
+    # the on-TPU configuration keeps the checker ON
+    assert _check_vma(64) is True
     mesh = create_mesh(MeshConfig(dp=8))
     cfg = _loop_only_normalized(TrainConfig(
         objective="binary", num_leaves=15, max_depth=4, max_bin=64,
@@ -104,11 +102,14 @@ def test_voting_builder_with_pallas_lowers_to_mosaic(monkeypatch):
 
 
 @pytest.mark.parametrize("subtract", [False, True])
-def test_serial_builder_lowers_for_tpu(subtract):
+def test_serial_builder_lowers_for_tpu(monkeypatch, subtract):
     """The core tree builder (XLA formulation, with and without the
     histogram-subtraction trick) lowers for TPU — no Mosaic involved,
     but sized-nonzero compaction and scatter shapes must pass the TPU
     lowering rules."""
+    # the lowering host's backend is cpu, whose default is the native
+    # host callback — not a program a TPU run ever selects
+    monkeypatch.setenv("MMLSPARK_TPU_NATIVE_HIST", "0")
     import jax.numpy as jnp
 
     from mmlspark_tpu.models.gbdt.trainer import (
@@ -242,7 +243,7 @@ def test_vw_sharded_pass_lowers_for_tpu():
 def test_full_fused_step_lowers_for_tpu(monkeypatch, flags):
     """The ENTIRE fused boosting step (gradients -> tree build -> raw
     update -> metrics) at bench config, in every kernel
-    configuration tpu_day.sh will run — the exact per-iteration
+    configuration a chip run can select — the exact per-iteration
     program bench.py dispatches."""
     for kk, vv in flags.items():
         monkeypatch.setenv(kk, vv)

@@ -11,7 +11,7 @@ structure to learn.
 Prints ONE JSON line:
 {"metric", "value" (Mrow-trees/s of fit), "unit", "backend",
  "ndcg@10" (train-set NDCG after fit, sanity floor 0.6)}.
-Run: python tools/bench_ranker.py [n_queries] [--cpu] [--small]
+Run: python tools/bench_ranker.py [n_queries] [--small]
 """
 
 import json
@@ -59,12 +59,8 @@ def main():
     skewed = "--skewed" in sys.argv
     if "--small" in sys.argv:
         n_queries, trees = 100, 10
-    if "--cpu" in sys.argv:
-        import jax
-        jax.config.update("jax_platforms", "cpu")
-    else:
-        from bench import wait_for_backend
-        wait_for_backend(metric="lambdarank_fit", unit="Mrow-trees/s")
+    from bench import device_stamp
+    stamp = device_stamp()
 
     import jax
     import numpy as np
@@ -103,6 +99,7 @@ def main():
         "value": round(mrow_trees, 4),
         "unit": "Mrow-trees/s",
         "backend": backend,
+        **stamp,
         "n_rows": n,
         "n_queries": n_queries,
         "trees": trees,

@@ -17,7 +17,7 @@ Prints ONE JSON line:
 {"metric", "value" (best ours, Mrow/s), "unit", "backend",
  "variants": {raw, binned, binned_incl_binning, sklearn_anchor},
  "vs_anchor"}.
-Run: python tools/bench_scoring.py [n_rows] [--cpu] [--small]
+Run: python tools/bench_scoring.py [n_rows] [--small]
 """
 
 import json
@@ -33,12 +33,8 @@ def main():
     n_score = int(args[0]) if args else 2_000_000
     if "--small" in sys.argv:
         n_score = min(n_score, 100_000)
-    if "--cpu" in sys.argv:
-        import jax
-        jax.config.update("jax_platforms", "cpu")
-    else:
-        from bench import wait_for_backend
-        wait_for_backend(metric="gbdt_batch_scoring", unit="Mrow/s")
+    from bench import device_stamp
+    stamp = device_stamp()
 
     import jax
     import numpy as np
@@ -121,6 +117,7 @@ def main():
         "value": round(best, 4),
         "unit": "Mrow/s",
         "backend": backend,
+        **stamp,
         "n_rows": n_score,
         "trees": trees,
         "variants": {

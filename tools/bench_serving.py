@@ -32,13 +32,13 @@ Two methodologies, selected by flag:
   hedges_fired/requests, bitwise reply check against the model) plus a
   p99-ratio summary row.
 
-Run: python tools/bench_serving.py [n_requests] [--cpu]
+Run: python tools/bench_serving.py [n_requests]
      python tools/bench_serving.py --sustained [--clients N]
-                                   [--duration S] [--cpu]
+                                   [--duration S]
      python tools/bench_serving.py --elastic [--clients N]
-                                   [--duration S] [--cpu]
+                                   [--duration S]
      python tools/bench_serving.py --hedging [--clients N]
-                                   [--duration S] [--cpu]
+                                   [--duration S]
 """
 
 import json
@@ -272,12 +272,14 @@ def run_sustained(model, rows, clients=64, duration_s=10.0, binned="auto",
     }
 
 
-def emit_sustained(clients=64, duration_s=10.0, model_rows=None):
+def emit_sustained(clients=64, duration_s=10.0, model_rows=None,
+                   extra=None):
     """Run three arms (generic comparator, the binned data plane, then
     the binned plane under MMLSPARK_TPU_INFER_AUTOCAST=bf16), print one
     JSON row per arm + ratio summary rows (binned-vs-generic and
     bf16-vs-f32); returns the binned-vs-generic summary. Shared by
-    ``--sustained`` here and bench.py's ``--serving-sustained``."""
+    ``--sustained`` here and bench.py's ``--serving-sustained``; every
+    row carries ``extra`` (the caller's device stamp)."""
     import jax
 
     from mmlspark_tpu.core.env import INFER_AUTOCAST, env_override
@@ -294,6 +296,7 @@ def emit_sustained(clients=64, duration_s=10.0, model_rows=None):
     bf16["arm"] = f"{bf16['arm']}_bf16"
     for row in (generic, binned, bf16):
         row["backend"] = backend
+        row.update(extra or {})
         print(json.dumps(row), flush=True)
     summary = {
         "metric": "serving_sustained_speedup",
@@ -302,6 +305,7 @@ def emit_sustained(clients=64, duration_s=10.0, model_rows=None):
         "unit": "x_vs_generic_transform",
         "qps_binned": binned["qps"], "qps_generic": generic["qps"],
         "clients": clients, "model": MODEL_DESC, "backend": backend,
+        **(extra or {}),
     }
     print(json.dumps(summary), flush=True)
     bf16_summary = {
@@ -313,6 +317,7 @@ def emit_sustained(clients=64, duration_s=10.0, model_rows=None):
         "score_max_abs_delta_vs_f32":
             bf16["score_max_abs_delta_vs_f32"],
         "clients": clients, "model": MODEL_DESC, "backend": backend,
+        **(extra or {}),
     }
     print(json.dumps(bf16_summary), flush=True)
     return summary
@@ -454,7 +459,7 @@ def emit_elastic(clients=16, duration_s=12.0, model_rows=None,
                  extra=None, **kwargs):
     """Run the elastic-fleet bench and print its JSON row; returns the
     row. Shared by ``--elastic`` here and bench.py's
-    ``--serving-elastic`` (which stamps its preflight verdict via
+    ``--serving-elastic`` (the caller's device stamp rides in
     ``extra``)."""
     import jax
 
@@ -594,6 +599,7 @@ def emit_gray(clients=8, duration_s=8.0, model_rows=None, extra=None,
                       duration_s=duration_s, hedging=True, **kwargs)
     for row in (plain, hedged):
         row["backend"] = backend
+        row.update(extra or {})
         print(json.dumps(row), flush=True)
     summary = {
         "metric": "serving_gray_p99_cut",
@@ -622,26 +628,25 @@ def main():
                       if not a.startswith("--")
                       and not sys.argv[sys.argv.index(a) - 1].startswith(
                           ("--clients", "--duration"))), 300))
-    if "--cpu" in sys.argv:
-        import jax
-        jax.config.update("jax_platforms", "cpu")
-    else:
-        from bench import wait_for_backend
-        wait_for_backend(metric="serving_latency", unit="ms")
+    from bench import device_stamp
+    stamp = device_stamp()
 
     if "--sustained" in sys.argv:
         emit_sustained(clients=_arg_value("--clients", 64),
-                       duration_s=_arg_value("--duration", 10.0))
+                       duration_s=_arg_value("--duration", 10.0),
+                       extra=stamp)
         return
 
     if "--elastic" in sys.argv:
         emit_elastic(clients=_arg_value("--clients", 16),
-                     duration_s=_arg_value("--duration", 12.0))
+                     duration_s=_arg_value("--duration", 12.0),
+                     extra=stamp)
         return
 
     if "--hedging" in sys.argv:
         emit_gray(clients=_arg_value("--clients", 8),
-                  duration_s=_arg_value("--duration", 8.0))
+                  duration_s=_arg_value("--duration", 8.0),
+                  extra=stamp)
         return
 
     import urllib.request
@@ -728,6 +733,7 @@ def main():
         "timeout_504": counters["timeout_504"],
         "model": MODEL_DESC,
         "backend": jax.default_backend(),
+        **stamp,
         "n_requests": n_req,
     }))
 

@@ -7,7 +7,7 @@ random weights — identical compute profile to the checkpointed model,
 which is what a throughput number measures.
 
 Prints ONE JSON line {"metric", "value", "unit", "batch", "backend"}.
-Run: python tools/bench_text.py [batch] [--cpu] [--small]
+Run: python tools/bench_text.py [batch] [--small]
 (--small: 2x128 dims for quick CPU sanity runs)
 """
 
@@ -22,12 +22,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 def main():
     args = [a for a in sys.argv[1:] if not a.startswith("--")]
     batch = int(args[0]) if args else 32
-    if "--cpu" in sys.argv:
-        import jax
-        jax.config.update("jax_platforms", "cpu")
-    else:
-        from bench import wait_for_backend
-        wait_for_backend(metric="text_finetune_step", unit="tokens/s")
+    from bench import device_stamp
+    stamp = device_stamp()
 
     import jax
     import jax.numpy as jnp
@@ -79,6 +75,7 @@ def main():
         "batch": batch,
         "shape": f"{layers}L-{dim}d-{heads}h-seq{seq}",
         "backend": jax.default_backend(),
+        **stamp,
     }))
 
 

@@ -4,7 +4,7 @@ BASELINE.json's tracked configs include a VW contextual-bandit run.
 Measures end-to-end fit throughput (featurize + IPS-weighted online
 updates) at a d=50-feature, 10-action workload.
 
-Prints ONE JSON line. Run: python tools/bench_vw.py [rows] [--cpu]
+Prints ONE JSON line. Run: python tools/bench_vw.py [rows]
 """
 
 import json
@@ -18,12 +18,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 def main():
     args = [a for a in sys.argv[1:] if not a.startswith("--")]
     n = int(args[0]) if args else 200_000
-    if "--cpu" in sys.argv:
-        import jax
-        jax.config.update("jax_platforms", "cpu")
-    else:
-        from bench import wait_for_backend
-        wait_for_backend(metric="vw_bandit_fit", unit="rows/s")
+    from bench import device_stamp
+    stamp = device_stamp()
 
     import jax
     import numpy as np
@@ -52,6 +48,7 @@ def main():
         "unit": "rows/s",
         "actions": actions,
         "backend": jax.default_backend(),
+        **stamp,
     }))
 
 
