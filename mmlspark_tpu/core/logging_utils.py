@@ -15,11 +15,12 @@ import json
 import logging
 import re
 import threading
-import time
 import traceback
 import uuid
 from contextlib import contextmanager
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Dict, List, Optional
+
+from mmlspark_tpu.core.timer import span
 
 logger = logging.getLogger("mmlspark_tpu")
 
@@ -44,11 +45,8 @@ class TelemetrySink:
     def __init__(self, capacity: int = 10_000):
         self.capacity = capacity
         self.events: List[Dict[str, Any]] = []
-        self.enabled = True
 
     def emit(self, event: Dict[str, Any]) -> None:
-        if not self.enabled:
-            return
         if len(self.events) >= self.capacity:
             del self.events[: self.capacity // 2]
         self.events.append(event)
@@ -97,40 +95,27 @@ def new_uid(prefix: str) -> str:
 @contextmanager
 def log_stage_method(uid: str, class_name: str, method: str,
                      extra: Optional[Dict[str, Any]] = None):
-    t0 = time.perf_counter()
+    """One record a ``fit()``/``transform()``, and the root of its
+    spans: the body runs under ``span("<Class>.<method>", uid=uid)``
+    (core/timer.py), and the record emitted at the end carries the
+    root's ``start_s``/``end_s`` (``time.perf_counter()``) and
+    ``spans``, everything that closed beneath it, in order of start."""
     record: Dict[str, Any] = {
         "uid": uid,
         "className": class_name,
         "method": method,
         **(extra or {}),
     }
+    root = span(f"{class_name}.{method}", uid=uid)
     try:
-        yield record
+        with root:
+            yield record
     except Exception as e:  # noqa: BLE001 — telemetry must not swallow
         record["error"] = scrub(f"{type(e).__name__}: {e}")
         record["traceback"] = scrub(traceback.format_exc(limit=5))
-        record["seconds"] = time.perf_counter() - t0
-        SINK.emit(record)
         raise
-    record["seconds"] = time.perf_counter() - t0
-    SINK.emit(record)
-
-
-def log_fit(fn: Callable) -> Callable:
-    def wrapper(self, dataset, *args, **kwargs):
-        with log_stage_method(self.uid, type(self).__name__, "fit",
-                              {"numRows": getattr(dataset, "num_rows", None)}):
-            return fn(self, dataset, *args, **kwargs)
-
-    wrapper.__name__ = fn.__name__
-    return wrapper
-
-
-def log_transform(fn: Callable) -> Callable:
-    def wrapper(self, dataset, *args, **kwargs):
-        with log_stage_method(self.uid, type(self).__name__, "transform",
-                              {"numRows": getattr(dataset, "num_rows", None)}):
-            return fn(self, dataset, *args, **kwargs)
-
-    wrapper.__name__ = fn.__name__
-    return wrapper
+    finally:
+        record["seconds"] = root.end_s - root.start_s
+        record["start_s"], record["end_s"] = root.start_s, root.end_s
+        record["spans"] = [s.as_record() for s in root.spans]
+        SINK.emit(record)

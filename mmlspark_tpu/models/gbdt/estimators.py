@@ -42,7 +42,7 @@ from mmlspark_tpu.core.param import (
     to_str,
 )
 from mmlspark_tpu.core.pipeline import Estimator, Model
-from mmlspark_tpu.core.timer import InstrumentationMeasures
+from mmlspark_tpu.core.timer import InstrumentationMeasures, span
 from mmlspark_tpu.models.gbdt.booster import BoosterArrays
 from mmlspark_tpu.models.gbdt.trainer import (TrainConfig, train,
                                               warm_start_scores)
@@ -421,14 +421,17 @@ class _LightGBMBase(Estimator, _LightGBMParams):
 
     def _fit_booster(self, df: DataFrame, objective: str, num_class: int = 1,
                      group_col: Optional[str] = None,
-                     extra_cfg: Optional[Dict[str, Any]] = None):
-        measures = InstrumentationMeasures()
+                     extra_cfg: Optional[Dict[str, Any]] = None,
+                     measures: Optional[InstrumentationMeasures] = None):
+        measures = measures or InstrumentationMeasures()
         train_df, valid_df = self._split_validation(df)
-        x, y, w = self._extract(train_df)
-        if self.get("zeroAsMissing"):
-            # LightGBM zero_as_missing: zeros enter the missing bin;
-            # scoring parity comes from the zero-missing decision bits
-            x = np.where(x == 0.0, np.nan, x)
+        with measures.phase("extract") as extract:
+            x, y, w = self._extract(train_df)
+            if self.get("zeroAsMissing"):
+                # LightGBM zero_as_missing: zeros enter the missing bin;
+                # scoring parity comes from the zero-missing decision bits
+                x = np.where(x == 0.0, np.nan, x)
+            extract.counts.update(rows=len(x), bytes=x.nbytes)
         # group ids must be computed on the *post-split* rows so they
         # stay aligned with binned/y when a validation indicator is set
         group_ids = vgroup_ids = None
@@ -450,18 +453,21 @@ class _LightGBMBase(Estimator, _LightGBMParams):
         cfg = _apply_pass_through(cfg, self.get("passThroughArgs")
                                   if self.is_set("passThroughArgs") else None)
         if cfg.zero_as_missing and not self.get("zeroAsMissing"):
-            x = np.where(x == 0.0, np.nan, x)
+            with measures.phase("extract"):
+                x = np.where(x == 0.0, np.nan, x)
         with measures.phase("binning"):
-            mapper = BinMapper.fit(
-                _sample_rows(x, self.get("seed"),
-                             max_sample=self.get("binSampleCount")),
-                max_bin=cfg.max_bin,
-                categorical_features=cat,
-                min_data_in_bin=cfg.min_data_in_bin,
-                max_bin_by_feature=(self.get("maxBinByFeature")
-                                    if self.is_set("maxBinByFeature")
-                                    else None))
-            binned = mapper.transform(x)
+            with span("binning.fit"):
+                mapper = BinMapper.fit(
+                    _sample_rows(x, self.get("seed"),
+                                 max_sample=self.get("binSampleCount")),
+                    max_bin=cfg.max_bin,
+                    categorical_features=cat,
+                    min_data_in_bin=cfg.min_data_in_bin,
+                    max_bin_by_feature=(self.get("maxBinByFeature")
+                                        if self.is_set("maxBinByFeature")
+                                        else None))
+            with span("binning.transform", rows=len(x)):
+                binned = mapper.transform(x)
         valid_sets = None
         if valid_df is not None and valid_df.num_rows:
             vx, vy, vw = self._extract(valid_df)
@@ -653,6 +659,22 @@ class _LightGBMBase(Estimator, _LightGBMParams):
                 mesh=self._mesh, measures=measures,
                 custom_objective=_cust(self))
         return result, mapper, measures
+
+    def _assemble_model(self, model_cls, result, mapper, measures):
+        """The fitted model object around ``result`` (span ``assembly``,
+        with the trainer's packing of the fetched trees)."""
+        with measures.phase("assembly"):
+            model = model_cls(
+                **{k: v for k, v in self._paramMap.items()
+                   if model_cls.has_param(k)})
+            model.booster = result.booster
+            model.bin_mapper = mapper
+            model._mesh = self._mesh
+            model.train_measures = measures
+            model.hist_stats = result.hist_stats
+            model.evals_result = result.evals
+            model.best_iteration = result.best_iteration
+        return model
 
     @staticmethod
     def _checkpoint_fingerprint(cfg, binned, y, w, bin_upper, init0=None,
@@ -1074,58 +1096,54 @@ class LightGBMClassifier(_LightGBMBase):
         "via passThroughArgs)", to_float, gt(0), default=1.0)
 
     def _fit(self, df: DataFrame) -> "LightGBMClassificationModel":
-        y_raw = np.asarray(df.col(self.get("labelCol")), dtype=np.float64)
-        classes = np.unique(y_raw[~np.isnan(y_raw)])
-        num_class = len(classes)
-        if num_class > self.get("maxNumClasses"):
-            raise ValueError(
-                f"{num_class} distinct labels exceeds maxNumClasses="
-                f"{self.get('maxNumClasses')} (guards runaway label "
-                "cardinality, LightGBMClassifier.scala maxNumClasses)")
-        objective = self.get("objective") or (
-            "binary" if num_class <= 2 else "multiclass")
-        if objective == "binary" and num_class > 2:
-            raise ValueError(f"binary objective with {num_class} classes")
-        # re-encode labels to 0..K-1 (objectives one-hot by index)
-        encoded = np.searchsorted(classes, y_raw).astype(np.float64)
-        df = df.with_column(self.get("labelCol"), encoded)
-        spw = self.get("scalePosWeight")
-        if ((self.get("isUnbalance") or spw != 1.0)
-                and objective == "binary"):
-            if self.get("isUnbalance") and spw != 1.0:
+        measures = InstrumentationMeasures()
+        # the label pass over every row: float64, the distinct classes
+        # (a sort), the re-encoding and the unbalance weights
+        with measures.phase("labels", rows=df.num_rows):
+            y_raw = np.asarray(df.col(self.get("labelCol")), dtype=np.float64)
+            classes = np.unique(y_raw[~np.isnan(y_raw)])
+            num_class = len(classes)
+            if num_class > self.get("maxNumClasses"):
                 raise ValueError(
-                    "isUnbalance and scalePosWeight are mutually "
-                    "exclusive (LightGBM: set only one)")
-            # scale positive-class rows by neg/pos (LightGBM
-            # is_unbalance) or by the explicit scale_pos_weight —
-            # weighting grad+hess equals row weighting
-            if self.get("isUnbalance"):
-                pos = max(float((encoded == 1).sum()), 1.0)
-                neg = float((encoded == 0).sum())
-                spw = neg / pos
-            w = np.where(encoded == 1, spw, 1.0)
-            if self.is_set("weightCol"):
-                w = w * np.asarray(df.col(self.get("weightCol")), np.float64)
-                df = df.with_column(self.get("weightCol"), w)
-            else:
-                df = df.with_column("_unbalance_weight", w)
-                self = self.copy(weightCol="_unbalance_weight")
+                    f"{num_class} distinct labels exceeds maxNumClasses="
+                    f"{self.get('maxNumClasses')} (guards runaway label "
+                    "cardinality, LightGBMClassifier.scala maxNumClasses)")
+            objective = self.get("objective") or (
+                "binary" if num_class <= 2 else "multiclass")
+            if objective == "binary" and num_class > 2:
+                raise ValueError(f"binary objective with {num_class} classes")
+            # re-encode labels to 0..K-1 (objectives one-hot by index)
+            encoded = np.searchsorted(classes, y_raw).astype(np.float64)
+            df = df.with_column(self.get("labelCol"), encoded)
+            spw = self.get("scalePosWeight")
+            if ((self.get("isUnbalance") or spw != 1.0)
+                    and objective == "binary"):
+                if self.get("isUnbalance") and spw != 1.0:
+                    raise ValueError(
+                        "isUnbalance and scalePosWeight are mutually "
+                        "exclusive (LightGBM: set only one)")
+                # scale positive-class rows by neg/pos (LightGBM
+                # is_unbalance) or by the explicit scale_pos_weight —
+                # weighting grad+hess equals row weighting
+                if self.get("isUnbalance"):
+                    pos = max(float((encoded == 1).sum()), 1.0)
+                    neg = float((encoded == 0).sum())
+                    spw = neg / pos
+                w = np.where(encoded == 1, spw, 1.0)
+                if self.is_set("weightCol"):
+                    w = w * np.asarray(df.col(self.get("weightCol")), np.float64)
+                    df = df.with_column(self.get("weightCol"), w)
+                else:
+                    df = df.with_column("_unbalance_weight", w)
+                    self = self.copy(weightCol="_unbalance_weight")
         extra: Dict[str, Any] = {}
         result, mapper, measures = self._fit_booster(
             df, objective, num_class=num_class if objective != "binary" else 1,
-            extra_cfg=extra)
-        model = LightGBMClassificationModel(
-            **{k: v for k, v in self._paramMap.items()
-               if LightGBMClassificationModel.has_param(k)})
-        model.booster = result.booster
-        model.bin_mapper = mapper
-        model._mesh = self._mesh
+            extra_cfg=extra, measures=measures)
+        model = self._assemble_model(LightGBMClassificationModel, result,
+                                     mapper, measures)
         model.num_classes = num_class
         model.classes_ = classes
-        model.train_measures = measures
-        model.hist_stats = result.hist_stats
-        model.evals_result = result.evals
-        model.best_iteration = result.best_iteration
         return model
 
 
@@ -1205,17 +1223,8 @@ class LightGBMRegressor(_LightGBMBase):
                  "tweedie_variance_power": self.get("tweedieVariancePower")}
         result, mapper, measures = self._fit_booster(df, objective,
                                                      extra_cfg=extra)
-        model = LightGBMRegressionModel(
-            **{k: v for k, v in self._paramMap.items()
-               if LightGBMRegressionModel.has_param(k)})
-        model.booster = result.booster
-        model.bin_mapper = mapper
-        model._mesh = self._mesh
-        model.train_measures = measures
-        model.hist_stats = result.hist_stats
-        model.evals_result = result.evals
-        model.best_iteration = result.best_iteration
-        return model
+        return self._assemble_model(LightGBMRegressionModel, result, mapper,
+                                    measures)
 
 
 class LightGBMRegressionModel(_LightGBMModelBase):
@@ -1261,17 +1270,8 @@ class LightGBMRanker(_LightGBMBase):
         result, mapper, measures = self._fit_booster(
             df, "lambdarank", group_col=self.get("groupCol"),
             extra_cfg=extra)
-        model = LightGBMRankerModel(
-            **{k: v for k, v in self._paramMap.items()
-               if LightGBMRankerModel.has_param(k)})
-        model.booster = result.booster
-        model.bin_mapper = mapper
-        model._mesh = self._mesh
-        model.train_measures = measures
-        model.hist_stats = result.hist_stats
-        model.evals_result = result.evals
-        model.best_iteration = result.best_iteration
-        return model
+        return self._assemble_model(LightGBMRankerModel, result, mapper,
+                                    measures)
 
 
 class LightGBMRankerModel(_LightGBMModelBase):

@@ -126,36 +126,40 @@ def _pallas_level_histogram(binned, grad, hess, live, local, *, width: int,
     # tile is zero-initialized by its first visit)
     nb = n // r + width + 1
 
-    local = local.astype(jnp.int32)
-    counts = jnp.bincount(local, length=width)                  # (width,)
-    offsets = jnp.concatenate(
-        [jnp.zeros(1, jnp.int32), jnp.cumsum(counts).astype(jnp.int32)])
-    blocks_per_node = jnp.maximum((counts + r - 1) // r, 1)
-    cum_blocks = jnp.cumsum(blocks_per_node).astype(jnp.int32)  # (width,)
-    order = jnp.argsort(local).astype(jnp.int32)
+    # device scopes (op_name metadata, no effect on the program):
+    # ``gbdt.hist.feed`` is the sort by node, the slot map and the two
+    # gathers that lay rows out for the kernel; ``gbdt.hist`` the kernel
+    with jax.named_scope("gbdt.hist.feed"):
+        local = local.astype(jnp.int32)
+        counts = jnp.bincount(local, length=width)                  # (width,)
+        offsets = jnp.concatenate(
+            [jnp.zeros(1, jnp.int32), jnp.cumsum(counts).astype(jnp.int32)])
+        blocks_per_node = jnp.maximum((counts + r - 1) // r, 1)
+        cum_blocks = jnp.cumsum(blocks_per_node).astype(jnp.int32)  # (width,)
+        order = jnp.argsort(local).astype(jnp.int32)
 
-    block_node = jnp.clip(
-        jnp.searchsorted(cum_blocks, jnp.arange(nb, dtype=jnp.int32),
-                         side="right"),
-        0, width - 1).astype(jnp.int32)
+        block_node = jnp.clip(
+            jnp.searchsorted(cum_blocks, jnp.arange(nb, dtype=jnp.int32),
+                             side="right"),
+            0, width - 1).astype(jnp.int32)
 
-    # padded slot -> source row (n = dummy zero row)
-    slot = jnp.arange(nb * r, dtype=jnp.int32)
-    blk = slot // r
-    w = block_node[blk]
-    base = jnp.where(w > 0, cum_blocks[jnp.maximum(w - 1, 0)], 0)
-    row_in_node = (blk - base) * r + (slot % r)
-    valid = (row_in_node >= 0) & (row_in_node < counts[w])
-    sorted_pos = jnp.clip(offsets[w] + row_in_node, 0, n - 1)
-    src = jnp.where(valid, order[sorted_pos], n)
+        # padded slot -> source row (n = dummy zero row)
+        slot = jnp.arange(nb * r, dtype=jnp.int32)
+        blk = slot // r
+        w = block_node[blk]
+        base = jnp.where(w > 0, cum_blocks[jnp.maximum(w - 1, 0)], 0)
+        row_in_node = (blk - base) * r + (slot % r)
+        valid = (row_in_node >= 0) & (row_in_node < counts[w])
+        sorted_pos = jnp.clip(offsets[w] + row_in_node, 0, n - 1)
+        src = jnp.where(valid, order[sorted_pos], n)
 
-    bins_pad = jnp.concatenate(
-        [binned, jnp.zeros((1, f), binned.dtype)])[src]          # (nb*r, f)
-    stats = jnp.zeros((_SPAD, n + 1), jnp.float32)
-    stats = stats.at[0, :n].set((grad * live).astype(jnp.float32))
-    stats = stats.at[1, :n].set((hess * live).astype(jnp.float32))
-    stats = stats.at[2, :n].set(live.astype(jnp.float32))
-    data = stats[:, src]                                         # (SPAD, nb*r)
+        bins_pad = jnp.concatenate(
+            [binned, jnp.zeros((1, f), binned.dtype)])[src]          # (nb*r, f)
+        stats = jnp.zeros((_SPAD, n + 1), jnp.float32)
+        stats = stats.at[0, :n].set((grad * live).astype(jnp.float32))
+        stats = stats.at[1, :n].set((hess * live).astype(jnp.float32))
+        stats = stats.at[2, :n].set(live.astype(jnp.float32))
+        data = stats[:, src]                                         # (SPAD, nb*r)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -181,15 +185,17 @@ def _pallas_level_histogram(binned, grad, hess, live, local, *, width: int,
     vma = operand_vma(binned, grad, hess, live, local)
     kernel = functools.partial(_hist_kernel, num_features=f,
                                bin_pad=_BIN_PAD)
-    out = pl.pallas_call(
-        kernel,
-        out_shape=shape_dtype_struct((width, f, _SPAD, _BIN_PAD),
-                                     jnp.float32, vma=vma),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(block_node, bins_pad, data)
-    # (width, f, SPAD, BIN_PAD) -> (width, f, b, 3)
-    return jnp.transpose(out[:, :, :3, :b], (0, 1, 3, 2))
+    with jax.named_scope("gbdt.hist"):
+        out = pl.pallas_call(
+            kernel,
+            out_shape=shape_dtype_struct((width, f, _SPAD, _BIN_PAD),
+                                         jnp.float32, vma=vma),
+            grid_spec=grid_spec,
+            interpret=interpret,
+            name="gbdt_level_hist",
+        )(block_node, bins_pad, data)
+        # (width, f, SPAD, BIN_PAD) -> (width, f, b, 3)
+        return jnp.transpose(out[:, :, :3, :b], (0, 1, 3, 2))
 
 
 _JIT_CACHE = {}

@@ -642,10 +642,11 @@ def train_ooc(spill: SpillReader, labels, cfg: TrainConfig, *,
         if own_work:
             shutil.rmtree(work_dir, ignore_errors=True)
 
-    booster = trainer_mod._assemble_booster(
-        (trees_sf, trees_tb, trees_nv, trees_cnt, [], []),
-        [1.0] * len(trees_sf), cfg, k, f, b, depth, num_slots,
-        bin_upper, base_score, -1, init_model)
+    with measures.phase("assembly"):
+        booster = trainer_mod._assemble_booster(
+            (trees_sf, trees_tb, trees_nv, trees_cnt, [], []),
+            [1.0] * len(trees_sf), cfg, k, f, b, depth, num_slots,
+            bin_upper, base_score, -1, init_model)
     stores = (carry_st, gq_st, hq_st, node_st)
     hist_stats: Dict[str, object] = {
         "grow_policy": "depthwise", "hist_quant": quant,

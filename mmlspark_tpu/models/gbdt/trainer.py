@@ -1328,16 +1328,29 @@ def make_build_tree(num_features: int, total_bins: int, cfg: TrainConfig,
         # exact integer accumulation — so a chunk-merged out-of-core
         # histogram reproduces the root bitwise too
         if hist_quant == "off":
-            root_g, root_h, root_c = (jnp.sum(grad * valid),
-                                      jnp.sum(hess * valid),
-                                      jnp.sum(valid))
-            rv, _ = leaf_objective(root_g, root_h)
-            if cfg.max_delta_step > 0:
-                rv = jnp.clip(rv, -cfg.max_delta_step, cfg.max_delta_step)
-            node_value = node_value.at[0].set(rv)
-            node_count = node_count.at[0].set(root_c)
+            with jax.named_scope("gbdt.leaf"):
+                root_g, root_h, root_c = (jnp.sum(grad * valid),
+                                          jnp.sum(hess * valid),
+                                          jnp.sum(valid))
+                rv, _ = leaf_objective(root_g, root_h)
+                if cfg.max_delta_step > 0:
+                    rv = jnp.clip(rv, -cfg.max_delta_step, cfg.max_delta_step)
+                node_value = node_value.at[0].set(rv)
+                node_count = node_count.at[0].set(root_c)
 
         remaining = remaining_leaves - 1  # root is one leaf
+
+        def route(node, done, local, do_split, best_feat, left_mask):
+            """Send each live row to its child, or settle it in a leaf."""
+            with jax.named_scope("gbdt.route"):
+                nfeat = best_feat[local]
+                nbin = jnp.take_along_axis(binned, nfeat[:, None], 1)[:, 0]
+                nsplit = do_split[local]
+                go_left = left_mask[local, nbin]
+                child = jnp.where(go_left, 2 * node + 1, 2 * node + 2)
+                newly_done = ~nsplit & ~done
+                node = jnp.where(done | ~nsplit, node, child)
+                return node, done | newly_done
 
         for d in range(depth):
             level_start = 2 ** d - 1
@@ -1346,76 +1359,282 @@ def make_build_tree(num_features: int, total_bins: int, cfg: TrainConfig,
             live = (~done).astype(grad.dtype) * valid
 
             # --- histogram --------------------------------------------
-            if subtract and d > 0:
-                # smaller child only; sibling by subtraction.
-                # INVARIANT (ADVICE r4): ``live`` must stay BINARY.
-                # prev_ss picks the smaller child by the cover stat
-                # (left_stats[:,2] = sum of live), which bounds its ROW
-                # count by n//2+1 only because every live row weighs
-                # exactly 1 (GOSS folds amplification into grad/hess,
-                # bagging masks are 0/1). A fractional row mask would
-                # let the weighted-smaller side hold more than n_half
-                # rows and the sized nonzero below would silently drop
-                # rows, corrupting histograms.
-                par_row = local // 2
-                side = (local % 2).astype(jnp.int32)
-                sel = (live > 0) & (side == prev_ss[par_row])
-                if masked_subtract:
-                    # native kernel: masked rows are skipped before
-                    # their bin row is read, so zeroing ``live`` on the
-                    # larger sibling IS the compaction — no gather
-                    hist_small = _hist(
-                        hist_mat, grad_h, hess_h,
-                        live * sel.astype(live.dtype), local, width)
+            with jax.named_scope("gbdt.hist"):
+                if subtract and d > 0:
+                    # smaller child only; sibling by subtraction.
+                    # INVARIANT (ADVICE r4): ``live`` must stay BINARY.
+                    # prev_ss picks the smaller child by the cover stat
+                    # (left_stats[:,2] = sum of live), which bounds its ROW
+                    # count by n//2+1 only because every live row weighs
+                    # exactly 1 (GOSS folds amplification into grad/hess,
+                    # bagging masks are 0/1). A fractional row mask would
+                    # let the weighted-smaller side hold more than n_half
+                    # rows and the sized nonzero below would silently drop
+                    # rows, corrupting histograms.
+                    par_row = local // 2
+                    side = (local % 2).astype(jnp.int32)
+                    sel = (live > 0) & (side == prev_ss[par_row])
+                    if masked_subtract:
+                        # native kernel: masked rows are skipped before
+                        # their bin row is read, so zeroing ``live`` on the
+                        # larger sibling IS the compaction — no gather
+                        hist_small = _hist(
+                            hist_mat, grad_h, hess_h,
+                            live * sel.astype(live.dtype), local, width)
+                    else:
+                        idx = jnp.nonzero(sel, size=n_half, fill_value=n)[0]
+                        live_pad = jnp.concatenate(
+                            [live, jnp.zeros(1, live.dtype)])
+                        local_pad = jnp.concatenate(
+                            [local, jnp.zeros(1, local.dtype)])
+                        hist_small = _hist(
+                            binned_pad[idx], grad_pad[idx], hess_pad[idx],
+                            live_pad[idx], local_pad[idx], width)
+                    hist = _derive_sibling_hist(hist_small, prev_hist,
+                                                prev_split, prev_ss)
                 else:
-                    idx = jnp.nonzero(sel, size=n_half, fill_value=n)[0]
-                    live_pad = jnp.concatenate(
-                        [live, jnp.zeros(1, live.dtype)])
-                    local_pad = jnp.concatenate(
-                        [local, jnp.zeros(1, local.dtype)])
-                    hist_small = _hist(
-                        binned_pad[idx], grad_pad[idx], hess_pad[idx],
-                        live_pad[idx], local_pad[idx], width)
-                hist = _derive_sibling_hist(hist_small, prev_hist,
-                                            prev_split, prev_ss)
-            else:
-                hist = _hist(hist_mat, grad_h, hess_h, live, local,
-                             width)
-            if subtract:
-                prev_hist = hist
+                    hist = _hist(hist_mat, grad_h, hess_h, live, local,
+                                 width)
+                if subtract:
+                    prev_hist = hist
             if hist_quant != "off" and d == 0:
-                # quantized-plane root stats from the level-0 histogram
-                # (any one feature's bins partition the live rows);
-                # recorded before split finding so path smoothing sees
-                # the root value at this level
-                tot0 = jnp.sum(hist[0, 0], axis=0)
-                rv0, _ = leaf_objective(tot0[0], tot0[1])
-                if cfg.max_delta_step > 0:
-                    rv0 = jnp.clip(rv0, -cfg.max_delta_step,
-                                   cfg.max_delta_step)
-                node_value = node_value.at[0].set(rv0)
-                node_count = node_count.at[0].set(tot0[2])
+                with jax.named_scope("gbdt.leaf"):
+                    # quantized-plane root stats from the level-0 histogram
+                    # (any one feature's bins partition the live rows);
+                    # recorded before split finding so path smoothing sees
+                    # the root value at this level
+                    tot0 = jnp.sum(hist[0, 0], axis=0)
+                    rv0, _ = leaf_objective(tot0[0], tot0[1])
+                    if cfg.max_delta_step > 0:
+                        rv0 = jnp.clip(rv0, -cfg.max_delta_step,
+                                       cfg.max_delta_step)
+                    node_value = node_value.at[0].set(rv0)
+                    node_count = node_count.at[0].set(tot0[2])
 
             slots = level_start + jnp.arange(width, dtype=jnp.int32)
             if simple_numeric:
-                (do_split, best_feat, best_bin, left_mask, lval, rval,
-                 left_stats, right_stats, remaining, small_side) = \
-                    _find_numeric_splits(
-                        hist, feat_mask, remaining, node_value[slots],
-                        b=b, lam1=lam1, lam2=lam2, min_child=min_child,
-                        min_hess=min_hess, min_gain=min_gain,
-                        path_smooth=cfg.path_smooth,
-                        max_delta_step=cfg.max_delta_step)
+                with jax.named_scope("gbdt.split"):
+                    (do_split, best_feat, best_bin, left_mask, lval, rval,
+                     left_stats, right_stats, remaining, small_side) = \
+                        _find_numeric_splits(
+                            hist, feat_mask, remaining, node_value[slots],
+                            b=b, lam1=lam1, lam2=lam2, min_child=min_child,
+                            min_hess=min_hess, min_gain=min_gain,
+                            path_smooth=cfg.path_smooth,
+                            max_delta_step=cfg.max_delta_step)
+                with jax.named_scope("gbdt.leaf"):
+                    split_feature = split_feature.at[slots].set(
+                        jnp.where(do_split, best_feat, -1))
+                    threshold_bin = threshold_bin.at[slots].set(
+                        jnp.where(do_split, best_bin, 0))
+                    num_bits = 6 if cfg.zero_as_missing else 10
+                    decision_type = decision_type.at[slots].set(
+                        jnp.where(do_split, num_bits, 0).astype(jnp.int8))
+                    bin_go_left = bin_go_left.at[slots].set(
+                        left_mask & do_split[:, None])
+                    lslots, rslots = 2 * slots + 1, 2 * slots + 2
+                    node_value = node_value.at[lslots].set(
+                        jnp.where(do_split, lval, 0.0))
+                    node_value = node_value.at[rslots].set(
+                        jnp.where(do_split, rval, 0.0))
+                    node_count = node_count.at[lslots].set(
+                        jnp.where(do_split, left_stats[:, 2], 0.0))
+                    node_count = node_count.at[rslots].set(
+                        jnp.where(do_split, right_stats[:, 2], 0.0))
+                if subtract:
+                    prev_split = do_split
+                    prev_ss = small_side
+                node, done = route(node, done, local, do_split,
+                                   best_feat, left_mask)
+                continue
+
+            # --- numerical split finding: ordered cumulative scan -------
+            with jax.named_scope("gbdt.split"):
+                cum = jnp.cumsum(hist, axis=2)              # left stats per bin
+                tot = cum[:, :, -1:, :]
+                gl, hl, cl = cum[..., 0], cum[..., 1], cum[..., 2]
+                gt, ht, ct = tot[..., 0], tot[..., 1], tot[..., 2]
+                gr, hr, cr = gt - gl, ht - hl, ct - cl
+                val_l, score_l = leaf_objective(gl, hl)
+                val_r, score_r = leaf_objective(gr, hr)
+                _, score_p = leaf_objective(gt, ht)
+                gain = 0.5 * (score_l + score_r - score_p)
+                ok = ((cl >= min_child) & (cr >= min_child)
+                      & (hl >= min_hess) & (hr >= min_hess)
+                      & (gain > min_gain))
+                # per-tree feature mask, optionally re-sampled per node
+                # (LightGBM feature_fraction_bynode)
+                node_fmask = feat_mask[None, :] > 0         # (1|width, F)
+                if cfg.feature_fraction_by_node < 1.0:
+                    # sample per node from the TREE's feature subset (as
+                    # LightGBM feature_fraction_bynode composes with
+                    # feature_fraction), never leaving a node featureless
+                    avail = jnp.sum(feat_mask > 0)
+                    keep_n = jnp.maximum(1, jnp.round(
+                        avail * cfg.feature_fraction_by_node)).astype(jnp.int32)
+                    kn = jax.random.fold_in(jax.random.fold_in(key, 101), d)
+                    draw = jax.random.uniform(kn, (width, num_features))
+                    draw = jnp.where(feat_mask[None, :] > 0, draw, -1.0)
+                    sortd = jnp.sort(draw, axis=1)[:, ::-1]  # descending
+                    kth = jnp.take_along_axis(
+                        sortd, jnp.broadcast_to(keep_n - 1, (width,))[:, None],
+                        axis=1)
+                    node_fmask = node_fmask & (draw >= kth)
+                ok &= node_fmask[:, :, None]
+                # last bin can't split (right side empty by construction)
+                ok &= jnp.arange(b, dtype=jnp.int32)[None, None, :] < b - 1
+                if has_mono:
+                    # reject splits whose child values violate the feature's
+                    # monotone direction (LightGBM "basic" rejection)
+                    ok &= mono_f[None, :, None] * (val_r - val_l) >= 0
+                if cfg.extra_trees:
+                    # one random candidate threshold per (node, feature)
+                    kd = jax.random.fold_in(key, d)
+                    rand_bin = jax.random.randint(kd, (width, f), 0, b - 1)
+                    ok &= jnp.arange(b, dtype=jnp.int32)[None, None, :] == rand_bin[..., None]
+                gain = jnp.where(ok, gain, -jnp.inf)
+
+                if has_cat:
+                    # --- categorical split finding ----------------------
+                    g_b, h_b, c_b = hist[..., 0], hist[..., 1], hist[..., 2]
+                    not_missing = jnp.arange(b, dtype=jnp.int32)[None, None, :] > 0
+                    used = (c_b > 0) & not_missing
+                    # LightGBM min_data_per_group: the sorted scan only
+                    # considers categories with enough rows (filtered ones
+                    # route right); one-hot mode keeps the plain used set
+                    used_sorted = used & (
+                        c_b >= float(max(cfg.min_data_per_group, 1)))
+                    ratio = jnp.where(used_sorted,
+                                      g_b / (h_b + cfg.cat_smooth), jnp.inf)
+                    sort_idx = jnp.argsort(ratio, axis=2)   # unused sort last
+                    shist = jnp.take_along_axis(
+                        hist, sort_idx[..., None], axis=2)
+                    scum = jnp.cumsum(shist, axis=2)
+                    num_used = jnp.sum(used, axis=2)        # (width, F)
+                    num_sorted = jnp.sum(used_sorted, axis=2)
+                    gl_c, hl_c, cl_c = scum[..., 0], scum[..., 1], scum[..., 2]
+                    gr_c, hr_c = gt - gl_c, ht - hl_c
+                    cr_c = ct - cl_c
+                    _, cscore_l = leaf_objective(gl_c, hl_c, cfg.cat_l2)
+                    _, cscore_r = leaf_objective(gr_c, hr_c, cfg.cat_l2)
+                    _, cscore_p = leaf_objective(gt, ht, cfg.cat_l2)
+                    cgain = 0.5 * (cscore_l + cscore_r - cscore_p)
+                    pos1 = jnp.arange(1, b + 1, dtype=jnp.int32)[None, None, :]  # left-set size
+                    side = jnp.minimum(pos1, num_sorted[..., None] - pos1)
+                    cok = ((pos1 < num_sorted[..., None])
+                           & (side <= cfg.max_cat_threshold)
+                           & (cl_c >= min_child) & (cr_c >= min_child)
+                           & (hl_c >= min_hess) & (hr_c >= min_hess)
+                           & (cgain > min_gain))
+                    cgain = jnp.where(cok, cgain, -jnp.inf)
+                    # one-vs-rest for low-cardinality nodes (indexed by the
+                    # actual bin id, not a sort position)
+                    gr_o, hr_o, cr_o = gt - g_b, ht - h_b, ct - c_b
+                    _, oscore_l = leaf_objective(g_b, h_b, cfg.cat_l2)
+                    _, oscore_r = leaf_objective(gr_o, hr_o, cfg.cat_l2)
+                    ogain = 0.5 * (oscore_l + oscore_r - cscore_p)
+                    ook = (used & (c_b >= min_child) & (cr_o >= min_child)
+                           & (h_b >= min_hess) & (hr_o >= min_hess)
+                           & (ogain > min_gain) & (num_used[..., None] > 1))
+                    ogain = jnp.where(ook, ogain, -jnp.inf)
+                    onehot = (num_used <= cfg.max_cat_to_onehot)[..., None]
+                    cat_gain = jnp.where(onehot, ogain, cgain)
+                    cat_gain = jnp.where(node_fmask[:, :, None],
+                                         cat_gain, -jnp.inf)
+                    gain = jnp.where(is_cat_f[None, :, None], cat_gain, gain)
+
+                flat_gain = gain.reshape(width, f * b)
+                best_fb = jnp.argmax(flat_gain, axis=1)
+                best_gain = jnp.take_along_axis(flat_gain, best_fb[:, None], 1)[:, 0]
+                best_feat = (best_fb // b).astype(jnp.int32)
+                best_bin = (best_fb % b).astype(jnp.int32)
+
+                # --- leaf budget: within-level gain ranking ------------------
+                can_split = jnp.isfinite(best_gain)
+                order = jnp.argsort(-jnp.where(can_split, best_gain, -jnp.inf))
+                rank = jnp.zeros(width, dtype=jnp.int32).at[order].set(
+                    jnp.arange(width, dtype=jnp.int32))
+                do_split = can_split & (rank < remaining)
+                remaining = remaining + 0 if width == 0 else (
+                    remaining - jnp.sum(do_split.astype(jnp.int32)))
+
+                # --- per-node left-bin mask for the chosen split -------------
+                sel = jnp.arange(width, dtype=jnp.int32)
+                mask_num = jnp.arange(b, dtype=jnp.int32)[None, :] <= best_bin[:, None]
+                if has_cat:
+                    chosen_cat = is_cat_f[best_feat] & do_split
+                    s_idx = sort_idx[sel, best_feat]        # (width, B)
+                    # rank of bin id in sorted order = inverse permutation
+                    bin_rank = jnp.argsort(s_idx, axis=1)
+                    used_sel = used_sorted[sel, best_feat]
+                    onehot_sel = num_used[sel, best_feat] <= cfg.max_cat_to_onehot
+                    mask_prefix = (bin_rank <= best_bin[:, None]) & used_sel
+                    mask_onehot = jnp.arange(b, dtype=jnp.int32)[None, :] == best_bin[:, None]
+                    mask_cat = jnp.where(onehot_sel[:, None], mask_onehot,
+                                         mask_prefix)
+                    left_mask = jnp.where(chosen_cat[:, None], mask_cat, mask_num)
+                else:
+                    chosen_cat = jnp.zeros(width, dtype=jnp.bool_)
+                    left_mask = mask_num
+
+            # --- record splits & child stats -----------------------------
+            with jax.named_scope("gbdt.leaf"):
                 split_feature = split_feature.at[slots].set(
                     jnp.where(do_split, best_feat, -1))
                 threshold_bin = threshold_bin.at[slots].set(
                     jnp.where(do_split, best_bin, 0))
+                # numerical splits carry default-left + NaN-missing bits
+                # (2 | 8 = 10): training routes the missing bin left, and
+                # loaded models reproduce that routing from the bits
                 num_bits = 6 if cfg.zero_as_missing else 10
                 decision_type = decision_type.at[slots].set(
-                    jnp.where(do_split, num_bits, 0).astype(jnp.int8))
+                    jnp.where(do_split,
+                              jnp.where(chosen_cat, 1, num_bits),
+                              0).astype(jnp.int8))
                 bin_go_left = bin_go_left.at[slots].set(
                     left_mask & do_split[:, None])
+
+                hist_best = hist[sel, best_feat]            # (width, B, 3)
+                left_stats = jnp.sum(hist_best * left_mask[..., None], axis=1)
+                tot_best = jnp.sum(hist_best, axis=1)
+                right_stats = tot_best - left_stats
+                lx2 = jnp.where(chosen_cat, cfg.cat_l2, 0.0)
+                lval, _ = leaf_objective(left_stats[:, 0], left_stats[:, 1], lx2)
+                rval, _ = leaf_objective(right_stats[:, 0], right_stats[:, 1], lx2)
                 lslots, rslots = 2 * slots + 1, 2 * slots + 2
+                if cfg.path_smooth > 0:
+                    # shrink child outputs toward the parent's by n/(n+ps)
+                    pv = node_value[slots]
+                    wl = left_stats[:, 2] / (left_stats[:, 2] + cfg.path_smooth)
+                    wr = right_stats[:, 2] / (right_stats[:, 2] + cfg.path_smooth)
+                    lval = lval * wl + pv * (1.0 - wl)
+                    rval = rval * wr + pv * (1.0 - wr)
+                if cfg.max_delta_step > 0:
+                    lval = jnp.clip(lval, -cfg.max_delta_step,
+                                    cfg.max_delta_step)
+                    rval = jnp.clip(rval, -cfg.max_delta_step,
+                                    cfg.max_delta_step)
+                if has_mono:
+                    # clamp child outputs into the parent's bounds, then
+                    # tighten the children's bounds at the split midpoint
+                    # when this split's feature is constrained
+                    p_lo, p_hi = node_lower[slots], node_upper[slots]
+                    lval = jnp.clip(lval, p_lo, p_hi)
+                    rval = jnp.clip(rval, p_lo, p_hi)
+                    c_mono = mono_f[best_feat] * (~chosen_cat)
+                    mid = (lval + rval) / 2.0
+                    l_hi = jnp.where(c_mono > 0, jnp.minimum(p_hi, mid), p_hi)
+                    r_lo = jnp.where(c_mono > 0, jnp.maximum(p_lo, mid), p_lo)
+                    l_lo = jnp.where(c_mono < 0, jnp.maximum(p_lo, mid), p_lo)
+                    r_hi = jnp.where(c_mono < 0, jnp.minimum(p_hi, mid), p_hi)
+                    node_lower = node_lower.at[lslots].set(
+                        jnp.where(do_split, l_lo, p_lo))
+                    node_upper = node_upper.at[lslots].set(
+                        jnp.where(do_split, l_hi, p_hi))
+                    node_lower = node_lower.at[rslots].set(
+                        jnp.where(do_split, r_lo, p_lo))
+                    node_upper = node_upper.at[rslots].set(
+                        jnp.where(do_split, r_hi, p_hi))
                 node_value = node_value.at[lslots].set(
                     jnp.where(do_split, lval, 0.0))
                 node_value = node_value.at[rslots].set(
@@ -1424,213 +1643,6 @@ def make_build_tree(num_features: int, total_bins: int, cfg: TrainConfig,
                     jnp.where(do_split, left_stats[:, 2], 0.0))
                 node_count = node_count.at[rslots].set(
                     jnp.where(do_split, right_stats[:, 2], 0.0))
-                if subtract:
-                    prev_split = do_split
-                    prev_ss = small_side
-                # --- route rows (shared with the general path below) --
-                nfeat = best_feat[local]
-                nbin = jnp.take_along_axis(binned, nfeat[:, None], 1)[:, 0]
-                nsplit = do_split[local]
-                go_left = left_mask[local, nbin]
-                child = jnp.where(go_left, 2 * node + 1, 2 * node + 2)
-                newly_done = ~nsplit & ~done
-                node = jnp.where(done | ~nsplit, node, child)
-                done = done | newly_done
-                continue
-
-            # --- numerical split finding: ordered cumulative scan -------
-            cum = jnp.cumsum(hist, axis=2)              # left stats per bin
-            tot = cum[:, :, -1:, :]
-            gl, hl, cl = cum[..., 0], cum[..., 1], cum[..., 2]
-            gt, ht, ct = tot[..., 0], tot[..., 1], tot[..., 2]
-            gr, hr, cr = gt - gl, ht - hl, ct - cl
-            val_l, score_l = leaf_objective(gl, hl)
-            val_r, score_r = leaf_objective(gr, hr)
-            _, score_p = leaf_objective(gt, ht)
-            gain = 0.5 * (score_l + score_r - score_p)
-            ok = ((cl >= min_child) & (cr >= min_child)
-                  & (hl >= min_hess) & (hr >= min_hess)
-                  & (gain > min_gain))
-            # per-tree feature mask, optionally re-sampled per node
-            # (LightGBM feature_fraction_bynode)
-            node_fmask = feat_mask[None, :] > 0         # (1|width, F)
-            if cfg.feature_fraction_by_node < 1.0:
-                # sample per node from the TREE's feature subset (as
-                # LightGBM feature_fraction_bynode composes with
-                # feature_fraction), never leaving a node featureless
-                avail = jnp.sum(feat_mask > 0)
-                keep_n = jnp.maximum(1, jnp.round(
-                    avail * cfg.feature_fraction_by_node)).astype(jnp.int32)
-                kn = jax.random.fold_in(jax.random.fold_in(key, 101), d)
-                draw = jax.random.uniform(kn, (width, num_features))
-                draw = jnp.where(feat_mask[None, :] > 0, draw, -1.0)
-                sortd = jnp.sort(draw, axis=1)[:, ::-1]  # descending
-                kth = jnp.take_along_axis(
-                    sortd, jnp.broadcast_to(keep_n - 1, (width,))[:, None],
-                    axis=1)
-                node_fmask = node_fmask & (draw >= kth)
-            ok &= node_fmask[:, :, None]
-            # last bin can't split (right side empty by construction)
-            ok &= jnp.arange(b, dtype=jnp.int32)[None, None, :] < b - 1
-            if has_mono:
-                # reject splits whose child values violate the feature's
-                # monotone direction (LightGBM "basic" rejection)
-                ok &= mono_f[None, :, None] * (val_r - val_l) >= 0
-            if cfg.extra_trees:
-                # one random candidate threshold per (node, feature)
-                kd = jax.random.fold_in(key, d)
-                rand_bin = jax.random.randint(kd, (width, f), 0, b - 1)
-                ok &= jnp.arange(b, dtype=jnp.int32)[None, None, :] == rand_bin[..., None]
-            gain = jnp.where(ok, gain, -jnp.inf)
-
-            if has_cat:
-                # --- categorical split finding ----------------------
-                g_b, h_b, c_b = hist[..., 0], hist[..., 1], hist[..., 2]
-                not_missing = jnp.arange(b, dtype=jnp.int32)[None, None, :] > 0
-                used = (c_b > 0) & not_missing
-                # LightGBM min_data_per_group: the sorted scan only
-                # considers categories with enough rows (filtered ones
-                # route right); one-hot mode keeps the plain used set
-                used_sorted = used & (
-                    c_b >= float(max(cfg.min_data_per_group, 1)))
-                ratio = jnp.where(used_sorted,
-                                  g_b / (h_b + cfg.cat_smooth), jnp.inf)
-                sort_idx = jnp.argsort(ratio, axis=2)   # unused sort last
-                shist = jnp.take_along_axis(
-                    hist, sort_idx[..., None], axis=2)
-                scum = jnp.cumsum(shist, axis=2)
-                num_used = jnp.sum(used, axis=2)        # (width, F)
-                num_sorted = jnp.sum(used_sorted, axis=2)
-                gl_c, hl_c, cl_c = scum[..., 0], scum[..., 1], scum[..., 2]
-                gr_c, hr_c = gt - gl_c, ht - hl_c
-                cr_c = ct - cl_c
-                _, cscore_l = leaf_objective(gl_c, hl_c, cfg.cat_l2)
-                _, cscore_r = leaf_objective(gr_c, hr_c, cfg.cat_l2)
-                _, cscore_p = leaf_objective(gt, ht, cfg.cat_l2)
-                cgain = 0.5 * (cscore_l + cscore_r - cscore_p)
-                pos1 = jnp.arange(1, b + 1, dtype=jnp.int32)[None, None, :]  # left-set size
-                side = jnp.minimum(pos1, num_sorted[..., None] - pos1)
-                cok = ((pos1 < num_sorted[..., None])
-                       & (side <= cfg.max_cat_threshold)
-                       & (cl_c >= min_child) & (cr_c >= min_child)
-                       & (hl_c >= min_hess) & (hr_c >= min_hess)
-                       & (cgain > min_gain))
-                cgain = jnp.where(cok, cgain, -jnp.inf)
-                # one-vs-rest for low-cardinality nodes (indexed by the
-                # actual bin id, not a sort position)
-                gr_o, hr_o, cr_o = gt - g_b, ht - h_b, ct - c_b
-                _, oscore_l = leaf_objective(g_b, h_b, cfg.cat_l2)
-                _, oscore_r = leaf_objective(gr_o, hr_o, cfg.cat_l2)
-                ogain = 0.5 * (oscore_l + oscore_r - cscore_p)
-                ook = (used & (c_b >= min_child) & (cr_o >= min_child)
-                       & (h_b >= min_hess) & (hr_o >= min_hess)
-                       & (ogain > min_gain) & (num_used[..., None] > 1))
-                ogain = jnp.where(ook, ogain, -jnp.inf)
-                onehot = (num_used <= cfg.max_cat_to_onehot)[..., None]
-                cat_gain = jnp.where(onehot, ogain, cgain)
-                cat_gain = jnp.where(node_fmask[:, :, None],
-                                     cat_gain, -jnp.inf)
-                gain = jnp.where(is_cat_f[None, :, None], cat_gain, gain)
-
-            flat_gain = gain.reshape(width, f * b)
-            best_fb = jnp.argmax(flat_gain, axis=1)
-            best_gain = jnp.take_along_axis(flat_gain, best_fb[:, None], 1)[:, 0]
-            best_feat = (best_fb // b).astype(jnp.int32)
-            best_bin = (best_fb % b).astype(jnp.int32)
-
-            # --- leaf budget: within-level gain ranking ------------------
-            can_split = jnp.isfinite(best_gain)
-            order = jnp.argsort(-jnp.where(can_split, best_gain, -jnp.inf))
-            rank = jnp.zeros(width, dtype=jnp.int32).at[order].set(
-                jnp.arange(width, dtype=jnp.int32))
-            do_split = can_split & (rank < remaining)
-            remaining = remaining + 0 if width == 0 else (
-                remaining - jnp.sum(do_split.astype(jnp.int32)))
-
-            # --- per-node left-bin mask for the chosen split -------------
-            sel = jnp.arange(width, dtype=jnp.int32)
-            mask_num = jnp.arange(b, dtype=jnp.int32)[None, :] <= best_bin[:, None]
-            if has_cat:
-                chosen_cat = is_cat_f[best_feat] & do_split
-                s_idx = sort_idx[sel, best_feat]        # (width, B)
-                # rank of bin id in sorted order = inverse permutation
-                bin_rank = jnp.argsort(s_idx, axis=1)
-                used_sel = used_sorted[sel, best_feat]
-                onehot_sel = num_used[sel, best_feat] <= cfg.max_cat_to_onehot
-                mask_prefix = (bin_rank <= best_bin[:, None]) & used_sel
-                mask_onehot = jnp.arange(b, dtype=jnp.int32)[None, :] == best_bin[:, None]
-                mask_cat = jnp.where(onehot_sel[:, None], mask_onehot,
-                                     mask_prefix)
-                left_mask = jnp.where(chosen_cat[:, None], mask_cat, mask_num)
-            else:
-                chosen_cat = jnp.zeros(width, dtype=jnp.bool_)
-                left_mask = mask_num
-
-            # --- record splits & child stats -----------------------------
-            split_feature = split_feature.at[slots].set(
-                jnp.where(do_split, best_feat, -1))
-            threshold_bin = threshold_bin.at[slots].set(
-                jnp.where(do_split, best_bin, 0))
-            # numerical splits carry default-left + NaN-missing bits
-            # (2 | 8 = 10): training routes the missing bin left, and
-            # loaded models reproduce that routing from the bits
-            num_bits = 6 if cfg.zero_as_missing else 10
-            decision_type = decision_type.at[slots].set(
-                jnp.where(do_split,
-                          jnp.where(chosen_cat, 1, num_bits),
-                          0).astype(jnp.int8))
-            bin_go_left = bin_go_left.at[slots].set(
-                left_mask & do_split[:, None])
-
-            hist_best = hist[sel, best_feat]            # (width, B, 3)
-            left_stats = jnp.sum(hist_best * left_mask[..., None], axis=1)
-            tot_best = jnp.sum(hist_best, axis=1)
-            right_stats = tot_best - left_stats
-            lx2 = jnp.where(chosen_cat, cfg.cat_l2, 0.0)
-            lval, _ = leaf_objective(left_stats[:, 0], left_stats[:, 1], lx2)
-            rval, _ = leaf_objective(right_stats[:, 0], right_stats[:, 1], lx2)
-            lslots, rslots = 2 * slots + 1, 2 * slots + 2
-            if cfg.path_smooth > 0:
-                # shrink child outputs toward the parent's by n/(n+ps)
-                pv = node_value[slots]
-                wl = left_stats[:, 2] / (left_stats[:, 2] + cfg.path_smooth)
-                wr = right_stats[:, 2] / (right_stats[:, 2] + cfg.path_smooth)
-                lval = lval * wl + pv * (1.0 - wl)
-                rval = rval * wr + pv * (1.0 - wr)
-            if cfg.max_delta_step > 0:
-                lval = jnp.clip(lval, -cfg.max_delta_step,
-                                cfg.max_delta_step)
-                rval = jnp.clip(rval, -cfg.max_delta_step,
-                                cfg.max_delta_step)
-            if has_mono:
-                # clamp child outputs into the parent's bounds, then
-                # tighten the children's bounds at the split midpoint
-                # when this split's feature is constrained
-                p_lo, p_hi = node_lower[slots], node_upper[slots]
-                lval = jnp.clip(lval, p_lo, p_hi)
-                rval = jnp.clip(rval, p_lo, p_hi)
-                c_mono = mono_f[best_feat] * (~chosen_cat)
-                mid = (lval + rval) / 2.0
-                l_hi = jnp.where(c_mono > 0, jnp.minimum(p_hi, mid), p_hi)
-                r_lo = jnp.where(c_mono > 0, jnp.maximum(p_lo, mid), p_lo)
-                l_lo = jnp.where(c_mono < 0, jnp.maximum(p_lo, mid), p_lo)
-                r_hi = jnp.where(c_mono < 0, jnp.minimum(p_hi, mid), p_hi)
-                node_lower = node_lower.at[lslots].set(
-                    jnp.where(do_split, l_lo, p_lo))
-                node_upper = node_upper.at[lslots].set(
-                    jnp.where(do_split, l_hi, p_hi))
-                node_lower = node_lower.at[rslots].set(
-                    jnp.where(do_split, r_lo, p_lo))
-                node_upper = node_upper.at[rslots].set(
-                    jnp.where(do_split, r_hi, p_hi))
-            node_value = node_value.at[lslots].set(
-                jnp.where(do_split, lval, 0.0))
-            node_value = node_value.at[rslots].set(
-                jnp.where(do_split, rval, 0.0))
-            node_count = node_count.at[lslots].set(
-                jnp.where(do_split, left_stats[:, 2], 0.0))
-            node_count = node_count.at[rslots].set(
-                jnp.where(do_split, right_stats[:, 2], 0.0))
 
             if subtract:
                 prev_split = do_split
@@ -1638,15 +1650,8 @@ def make_build_tree(num_features: int, total_bins: int, cfg: TrainConfig,
                     left_stats[:, 2] <= right_stats[:, 2], 0, 1
                 ).astype(jnp.int32)
 
-            # --- route rows ---------------------------------------------
-            nfeat = best_feat[local]
-            nbin = jnp.take_along_axis(binned, nfeat[:, None], 1)[:, 0]
-            nsplit = do_split[local]
-            go_left = left_mask[local, nbin]
-            child = jnp.where(go_left, 2 * node + 1, 2 * node + 2)
-            newly_done = ~nsplit & ~done
-            node = jnp.where(done | ~nsplit, node, child)
-            done = done | newly_done
+            node, done = route(node, done, local, do_split, best_feat,
+                               left_mask)
 
         return (split_feature, threshold_bin, node_value, node_count,
                 decision_type, bin_go_left)
@@ -1698,14 +1703,17 @@ def _make_predict_tree(depth: int) -> Callable:
     import jax.numpy as jnp
 
     def predict_tree_binned(sf, bgl, nv, bd):
-        nodev = jnp.zeros(bd.shape[0], dtype=jnp.int32)
-        for _ in range(depth):
-            feat = sf[nodev]
-            is_leaf = feat < 0
-            fb = jnp.take_along_axis(bd, jnp.maximum(feat, 0)[:, None], 1)[:, 0]
-            child = jnp.where(bgl[nodev, fb], 2 * nodev + 1, 2 * nodev + 2)
-            nodev = jnp.where(is_leaf, nodev, child)
-        return nv[nodev]
+        with jax.named_scope("gbdt.predict"):
+            nodev = jnp.zeros(bd.shape[0], dtype=jnp.int32)
+            for _ in range(depth):
+                feat = sf[nodev]
+                is_leaf = feat < 0
+                fb = jnp.take_along_axis(
+                    bd, jnp.maximum(feat, 0)[:, None], 1)[:, 0]
+                child = jnp.where(bgl[nodev, fb], 2 * nodev + 1,
+                                  2 * nodev + 2)
+                nodev = jnp.where(is_leaf, nodev, child)
+            return nv[nodev]
 
     return predict_tree_binned
 
@@ -1907,6 +1915,15 @@ def _make_step_fn(num_f: int, total_bins: int, cfg: TrainConfig, k: int,
     slowly (minutes for a 20-iteration scan at depth 6); a single-step
     jit compiles in seconds and async dispatch hides the per-step
     launch cost.
+
+    The stages carry ``jax.named_scope`` names, so that every device op
+    of the compiled program says in its ``op_name`` which stage it came
+    from: ``gbdt.sample``, ``gbdt.grad``, then inside the builder a
+    level ``gbdt.hist`` (with the Pallas feed as ``gbdt.hist.feed``),
+    ``gbdt.split``, ``gbdt.leaf``, ``gbdt.route``, and ``gbdt.predict``,
+    ``gbdt.metric``. Where scopes nest, the innermost names the op.
+    They are metadata: the program and its compile-cache key are as
+    without them. The jitted function stays named ``step``.
     """
     import jax
     import jax.numpy as jnp
@@ -1940,72 +1957,74 @@ def _make_step_fn(num_f: int, total_bins: int, cfg: TrainConfig, k: int,
         rv = data["row_valid"]
         raw, vraws = carry
         # ----- sampling masks (device RNG, deterministic by seed) ----
-        if bag_active:
-            # key by the last refresh iteration rather than carrying the
-            # mask: iterations within a bagging period draw the same
-            # mask, and a resumed segment (iteration_offset) reproduces
-            # it exactly
-            if freq > 0:
-                ref_it = it - (it % freq)
+        with jax.named_scope("gbdt.sample"):
+            if bag_active:
+                # key by the last refresh iteration rather than carrying the
+                # mask: iterations within a bagging period draw the same
+                # mask, and a resumed segment (iteration_offset) reproduces
+                # it exactly
+                if freq > 0:
+                    ref_it = it - (it % freq)
+                else:
+                    ref_it = 0  # rf with no freq: one fixed bag
+                kbag = jax.random.fold_in(jax.random.fold_in(
+                    jax.random.fold_in(base_key, 1), cfg.bagging_seed),
+                    ref_it)
+                draw = jax.random.uniform(kbag, (n,))
+                if pos_neg and not is_rf:
+                    # per-class rates (LightGBM pos/neg_bagging_fraction)
+                    thr_vec = jnp.where(labels > 0,
+                                        cfg.pos_bagging_fraction,
+                                        cfg.neg_bagging_fraction)
+                    sample_mask = (draw < thr_vec).astype(jnp.float32) * rv
+                else:
+                    use_frac = rf_frac if is_rf else frac
+                    sample_mask = (draw < use_frac).astype(jnp.float32) * rv
             else:
-                ref_it = 0  # rf with no freq: one fixed bag
-            kbag = jax.random.fold_in(jax.random.fold_in(
-                jax.random.fold_in(base_key, 1), cfg.bagging_seed),
-                ref_it)
-            draw = jax.random.uniform(kbag, (n,))
-            if pos_neg and not is_rf:
-                # per-class rates (LightGBM pos/neg_bagging_fraction)
-                thr_vec = jnp.where(labels > 0,
-                                    cfg.pos_bagging_fraction,
-                                    cfg.neg_bagging_fraction)
-                sample_mask = (draw < thr_vec).astype(jnp.float32) * rv
+                sample_mask = rv
+            if cfg.feature_fraction < 1.0:
+                keep = max(1, int(round(num_f * cfg.feature_fraction)))
+                kf = jax.random.fold_in(jax.random.fold_in(
+                    jax.random.fold_in(base_key, 2),
+                    cfg.feature_fraction_seed), it)
+                perm = jax.random.permutation(kf, num_f)
+                feat_mask = jnp.zeros(num_f, jnp.float32).at[perm[:keep]].set(1.0)
             else:
-                use_frac = rf_frac if is_rf else frac
-                sample_mask = (draw < use_frac).astype(jnp.float32) * rv
-        else:
-            sample_mask = rv
-        if cfg.feature_fraction < 1.0:
-            keep = max(1, int(round(num_f * cfg.feature_fraction)))
-            kf = jax.random.fold_in(jax.random.fold_in(
-                jax.random.fold_in(base_key, 2),
-                cfg.feature_fraction_seed), it)
-            perm = jax.random.permutation(kf, num_f)
-            feat_mask = jnp.zeros(num_f, jnp.float32).at[perm[:keep]].set(1.0)
-        else:
-            feat_mask = jnp.ones(num_f, jnp.float32)
+                feat_mask = jnp.ones(num_f, jnp.float32)
 
         # ----- gradients --------------------------------------------
-        score_in = raw if not is_rf else jnp.full_like(raw, base)
-        okw = dict(obj_kwargs)
-        if cfg.objective == "lambdarank":
-            okw["group_ids"] = groups
-            if data.get("group_layout") is not None:
-                okw["group_layout"] = data["group_layout"]
-        g, h = objective_fn(score_in, labels, weights, **okw)
-        if mode == "data_sharded" and mesh is not None:
-            # pin the per-round grad/hess recompute to the dp slice
-            # owning the rows — the sharded histogram builder consumes
-            # them shard-local, so nothing may force a gather here
-            from mmlspark_tpu.parallel.mesh import row_sharded
-            g = jax.lax.with_sharding_constraint(
-                g, row_sharded(mesh, g.ndim))
-            h = jax.lax.with_sharding_constraint(
-                h, row_sharded(mesh, h.ndim))
+        with jax.named_scope("gbdt.grad"):
+            score_in = raw if not is_rf else jnp.full_like(raw, base)
+            okw = dict(obj_kwargs)
+            if cfg.objective == "lambdarank":
+                okw["group_ids"] = groups
+                if data.get("group_layout") is not None:
+                    okw["group_layout"] = data["group_layout"]
+            g, h = objective_fn(score_in, labels, weights, **okw)
+            if mode == "data_sharded" and mesh is not None:
+                # pin the per-round grad/hess recompute to the dp slice
+                # owning the rows — the sharded histogram builder consumes
+                # them shard-local, so nothing may force a gather here
+                from mmlspark_tpu.parallel.mesh import row_sharded
+                g = jax.lax.with_sharding_constraint(
+                    g, row_sharded(mesh, g.ndim))
+                h = jax.lax.with_sharding_constraint(
+                    h, row_sharded(mesh, h.ndim))
 
-        if is_goss:
-            absg = jnp.abs(g) if k == 1 else jnp.sum(jnp.abs(g), axis=1)
-            # padded rows are excluded from the gradient quantile
-            thr = jnp.nanquantile(jnp.where(rv > 0, absg, jnp.nan),
-                                  1.0 - cfg.top_rate)
-            big = absg >= thr
-            kg = jax.random.fold_in(jax.random.fold_in(base_key, 3), it)
-            small_keep = jax.random.uniform(kg, absg.shape) < (
-                cfg.other_rate / max(1.0 - cfg.top_rate, 1e-12))
-            amplify = (1.0 - cfg.top_rate) / max(cfg.other_rate, 1e-12)
-            mult = jnp.where(big, 1.0, jnp.where(small_keep, amplify, 0.0))
-            sample_mask = sample_mask * (mult > 0)
-            gm = mult if k == 1 else mult[:, None]
-            g, h = g * gm, h * gm
+            if is_goss:
+                absg = jnp.abs(g) if k == 1 else jnp.sum(jnp.abs(g), axis=1)
+                # padded rows are excluded from the gradient quantile
+                thr = jnp.nanquantile(jnp.where(rv > 0, absg, jnp.nan),
+                                      1.0 - cfg.top_rate)
+                big = absg >= thr
+                kg = jax.random.fold_in(jax.random.fold_in(base_key, 3), it)
+                small_keep = jax.random.uniform(kg, absg.shape) < (
+                    cfg.other_rate / max(1.0 - cfg.top_rate, 1e-12))
+                amplify = (1.0 - cfg.top_rate) / max(cfg.other_rate, 1e-12)
+                mult = jnp.where(big, 1.0, jnp.where(small_keep, amplify, 0.0))
+                sample_mask = sample_mask * (mult > 0)
+                gm = mult if k == 1 else mult[:, None]
+                g, h = g * gm, h * gm
 
         # ----- one tree per class, raw updates ----------------------
         sfs, tbs, nvs, cnts, dts, bgls = [], [], [], [], [], []
@@ -2031,31 +2050,36 @@ def _make_step_fn(num_f: int, total_bins: int, cfg: TrainConfig, k: int,
                     binned, gc.astype(jnp.float32), hc.astype(jnp.float32),
                     sample_mask.astype(jnp.float32), feat_mask,
                     jnp.int32(nl), **tkw)
-            nv = nv * shrink
+            with jax.named_scope("gbdt.leaf"):
+                nv = nv * shrink
             sfs.append(sf); tbs.append(tb); nvs.append(nv); cnts.append(cnt)
             dts.append(dt); bgls.append(bgl)
-            pred = predict_tree(sf, bgl, nv, binned)
-            raw = raw + pred if k == 1 else raw.at[:, cls].add(pred)
-            for vi in range(n_valid):
-                vpred = predict_tree(sf, bgl, nv,
-                                     data["valids"][vi]["binned"])
-                new_vraws[vi] = (new_vraws[vi] + vpred if k == 1
-                                 else new_vraws[vi].at[:, cls].add(vpred))
+            with jax.named_scope("gbdt.predict"):  # the raw updates too
+                pred = predict_tree(sf, bgl, nv, binned)
+                raw = raw + pred if k == 1 else raw.at[:, cls].add(pred)
+                for vi in range(n_valid):
+                    vpred = predict_tree(sf, bgl, nv,
+                                         data["valids"][vi]["binned"])
+                    new_vraws[vi] = (
+                        new_vraws[vi] + vpred if k == 1
+                        else new_vraws[vi].at[:, cls].add(vpred))
 
         # ----- per-iteration metrics (on device) --------------------
-        mvals = []
-        for m_label, m_fn in metric_list:
-            mkw = dict(metric_kwargs)
-            if metric_name == "ndcg" and groups is not None:
-                mkw["group_ids"] = groups
-            mvals.append(m_fn(raw, labels, weights, **mkw))
-            for vi in range(n_valid):
-                vs = data["valids"][vi]
-                vkw = dict(metric_kwargs)
-                if metric_name == "ndcg":
-                    vkw["group_ids"] = vs["groups"]
-                mvals.append(m_fn(new_vraws[vi], vs["labels"],
-                                  vs["weights"], **vkw))
+        with jax.named_scope("gbdt.metric"):
+            mvals = []
+            for m_label, m_fn in metric_list:
+                mkw = dict(metric_kwargs)
+                if metric_name == "ndcg" and groups is not None:
+                    mkw["group_ids"] = groups
+                mvals.append(m_fn(raw, labels, weights, **mkw))
+                for vi in range(n_valid):
+                    vs = data["valids"][vi]
+                    vkw = dict(metric_kwargs)
+                    if metric_name == "ndcg":
+                        vkw["group_ids"] = vs["groups"]
+                    mvals.append(m_fn(new_vraws[vi], vs["labels"],
+                                      vs["weights"], **vkw))
+
         ys = (jnp.stack(sfs), jnp.stack(tbs), jnp.stack(nvs),
               jnp.stack(cnts), jnp.stack(mvals).astype(jnp.float32))
         if cfg.categorical_features:
@@ -2088,7 +2112,8 @@ def _get_step_fn(num_f, total_bins, cfg, k, n_valid, mode, mesh,
 
 def aot_lower_step(cfg: TrainConfig, n: int, num_f: int,
                    platform: str = "tpu",
-                   rows_per_group: int = 0) -> str:
+                   rows_per_group: int = 0,
+                   debug_info: bool = False) -> str:
     """AOT-lower ONE fused boosting step for ``platform`` and return
     its StableHLO text — the exact program ``train()`` dispatches per
     iteration (bench.py's hot loop), checkable on any host. Used by
@@ -2096,7 +2121,8 @@ def aot_lower_step(cfg: TrainConfig, n: int, num_f: int,
     handy on TPU day itself to inspect what XLA is given.
 
     ``rows_per_group``: > 0 builds lambdarank group structure (uniform
-    query sizes) with the bucketed pairwise layout."""
+    query sizes) with the bucketed pairwise layout. ``debug_info``
+    keeps the locations, which carry the ``gbdt.*`` scope of every op."""
     import jax
     import jax.numpy as jnp
 
@@ -2109,11 +2135,12 @@ def aot_lower_step(cfg: TrainConfig, n: int, num_f: int,
     # real TPU run (backend == tpu) never selects
     with env_override("MMLSPARK_TPU_NATIVE_HIST", "0"):
         return _aot_lower_step_inner(cfg, n, num_f, k, platform,
-                                     rows_per_group)
+                                     rows_per_group, debug_info)
 
 
 def _aot_lower_step_inner(cfg: TrainConfig, n: int, num_f: int, k: int,
-                          platform: str, rows_per_group: int) -> str:
+                          platform: str, rows_per_group: int,
+                          debug_info: bool) -> str:
     import jax
     import jax.numpy as jnp
 
@@ -2152,7 +2179,7 @@ def _aot_lower_step_inner(cfg: TrainConfig, n: int, num_f: int, k: int,
     carry = (jnp.zeros(raw_shape, jnp.float32), ())
     # step_fn is already jitted by _make_step_fn
     return step_fn.trace(data, carry, jnp.int32(0)).lower(
-        lowering_platforms=(platform,)).as_text()
+        lowering_platforms=(platform,)).as_text(debug_info=debug_info)
 
 
 # ---------------------------------------------------------------------------
@@ -2571,9 +2598,10 @@ def train(binned: np.ndarray, labels: np.ndarray, cfg: TrainConfig,
                 pass  # a poisoned step must not mask the real error
         for tok in host_tokens:
             _release_host_binned(tok)
-    booster = _assemble_booster(trees, tree_weights, cfg, k, num_f,
-                                total_bins, depth, num_slots, bin_upper,
-                                base_score, best_iter, init_model)
+    with measures.phase("assembly"):
+        booster = _assemble_booster(trees, tree_weights, cfg, k, num_f,
+                                    total_bins, depth, num_slots, bin_upper,
+                                    base_score, best_iter, init_model)
     return TrainResult(booster=booster, evals=evals,
                        best_iteration=best_iter, hist_stats=hist_stats)
 
@@ -2854,6 +2882,7 @@ def _train_scan(cfg, k, num_f, total_bins, binned_d, labels_d, weights_d,
     sanitizer.check_dtype_contract("gbdt.train_scan.exit", carry)
     with measures.phase("validation"):
         sync_metrics_through(stop_after)
+    with measures.phase("treeFetch", trees=len(kept) * k):
         # single batched transfer of all kept trees
         sf_h, tb_h, nv_h, cnt_h = jax.device_get((
             jnp.stack([o[0] for o in kept]),
@@ -2866,19 +2895,20 @@ def _train_scan(cfg, k, num_f, total_bins, binned_d, labels_d, weights_d,
                 jnp.stack([o[6] for o in kept])))
     resilience.step_end()
 
-    for j in range(stop_after):
-        for cls in range(k):
-            trees_sf.append(sf_h[j, cls])
-            trees_tb.append(tb_h[j, cls])
-            trees_nv.append(nv_h[j, cls])
-            trees_cnt.append(cnt_h[j, cls])
-            if has_cat:
-                trees_dt.append(dt_h[j, cls])
-                trees_bgl.append(bgl_h[j, cls])
-        record: Dict[str, float] = {"iteration": j}
-        for mi, name in enumerate(labels_order):
-            record[name] = float(met_host[j][mi])
-        evals.append(record)
+    with measures.phase("assembly"):
+        for j in range(stop_after):
+            for cls in range(k):
+                trees_sf.append(sf_h[j, cls])
+                trees_tb.append(tb_h[j, cls])
+                trees_nv.append(nv_h[j, cls])
+                trees_cnt.append(cnt_h[j, cls])
+                if has_cat:
+                    trees_dt.append(dt_h[j, cls])
+                    trees_bgl.append(bgl_h[j, cls])
+            record: Dict[str, float] = {"iteration": j}
+            for mi, name in enumerate(labels_order):
+                record[name] = float(met_host[j][mi])
+            evals.append(record)
     return ((trees_sf, trees_tb, trees_nv, trees_cnt, trees_dt, trees_bgl),
             [1.0] * len(trees_sf), evals, best_iter)
 

@@ -23,6 +23,7 @@ from mmlspark_tpu.core.param import (
     HasInputCol, HasOutputCol, Param, gt, to_int, to_str,
 )
 from mmlspark_tpu.core.pipeline import Transformer
+from mmlspark_tpu.core.timer import span
 from mmlspark_tpu.onnx.convert import OnnxGraph, load_model
 
 
@@ -99,44 +100,51 @@ class ONNXModel(Transformer):
         fetch = self.get("fetchDict") or {
             "output": graph.output_names[0]}
 
+        # spans (core/timer.py): ``onnx.stack`` is the object column made
+        # one array, ``onnx.cast`` the dtype pass, ``onnx.columns`` the
+        # output columns and post-ops; the engine's own are ``scorer.*``
         feeds = {}
         for input_name, col_name in feed.items():
             col = dataset.col(col_name)
-            if col.dtype == object:
-                batch = np.stack([np.asarray(v) for v in col])
-            else:
-                batch = col
+            with span("onnx.stack", rows=len(col)) as stack:
+                if col.dtype == object:
+                    batch = np.stack([np.asarray(v) for v in col])
+                else:
+                    batch = col
+                stack.counts["bytes"] = batch.nbytes
             # honor the graph's declared input dtype; otherwise keep
             # int/bool columns intact and only downcast f64 -> f32
-            declared = graph.input_dtypes.get(input_name)
-            if declared is not None:
-                batch = np.asarray(batch, declared)
-            elif batch.dtype == np.float64:
-                batch = batch.astype(np.float32)
-            feeds[input_name] = np.asarray(batch)
+            with span("onnx.cast"):
+                declared = graph.input_dtypes.get(input_name)
+                if declared is not None:
+                    batch = np.asarray(batch, declared)
+                elif batch.dtype == np.float64:
+                    batch = batch.astype(np.float32)
+                feeds[input_name] = np.asarray(batch)
         # one engine call: the scorer chunks to miniBatchSize-capped
         # bucket rungs internally and keeps weights resident on-device
         fetched = scorer(feeds)
 
-        out = dataset
-        for out_col, tensor_name in fetch.items():
-            stacked = np.asarray(fetched[tensor_name])
-            if stacked.ndim > 2:  # ragged-safe object column
-                obj = np.empty(len(stacked), dtype=object)
-                for i in range(len(stacked)):
-                    obj[i] = stacked[i]
-                stacked = obj
-            out = out.with_column(out_col, stacked)
+        with span("onnx.columns"):
+            out = dataset
+            for out_col, tensor_name in fetch.items():
+                stacked = np.asarray(fetched[tensor_name])
+                if stacked.ndim > 2:  # ragged-safe object column
+                    obj = np.empty(len(stacked), dtype=object)
+                    for i in range(len(stacked)):
+                        obj[i] = stacked[i]
+                    stacked = obj
+                out = out.with_column(out_col, stacked)
 
-        import jax
-        for src, dst in (self.get("softMaxDict") or {}).items():
-            vals = np.asarray(list(out.col(src)), np.float64)
-            out = out.with_column(dst, np.asarray(
-                jax.nn.softmax(vals, axis=-1)))
-        for src, dst in (self.get("argMaxDict") or {}).items():
-            vals = np.asarray(list(out.col(src)), np.float64)
-            out = out.with_column(dst, vals.argmax(axis=-1)
-                                  .astype(np.float64))
+            import jax
+            for src, dst in (self.get("softMaxDict") or {}).items():
+                vals = np.asarray(list(out.col(src)), np.float64)
+                out = out.with_column(dst, np.asarray(
+                    jax.nn.softmax(vals, axis=-1)))
+            for src, dst in (self.get("argMaxDict") or {}).items():
+                vals = np.asarray(list(out.col(src)), np.float64)
+                out = out.with_column(dst, vals.argmax(axis=-1)
+                                      .astype(np.float64))
         return out
 
     def slice_at_output(self, tensor_name: str,

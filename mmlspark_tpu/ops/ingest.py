@@ -24,6 +24,7 @@ import numpy as np
 
 from mmlspark_tpu.core.faults import FaultInjected, fault_point
 from mmlspark_tpu.core.serialize import DiskFull
+from mmlspark_tpu.core.timer import span
 
 
 def chunked_device_put(arr: np.ndarray, sharding=None,
@@ -36,6 +37,12 @@ def chunked_device_put(arr: np.ndarray, sharding=None,
 
     ``row_multiple``: chunk row counts stay multiples of this (the mesh
     dp axis size when sharded). Small arrays fall through to one put.
+
+    The whole of it is the span ``dataPreparation.transfer`` (bytes on
+    the wire, chunks): the host's share of the transfer, which is the
+    narrowing copies and the enqueues. Nothing here waits for the
+    device, so the copies still in flight at the end are paid for by
+    whoever first needs the array.
     """
     import jax
     import jax.numpy as jnp
@@ -56,24 +63,27 @@ def chunked_device_put(arr: np.ndarray, sharding=None,
             part = part.astype(dtype, copy=False)
         return part
 
-    if chunk_rows >= n:
-        full = prep(arr)
-        return (jax.device_put(full, sharding) if sharding is not None
-                else jnp.asarray(full))
+    with span("dataPreparation.transfer", bytes=n * row_nbytes,
+              chunks=-(-n // chunk_rows)):
+        if chunk_rows >= n:
+            full = prep(arr)
+            return (jax.device_put(full, sharding) if sharding is not None
+                    else jnp.asarray(full))
 
-    parts = []
-    for s in range(0, n, chunk_rows):
-        # device_put returns immediately: the next chunk's host prep
-        # overlaps this chunk's transfer. Each chunk carries the final
-        # sharding (chunk rows are row_multiple-aligned), so shards go
-        # straight to their devices — no single-device staging
-        part = prep(arr[s:s + chunk_rows])
-        parts.append(jax.device_put(part, sharding)
-                     if sharding is not None and len(part) % row_multiple == 0
-                     else jax.device_put(part))
-    concat = jax.jit(lambda *p: jnp.concatenate(p, axis=0),
-                     out_shardings=sharding)
-    return concat(*parts)
+        parts = []
+        for s in range(0, n, chunk_rows):
+            # device_put returns immediately: the next chunk's host prep
+            # overlaps this chunk's transfer. Each chunk carries the final
+            # sharding (chunk rows are row_multiple-aligned), so shards go
+            # straight to their devices — no single-device staging
+            part = prep(arr[s:s + chunk_rows])
+            parts.append(jax.device_put(part, sharding)
+                         if sharding is not None
+                         and len(part) % row_multiple == 0
+                         else jax.device_put(part))
+        concat = jax.jit(lambda *p: jnp.concatenate(p, axis=0),
+                         out_shardings=sharding)
+        return concat(*parts)
 
 
 def binned_ingest_dtype(total_bins: int):

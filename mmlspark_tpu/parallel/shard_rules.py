@@ -40,6 +40,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from mmlspark_tpu.core.logging_utils import warn_once
+from mmlspark_tpu.core.timer import span
 from mmlspark_tpu.parallel.mesh import (
     DATA_AXIS,
     MODEL_AXIS,
@@ -478,7 +479,18 @@ class ShardedScorer:
     def __call__(self, x):
         """Score rows; returns host numpy with the same tree structure
         as ``apply_fn``'s output, batch-dim outputs sliced to the true
-        row count."""
+        row count.
+
+        Spans (core/timer.py), each group of ``dp x rung`` rows:
+        ``scorer.pad`` (slice, zero-fill to the rung), ``scorer.put``
+        and ``scorer.dispatch``; then one ``scorer.fetch``. Put and
+        dispatch are asynchronous, so their spans time the host's part
+        of each (layout and enqueue) and nothing waits for the device
+        until the fetch: ``scorer.fetch`` holds the wait for the copies
+        and the program as well as the copy back and the concatenate.
+        The profiler's trace, on the same clock, is what splits it into
+        device-busy and device-idle.
+        """
         import jax
 
         from mmlspark_tpu.core import sanitizer
@@ -496,17 +508,24 @@ class ShardedScorer:
                 f"(global {step})")
         chunks = []
         for g in range(0, max(n, 1), step):
-            group = {}
-            for k, v in cols.items():
-                gv = v[g:g + step]
-                if gv.shape[0] < step:
-                    fill = np.zeros((step - gv.shape[0],) + gv.shape[1:],
-                                    dtype=gv.dtype)
-                    gv = np.concatenate([gv, fill]) if gv.shape[0] \
-                        else fill
-                group[k] = self._put(gv)
-            chunks.append(self._dispatch(
-                group if is_dict else group["__x__"]))
+            with span("scorer.pad", rows=step):
+                group = {}
+                for k, v in cols.items():
+                    gv = v[g:g + step]
+                    if gv.shape[0] < step:
+                        fill = np.zeros(
+                            (step - gv.shape[0],) + gv.shape[1:],
+                            dtype=gv.dtype)
+                        gv = np.concatenate([gv, fill]) if gv.shape[0] \
+                            else fill
+                    group[k] = gv
+            with span("scorer.put",
+                      bytes=sum(gv.nbytes for gv in group.values())):
+                group = {k: self._put(gv) for k, gv in group.items()}
+            with span("scorer.dispatch"):
+                chunks.append(self._dispatch(
+                    group if is_dict else group["__x__"]))
+
         def fetch(a):
             if getattr(a, "is_fully_addressable", True):
                 return np.asarray(jax.device_get(a))
@@ -516,16 +535,18 @@ class ShardedScorer:
             return np.asarray(
                 multihost_utils.process_allgather(a, tiled=True))
 
-        flat0, treedef = jax.tree_util.tree_flatten(chunks[0])
-        gathered = []
-        for i in range(len(flat0)):
-            leaves = [fetch(jax.tree_util.tree_flatten(c)[0][i])
-                      for c in chunks]
-            a = leaves[0]
-            if a.ndim >= 1 and a.shape[0] == step:
-                gathered.append(np.concatenate(leaves)[:n])
-            else:
-                gathered.append(a)  # non-batch output: first chunk's
+        with span("scorer.fetch") as fetched:
+            flat0, treedef = jax.tree_util.tree_flatten(chunks[0])
+            gathered = []
+            for i in range(len(flat0)):
+                leaves = [fetch(jax.tree_util.tree_flatten(c)[0][i])
+                          for c in chunks]
+                a = leaves[0]
+                if a.ndim >= 1 and a.shape[0] == step:
+                    gathered.append(np.concatenate(leaves)[:n])
+                else:
+                    gathered.append(a)  # non-batch output: first chunk's
+            fetched.counts["bytes"] = sum(a.nbytes for a in gathered)
         return jax.tree_util.tree_unflatten(treedef, gathered)
 
     # -- metadata ------------------------------------------------------

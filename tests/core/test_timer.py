@@ -1,0 +1,309 @@
+"""The span recorder (core/timer.py), its root (core/logging_utils.py),
+and the names the program gives the host and the device with it."""
+
+import glob
+import os
+import re
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from mmlspark_tpu.core.dataframe import DataFrame
+from mmlspark_tpu.core.logging_utils import SINK, log_stage_method
+from mmlspark_tpu.core.timer import InstrumentationMeasures, span
+
+FIT_SPANS = {"labels", "extract", "binning", "binning.fit", "binning.transform",
+             "dataPreparation", "dataPreparation.transfer", "training",
+             "validation", "treeFetch", "assembly"}
+TRANSFORM_SPANS = {"onnx.stack", "onnx.cast", "scorer.pad", "scorer.put",
+                   "scorer.dispatch", "scorer.fetch", "onnx.columns"}
+BENCHMARKS_OWN = {"fit", "transform_call", "between_calls", "traced_part"}
+DEVICE_SCOPES = {"gbdt.sample", "gbdt.grad", "gbdt.hist.feed", "gbdt.hist",
+                 "gbdt.split", "gbdt.route", "gbdt.leaf", "gbdt.predict"}
+
+
+def test_nesting_gives_parent_root_uid_and_order_of_start():
+    with span("Stage.fit", uid="Stage_1") as root:
+        with span("outer", rows=3) as outer:
+            with span("outer.inner") as inner:
+                pass
+            outer.counts["bytes"] = 24
+        with span("second") as second:
+            pass
+    assert root.parent is None and root.uid == "Stage_1"
+    assert outer.parent is root and inner.parent is outer
+    assert {s.uid for s in (outer, inner, second)} == {"Stage_1"}
+    assert root.spans == [outer, inner, second]
+    assert all(s.spans is None for s in (outer, inner, second))
+    assert (root.start_s <= outer.start_s <= inner.start_s <= inner.end_s
+            <= outer.end_s <= second.start_s <= second.end_s <= root.end_s)
+    assert outer.as_record() == {
+        "name": "outer", "start_s": outer.start_s, "end_s": outer.end_s,
+        "parent": "Stage.fit", "counts": {"rows": 3, "bytes": 24}}
+    assert inner.as_record()["parent"] == "outer"
+
+
+def test_a_span_with_no_root_above_it_is_its_own_root_and_is_kept_nowhere():
+    with span("scorer.pad") as alone:
+        with span("child") as child:
+            pass
+    assert alone.parent is None and alone.uid is None
+    assert alone.spans == [child] and child.uid is None
+    with span("scorer.pad") as again:
+        pass
+    assert again.spans == []            # nothing of the first one
+
+
+def test_a_nested_stage_is_a_root_of_its_own_listed_once_above():
+    with span("Pipeline.fit", uid="Pipeline_1") as outer:
+        with span("Inner.fit", uid="Inner_2") as inner:
+            with span("work") as work:
+                pass
+    assert outer.spans == [inner] and inner.spans == [work]
+    assert work.uid == "Inner_2" and inner.parent is outer
+    assert inner.as_record()["uid"] == "Inner_2"
+    assert "uid" not in work.as_record()
+
+
+def test_sums_by_name_are_what_phase_gave():
+    m = InstrumentationMeasures()
+    with m.phase("binning") as whole:
+        with span("binning.fit") as first:
+            time.sleep(0.002)
+        with span("binning.transform") as second:
+            time.sleep(0.002)
+    for _ in range(3):
+        with m.phase("training"):
+            pass
+    # a nested span stands under its own dotted name and adds nothing
+    # to its parent's figure, which is the parent's own interval
+    assert m.seconds("binning") == pytest.approx(whole.seconds)
+    assert m.seconds("binning.fit") == pytest.approx(first.seconds)
+    assert m.seconds("binning.transform") == pytest.approx(second.seconds)
+    assert m.seconds("binning") >= (m.seconds("binning.fit")
+                                    + m.seconds("binning.transform"))
+    assert m.count("training") == 3 and m.count("binning") == 1
+    assert m.seconds("never") == 0.0 and m.count("never") == 0
+    assert list(m.as_dict()) == ["binning.fit", "binning.transform",
+                                 "binning", "training"]
+    assert m.as_dict()["training"] == m.seconds("training")
+    # a span opened outside any phase of this object adds nothing to it
+    with span("elsewhere"):
+        pass
+    assert "elsewhere" not in m.as_dict()
+
+
+def test_a_raising_body_still_closes_and_records():
+    m = InstrumentationMeasures()
+    with pytest.raises(KeyError):
+        with span("Stage.transform", uid="u") as root:
+            with m.phase("doomed") as doomed:
+                raise KeyError("boom")
+    assert doomed.end_s >= doomed.start_s and root.end_s >= doomed.end_s
+    assert root.spans == [doomed] and m.count("doomed") == 1
+    with span("after") as after:        # the stack was unwound
+        pass
+    assert after.parent is None
+
+
+def test_the_stage_record_carries_the_spans_even_when_the_body_raises():
+    SINK.drain()
+    with pytest.raises(ValueError):
+        with log_stage_method("Stage_9", "Stage", "transform",
+                              {"numRows": 2}):
+            with span("part", rows=2):
+                raise ValueError("bad row")
+    (record,) = [e for e in SINK.drain() if e.get("uid") == "Stage_9"]
+    assert record["error"].startswith("ValueError")
+    assert record["seconds"] == pytest.approx(
+        record["end_s"] - record["start_s"])
+    (part,) = record["spans"]
+    assert part["name"] == "part" and part["parent"] == "Stage.transform"
+    assert record["start_s"] <= part["start_s"] <= part["end_s"] \
+        <= record["end_s"]
+
+
+def test_two_threads_never_adopt_each_others_spans():
+    barrier = threading.Barrier(2, timeout=10)
+    roots, failures = {}, []
+
+    def serve(tag):
+        try:
+            with span(f"Model_{tag}.transform", uid=tag) as root:
+                barrier.wait()          # both roots are open now
+                for i in range(200):
+                    with span(f"{tag}.work") as s:
+                        if s.parent is not root or s.uid != tag:
+                            failures.append((tag, i))
+                barrier.wait()
+            roots[tag] = root
+        except Exception as e:  # noqa: BLE001 — reported below
+            failures.append((tag, repr(e)))
+
+    threads = [threading.Thread(target=serve, args=(t,)) for t in "ab"]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=30)
+    assert not any(t.is_alive() for t in threads) and not failures
+    for tag in "ab":
+        assert len(roots[tag].spans) == 200
+        assert {s.name for s in roots[tag].spans} == {f"{tag}.work"}
+
+
+def test_a_span_with_no_profiler_session_costs_microseconds():
+    """Spans ride every transform call and every tree of a fit with
+    nothing to switch them off: no session, no cost to speak of (as
+    test_resilience's disabled-hook bound; typical is 1-2 us)."""
+    m = InstrumentationMeasures()
+    with span("warm"):
+        pass
+    reps = 20_000
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        with span("x"):
+            pass
+    alone_ns = (time.perf_counter() - t0) / reps * 1e9
+    t0 = time.perf_counter()
+    for _ in range(reps // 100):        # a fit's worth under each root
+        with span("Stage.fit", uid="u"):
+            for _ in range(100):
+                with m.phase("x", rows=1):
+                    pass
+    under_root_ns = (time.perf_counter() - t0) / reps * 1e9
+    assert alone_ns < 5_000 and under_root_ns < 5_000
+
+
+# -- the names, where the work happens ---------------------------------
+
+def _tiny_onnx_model():
+    from tests.onnx.test_onnx import _mlp_model
+    from mmlspark_tpu.onnx import ONNXModel
+
+    data, _ = _mlp_model(np.random.default_rng(0))
+    return ONNXModel(modelPayload=data, miniBatchSize=8)
+
+
+def _tiny_frames():
+    rng = np.random.default_rng(1)
+    x = rng.normal(size=(400, 4))
+    fit_df = DataFrame({"features": x,
+                        "label": (x[:, 0] > 0).astype(np.float64)})
+    column = np.empty(6, dtype=object)
+    for i in range(6):
+        column[i] = rng.normal(size=4).astype(np.float32)
+    return fit_df, DataFrame({"features": column})
+
+
+def _host_event_names(trace_dir):
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    names = set()
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                names.update(ev.name for ev in line.events)
+    return names
+
+
+def test_span_names_stand_in_the_profilers_host_plane_and_in_the_record(
+        tmp_path):
+    import jax
+
+    from mmlspark_tpu.models.gbdt.estimators import LightGBMClassifier
+
+    fit_df, onnx_df = _tiny_frames()
+    onnx_model = _tiny_onnx_model()
+    estimator = LightGBMClassifier(numIterations=3, numLeaves=4, maxBin=16)
+    onnx_model.transform(onnx_df)                     # compiles
+    estimator.fit(fit_df)
+    SINK.drain()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        onnx_model.transform(onnx_df)
+        model = estimator.fit(fit_df)
+    finally:
+        jax.profiler.stop_trace()
+    records = {(e["className"], e["method"]): e for e in SINK.drain()
+               if "spans" in e}
+
+    in_trace = _host_event_names(str(tmp_path))
+    assert FIT_SPANS | TRANSFORM_SPANS <= in_trace
+    assert {"ONNXModel.transform", "LightGBMClassifier.fit"} <= in_trace
+
+    transform = records[("ONNXModel", "transform")]
+    assert transform["uid"] == onnx_model.uid
+    assert [s["name"] for s in transform["spans"]] == [
+        "onnx.stack", "onnx.cast", "scorer.pad", "scorer.put",
+        "scorer.dispatch", "scorer.fetch", "onnx.columns"]
+    assert {s["parent"] for s in transform["spans"]} == {
+        "ONNXModel.transform"}
+    by_name = {s["name"]: s for s in transform["spans"]}
+    assert by_name["onnx.stack"]["counts"] == {"rows": 6, "bytes": 6 * 4 * 4}
+    assert by_name["scorer.put"]["counts"]["bytes"] == 8 * 4 * 4
+    assert by_name["scorer.fetch"]["counts"]["bytes"] == 6 * 3 * 4
+
+    fit = records[("LightGBMClassifier", "fit")]
+    assert fit["uid"] == estimator.uid
+    parents = {s["name"]: s["parent"] for s in fit["spans"]}
+    assert set(parents) == FIT_SPANS
+    assert parents["binning.fit"] == parents["binning.transform"] == "binning"
+    assert parents["dataPreparation.transfer"] == "dataPreparation"
+    assert {parents[n] for n in FIT_SPANS if "." not in n} == {
+        "LightGBMClassifier.fit"}
+    names = [s["name"] for s in fit["spans"]]
+    assert names.count("training") >= 3            # one a tree, and the drain
+    assert names.index("labels") < names.index("extract") \
+        < names.index("binning") \
+        < names.index("dataPreparation") < names.index("training") \
+        < names.index("treeFetch") < names.index("assembly")
+    by_name = {s["name"]: s for s in fit["spans"]}
+    assert by_name["labels"]["counts"] == {"rows": 400}
+    assert by_name["extract"]["counts"] == {"rows": 400, "bytes": 400 * 4 * 8}
+    assert by_name["binning.transform"]["counts"] == {"rows": 400}
+    assert by_name["dataPreparation.transfer"]["counts"] == {
+        "bytes": 400 * 4, "chunks": 1}
+    assert by_name["treeFetch"]["counts"] == {"trees": 3}
+
+    # the sums the model hands out are the same spans, by name
+    measures = model.get_all_instrumentation()
+    assert set(measures) == FIT_SPANS
+    for name in FIT_SPANS:
+        spent = sum(s["end_s"] - s["start_s"] for s in fit["spans"]
+                    if s["name"] == name)
+        assert measures[name] == pytest.approx(spent)
+    # no program span takes a name the benchmark's own annotations use
+    assert not BENCHMARKS_OWN & (FIT_SPANS | TRANSFORM_SPANS)
+
+
+def test_the_lowered_step_carries_every_scope_and_the_kernels_name(
+        monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    monkeypatch.setenv("MMLSPARK_TPU_PALLAS_HIST", "1")
+    monkeypatch.setenv("MMLSPARK_TPU_PALLAS_FORCE_COMPILE", "1")
+    from mmlspark_tpu.models.gbdt.hist_pallas import pallas_level_histogram
+    from mmlspark_tpu.models.gbdt.trainer import TrainConfig, aot_lower_step
+
+    cfg = TrainConfig(objective="binary", num_leaves=7, max_depth=3,
+                      max_bin=255, min_data_in_leaf=20)
+    text = aot_lower_step(cfg, n=2048, num_f=28, platform="tpu",
+                          debug_info=True)
+    assert set(re.findall(r"gbdt\.[a-z.]+[a-z]", text)) >= DEVICE_SCOPES
+    assert "gbdt_level_hist" in text and "tpu_custom_call" in text
+    assert "jit(step)" in text          # device_idle_share.fit's anchor
+    assert "gbdt." not in aot_lower_step(cfg, n=2048, num_f=28)
+
+    n = 1024
+    jaxpr = str(jax.make_jaxpr(
+        lambda *a: pallas_level_histogram(*a, 4, 28, 255))(
+            jnp.zeros((n, 28), jnp.uint8), jnp.ones(n), jnp.ones(n),
+            jnp.ones(n), jnp.zeros(n, jnp.int32)))
+    assert "name=gbdt_level_hist" in jaxpr
+    # hist_kernel_roofline finds the kernel by this result shape
+    assert "f32[4,28,8,256]" in jaxpr
