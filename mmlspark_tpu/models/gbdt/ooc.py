@@ -182,11 +182,15 @@ _CARRY_CACHE: Dict[int, Callable] = {}
 
 def _carry_step(depth: int):
     """Jitted raw-score carry update for one chunk: shrink -> leaf
-    gather -> add, the exact expression order the fused in-core step
-    traces (``nv * lr`` then ``predict_tree`` then ``raw + pred``), so
-    XLA makes the same fusion/rounding decisions — a host numpy
-    mul-then-add is NOT bitwise equivalent on backends that fuse the
-    multiply into the gather consumer."""
+    gather -> add. A chunk is streamed from the spill and no builder
+    kept a slot for its rows, so it walks the finished tree
+    (``predict_tree``) to the slot the fused in-core step is handed by
+    its builder as ``node``; from there the two trace the same
+    expression (``nv * lr``, then one gather of it by slot, then
+    ``raw + pred``), so XLA makes the same fusion/rounding decisions
+    and the sums are bitwise equal — the walk only decides which slot,
+    an integer. A host numpy mul-then-add is NOT bitwise equivalent on
+    backends that fuse the multiply into the gather consumer."""
     fn = _CARRY_CACHE.get(depth)
     if fn is not None:
         return fn
@@ -654,6 +658,9 @@ def train_ooc(spill: SpillReader, labels, cfg: TrainConfig, *,
         # (trainer._ooc_supported screens everything else out)
         "hist_formulation": "native", "tree_mode": "serial",
         "pallas_interpret": None,
+        # chunks are streamed and keep no slot from the level loop, so
+        # the carry walks each finished tree (_carry_step)
+        "raw_update": "tree_walk",
         "hist_shard": "off", "grad_shard": "off",
         "efb_bundles": 0, "efb_bundled_features": 0,
         "ooc": True, "ooc_reason": None, "chunk_rows": chunk_rows,

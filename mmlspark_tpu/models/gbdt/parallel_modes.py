@@ -18,8 +18,11 @@ top-K constant LightGBMConstants.scala:22-24):
   best gains. Row routing for a winning feature owned by one shard is
   broadcast with a masked psum.
 
-Both builders return the same SoA tree arrays as the serial builder
-(make_build_tree) and plug into the same boosting loop.
+The builders return the serial builder's (make_build_tree) numerical
+SoA tree arrays and, like it, ``node``: the slot each row settled in,
+sharded as the rows of ``binned`` are (over ``dp``; replicated under
+``fp``). ``trainer._with_bin_mask`` completes the contract, and they
+plug into the same boosting loop.
 """
 
 from __future__ import annotations
@@ -120,7 +123,9 @@ def make_build_tree_voting(num_features: int, total_bins: int, cfg,
                            mesh) -> Callable:
     """Voting-parallel builder: shard_map over ``dp``; same signature as
     the serial builder — (binned, grad, hess, valid, feat_mask,
-    remaining_leaves) with ROW-SHARDED binned/grad/hess/valid."""
+    remaining_leaves) with ROW-SHARDED binned/grad/hess/valid. Returns
+    (split_feature, threshold_bin, node_value, count, node), ``node``
+    row-sharded."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -238,13 +243,13 @@ def make_build_tree_voting(num_features: int, total_bins: int, cfg,
             node = jnp.where(done | ~nsplit, node, child)
             done = done | newly_done
 
-        return split_feature, threshold_bin, node_value, node_count
+        return split_feature, threshold_bin, node_value, node_count, node
 
     row = P(DATA_AXIS)
     return shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(DATA_AXIS, None), row, row, row, P(), P()),
-        out_specs=(P(), P(), P(), P()),
+        out_specs=(P(), P(), P(), P(), row),
         check_vma=_check_vma(total_bins))
 
 
@@ -280,7 +285,9 @@ def make_build_tree_data_parallel(num_features: int, total_bins: int,
                                   shard_hist: bool = True) -> Callable:
     """Data-parallel builder with a reduce-scattered histogram:
     shard_map over ``dp`` with ROW-SHARDED binned/grad/hess/valid (the
-    same signature as the serial builder). Instead of materializing the
+    same signature as the serial builder; returns (split_feature,
+    threshold_bin, node_value, count, node), ``node`` row-sharded, the
+    padded rows' slots included). Instead of materializing the
     full ``(width, F, B, 3)`` reduced histogram on every replica (the
     GSPMD full-``psum`` path), the per-level histogram is
     ``psum_scatter``'d across ``dp`` so each replica receives only its
@@ -501,20 +508,21 @@ def make_build_tree_data_parallel(num_features: int, total_bins: int,
             record_collective("pmax", DATA_AXIS, v.shape, v.dtype)
         return tuple(jax.lax.pmax(v, DATA_AXIS) for v in
                      (split_feature, threshold_bin, node_value,
-                      node_count))
+                      node_count)) + (node,)
 
     row = P(DATA_AXIS)
     return shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(DATA_AXIS, None), row, row, row, P(), P()),
-        out_specs=(P(), P(), P(), P()),
+        out_specs=(P(), P(), P(), P(), row),
         check_vma=_check_vma(total_bins))
 
 
 def make_build_tree_feature_parallel(num_features: int, total_bins: int,
                                      cfg, mesh) -> Callable:
     """Feature-parallel builder: shard_map over ``fp``; binned and
-    feat_mask are FEATURE-SHARDED, rows replicated."""
+    feat_mask are FEATURE-SHARDED, rows replicated (and so is the
+    returned ``node``)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -656,16 +664,16 @@ def make_build_tree_feature_parallel(num_features: int, total_bins: int,
 
         # every shard computed identical values (all cross-shard state went
         # through psum); pmax is an identity that marks them fp-invariant
-        # so out_specs=P() typechecks
-        for v in (split_feature, threshold_bin, node_value,
-                  node_count):
+        # so out_specs=P() typechecks; ``node`` too: rows are replicated
+        # over fp and every shard routed them by the same psum'd vote
+        outs = (split_feature, threshold_bin, node_value, node_count, node)
+        for v in outs:
             record_collective("pmax", FEATURE_AXIS, v.shape, v.dtype)
-        return tuple(jax.lax.pmax(v, FEATURE_AXIS) for v in
-                     (split_feature, threshold_bin, node_value, node_count))
+        return tuple(jax.lax.pmax(v, FEATURE_AXIS) for v in outs)
 
     return shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(None, FEATURE_AXIS), P(), P(), P(), P(FEATURE_AXIS),
                   P()),
-        out_specs=(P(), P(), P(), P()),
+        out_specs=(P(), P(), P(), P(), P()),
         check_vma=_check_vma(total_bins))

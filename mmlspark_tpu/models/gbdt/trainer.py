@@ -17,13 +17,23 @@ allreduce). Design:
     XLA), with a traced ``num_leaves`` budget that gates splits by
     within-level gain rank — the budgeted analog of LightGBM's leaf-wise
     growth;
-  - the per-iteration loop stays in Python (one compiled ``build_tree``
-    reused every iteration), matching the reference's driver-side loop
-    shape while keeping all math on device.
+  - the per-iteration loop stays in Python, matching the reference's
+    driver-side loop shape while keeping all math on device: gbdt, goss
+    and rf dispatch one fused jitted step a tree (``_make_step_fn``:
+    gradients, tree build, raw-score updates, metrics); DART, custom
+    objectives and leaf-wise growth run the pieces eagerly
+    (``_train_loop``);
+  - every tree builder hands back, beside the tree, the slot each of its
+    rows settled in (``node``), so the fused step updates the training
+    rows' raw scores with one gather, ``node_value[node]``. Rows no
+    builder routed (validation sets, out-of-core chunks, the eager
+    loop) walk the finished tree (``_make_predict_tree``). A fit's
+    ``hist_stats["raw_update"]`` says which: ``builder_leaf`` or
+    ``tree_walk``.
 
 GOSS / bagging / feature-fraction / DART semantics follow
-params/LightGBMParams.scala; voting/feature parallel variants live in
-``mmlspark_tpu.parallel``.
+params/LightGBMParams.scala; voting/feature/data-parallel builders live
+in ``mmlspark_tpu.models.gbdt.parallel_modes``.
 """
 
 from __future__ import annotations
@@ -1124,7 +1134,7 @@ def make_build_tree(num_features: int, total_bins: int, cfg: TrainConfig,
                     allow_native: bool = True, efb_plan=None):
     """Compile-once tree builder: (binned, grad, hess, valid, feat_mask,
     remaining_leaves) -> (split_feature, threshold_bin, node_value, count,
-    decision_type, bin_go_left).
+    decision_type, bin_go_left, node).
 
     All shapes static: N rows, F features, B bins, depth D. Returns the
     full-layout arrays described in booster.py; ``bin_go_left`` is a
@@ -1132,6 +1142,12 @@ def make_build_tree(num_features: int, total_bins: int, cfg: TrainConfig,
     route left. Numerical splits fill it with ``bin <= threshold``;
     categorical splits with the chosen category subset, so row routing
     and binned prediction are a single gather regardless of split type.
+    ``node`` is int32 (N,): the slot every row of ``binned`` settled in
+    after the last level's routing, bagged-out and padded rows included
+    — the slot ``_make_predict_tree``'s walk of the finished tree
+    reaches, so ``node_value[node]`` is the tree's prediction for the
+    rows it was built on. Every builder ``_get_builder`` returns gives
+    the same seven.
 
     ``subtract=True`` enables LightGBM's histogram-subtraction trick
     (feature_histogram.hpp Subtract): below the root, only the SMALLER
@@ -1654,7 +1670,7 @@ def make_build_tree(num_features: int, total_bins: int, cfg: TrainConfig,
                                left_mask)
 
         return (split_feature, threshold_bin, node_value, node_count,
-                decision_type, bin_go_left)
+                decision_type, bin_go_left, node)
 
     return build_tree
 
@@ -1698,7 +1714,9 @@ _PREDICT_CACHE: Dict[int, Callable] = {}
 def _make_predict_tree(depth: int) -> Callable:
     """(sf, bin_go_left, nv, binned) -> (N,) leaf values. Routing is one
     gather into the per-slot left-bin mask, uniform across numerical and
-    categorical splits."""
+    categorical splits. For rows no builder in the same program routed:
+    validation sets, streamed chunks, the Python loop. The rows a tree
+    was built on have their slot in the builder's ``node``."""
     import jax
     import jax.numpy as jnp
 
@@ -1748,16 +1766,17 @@ def _resolve_mode(cfg: TrainConfig, mesh) -> str:
 
 
 def _with_bin_mask(fn, total_bins):
-    """Adapt a 4-tuple (numerical-only) builder to the 6-tuple contract:
-    synthesize decision_type=0 and the ordered ``bin <= threshold`` left
-    mask from the recorded thresholds."""
+    """Adapt a numerical-only builder, (split_feature, threshold_bin,
+    node_value, count, node), to ``make_build_tree``'s seven: synthesize
+    decision_type=0 and the ordered ``bin <= threshold`` left mask from
+    the recorded thresholds; ``node`` passes through."""
     import jax.numpy as jnp
 
     def wrapped(*args):
-        sf, tb, nv, cnt = fn(*args)
+        sf, tb, nv, cnt, node = fn(*args)
         bins = jnp.arange(total_bins, dtype=jnp.int32)
         bgl = (bins[None, :] <= tb[:, None]) & (sf >= 0)[:, None]
-        return sf, tb, nv, cnt, jnp.zeros(sf.shape[0], jnp.int8), bgl
+        return sf, tb, nv, cnt, jnp.zeros(sf.shape[0], jnp.int8), bgl, node
 
     return wrapped
 
@@ -1904,6 +1923,13 @@ def _make_step_fn(num_f: int, total_bins: int, cfg: TrainConfig, k: int,
     """One jitted function running ONE fused boosting iteration on device:
     gradients → tree build → raw/valid-raw updates → metric vector.
 
+    The training rows' update is ``raw + nv[node]``: the builder routed
+    exactly these rows through exactly this tree, and ``node`` is where
+    each one settled. Validation rows were routed by no builder, so they
+    walk the finished tree (``_make_predict_tree``). Both read
+    ``nv * shrink`` through one gather and add it, so either gives the
+    same sums bitwise.
+
     ``step(data, carry, it)`` takes the global iteration number as a
     traced scalar (so bagging refresh schedules and RNG folding don't
     recompile per iteration). Carry: (raw, valid raws, bag mask). The
@@ -1920,8 +1946,9 @@ def _make_step_fn(num_f: int, total_bins: int, cfg: TrainConfig, k: int,
     of the compiled program says in its ``op_name`` which stage it came
     from: ``gbdt.sample``, ``gbdt.grad``, then inside the builder a
     level ``gbdt.hist`` (with the Pallas feed as ``gbdt.hist.feed``),
-    ``gbdt.split``, ``gbdt.leaf``, ``gbdt.route``, and ``gbdt.predict``,
-    ``gbdt.metric``. Where scopes nest, the innermost names the op.
+    ``gbdt.split``, ``gbdt.leaf``, ``gbdt.route``, and ``gbdt.predict``
+    (the raw updates: the leaf gather and, for validation rows, the
+    walk), ``gbdt.metric``. Where scopes nest, the innermost names the op.
     They are metadata: the program and its compile-cache key are as
     without them. The jitted function stays named ``step``.
     """
@@ -2038,24 +2065,19 @@ def _make_step_fn(num_f: int, total_bins: int, cfg: TrainConfig, k: int,
             gc = g if k == 1 else g[:, cls]
             hc = h if k == 1 else h[:, cls]
             if cfg.extra_trees or cfg.feature_fraction_by_node < 1.0:
-                kt = jax.random.fold_in(jax.random.fold_in(
+                tkw["key"] = jax.random.fold_in(jax.random.fold_in(
                     jax.random.fold_in(base_key, 4 + cls),
                     cfg.extra_seed), it)
-                sf, tb, nv, cnt, dt, bgl = build_tree(
-                    binned, gc.astype(jnp.float32), hc.astype(jnp.float32),
-                    sample_mask.astype(jnp.float32), feat_mask,
-                    jnp.int32(nl), key=kt, **tkw)
-            else:
-                sf, tb, nv, cnt, dt, bgl = build_tree(
-                    binned, gc.astype(jnp.float32), hc.astype(jnp.float32),
-                    sample_mask.astype(jnp.float32), feat_mask,
-                    jnp.int32(nl), **tkw)
+            sf, tb, nv, cnt, dt, bgl, node = build_tree(
+                binned, gc.astype(jnp.float32), hc.astype(jnp.float32),
+                sample_mask.astype(jnp.float32), feat_mask,
+                jnp.int32(nl), **tkw)
             with jax.named_scope("gbdt.leaf"):
                 nv = nv * shrink
             sfs.append(sf); tbs.append(tb); nvs.append(nv); cnts.append(cnt)
             dts.append(dt); bgls.append(bgl)
             with jax.named_scope("gbdt.predict"):  # the raw updates too
-                pred = predict_tree(sf, bgl, nv, binned)
+                pred = nv[node]
                 raw = raw + pred if k == 1 else raw.at[:, cls].add(pred)
                 for vi in range(n_valid):
                     vpred = predict_tree(sf, bgl, nv,
@@ -2113,7 +2135,8 @@ def _get_step_fn(num_f, total_bins, cfg, k, n_valid, mode, mesh,
 def aot_lower_step(cfg: TrainConfig, n: int, num_f: int,
                    platform: str = "tpu",
                    rows_per_group: int = 0,
-                   debug_info: bool = False) -> str:
+                   debug_info: bool = False,
+                   valid_rows: int = 0) -> str:
     """AOT-lower ONE fused boosting step for ``platform`` and return
     its StableHLO text — the exact program ``train()`` dispatches per
     iteration (bench.py's hot loop), checkable on any host. Used by
@@ -2122,7 +2145,9 @@ def aot_lower_step(cfg: TrainConfig, n: int, num_f: int,
 
     ``rows_per_group``: > 0 builds lambdarank group structure (uniform
     query sizes) with the bucketed pairwise layout. ``debug_info``
-    keeps the locations, which carry the ``gbdt.*`` scope of every op."""
+    keeps the locations, which carry the ``gbdt.*`` scope of every op.
+    ``valid_rows``: > 0 gives the step one validation set of that many
+    rows, as ``train(valid_sets=...)`` would (not with lambdarank)."""
     import jax
     import jax.numpy as jnp
 
@@ -2135,16 +2160,19 @@ def aot_lower_step(cfg: TrainConfig, n: int, num_f: int,
     # real TPU run (backend == tpu) never selects
     with env_override("MMLSPARK_TPU_NATIVE_HIST", "0"):
         return _aot_lower_step_inner(cfg, n, num_f, k, platform,
-                                     rows_per_group, debug_info)
+                                     rows_per_group, debug_info,
+                                     valid_rows)
 
 
 def _aot_lower_step_inner(cfg: TrainConfig, n: int, num_f: int, k: int,
                           platform: str, rows_per_group: int,
-                          debug_info: bool) -> str:
+                          debug_info: bool, valid_rows: int) -> str:
     import jax
     import jax.numpy as jnp
 
-    step_fn = _get_step_fn(num_f, cfg.max_bin, cfg, k, 0, "serial", None)
+    n_valid = 1 if valid_rows > 0 else 0
+    step_fn = _get_step_fn(num_f, cfg.max_bin, cfg, k, n_valid, "serial",
+                           None)
     rng = np.random.default_rng(0)
     ones = jnp.ones(n, jnp.float32)
     if cfg.objective == "lambdarank":
@@ -2173,10 +2201,18 @@ def _aot_lower_step_inner(cfg: TrainConfig, n: int, num_f: int, k: int,
         "base": jnp.float32(0.0),
         "key": jax.random.key(0),
         "lr": jnp.float32(0.1),
-        "valids": (),
+        # a validation set as train() stages one: int32 bins
+        "valids": tuple({
+            "binned": jnp.asarray(rng.integers(
+                0, cfg.max_bin, size=(valid_rows, num_f)).astype(np.int32)),
+            "labels": jnp.zeros(valid_rows, jnp.float32),
+            "weights": jnp.ones(valid_rows, jnp.float32),
+            "groups": None} for _ in range(n_valid)),
     }
     raw_shape = (n,) if k == 1 else (n, k)
-    carry = (jnp.zeros(raw_shape, jnp.float32), ())
+    carry = (jnp.zeros(raw_shape, jnp.float32),
+             tuple(jnp.zeros((valid_rows,) + raw_shape[1:], jnp.float32)
+                   for _ in range(n_valid)))
     # step_fn is already jitted by _make_step_fn
     return step_fn.trace(data, carry, jnp.int32(0)).lower(
         lowering_platforms=(platform,)).as_text(debug_info=debug_info)
@@ -2447,8 +2483,18 @@ def train(binned: np.ndarray, labels: np.ndarray, cfg: TrainConfig,
                                                    mesh)
         from mmlspark_tpu.models.gbdt.hist_pallas import (
             resolve_pallas_interpret)
+        # the eager loop: DART's dropped-tree set and a custom
+        # objective's host code fit no fixed-shape step, and the
+        # leaf-wise frontier is grown on the host
+        eager_loop = (cfg.boosting_type == "dart"
+                      or custom_objective is not None
+                      or grow_policy == "leafwise")
         hist_stats: Dict[str, object] = {
             "grow_policy": grow_policy, "hist_quant": "off",
+            # how the training rows' raw scores take each new tree: from
+            # the slot the builder left each row in (the fused step), or
+            # by a second walk of the finished tree (the eager loop)
+            "raw_update": "tree_walk" if eager_loop else "builder_leaf",
             # the kernel and tree learner THIS fit resolved (not a
             # caller-side re-resolution, which disagrees whenever a mesh
             # is attached), and whether the Pallas kernel was compiled
@@ -2565,8 +2611,7 @@ def train(binned: np.ndarray, labels: np.ndarray, cfg: TrainConfig,
 
     try:
         with resilience.fit_watchdog("gbdt.train"):
-            if (cfg.boosting_type == "dart" or custom_objective is not None
-                    or grow_policy == "leafwise"):
+            if eager_loop:
                 trees, tree_weights, evals, best_iter = _train_loop(
                     cfg, k, num_f, total_bins, depth, binned_d, labels_d,
                     weights_d, group_ids_dev, raw, valid_states,
@@ -3083,13 +3128,15 @@ def _train_loop(cfg, k, num_f, total_bins, depth, binned_d, labels_d,
                         kw["hist_token"] = hist_token
                     if binned_hist is not None:
                         kw["binned_hist"] = binned_hist
+                # the leaf-wise builder routes on the host and returns
+                # no ``node``; this loop walks every tree either way
                 sf, tb, nv, cnt, dt, bgl = build_tree(
                     binned_d, jnp.asarray(gc, jnp.float32),
                     jnp.asarray(hc, jnp.float32),
                     sample_mask.astype(jnp.float32),
                     jnp.asarray(feat_mask),
                     jnp.int32(cfg.num_leaves if cfg.num_leaves > 0 else 2 ** depth),
-                    **kw)
+                    **kw)[:6]
             nv = nv * (1.0 if is_rf else cfg.learning_rate)
             trees_sf.append(np.asarray(sf))
             trees_tb.append(np.asarray(tb))

@@ -307,3 +307,27 @@ def test_the_lowered_step_carries_every_scope_and_the_kernels_name(
     assert "name=gbdt_level_hist" in jaxpr
     # hist_kernel_roofline finds the kernel by this result shape
     assert "f32[4,28,8,256]" in jaxpr
+
+
+@pytest.mark.parametrize("valid_rows", [0, 512])
+def test_the_lowered_step_reads_one_bin_a_row_once_a_route_level(
+        monkeypatch, valid_rows):
+    """The tree is not walked again over the rows it was built on: at
+    depth 6 the (N, 28) binned matrix feeds six one-bin-a-row gathers,
+    one a route level (it was twelve, with the walk's six); rows no
+    builder routed, a validation set's, still take the walk's six."""
+    monkeypatch.setenv("MMLSPARK_TPU_PALLAS_HIST", "1")
+    monkeypatch.setenv("MMLSPARK_TPU_PALLAS_FORCE_COMPILE", "1")
+    from mmlspark_tpu.models.gbdt.trainer import TrainConfig, aot_lower_step
+
+    cfg = TrainConfig(objective="binary", num_leaves=63, max_depth=6,
+                      max_bin=255, min_data_in_leaf=20)
+    text = aot_lower_step(cfg, n=2048, num_f=28, platform="tpu",
+                          debug_info=True, valid_rows=valid_rows)
+    # take_along_axis(binned, feature[:, None], 1), by the rows it reads
+    reads = re.findall(r"call @take_along_axis\w*\(.*: \(tensor<(\d+)x28x"
+                       r"(?:ui8|i32)>, tensor<\d+x1xi32>\)", text)
+    assert reads.count("2048") == 6
+    assert reads.count("512") == (6 if valid_rows else 0)
+    assert len(reads) == (12 if valid_rows else 6)
+    assert "gbdt.predict" in text and "gbdt.route" in text
