@@ -99,7 +99,8 @@ def log_stage_method(uid: str, class_name: str, method: str,
     spans: the body runs under ``span("<Class>.<method>", uid=uid)``
     (core/timer.py), and the record emitted at the end carries the
     root's ``start_s``/``end_s`` (``time.perf_counter()``) and
-    ``spans``, everything that closed beneath it, in order of start."""
+    ``spans``, everything that closed beneath it, in order of start, and
+    ``counts``, what the stage counted on the root itself."""
     record: Dict[str, Any] = {
         "uid": uid,
         "className": class_name,
@@ -118,4 +119,6 @@ def log_stage_method(uid: str, class_name: str, method: str,
         record["seconds"] = root.end_s - root.start_s
         record["start_s"], record["end_s"] = root.start_s, root.end_s
         record["spans"] = [s.as_record() for s in root.spans]
+        if root.counts:
+            record["counts"] = dict(root.counts)
         SINK.emit(record)

@@ -111,6 +111,13 @@ class span:
         return record
 
 
+def current_span() -> Optional[span]:
+    """The innermost span open on this thread; directly inside a stage's
+    ``_fit``/``_transform`` that is the root ``log_stage_method`` opened,
+    whose ``counts`` go into the stage's telemetry record."""
+    return _CURRENT.get()
+
+
 class StopWatch:
     def __init__(self):
         self._start: Optional[float] = None

@@ -1,0 +1,308 @@
+"""Causal language model as a transformer stage: a column of prompts
+in, a column of greedy completions (and their log-probabilities) out.
+
+Parity: SynapseML's ``HuggingFaceCausalLM`` (a ``Transformer`` from a
+prompt column to a completion column, batched generation behind it).
+The model is :class:`~mmlspark_tpu.dl.backbones.RetentionLM`, whose
+layers keep a recurrent state and no key-value cache, so a sequence
+costs the same device memory whatever its length, and a device batch is
+sized by state bytes.
+
+One ``transform()``: the ragged prompts are sorted by length and cut
+into device batches (``ShardedScorer.length_batches``: the row ladder
+and the length ladder), each batch padded to its rungs (``lm.stack``)
+and scored by the shared engine, which pads rows, places, dispatches
+and fetches (``scorer.*``). A batch runs two programs: ``lm_prefill``
+absorbs the prompt a chunk of tokens at a time with the state as the
+scan's carry; ``lm_generate`` takes that state donated and decodes
+``maxNewTokens`` greedy tokens in one ``lax.scan``. Padding never
+touches the state, so a row's output does not depend on its rungs or
+its neighbours.
+"""
+
+from __future__ import annotations
+
+from typing import Optional
+
+import numpy as np
+
+from mmlspark_tpu.core.dataframe import DataFrame
+from mmlspark_tpu.core.param import (
+    HasInputCol, HasOutputCol, Param, gt, to_bool, to_int, to_str,
+)
+from mmlspark_tpu.core.pipeline import Transformer
+from mmlspark_tpu.core.timer import current_span, span
+
+
+def _free_device_bytes() -> Optional[int]:
+    import jax
+
+    stats = jax.local_devices()[0].memory_stats() or {}
+    if "bytes_limit" not in stats:
+        return None
+    return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+
+
+class CausalLM(Transformer, HasInputCol, HasOutputCol):
+    modelConfig = Param("modelConfig", "the model's config.json as a dict "
+                        "(hidden_size, num_attention_heads, "
+                        "num_key_value_heads, head_dim, intermediate_size, "
+                        "vocab_size, num_hidden_layers, rms_norm_eps, "
+                        "rope_theta, torch_dtype)", is_complex=True)
+    maxNewTokens = Param("maxNewTokens", "tokens generated a row (greedy, "
+                         "no early stop)", to_int, gt(0), default=32)
+    batchSize = Param("batchSize", "rows a device batch; unset, the "
+                      "largest power of two whose retention state fits "
+                      "half the device's free memory", to_int, gt(0))
+    maxLength = Param("maxLength", "longest prompt in tokens (longer ones "
+                      "keep their last maxLength tokens)", to_int, gt(0),
+                      default=1024)
+    prefillChunk = Param("prefillChunk", "tokens a row absorbed a prefill "
+                         "step", to_int, gt(0), default=128)
+    seed = Param("seed", "seed of the weights when none are given",
+                 to_int, default=0)
+    logProbsCol = Param("logProbsCol", "output column of the generated "
+                        "tokens' log-probabilities", to_str,
+                        default="logprobs")
+    logitsCol = Param("logitsCol", "output column of every generated "
+                      "position's logits (maxNewTokens x vocab a row; "
+                      "unset: not returned)", to_str)
+    allowRandomWeights = Param(
+        "allowRandomWeights", "explicitly allow seeded random weights "
+        "(completions then carry NO meaning)", to_bool, default=False)
+
+    _weights = None          # the model's parameter pytree, when given
+    _module = None
+    _scorer = None
+
+    def set_weights(self, params) -> "CausalLM":
+        """Use this parameter pytree (the structure of
+        ``backbones.lm_param_shapes(modelConfig)``)."""
+        self._weights = params
+        self._scorer = None
+        return self
+
+    # -- the model -----------------------------------------------------
+
+    def _config(self) -> dict:
+        config = self.get("modelConfig")
+        if not config:
+            raise ValueError("CausalLM needs modelConfig: the model's "
+                             "config.json as a dict")
+        return dict(config)
+
+    def _ensure_weights(self):
+        from mmlspark_tpu.dl.backbones import lm_init_params
+
+        if self._weights is None:
+            if not self.get("allowRandomWeights"):
+                raise ValueError(
+                    "CausalLM has no weights: give it a parameter pytree "
+                    "with set_weights(params), load a saved stage, or opt "
+                    "in to seeded random weights with "
+                    "allowRandomWeights=True (completions then carry NO "
+                    "meaning)")
+            self._weights = lm_init_params(self._config(),
+                                           self.get("seed"))
+        return self._weights
+
+    def _batch_rows(self) -> int:
+        from mmlspark_tpu.dl.backbones import lm_state_bytes
+
+        if self.is_set("batchSize"):
+            return self.get("batchSize")
+        free = _free_device_bytes()
+        if free is None:                     # no memory statistics: the CPU
+            return 8
+        rows = 1
+        while (rows < 1024 and
+               lm_state_bytes(self._config(), rows * 2) <= free // 2):
+            rows *= 2
+        return rows
+
+    def _ensure_scorer(self):
+        from mmlspark_tpu.dl.backbones import RetentionLM, lm_dtype
+        from mmlspark_tpu.parallel.shard_rules import ShardedScorer
+
+        if self._scorer is None:
+            config = self._config()
+            self._module = RetentionLM(config)
+            self._scorer = ShardedScorer(
+                self._generate, self._ensure_weights(), family="dl",
+                max_batch=self._batch_rows(), label="causal_lm",
+                param_dtype=lm_dtype(config),
+                max_length=self.get("maxLength"), jit=False)
+        return self._scorer
+
+    def _program(self, name: str, with_logits: bool = False):
+        """The two jitted programs of a device batch, built once a
+        setting: ``lm_prefill`` and ``lm_generate`` (their names are what
+        a profiler's module line shows). Ids, the last hidden state and
+        the retention state are donated."""
+        import jax
+
+        programs = self.__dict__.setdefault("_programs", {})
+        key = (name, with_logits, self.get("maxNewTokens"),
+               self.get("prefillChunk"))
+        if key not in programs:
+            if name == "lm_prefill":
+                fn = self._lm_prefill
+            else:
+                def lm_generate(params, last, state):
+                    return self._decode(params, last, state, with_logits)
+                fn = lm_generate
+            donate = () if jax.default_backend() == "cpu" else (1, 2)
+            programs[key] = jax.jit(fn, donate_argnums=donate)
+        return programs[key]
+
+    def _lm_prefill(self, params, ids, lengths):
+        """``(hidden after each row's last prompt token, state)``: the
+        prompt absorbed ``prefillChunk`` tokens a row at a time, the
+        state the scan's carry."""
+        import jax
+        import jax.numpy as jnp
+
+        from mmlspark_tpu.dl.backbones import lm_init_state
+
+        config = self._config()
+        rows, length = ids.shape
+        chunk = min(self.get("prefillChunk"), length)
+        steps = -(-length // chunk)
+        ids = jnp.pad(ids, ((0, 0), (0, steps * chunk - length)))
+        ids = jnp.moveaxis(ids.reshape(rows, steps, chunk), 1, 0)
+
+        def step(carry, xs):
+            state, last = carry
+            chunk_ids, start = xs
+            real = jnp.clip(lengths - start, 0, chunk)
+            h, state = self._module.apply(params, chunk_ids, real, state,
+                                          method="hidden")
+            return (state, jnp.where((real > 0)[:, None], h, last)), None
+
+        first = (lm_init_state(config, rows),
+                 jnp.zeros((rows, config["hidden_size"]), jnp.float32))
+        (state, last), _ = jax.lax.scan(
+            step, first, (ids, jnp.arange(steps) * chunk))
+        return last, state
+
+    def _decode(self, params, last, state, with_logits):
+        """``maxNewTokens`` greedy tokens a row in one scan, the state its
+        carry: ``({"tokens", "logprobs"[, "logits"]}, state)``."""
+        import jax
+        import jax.numpy as jnp
+
+        def emit(logits):
+            token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            chosen = jnp.take_along_axis(logits, token[:, None], axis=1)
+            logprob = chosen[:, 0] - jax.nn.logsumexp(logits, axis=-1)
+            out = {"tokens": token, "logprobs": logprob}
+            return dict(out, logits=logits) if with_logits else out
+
+        def step(carry, _):
+            logits, state = carry
+            out = emit(logits)
+            token = out["tokens"]
+            logits, state = self._module.apply(
+                params, token[:, None], jnp.ones_like(token), state)
+            return (logits, state), out
+
+        logits = self._module.apply(params, last, method="head")
+        (logits, state), outs = jax.lax.scan(
+            step, (logits, state), None, length=self.get("maxNewTokens") - 1)
+        final = emit(logits)
+        outs = {k: jnp.concatenate([jnp.moveaxis(v, 0, 1),
+                                    final[k][:, None]], axis=1)
+                for k, v in outs.items()}
+        # the state goes out again so that the donated buffers have an
+        # output to alias: the scan then updates them in place, and a
+        # second copy of the state (which would not fit) is never made
+        return outs, state
+
+    def _generate(self, params, batch):
+        """What the engine calls a device batch with: two programs."""
+        last, state = self._program("lm_prefill")(
+            params, batch["ids"], batch["lengths"])
+        out, _ = self._program("lm_generate", self.is_set("logitsCol"))(
+            params, last, state)
+        return out
+
+    # -- the stage -----------------------------------------------------
+
+    def _prompts(self, dataset: DataFrame):
+        col = dataset.col(self.get("inputCol"))
+        config = self._config()
+        if len(col) and isinstance(col[0], str):
+            from mmlspark_tpu.dl.text import hash_tokenize
+            ids = hash_tokenize([str(v) for v in col], self.get("maxLength"),
+                                config["vocab_size"])
+            return [row[:max(int((row > 0).sum()), 1)] for row in ids]
+        return [np.asarray(v, np.int32).reshape(-1)[-self.get("maxLength"):]
+                for v in col]
+
+    def _transform(self, dataset: DataFrame) -> DataFrame:
+        from mmlspark_tpu.dl.backbones import lm_state_bytes
+
+        scorer = self._ensure_scorer()
+        new = self.get("maxNewTokens")
+        root = current_span()
+        with span("lm.stack", rows=dataset.num_rows) as stack:
+            prompts = self._prompts(dataset)
+            lengths = np.array([len(p) for p in prompts], np.int32)
+            batches = []
+            for index, rung in scorer.length_batches(lengths):
+                ids = np.zeros((len(index), rung), np.int32)
+                for row, i in enumerate(index):
+                    ids[row, :lengths[i]] = prompts[i]
+                batches.append((index, {"ids": ids,
+                                        "lengths": lengths[index]}))
+            padded = sum(b["ids"].size for _, b in batches)
+            stack.counts.update(prompt_tokens=int(lengths.sum()),
+                                padded_tokens=int(padded))
+        outputs = [(index, scorer(batch)) for index, batch in batches]
+        with span("lm.columns"):
+            out = dataset
+            names = {"tokens": self.get("outputCol"),
+                     "logprobs": self.get("logProbsCol"),
+                     "logits": self.get("logitsCol")}
+            for key, name in names.items():
+                if not outputs or key not in outputs[0][1]:
+                    continue
+                first = outputs[0][1][key]
+                column = np.zeros((len(prompts),) + first.shape[1:],
+                                  first.dtype)
+                for index, scored in outputs:
+                    column[index] = scored[key]
+                if column.ndim > 2:          # ragged-safe object column
+                    boxed = np.empty(len(column), dtype=object)
+                    for i in range(len(column)):
+                        boxed[i] = column[i]
+                    column = boxed
+                out = out.with_column(name, column)
+        if root is not None:
+            rows = max((len(index) for index, _ in batches), default=0)
+            root.counts.update(
+                new_tokens=int(new * len(prompts)),
+                state_bytes=int(lm_state_bytes(self._config(), rows)),
+                length_rung=int(max((b["ids"].shape[1] for _, b in batches),
+                                    default=0)))
+        return out
+
+    # -- persistence (as DeepModel: leaves in order) -------------------
+
+    def _get_state(self):
+        import jax
+
+        flat, _ = jax.tree_util.tree_flatten(self._ensure_scorer()._params)
+        return {f"p{i}": np.asarray(v, np.float32)
+                for i, v in enumerate(flat)}
+
+    def _set_state(self, state):
+        import jax
+
+        from mmlspark_tpu.dl.backbones import lm_param_shapes
+
+        shapes = lm_param_shapes(self._config())
+        flat, treedef = jax.tree_util.tree_flatten(shapes)
+        self._weights = jax.tree_util.tree_unflatten(
+            treedef, [state[f"p{i}"] for i in range(len(flat))])
+        self._scorer = None

@@ -10,7 +10,7 @@ collectives in the scoring graph at all.
 
 from __future__ import annotations
 
-from typing import Any, Callable, List, Optional
+from typing import Any, Callable, List, Optional, Tuple
 
 import numpy as np
 
@@ -62,6 +62,32 @@ def bucket_for(n: int, ladder: List[int]) -> int:
         if n <= b:
             return b
     return ladder[-1]
+
+
+def length_ladder(max_length: int, floor: int = 128) -> List[int]:
+    """Padding ladder over sequence length: powers of two from
+    ``floor`` up, ending at the first one that holds ``max_length``."""
+    ladder, rung = [], floor
+    while rung < max_length:
+        ladder.append(rung)
+        rung *= 2
+    ladder.append(rung)
+    return ladder
+
+
+def length_batches(lengths: np.ndarray, rows: int, ladder: List[int]
+                   ) -> List[Tuple[np.ndarray, int]]:
+    """Device batches of ragged sequences: rows sorted by length (stable)
+    and cut into runs of ``rows``; each run pads to the ladder rung of
+    its longest row, so rows of like length share a batch and a short
+    row is never padded to the column's longest. Returns ``[(row
+    indices, length rung)]``."""
+    order = np.argsort(np.asarray(lengths), kind="stable")
+    out = []
+    for start in range(0, len(order), max(int(rows), 1)):
+        index = order[start:start + rows]
+        out.append((index, bucket_for(int(lengths[index[-1]]), ladder)))
+    return out
 
 
 def sharded_apply(fn: Callable, x: Any, mesh, axis: str = DATA_AXIS):

@@ -24,8 +24,8 @@ arXiv:1605.08695 for a single placement layer under many workloads):
 
 Bitwise contract: the engine's unit of compilation is a fixed
 per-device micro-batch rung chosen from the ladder by row count
-only — never by mesh size — and each dispatch feeds ``dp x rung``
-rows sharded over ``dp``. XLA:CPU (and TPU) matmul numerics vary
+(and, for ragged sequences, a second rung by length) — never by mesh
+size — and each dispatch feeds ``dp x rung`` rows sharded over ``dp``. XLA:CPU (and TPU) matmul numerics vary
 with the batch dimension, so keeping the per-device shape constant
 across dp is what makes dp=1/2/8 outputs bitwise-identical to each
 other and to the serial chunked path (pinned by
@@ -402,20 +402,38 @@ class ShardedScorer:
 
     On construction the params shard once onto the mesh under their
     family rule table and stay resident — no per-batch ``device_put``
-    of model state. Each call picks a per-device rung from the pow2
-    ladder (by row count only), pads with zero rows, and dispatches
-    ``dp x rung`` rows sharded over ``dp``; compile count is bounded
-    by the ladder and counted under graftsan. Input buffers are
-    donated on non-CPU backends (XLA:CPU device_put aliases host
-    numpy, so donation there could hand the user's buffer to XLA).
+    of model state; ``param_dtype`` is the dtype the model itself
+    states (float leaves are placed in it through ``placement_cast``;
+    unset, the ``MMLSPARK_TPU_INFER_AUTOCAST`` policy decides). Each
+    call picks a per-device rung from the pow2 ladder by row count,
+    pads with zero rows, and dispatches ``dp x rung`` rows sharded over
+    ``dp``; compile count is bounded by the ladder and counted under
+    graftsan. Input buffers are donated on non-CPU backends (XLA:CPU
+    device_put aliases host numpy, so donation there could hand the
+    user's buffer to XLA).
+
+    Ragged sequences take a second ladder, over length
+    (``max_length``): ``length_batches(lengths)`` sorts the rows by
+    length and cuts them into device batches, each padded to the length
+    rung of its longest row; the caller pads each batch to its rung and
+    scores it as a dict batch (``{"ids", "lengths"}``), so one program
+    compiles a (row rung, length rung). With ``jit=False`` the engine
+    places the params and calls ``apply_fn(params, batch)`` as it is: a
+    stage whose call is several programs (a prefill, then a decode that
+    takes the state donated) brings its own, named, jits.
     """
 
     def __init__(self, apply_fn: Callable, params=None,
                  family: str = "gbdt", mesh=None, *,
-                 max_batch: int = 1024, label: str = "scorer"):
+                 max_batch: int = 1024, label: str = "scorer",
+                 param_dtype=None, max_length: Optional[int] = None,
+                 jit: bool = True):
         import jax
 
-        from mmlspark_tpu.parallel.inference import bucket_ladder
+        from mmlspark_tpu.parallel.inference import (
+            bucket_ladder,
+            length_ladder,
+        )
 
         if family not in FAMILY_RULES:
             raise ValueError(f"unknown model family {family!r}; "
@@ -427,10 +445,12 @@ class ShardedScorer:
         self._dp = (axis_size(self._mesh, DATA_AXIS)
                     if self.mode == "rules" else 1)
         self._ladder = bucket_ladder(max(int(max_batch), 1))
+        self._length_ladder = (length_ladder(int(max_length))
+                               if max_length else None)
         self._seen_rungs: set = set()
         self.autocast = resolve_infer_autocast()
-        dtype = None
-        if self.autocast == "bf16":
+        dtype = param_dtype
+        if dtype is None and self.autocast == "bf16":
             import jax.numpy as jnp
             dtype = jnp.bfloat16
         if params is not None:
@@ -442,8 +462,9 @@ class ShardedScorer:
             self._params = jax.tree_util.tree_map(
                 lambda f, x: f(x), shard_fns, params)
             donate = (1,) if jax.default_backend() != "cpu" else ()
-            self._call = jax.jit(lambda p, x: apply_fn(p, x),
-                                 donate_argnums=donate)
+            self._call = (jax.jit(lambda p, x: apply_fn(p, x),
+                                  donate_argnums=donate)
+                          if jit else apply_fn)
         else:
             self._params = None
             self._call = apply_fn  # caller supplies a jitted closure
@@ -454,6 +475,18 @@ class ShardedScorer:
         from mmlspark_tpu.parallel.inference import bucket_for
 
         return bucket_for(max(n, 1), self._ladder)
+
+    def length_batches(self, lengths) -> List[Tuple[np.ndarray, int]]:
+        """``[(row indices, length rung)]``: the device batches of a
+        ragged column (``parallel/inference.length_batches``), ``dp x``
+        the top row rung rows each."""
+        from mmlspark_tpu.parallel.inference import length_batches
+
+        if self._length_ladder is None:
+            raise ValueError("this scorer was built without max_length")
+        return length_batches(np.asarray(lengths),
+                              self._dp * self._ladder[-1],
+                              self._length_ladder)
 
     def _row_sharding(self, ndim: int):
         import jax
@@ -501,10 +534,14 @@ class ShardedScorer:
         n = next(iter(cols.values())).shape[0]
         r = self._rung(n)
         step = self._dp * r
-        if r not in self._seen_rungs:
-            self._seen_rungs.add(r)
+        # one program a row rung; with a length ladder, a (row rung,
+        # length rung), the length being the batch's second dimension
+        seen = r if self._length_ladder is None else (
+            r, max(v.shape[1] for v in cols.values() if v.ndim > 1))
+        if seen not in self._seen_rungs:
+            self._seen_rungs.add(seen)
             sanitizer.count_recompile(
-                f"shard_rules {self.family}:{self.label} rung {r} "
+                f"shard_rules {self.family}:{self.label} rung {seen} "
                 f"(global {step})")
         chunks = []
         for g in range(0, max(n, 1), step):

@@ -107,6 +107,7 @@ def build_registry() -> Dict[str, TestObject]:
                                             PartitionedStandardScaler)
     from mmlspark_tpu.dl.text import DeepTextClassifier
     from mmlspark_tpu.dl.vision import DeepVisionClassifier
+    from mmlspark_tpu.dl.causal_lm import CausalLM
     from mmlspark_tpu.dl.embedder import SentenceEmbedder
     from mmlspark_tpu.exploratory.balance import (AggregateBalanceMeasure,
                                                   DistributionBalanceMeasure,
@@ -445,6 +446,17 @@ def build_registry() -> Dict[str, TestObject]:
                              allowRandomEncoder=True,
                              embeddingDim=16, numLayers=1, numHeads=2),
             tab.head(8), skip_serialization=True),
+        "CausalLM": TestObject(
+            CausalLM(inputCol="text", outputCol="completion",
+                     modelConfig=dict(
+                         hidden_size=32, num_attention_heads=2,
+                         num_key_value_heads=1, head_dim=16,
+                         intermediate_size=64, vocab_size=128,
+                         num_hidden_layers=1, rms_norm_eps=1e-6,
+                         rope_theta=1e4),
+                     maxNewTokens=3, batchSize=4, maxLength=16,
+                     prefillChunk=8, allowRandomWeights=True),
+            tab.head(8), approx=1e-5),
         # image
         "ImageTransformer": TestObject(
             ImageTransformer(inputCol="image", outputCol="out").resize(8, 8),

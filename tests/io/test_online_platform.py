@@ -187,6 +187,12 @@ def test_serving_tap_feeds_refresh_buffer(base, tmp_path):
         faults.arm("serving.observe_log", "raise", count=1)
         reply = _post(server.url, {"features": x[4].tolist()})
         assert reply["prediction"] == _local_pred(model, x[4])
+        # the reply leaves before the tap runs, so on a loaded host the
+        # counter may lag the reply by a moment
+        deadline = time.monotonic() + 5.0
+        while (server._health()["log_tap_errors"] == 0
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
         assert server._health()["log_tap_errors"] == 1
         assert ctrl.buffer.rows == 4
 

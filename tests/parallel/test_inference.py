@@ -3,6 +3,7 @@
 onnx/ONNXModel.scala:242-251)."""
 
 import numpy as np
+import pytest
 
 from mmlspark_tpu.core.dataframe import DataFrame
 
@@ -98,3 +99,72 @@ def test_onnx_sharded_scoring_matches(mesh8, rng):
         np.asarray(list(single["output"]), np.float64),
         np.asarray(list(sharded["output"]), np.float64),
         rtol=1e-5, atol=1e-6)
+
+
+def test_length_ladder_and_batches_group_rows_of_like_length():
+    from mmlspark_tpu.parallel.inference import (
+        length_batches,
+        length_ladder,
+    )
+
+    assert length_ladder(1024) == [128, 256, 512, 1024]
+    assert length_ladder(100) == [128]
+    assert length_ladder(1025) == [128, 256, 512, 1024, 2048]
+    lengths = np.array([900, 130, 40, 300, 128, 129, 700])
+    batches = length_batches(lengths, 3, length_ladder(1024))
+    # sorted by length and cut into runs of 3; a run takes the rung of
+    # its longest row, so the 40-token row is never padded to 1024
+    assert [(list(i), r) for i, r in batches] == [
+        ([2, 4, 5], 256), ([1, 3, 6], 1024), ([0], 1024)]
+    assert sorted(int(i) for index, _ in batches for i in index) == list(
+        range(len(lengths)))
+
+
+def test_scorer_places_params_in_the_dtype_the_model_states(monkeypatch):
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.parallel.shard_rules import ShardedScorer
+
+    monkeypatch.delenv("MMLSPARK_TPU_INFER_AUTOCAST", raising=False)
+    params = {"kernel": np.ones((4, 3), np.float32),
+              "steps": np.arange(3, dtype=np.int32)}
+    scorer = ShardedScorer(lambda p, x: x @ p["kernel"].astype(jnp.float32),
+                           params, family="dl", max_batch=4,
+                           param_dtype=jnp.bfloat16)
+    assert scorer._params["kernel"].dtype == jnp.bfloat16
+    assert scorer._params["steps"].dtype == jnp.int32      # not a float
+    assert scorer.metadata()["infer_autocast"] == "off"
+    out = scorer(np.ones((3, 4), np.float32))
+    assert out.shape == (3, 3) and np.allclose(out, 4.0)
+    with pytest.raises(ValueError, match="max_length"):
+        scorer.length_batches([1, 2])
+
+
+def test_scorer_with_a_length_ladder_counts_a_program_a_pair_of_rungs():
+    """``jit=False``: the engine places the params and calls the stage's
+    own programs; a dict batch ``{"ids", "lengths"}`` compiles once a
+    (row rung, length rung)."""
+    from mmlspark_tpu.core import sanitizer
+    from mmlspark_tpu.parallel.shard_rules import ShardedScorer
+
+    seen = []
+
+    def apply(params, batch):
+        seen.append(tuple(batch["ids"].shape))
+        return {"sum": np.asarray(batch["ids"]).sum(axis=1)
+                + params["bias"]}
+
+    scorer = ShardedScorer(apply, {"bias": np.float32(1.0)}, family="dl",
+                           max_batch=4, max_length=300, jit=False)
+    lengths = np.array([5, 200, 7, 130, 9])
+    counted = []
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(sanitizer, "count_recompile", counted.append)
+        for index, rung in scorer.length_batches(lengths):
+            ids = np.ones((len(index), rung), np.int32)
+            out = scorer({"ids": ids, "lengths": lengths[index]})
+            assert np.allclose(out["sum"], rung + 1.0)
+            assert out["sum"].shape == (len(index),)
+    # 4 rows at the 256 rung (lengths 5, 7, 9, 130), 1 row at 256
+    assert seen == [(4, 256), (1, 256)]
+    assert len(counted) == 2 and "(4, 256)" in counted[0]

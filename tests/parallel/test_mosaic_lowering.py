@@ -510,3 +510,40 @@ def test_voting_builder_with_onehot_lowers_for_tpu(monkeypatch):
             jnp.int32(15))
     txt = _lower_tpu(fn, *args)
     assert "dot" in txt or len(txt) > 1000
+
+
+def test_retention_kernels_lower_to_mosaic_at_the_published_widths():
+    """Both power-retention kernels at Brumby's head shapes (8 key-value
+    heads of 128, 5 query heads each, a 128-token chunk): the dynamic
+    lane roll, the transposed products at HIGHEST and the in-place state
+    go through the Mosaic pipeline."""
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.parallel import retention as R
+
+    b, kv, heads, d, c = 2, 8, 40, 128, 128
+    f32 = jnp.float32
+    state = {k: jax.ShapeDtypeStruct(shape, f32)
+             for k, shape in R.state_shapes(b, kv, d).items()}
+
+    def step(q, k, v, log_g, state):
+        return R.retention_step(q, k, v, log_g, state, scale=d ** -0.5,
+                                pallas=True)
+
+    txt = _lower_tpu(step, jax.ShapeDtypeStruct((b, heads, d), f32),
+                     jax.ShapeDtypeStruct((b, kv, d), f32),
+                     jax.ShapeDtypeStruct((b, kv, d), f32),
+                     jax.ShapeDtypeStruct((b, kv), f32), state)
+    assert "tpu_custom_call" in txt and "retention_decode" in txt
+
+    def prefill(q, k, v, log_g, lengths, state):
+        return R.retention_prefill(q, k, v, log_g, lengths, state,
+                                   scale=d ** -0.5, chunk=c, pallas=True)
+
+    txt = _lower_tpu(prefill, jax.ShapeDtypeStruct((b, 2 * c, heads, d), f32),
+                     jax.ShapeDtypeStruct((b, 2 * c, kv, d), f32),
+                     jax.ShapeDtypeStruct((b, 2 * c, kv, d), f32),
+                     jax.ShapeDtypeStruct((b, 2 * c, kv), f32),
+                     jax.ShapeDtypeStruct((b,), jnp.int32), state)
+    assert "tpu_custom_call" in txt and "retention_prefill" in txt
