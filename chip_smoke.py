@@ -235,45 +235,59 @@ def kernel_parity(binned, formulation: str) -> None:
     formulation the fit resolved and through ``per_feature``: counts
     exact, grad and hess to float-sum tolerance — the contract
     tests/gbdt/test_hist_pallas.py pins in interpret mode, here on
-    whatever compiled the kernel."""
+    whatever compiled the kernel. The Pallas kernel has two paths told
+    apart by the level's width (hist_pallas.level_feed): one width on
+    each side of the bound is checked."""
     import jax
     import jax.numpy as jnp
 
+    from mmlspark_tpu.models.gbdt.hist_pallas import (IN_PLACE_MAX_WIDTH,
+                                                      level_feed)
     from mmlspark_tpu.models.gbdt.trainer import _level_histogram
 
     n, f = binned.shape
     rng = np.random.default_rng(1)
-    args = (jnp.asarray(binned),
+    rows = (jnp.asarray(binned),
             jnp.asarray(rng.normal(size=n).astype(np.float32)),
             jnp.asarray(rng.uniform(0.1, 1.0, size=n).astype(np.float32)),
-            jnp.asarray((rng.random(n) < 0.9).astype(np.float32)),
-            jnp.asarray(rng.integers(0, HIST_WIDTH, size=n,
-                                     dtype=np.int32)))
-    results, seconds = {}, {}
-    for name in dict.fromkeys((formulation, "per_feature")):
-        fn = jax.jit(functools.partial(
-            _level_histogram, width=HIST_WIDTH, f=f, b=MAX_BIN,
-            formulation=name))
-        _, first_s = timed(lambda: jax.block_until_ready(fn(*args)))
-        out, second_s = timed(lambda: jax.block_until_ready(fn(*args)))
-        results[name], seconds[name] = np.asarray(out), (first_s, second_s)
-    got, ref = results[formulation], results["per_feature"]
-    check(got.shape == ref.shape == (HIST_WIDTH, f, MAX_BIN, 3),
-          f"kernel: shapes {got.shape} {ref.shape}")
-    check(bool(np.isfinite(got).all()), "kernel: non-finite histogram")
-    check(float(ref[..., 2].sum()) > 0, "kernel: empty reference")
-    check(np.array_equal(got[..., 2], ref[..., 2]),
-          "kernel: counts differ from per_feature")
-    err = np.abs(got[..., :2] - ref[..., :2])
-    tol = 1e-4 + 1e-5 * np.abs(ref[..., :2])
-    check(bool((err <= tol).all()),
-          f"kernel: grad/hess off by up to {float(err.max()):.3e} "
-          "(rtol 1e-5, atol 1e-4)")
+            jnp.asarray((rng.random(n) < 0.9).astype(np.float32)))
+    widths = [HIST_WIDTH]
+    if formulation == "pallas":
+        widths.append(2 * IN_PLACE_MAX_WIDTH)
+    facts = {}
+    for width in widths:
+        args = rows + (jnp.asarray(rng.integers(0, width, size=n,
+                                                dtype=np.int32)),)
+        results, seconds = {}, {}
+        for name in dict.fromkeys((formulation, "per_feature")):
+            fn = jax.jit(functools.partial(
+                _level_histogram, width=width, f=f, b=MAX_BIN,
+                formulation=name))
+            _, first_s = timed(lambda: jax.block_until_ready(fn(*args)))
+            out, second_s = timed(lambda: jax.block_until_ready(fn(*args)))
+            results[name], seconds[name] = np.asarray(out), (first_s,
+                                                             second_s)
+        got, ref = results[formulation], results["per_feature"]
+        check(got.shape == ref.shape == (width, f, MAX_BIN, 3),
+              f"kernel: shapes {got.shape} {ref.shape}")
+        check(bool(np.isfinite(got).all()), "kernel: non-finite histogram")
+        check(float(ref[..., 2].sum()) > 0, "kernel: empty reference")
+        check(np.array_equal(got[..., 2], ref[..., 2]),
+              f"kernel: counts differ from per_feature at width {width}")
+        err = np.abs(got[..., :2] - ref[..., :2])
+        tol = 1e-4 + 1e-5 * np.abs(ref[..., :2])
+        check(bool((err <= tol).all()),
+              f"kernel: grad/hess off by up to {float(err.max()):.3e} at "
+              f"width {width} (rtol 1e-5, atol 1e-4)")
+        facts[width] = {
+            "feed": level_feed(width) if formulation == "pallas" else None,
+            "max_abs_err": float(err.max()),
+            **{f"{name}_first_s": s[0] for name, s in seconds.items()},
+            **{f"{name}_second_s": s[1] for name, s in seconds.items()}}
     emit("kernel", formulation=formulation, reference="per_feature",
          rows=n, features=f, bins=MAX_BIN, width=HIST_WIDTH,
-         counts_exact=True, max_abs_err=float(err.max()),
-         **{f"{name}_first_s": s[0] for name, s in seconds.items()},
-         **{f"{name}_second_s": s[1] for name, s in seconds.items()},
+         counts_exact=True, **facts.pop(HIST_WIDTH),
+         other_widths={str(w): v for w, v in facts.items()},
          peak_bytes_in_use=peak_bytes())
 
 

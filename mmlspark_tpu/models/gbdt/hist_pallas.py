@@ -7,25 +7,45 @@ XLA lowers the ``segment_sum`` formulation in ``trainer._level_histogram``
 through a generic scatter; this kernel restructures the op for the TPU
 memory system instead of scattering at all:
 
-1. Rows are grouped by tree node (one ``argsort`` of the node index per
-   level) and each node's segment is padded to a whole number of
-   ``block_rows`` row blocks, so every grid step works on rows of ONE
-   node.
-2. A scalar-prefetched ``block -> node`` map routes each grid step's
-   output block: the (node, F, stats, bins) accumulator tile stays in
-   VMEM across the consecutive run of blocks that share a node (the
-   output index map is constant over that run) and is flushed to HBM
-   once per node, not once per row.
-3. Inside a block the per-feature histogram is an equality-compare
-   one-hot (rows x bins, built on the VPU) contracted against the
+1. Inside a row block the per-feature histogram is an equality-compare
+   one-hot (rows x bins, built on the VPU) contracted against a
    (stats x rows) matrix on the MXU — bin accumulation becomes a
    matmul, the operation shape TPUs are built for, instead of a
    data-dependent scatter.
+2. Nodes are told apart in one of two ways, chosen by the level's static
+   ``width`` alone (``level_feed``; nothing a user sets):
 
-Cost per row block per feature: R*B compares + an (S, R) @ (R, B)
-matmul. With B=256 padded bins that is ~1.5 KFLOP per (row, feature)
-update — far below MXU throughput, so the level histogram is
-bandwidth-bound on reading the binned matrix, which is the roofline.
+   - **in place** (``width <= IN_PLACE_MAX_WIDTH``): the grid runs over
+     ``binned`` in the order its rows lie. Each block takes its (R, F)
+     rows of bins and its (8, R) block of stats (grad*live, hess*live,
+     live, node index: element-wise writes, no gather), and the node is
+     a mask: the left operand is the node-expanded stats, row
+     ``s * wq + w`` being ``stats[s] * (node == w)``. One accumulator
+     for the whole level stays in VMEM across the grid and is written
+     to HBM once. No sort, no slot map, no copy of the matrix a level.
+   - **sorted** (wider levels): rows are grouped by node (one
+     ``argsort`` of the node index a level), each node's segment padded
+     to whole row blocks and gathered into that layout, so every grid
+     step works on rows of ONE node; a scalar-prefetched
+     ``block -> node`` map routes each step's output tile, which stays
+     in VMEM across the run of blocks that share a node. Its MXU work
+     does not grow with the width; its feed (the sort and two gathers
+     of every row) costs twice the kernel.
+
+3. Both paths take ``binned`` row-major: the device keeps a u8 (N, F)
+   array column-major, so XLA makes one row-major copy (F byte columns
+   padded to 128 lanes) where the kernel is called; inside the tree step
+   the six levels share one such copy a tree.
+
+What it costs (TPU v5e, one level at 20M x 28 x 255, my chip run, PR 30):
+the table beside ``IN_PLACE_MAX_WIDTH`` below. The kernel is NOT
+bandwidth-bound: a level reads 2.6 GB of lane-padded bins and 0.6 GB of
+stats (4 ms at the HBM peak) and takes 0.33 to 0.92 s in place, 0.52 s
+sorted, two orders of magnitude over its floor (``hist_kernel_roofline``
+about 1%). The time goes to the kernel's inside: building an (R, 256)
+one-hot a feature from (R, 1) lane slices on the VPU (what 0.33 s at
+width 1 is), and the product at ``HIGHEST`` (six bf16 passes; what grows
+with the width in place). ROADMAP S1 lists what is left there.
 
 The kernel accumulates in float32 in block order; results match the
 XLA formulations exactly on integer-valued grad/hess (no rounding) and
@@ -42,6 +62,17 @@ import numpy as np
 
 _SPAD = 8        # stats rows (grad, hess, count) padded to a sublane tile
 _BIN_PAD = 256   # bin axis padded to two full lane tiles
+# Widest level that takes the in-place path. Set by one level at
+# 20M x 28 x 255 on a TPU v5e, arrays passed as arguments, seconds a call
+# (tools/hist_level_ab.py; my chip run, PR 30), in place | sorted:
+#   width   1: 0.344 | 2.230      width  64: 1.769 | 1.705
+#   width   8: 0.384 | 1.460      width 128: 3.876 | 1.921
+#   width  32: 0.929 | 1.507      width 256: 7.346 | 1.943
+# The in-place kernel's time follows the rows of its left operand (3 x
+# width: 0.33 s at 8 rows, 0.92 s at 96, 7.33 s at 768: the MXU's six
+# passes at HIGHEST); the sorted kernel takes 0.52 s at every width and
+# its feed 0.94 to 1.71 s. They cross between 32 and 64.
+IN_PLACE_MAX_WIDTH = 32
 
 
 def pallas_histogram_enabled() -> bool:
@@ -72,12 +103,34 @@ def resolve_pallas_interpret() -> bool:
             and not env_flag("MMLSPARK_TPU_PALLAS_FORCE_COMPILE"))
 
 
+def _feature_sums(stats, bins_ref, fi: int, bin_pad: int):
+    """(rows of stats, bins) sums of one feature over a row block: the
+    (S, R) stats against the feature's (R, bin_pad) one-hot, the product
+    both kernels share."""
+    import jax
+    import jax.numpy as jnp
+
+    iota_b = jax.lax.broadcasted_iota(jnp.int32, (1, bin_pad), 1)
+    col = bins_ref[:, fi:fi + 1].astype(jnp.int32)      # (R, 1)
+    eq = (col == iota_b).astype(jnp.float32)            # (R, bin_pad)
+    # HIGHEST: at default precision the MXU rounds the f32 stats to
+    # bf16 (measured on the v5e, PR 22: counts stay exact, grad/hess
+    # sums come out up to 0.12 off at 2M rows), which breaks the
+    # float-sum parity contract with the XLA formulations; the
+    # one-hot operand is exact in bf16, so the multi-pass product
+    # is exact and only the accumulation order differs
+    return jax.lax.dot_general(
+        stats, eq, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+
+
 def _hist_kernel(bn_ref, bins_ref, data_ref, out_ref, *, num_features: int,
                  bin_pad: int):
-    """One row block (all rows belong to node ``bn_ref[i]``): add the
-    block's per-feature (stats, bins) sums into the node's accumulator.
+    """Sorted path. One row block (all rows belong to node ``bn_ref[i]``):
+    add the block's per-feature (stats, bins) sums into the node's
+    accumulator.
     """
-    import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
@@ -87,20 +140,8 @@ def _hist_kernel(bn_ref, bins_ref, data_ref, out_ref, *, num_features: int,
     first = (i == 0) | (node != prev)
 
     data = data_ref[...].astype(jnp.float32)           # (SPAD, R)
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (1, bin_pad), 1)
     for fi in range(num_features):
-        col = bins_ref[:, fi:fi + 1].astype(jnp.int32)  # (R, 1)
-        eq = (col == iota_b).astype(jnp.float32)        # (R, bin_pad)
-        # HIGHEST: at default precision the MXU rounds the f32 stats to
-        # bf16 (measured on the v5e, PR 22: counts stay exact, grad/hess
-        # sums come out up to 0.12 off at 2M rows), which breaks the
-        # float-sum parity contract with the XLA formulations; the
-        # one-hot operand is exact in bf16, so the multi-pass product
-        # is exact and only the accumulation order differs
-        s = jax.lax.dot_general(
-            data, eq, (((1,), (0,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)         # (SPAD, bin_pad)
+        s = _feature_sums(data, bins_ref, fi, bin_pad)  # (SPAD, bin_pad)
 
         @pl.when(first)
         def _init(fi=fi, s=s):
@@ -111,7 +152,118 @@ def _hist_kernel(bn_ref, bins_ref, data_ref, out_ref, *, num_features: int,
             out_ref[0, fi] += s
 
 
-def _pallas_level_histogram(binned, grad, hess, live, local, *, width: int,
+def _in_place_rows(width: int):
+    """Rows of the in-place kernel's left operand: ``3 * wq`` (grad, hess
+    and count of each of ``wq`` nodes, ``wq`` the power of two at or
+    above ``width`` so that a row's node and stat are a mask and a shift
+    of its index), padded to whole sublane tiles. -> (rows, log2(wq))."""
+    shift = max(int(width) - 1, 0).bit_length()
+    rows = 3 << shift
+    return -(-rows // _SPAD) * _SPAD, shift
+
+
+def _hist_kernel_in_place(bins_ref, data_ref, out_ref, *, num_features: int,
+                          bin_pad: int, rows: int, shift: int, n: int):
+    """In-place path. One row block as it lies in ``binned``, whatever
+    nodes its rows belong to: the stats are expanded by node (row
+    ``s * wq + w`` is stat ``s`` where the row's node is ``w``, else 0)
+    and contracted against the same per-feature one-hot as the sorted
+    path, into one accumulator for the whole level that stays in VMEM
+    across the grid."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    i = pl.program_id(0)
+    data = data_ref[...]                                # (SPAD, R) f32
+    r = data.shape[1]
+    if n % r:
+        # the ragged last block reads past both operands: whatever lies
+        # there is no row (a one-hot of any byte is 0 or 1, so zero
+        # stats add nothing)
+        lane = jax.lax.broadcasted_iota(jnp.int32, data.shape, 1)
+        data = jnp.where(i * r + lane < n, data, 0.0)
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, r), 0)
+    stat = row >> shift
+    # row 3 of the stats carries the node index (exact in float32)
+    node = jnp.broadcast_to(data[3:4].astype(jnp.int32), (rows, r))
+    picked = jnp.where(
+        stat == 0, jnp.broadcast_to(data[0:1], (rows, r)),
+        jnp.where(stat == 1, jnp.broadcast_to(data[1:2], (rows, r)),
+                  jnp.broadcast_to(data[2:3], (rows, r))))
+    expanded = jnp.where(
+        (stat < 3) & ((row & ((1 << shift) - 1)) == node), picked, 0.0)
+
+    @pl.when(i == 0)
+    def _init():
+        out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
+
+    for fi in range(num_features):
+        s = _feature_sums(expanded, bins_ref, fi, bin_pad)  # (rows, bin_pad)
+        out_ref[fi] += s.reshape(rows // _SPAD, _SPAD, bin_pad)
+
+
+def _level_stats(grad, hess, live, local):
+    """(SPAD, N) float32: grad*live, hess*live, live and the node index
+    of each row, zeros in the other sublanes. Element-wise writes:
+    nothing is gathered."""
+    import jax.numpy as jnp
+
+    rows = [grad * live, hess * live, live, local]
+    stats = jnp.stack([v.astype(jnp.float32) for v in rows])
+    return jnp.pad(stats, ((0, _SPAD - len(rows)), (0, 0)))
+
+
+def _in_place_level_histogram(binned, grad, hess, live, local, *, width: int,
+                              f: int, b: int, block_rows: int,
+                              interpret: bool):
+    """Sort-free level histogram: the grid runs over ``binned`` in the
+    order its rows lie, and the kernel masks the ragged last block. No
+    copy of the matrix is made."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from mmlspark_tpu.core.jax_compat import (operand_vma,
+                                              shape_dtype_struct)
+
+    n = binned.shape[0]
+    r = block_rows
+    rows, shift = _in_place_rows(width)
+    with jax.named_scope("gbdt.hist.feed"):
+        data = _level_stats(grad, hess, live, local)
+
+    vma = operand_vma(binned, grad, hess, live, local)
+    kernel = functools.partial(_hist_kernel_in_place, num_features=f,
+                               bin_pad=_BIN_PAD, rows=rows, shift=shift,
+                               n=n)
+    out_block = (f, rows // _SPAD, _SPAD, _BIN_PAD)
+    # the accumulator (2.75 MB at width 32) twice, as Pallas buffers every
+    # block twice, and room for the row blocks, the expanded stats and
+    # one feature's one-hot and product
+    vmem_limit = 2 * 4 * f * rows * _BIN_PAD + (24 << 20)
+    with jax.named_scope("gbdt.hist"):
+        out = pl.pallas_call(
+            kernel,
+            out_shape=shape_dtype_struct(out_block, jnp.float32, vma=vma),
+            grid=(-(-n // r),),
+            in_specs=[pl.BlockSpec((r, f), lambda i: (i, 0)),
+                      pl.BlockSpec((_SPAD, r), lambda i: (0, i))],
+            out_specs=pl.BlockSpec(out_block, lambda i: (0, 0, 0, 0)),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=vmem_limit),
+            interpret=interpret,
+            name="gbdt_level_hist",
+        )(binned, data)
+        # (f, rows/8, 8, BIN_PAD) -> (3, wq, f, BIN_PAD) -> (width, f, b, 3)
+        out = out.reshape(f, rows, _BIN_PAD)[:, :3 << shift]
+        out = out.reshape(f, 3, 1 << shift, _BIN_PAD)[:, :, :width, :b]
+        return jnp.transpose(out, (2, 0, 3, 1))
+
+
+def _sorted_level_histogram(binned, grad, hess, live, local, *, width: int,
                             f: int, b: int, block_rows: int,
                             interpret: bool):
     import jax
@@ -171,15 +323,6 @@ def _pallas_level_histogram(binned, grad, hess, live, local, *, width: int,
         out_specs=pl.BlockSpec((1, f, _SPAD, _BIN_PAD),
                                lambda i, bn: (bn[i], 0, 0, 0)),
     )
-    # under shard_map (the voting/feature tree learners) the output
-    # varies over whatever mesh axes the inputs vary over — declare the
-    # union so a check_vma-enabled enclosing shard_map accepts the
-    # per-shard call on the Mosaic (compiled) path; outside shard_map
-    # every vma is empty and this is a no-op. The interpret path
-    # instead runs with the enclosing shard_map's checker off (see
-    # parallel_modes._check_vma): interpret discharges the kernel body
-    # into the manual trace, where kernel-internal constants trip the
-    # checker.
     from mmlspark_tpu.core.jax_compat import (operand_vma,
                                               shape_dtype_struct)
     vma = operand_vma(binned, grad, hess, live, local)
@@ -196,6 +339,41 @@ def _pallas_level_histogram(binned, grad, hess, live, local, *, width: int,
         )(block_node, bins_pad, data)
         # (width, f, SPAD, BIN_PAD) -> (width, f, b, 3)
         return jnp.transpose(out[:, :, :3, :b], (0, 1, 3, 2))
+
+
+def level_feed(width: int) -> str:
+    """The path a level of ``width`` nodes takes: a static shape decides,
+    nothing a user sets."""
+    return "in_place" if width <= IN_PLACE_MAX_WIDTH else "sorted"
+
+
+def feed_by_path(widths) -> dict:
+    """How many of a tree's histogram calls, of these widths, take each
+    path: what ``hist_stats["hist_feed"]`` records."""
+    paths = [level_feed(w) for w in widths]
+    return {p: paths.count(p) for p in ("in_place", "sorted")}
+
+
+def _pallas_level_histogram(binned, grad, hess, live, local, *, width: int,
+                            f: int, b: int, block_rows: int,
+                            interpret: bool):
+    # under shard_map (the voting/feature tree learners) the output
+    # varies over whatever mesh axes the inputs vary over — both paths
+    # declare the union so a check_vma-enabled enclosing shard_map
+    # accepts the per-shard call on the Mosaic (compiled) path; outside
+    # shard_map every vma is empty and this is a no-op. The interpret
+    # path instead runs with the enclosing shard_map's checker off (see
+    # parallel_modes._check_vma): interpret discharges the kernel body
+    # into the manual trace, where kernel-internal constants trip the
+    # checker.
+    import jax.numpy as jnp
+
+    if binned.shape[0] == 0:
+        return jnp.zeros((width, f, b, 3), jnp.float32)
+    path = (_in_place_level_histogram if level_feed(width) == "in_place"
+            else _sorted_level_histogram)
+    return path(binned, grad, hess, live, local, width=width, f=f, b=b,
+                block_rows=block_rows, interpret=interpret)
 
 
 _JIT_CACHE = {}
@@ -232,10 +410,11 @@ def pallas_level_histogram_quant(binned, grad_q, hess_q, live, local,
     exact in float32, so dequantizing up front feeds the f32 matmul
     kernel the SAME values the int32-accumulating native kernel sums —
     the three backends agree to f32 accumulation order, which is the
-    same parity contract as the unquantized path. (A native-int MXU
-    accumulation would need an int8 operand layout and per-block
-    rescale; not worth it while the kernel is bandwidth-bound on the
-    binned matrix, see the cost note in the module docstring.)"""
+    same parity contract as the unquantized path. A native-int MXU
+    accumulation (an int8 operand layout and a per-block rescale) is one
+    of the directions left for the kernel's inside: the kernel is bound
+    by its own VPU and MXU work, not by reading the binned matrix (the
+    cost note in the module docstring; ROADMAP S1, direction 4)."""
     import jax.numpy as jnp
 
     grad = grad_q.astype(jnp.float32) * gscale_inv

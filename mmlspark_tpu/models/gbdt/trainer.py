@@ -880,13 +880,16 @@ def _level_histogram(binned, grad, hess, live, local, width, f, b,
     per-feature segment_sums avoids materializing the (N*F, 3)
     broadcast and wins ~4x on CPU over the fused scatter, so
     per_feature is the XLA default outside shard_map. On the v5e it
-    takes 0.384 s a level at bench shape against the Pallas kernel's
-    0.142 s (PERF.md, PR 22); the other XLA formulations are unmeasured
-    on the current stack (ROADMAP S1 keeps the retired stack's figures,
-    where per_feature beat three separate segment_sums and the fused
-    3-channel stack did not compile). Under shard_map the fori_loop carry would need manual
-    varying-axes casts, so those callers use the separate formulation
-    on TPU and keep the fused scatter on CPU (the long-tested path).
+    took 0.384 s a level at 2M x 28 against the Pallas kernel's 0.142 s
+    (one host-timed run each, PERF.md, PR 22); the other XLA
+    formulations are unmeasured on the chip, and the A/B that would
+    rank them is still owed (ROADMAP S1, D1). The Pallas kernel itself
+    is two orders of magnitude from its roofline and bound by its own
+    VPU and MXU work, not by bandwidth (hist_pallas.py's cost note), so
+    none of these figures says what the chip allows. Under shard_map
+    the fori_loop carry would need manual varying-axes casts, so those
+    callers use the separate formulation on TPU and keep the fused
+    scatter on CPU (the long-tested path).
     onehot is the chunked MXU one-hot contraction, insurance for the
     Pallas kernel.
     """
@@ -2482,7 +2485,7 @@ def train(binned: np.ndarray, labels: np.ndarray, cfg: TrainConfig,
         hist_formulation = resolve_fit_formulation(total_bins, tree_mode,
                                                    mesh)
         from mmlspark_tpu.models.gbdt.hist_pallas import (
-            resolve_pallas_interpret)
+            feed_by_path, resolve_pallas_interpret)
         # the eager loop: DART's dropped-tree set and a custom
         # objective's host code fit no fixed-shape step, and the
         # leaf-wise frontier is grown on the host
@@ -2504,6 +2507,14 @@ def train(binned: np.ndarray, labels: np.ndarray, cfg: TrainConfig,
             "pallas_interpret": (resolve_pallas_interpret()
                                  if hist_formulation == "pallas"
                                  else None),
+            # the levels of a tree by the path the Pallas kernel takes
+            # at their width (hist_pallas.level_feed): in place over the
+            # rows as they lie, or through the sort by node. Leaf-wise
+            # growth asks for one node at a time
+            "hist_feed": (feed_by_path(
+                [1] if grow_policy == "leafwise"
+                else [2 ** d for d in range(cfg.effective_depth)])
+                if hist_formulation == "pallas" else None),
             # rows of the binned matrix resident on each device: N/dp
             # apiece when the ingest sharded them, nothing staged whole
             "binned_rows_per_device": _rows_per_device(binned_d),

@@ -300,13 +300,16 @@ def test_the_lowered_step_carries_every_scope_and_the_kernels_name(
     assert "gbdt." not in aot_lower_step(cfg, n=2048, num_f=28)
 
     n = 1024
-    jaxpr = str(jax.make_jaxpr(
-        lambda *a: pallas_level_histogram(*a, 4, 28, 255))(
-            jnp.zeros((n, 28), jnp.uint8), jnp.ones(n), jnp.ones(n),
-            jnp.ones(n), jnp.zeros(n, jnp.int32)))
-    assert "name=gbdt_level_hist" in jaxpr
-    # hist_kernel_roofline finds the kernel by this result shape
-    assert "f32[4,28,8,256]" in jaxpr
+    # hist_kernel_roofline finds the kernel by its result shape,
+    # f32[·,·,8,256], on either path: (F, 3·width/8, 8, 256) in place,
+    # (width, F, 8, 256) sorted
+    for width, shape in ((4, "f32[28,2,8,256]"), (64, "f32[64,28,8,256]")):
+        jaxpr = str(jax.make_jaxpr(
+            lambda *a: pallas_level_histogram(*a, width, 28, 255))(
+                jnp.zeros((n, 28), jnp.uint8), jnp.ones(n), jnp.ones(n),
+                jnp.ones(n), jnp.zeros(n, jnp.int32)))
+        assert "name=gbdt_level_hist" in jaxpr
+        assert shape in jaxpr
 
 
 @pytest.mark.parametrize("valid_rows", [0, 512])

@@ -7,8 +7,12 @@ Interpret mode on CPU; the TPU compile + timing runs through
 import numpy as np
 import pytest
 
+from mmlspark_tpu.models.gbdt import hist_pallas
 from mmlspark_tpu.models.gbdt.hist_pallas import pallas_level_histogram
 from mmlspark_tpu.models.gbdt.trainer import _level_histogram
+
+# one width past the bound: the sorted path at the smallest size it runs
+_WIDE = 2 * hist_pallas.IN_PLACE_MAX_WIDTH
 
 
 def _case(n, f, b, width, seed=0, integer_stats=False):
@@ -34,6 +38,14 @@ def _case(n, f, b, width, seed=0, integer_stats=False):
     (999, 3, 255, 8),     # n not divisible by block, full bin range
     (100, 5, 16, 16),     # more nodes than fit one row block; empty nodes
     (4096, 2, 64, 1),     # single node (root level)
+    # the in-place path, N not a multiple of block_rows, 10% dead rows
+    (1301, 3, 255, 1),
+    (1301, 3, 63, 2),
+    (777, 5, 32, 8),
+    (2600, 4, 255, 32),   # 96 rows of node-expanded stats
+    (1500, 3, 16, 5),     # a width that is no power of two
+    # above the bound: the sorted path, still right
+    (2600, 3, 31, _WIDE),
 ])
 def test_matches_xla_histogram(n, f, b, width):
     binned, grad, hess, live, local = _case(n, f, b, width)
@@ -48,26 +60,32 @@ def test_matches_xla_histogram(n, f, b, width):
     np.testing.assert_array_equal(got[..., 2], ref[..., 2])
 
 
-def test_bitwise_exact_on_integer_stats():
+@pytest.mark.parametrize("width,path", [(8, "in_place"), (32, "in_place"),
+                                        (_WIDE, "sorted")])
+def test_bitwise_exact_on_integer_stats(width, path):
     """With integer-valued grad/hess every f32 add is exact, so block
-    order cannot matter: the kernel must be bit-for-bit."""
-    binned, grad, hess, live, local = _case(3000, 4, 63, 8,
+    order cannot matter: the kernel must be bit-for-bit, on both paths."""
+    assert hist_pallas.level_feed(width) == path
+    binned, grad, hess, live, local = _case(3000, 4, 63, width,
                                             integer_stats=True)
     ref = np.asarray(_level_histogram(binned, grad, hess, live, local,
-                                      8, 4, 63))
+                                      width, 4, 63))
     got = np.asarray(pallas_level_histogram(binned, grad, hess, live,
-                                            local, 8, 4, 63,
+                                            local, width, 4, 63,
                                             interpret=True))
     np.testing.assert_array_equal(got, ref)
 
 
-def test_skewed_node_distribution():
-    """One dominant node + several empties exercises the per-node block
-    padding and the first-visit zero-init of untouched output tiles."""
+@pytest.mark.parametrize("width", [8, _WIDE])
+def test_skewed_node_distribution(width):
+    """One dominant node + several empties: on the sorted path the
+    per-node block padding and the first-visit zero-init of untouched
+    output tiles, on the in-place path the rows of the accumulator no
+    mask ever selects."""
     import jax.numpy as jnp
 
     rng = np.random.default_rng(7)
-    n, f, b, width = 2500, 3, 32, 8
+    n, f, b = 2500, 3, 32
     binned = jnp.asarray(rng.integers(0, b, size=(n, f), dtype=np.int64)
                          .astype(np.uint8))
     grad = jnp.asarray(rng.normal(size=n).astype(np.float32))
@@ -82,8 +100,64 @@ def test_skewed_node_distribution():
                                             interpret=True))
     np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-4)
     # empty nodes are exactly zero, not stale VMEM
-    for w in (0, 1, 2, 4, 5, 7):
+    for w in set(range(width)) - {3, 6}:
         assert not np.any(got[w])
+
+
+def test_path_is_chosen_by_width_alone(monkeypatch):
+    """One algorithm, two paths, told apart by a static shape: no
+    environment variable is read on the way, and each path is the one
+    ``level_feed`` names."""
+    bound = hist_pallas.IN_PLACE_MAX_WIDTH
+    assert [hist_pallas.level_feed(w) for w in (1, bound, bound + 1)] == [
+        "in_place", "in_place", "sorted"]
+    assert hist_pallas.feed_by_path([1, 2, 4, bound, 2 * bound]) == {
+        "in_place": 4, "sorted": 1}
+
+    taken = []
+    for name in ("_in_place_level_histogram", "_sorted_level_histogram"):
+        orig = getattr(hist_pallas, name)
+        monkeypatch.setattr(
+            hist_pallas, name,
+            lambda *a, _o=orig, _n=name, **k: taken.append(_n) or _o(*a, **k))
+    from mmlspark_tpu.core import env as env_mod
+    reads = []
+    for name in ("env_raw", "env_flag", "env_str", "env_int", "env_float"):
+            monkeypatch.setattr(
+                env_mod, name,
+                lambda key, *a, **k: reads.append(key) or pytest.fail(
+                    f"the kernel read {key}"))
+    for width in (bound, bound + 1):
+        binned, grad, hess, live, local = _case(600, 2, 15, width)
+        # straight into the traced function: the jit cache of the entry
+        # point would hide the second look
+        hist_pallas._pallas_level_histogram(
+            binned, grad, hess, live, local, width=width, f=2, b=15,
+            block_rows=512, interpret=True)
+    assert taken == ["_in_place_level_histogram", "_sorted_level_histogram"]
+    assert not reads
+
+
+def test_fit_records_hist_feed(monkeypatch):
+    """``hist_stats["hist_feed"]``: the levels of a tree by path, beside
+    ``raw_update``; nothing for a fit that runs another formulation."""
+    from mmlspark_tpu.models.gbdt.trainer import TrainConfig, train
+    from mmlspark_tpu.ops.binning import BinMapper
+
+    rng = np.random.default_rng(3)
+    x = rng.normal(size=(400, 4))
+    y = (x[:, 0] - 0.5 * x[:, 1] > 0).astype(np.float64)
+    mapper = BinMapper.fit(x, max_bin=16)
+    binned = mapper.transform(x)
+    cfg = TrainConfig(objective="binary", num_iterations=2, num_leaves=8,
+                      max_depth=3, min_data_in_leaf=5, max_bin=16)
+    bu = mapper.bin_upper_values(16)
+    assert train(binned, y, cfg, bin_upper=bu).hist_stats["hist_feed"] is None
+    monkeypatch.setenv("MMLSPARK_TPU_PALLAS_HIST", "1")
+    stats = train(binned, y, cfg, bin_upper=bu).hist_stats
+    assert stats["hist_formulation"] == "pallas"
+    assert stats["hist_feed"] == {"in_place": 3, "sorted": 0}
+    assert stats["raw_update"] == "builder_leaf"
 
 
 def test_trainer_env_flag_routes_to_pallas(monkeypatch):

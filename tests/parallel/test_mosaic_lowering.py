@@ -25,14 +25,19 @@ def _lower_tpu(fn, *args) -> str:
         lowering_platforms=("tpu",)).as_text()
 
 
-def test_hist_kernel_lowers_to_mosaic():
+@pytest.mark.parametrize("path,width", [("in_place", 8), ("in_place", 32),
+                                        ("sorted", 64)])
+def test_hist_kernel_lowers_to_mosaic(path, width):
+    """Both paths of the level histogram go through Mosaic; the in-place
+    one asks XLA for no sort and no gather (it has no feed beyond the
+    stats' element-wise writes)."""
     import jax.numpy as jnp
 
-    from mmlspark_tpu.models.gbdt.hist_pallas import (
-        _pallas_level_histogram)
+    from mmlspark_tpu.models.gbdt import hist_pallas
 
-    # bench-like dims: 255 bins, 28 features, depth-3 level
-    n, f, b, width = 4096, 28, 255, 8
+    assert hist_pallas.level_feed(width) == path
+    # bench-like dims: 255 bins, 28 features; N no multiple of the block
+    n, f, b = 4100, 28, 255
     rng = np.random.default_rng(0)
     args = (jnp.asarray(rng.integers(0, b, size=(n, f)).astype(np.uint8)),
             jnp.asarray(rng.normal(size=n).astype(np.float32)),
@@ -40,9 +45,14 @@ def test_hist_kernel_lowers_to_mosaic():
             jnp.ones(n, jnp.float32),
             jnp.asarray(rng.integers(0, width, size=n).astype(np.int32)))
     txt = _lower_tpu(
-        functools.partial(_pallas_level_histogram, width=width, f=f, b=b,
-                          block_rows=512, interpret=False), *args)
+        functools.partial(hist_pallas._pallas_level_histogram, width=width,
+                          f=f, b=b, block_rows=512, interpret=False), *args)
     assert "tpu_custom_call" in txt  # the serialized Mosaic module
+    has_feed = "stablehlo.sort" in txt and "stablehlo.gather" in txt
+    assert has_feed == (path == "sorted")
+    if path == "in_place":
+        assert "sort" not in txt and "gather" not in txt
+        assert "scatter" not in txt
 
 
 def test_flash_kernel_lowers_to_mosaic():
