@@ -71,7 +71,8 @@ def timed(fn):
 
 
 def make_data(n: int):
-    """bench.py's HIGGS-shaped generator, seed 0."""
+    """The HIGGS-shaped generator of the tracked configuration
+    (BASELINE.md), seed 0."""
     rng = np.random.default_rng(0)
     x = rng.normal(size=(n, FEATURES)).astype(np.float32)
     logit = (x[:, 0] * 1.2 - x[:, 1] + 0.5 * x[:, 2] * x[:, 3]

@@ -13,7 +13,7 @@ the package is a lint error (GL004); non-framework variables (JAX_*,
 XLA_*, platform detection) are out of scope and stay where they are.
 
 Parsing contract (shared with the pre-existing knobs, see
-``resolve_histogram_formulation``'s bad-value handling): a malformed
+``resolve_hist_quant``'s bad-value handling): a malformed
 value must not abort — or silently mislabel — a run, so ``env_flag`` /
 ``env_int`` warn once per variable and fall back to the default instead
 of raising.
@@ -55,10 +55,6 @@ def register(name: str, kind: str, default: object,
 
 # --- the one registry (keep PARAMS.md "Engine knobs" tables in sync;
 # --- GL004 fails the build when they drift) ---------------------------
-HIST_FORMULATION = register(
-    "MMLSPARK_TPU_HIST_FORMULATION", "str", "",
-    "force a histogram formulation: per_feature|separate|fused|onehot|"
-    "native; impossible combinations downgrade with a warning")
 NATIVE_HIST = register(
     "MMLSPARK_TPU_NATIVE_HIST", "flag", True,
     "=0 disables the native C++ CPU histogram default (back to XLA)")
@@ -75,12 +71,6 @@ PALLAS_FORCE_COMPILE = register(
     "MMLSPARK_TPU_PALLAS_FORCE_COMPILE", "flag", False,
     "=1 compiles Pallas kernels through Mosaic even off-TPU (AOT "
     "lowering tests / TPU-day debugging) instead of interpret mode")
-ONEHOT_CHUNK = register(
-    "MMLSPARK_TPU_ONEHOT_CHUNK", "int", 4096,
-    "rows per MXU dot in the onehot formulation")
-ONEHOT_BF16 = register(
-    "MMLSPARK_TPU_ONEHOT_BF16", "flag", False,
-    "=1 runs onehot-formulation operands in bf16")
 FLASH = register(
     "MMLSPARK_TPU_FLASH", "flag", False,
     "=1 opts into the Pallas flash-attention kernel on TPU")

@@ -141,9 +141,6 @@ class FleetSupervisor:
                        "scale_ups": 0, "scale_downs": 0, "drained": 0,
                        "spawn_failures": 0, "fleet_swaps": 0,
                        "fleet_swap_rollbacks": 0, "gray_recycles": 0}
-        # (t_monotonic, n_workers) after every pass — the worker-count
-        # trajectory the serving_elastic bench row reports
-        self.history: List[Tuple[float, int]] = []
         self._stop_ev = threading.Event()
         self._thread: Optional[threading.Thread] = None
 
@@ -433,8 +430,6 @@ abort_swap`; nothing was flipped, so the old model never stopped
         # its replacement arrives via convergence, not via target bump
         self._decide([h for s, h in healths if id(s) not in recycled])
         self._converge()
-        self.history.append((time.monotonic(),
-                             len(self.fleet.worker_urls)))
 
     # -- lifecycle -----------------------------------------------------------
     def _run(self) -> None:

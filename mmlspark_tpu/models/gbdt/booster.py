@@ -283,7 +283,7 @@ class BoosterArrays:
         (``ops.ingest.binned_ingest_dtype``: uint8 for <=256 bins) —
         gathers run in the input dtype, so uint8 moves 4x fewer bytes
         than the int32 ``BinMapper.transform`` default (measured ~2x
-        end-to-end on CPU, tools/bench_scoring.py). Numerical splits
+        end-to-end on CPU). Numerical splits
         only: categorical models route by raw-value bitsets, so they
         take ``predict_fn``.
 
@@ -335,7 +335,7 @@ class BoosterArrays:
                 # widen only the gathered column for the compare — the
                 # (N, F) matrix stays in the caller's dtype so a uint8
                 # input gathers 4x fewer bytes than int32 (measured
-                # ~2x total on CPU at bench shape, tools/bench_scoring)
+                # ~2x total on CPU at 2M x 28)
                 go_left = fb.astype(jnp.int32) <= tb[tree_idx][node]
                 child = jnp.where(go_left, 2 * node + 1, 2 * node + 2)
                 node = jnp.where(is_leaf, node, child)

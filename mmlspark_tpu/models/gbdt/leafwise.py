@@ -77,7 +77,7 @@ def make_build_tree_leafwise(num_features: int, total_bins: int, cfg):
     num_bits = 6 if cfg.zero_as_missing else 10
     f, b = num_features, total_bins
     formulation = _trainer.resolve_histogram_formulation(
-        total_bins, in_shard_map=False, warn=False)
+        total_bins, in_shard_map=False)
 
     def leaf_obj(g, h):
         g_adj = np.sign(g) * np.maximum(np.abs(g) - lam1, 0.0)

@@ -94,8 +94,7 @@ def _check_vma(total_bins: int) -> bool:
         resolve_pallas_interpret)
     from mmlspark_tpu.models.gbdt.trainer import (
         resolve_histogram_formulation)
-    choice = resolve_histogram_formulation(total_bins, in_shard_map=True,
-                                           warn=False)
+    choice = resolve_histogram_formulation(total_bins, in_shard_map=True)
     if choice == "native":
         return False
     return not (choice == "pallas" and resolve_pallas_interpret())

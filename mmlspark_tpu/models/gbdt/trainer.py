@@ -229,14 +229,6 @@ def _objective_kwargs(cfg: TrainConfig) -> Dict[str, Any]:
 # Tree building (device side)
 # ---------------------------------------------------------------------------
 
-_WARNED_BAD_FORMULATION = False
-_WARNED_SHARD_DOWNGRADE = False
-_WARNED_NATIVE_DOWNGRADE = False
-
-_VALID_FORMULATIONS = ("per_feature", "separate", "fused", "onehot",
-                       "native")
-
-
 def native_histogram_available() -> bool:
     """Is the C++ level-histogram kernel loadable (builds lazily)?"""
     from mmlspark_tpu.native import bindings
@@ -258,80 +250,33 @@ def _native_hist_default_enabled() -> bool:
 
 def resolve_histogram_formulation(b: int, in_shard_map: bool = False,
                                   allow_pallas: bool = True,
-                                  allow_native: bool = True,
-                                  warn: bool = True) -> str:
-    """Single best-available histogram-kernel policy, shared by the
-    trainer dispatch, the shard_map builders and bench attribution:
+                                  allow_native: bool = True) -> str:
+    """The one histogram-kernel policy, shared by the trainer dispatch
+    and the shard_map builders. The first rule that holds decides:
 
-      1. the Pallas kernel where it is enabled — by default on the TPU
-         backend, elsewhere only with MMLSPARK_TPU_PALLAS_HIST=1
-         (interpret mode) — and the caller allows it (single-program
-         or per-shard, <=256 bins);
-      2. an explicit MMLSPARK_TPU_HIST_FORMULATION override, with
-         constraint downgrades warned once per process so A/B labels
-         stay honest: per_feature -> separate inside shard_map (the
-         fori_loop carry is not shard_map-safe), native -> XLA default
-         under GSPMD auto-partitioning (host callbacks cannot be
-         partitioned);
-      3. the native cache-blocked C++ kernel on the CPU backend
-         (mmls_level_hist_*, via a host callback) — the competitive
-         CPU path, also selected per-shard inside the explicit
-         shard_map tree learners;
-      4. the XLA segment_sum formulations otherwise: per_feature
-         outside shard_map, separate under shard_map on TPU (fused does
-         not compile there), fused under shard_map on CPU.
+      1. ``pallas`` where the kernel is enabled (by default on the TPU
+         backend), the caller allows it (single-program or per-shard:
+         GSPMD cannot partition a custom call) and the bins fit its
+         256-lane tile;
+      2. ``native``, the cache-blocked C++ kernel behind a host
+         callback, on the CPU backend when the library loaded and the
+         caller allows it (GSPMD cannot partition a callback either;
+         on the TPU the rows never visit the host);
+      3. ``per_feature`` outside shard_map: a fori_loop of per-feature
+         segment_sums never materializes the (N*F, 3) broadcast;
+      4. ``separate`` inside shard_map, where that loop's carry would
+         need manual varying-axes casts: three scalar segment_sums over
+         one shared index vector, no carry.
     """
-    import jax
-
     from mmlspark_tpu.models.gbdt.hist_pallas import (
         pallas_histogram_enabled,
     )
 
-    global _WARNED_BAD_FORMULATION, _WARNED_SHARD_DOWNGRADE, \
-        _WARNED_NATIVE_DOWNGRADE
     if pallas_histogram_enabled() and allow_pallas and b <= 256:
         return "pallas"
-    forced = env_str("MMLSPARK_TPU_HIST_FORMULATION", "").strip()
-    if forced and forced not in _VALID_FORMULATIONS:
-        # a mistyped value silently running the default would mislabel
-        # an A/B measurement — warn loudly (once per process)
-        if warn and not _WARNED_BAD_FORMULATION:
-            _WARNED_BAD_FORMULATION = True
-            import warnings
-            warnings.warn(
-                f"MMLSPARK_TPU_HIST_FORMULATION={forced!r} is not one "
-                "of per_feature|separate|fused|onehot|native; using the "
-                "default formulation instead", stacklevel=2)
-        forced = ""
-    if forced == "native" and not allow_native:
-        if warn and not _WARNED_NATIVE_DOWNGRADE:
-            _WARNED_NATIVE_DOWNGRADE = True
-            import warnings
-            warnings.warn(
-                "MMLSPARK_TPU_HIST_FORMULATION=native cannot run under "
-                "GSPMD auto-partitioning (host callbacks are not "
-                "partitionable); this builder uses the XLA default — "
-                "label A/B measurements accordingly", stacklevel=2)
-        forced = ""
-    if forced == "per_feature" and in_shard_map:
-        # ADVICE r5: this downgrade used to be silent while mistyped
-        # values warned loudly — inconsistent for A/B labeling
-        if warn and not _WARNED_SHARD_DOWNGRADE:
-            _WARNED_SHARD_DOWNGRADE = True
-            import warnings
-            warnings.warn(
-                "MMLSPARK_TPU_HIST_FORMULATION=per_feature is not "
-                "shard_map-safe (fori_loop carry); running the "
-                "'separate' formulation inside shard_map — label A/B "
-                "measurements accordingly", stacklevel=2)
-        forced = "separate"
-    if forced:
-        return forced
     if allow_native and _native_hist_default_enabled():
         return "native"
-    if not in_shard_map:
-        return "per_feature"
-    return "separate" if jax.default_backend() == "tpu" else "fused"
+    return "separate" if in_shard_map else "per_feature"
 
 
 def _single_program(mesh) -> bool:
@@ -350,12 +295,11 @@ def resolve_fit_formulation(total_bins: int, mode: str, mesh) -> str:
     Shared by the builder cache, the subtraction policy and the fit's
     ``hist_stats`` so the recorded name is the kernel that ran."""
     if mode != "serial":
-        return resolve_histogram_formulation(total_bins, in_shard_map=True,
-                                             warn=False)
+        return resolve_histogram_formulation(total_bins, in_shard_map=True)
     single = _single_program(mesh)
     return resolve_histogram_formulation(
         total_bins, in_shard_map=False, allow_pallas=single,
-        allow_native=single, warn=False)
+        allow_native=single)
 
 
 _WARNED_BAD_QUANT = False
@@ -371,10 +315,10 @@ def resolve_hist_quant(in_shard_map: bool = False,
     quantized to int16 (q16) or int8 (q8) with a shared power-of-two
     scale, accumulated in int32 with periodic rescale into wide
     accumulators, dequantized only at split-gain evaluation
-    (arXiv:2011.02022's quantized training scheme). Follows the same
-    bad-value contract as ``resolve_histogram_formulation``: a mistyped
-    value warns once and runs unquantized rather than mislabeling a
-    measurement. Single-program only — the shard_map builders keep f32
+    (arXiv:2011.02022's quantized training scheme). Follows core.env's
+    bad-value contract: a mistyped value warns once and runs
+    unquantized rather than mislabeling a measurement.
+    Single-program only — the shard_map builders keep f32
     histograms (the native quant kernel is a host callback and the
     chunked-scan XLA mirror's carry is not shard_map-safe), downgrading
     with a warning so A/B labels stay honest."""
@@ -523,7 +467,7 @@ def _ooc_supported(cfg: "TrainConfig", mesh, k: int, has_valid: bool,
         return "categorical_features"
     if any(cfg.monotone_constraints or ()):
         return "monotone_constraints"
-    if resolve_histogram_formulation(total_bins, warn=False) != "native":
+    if resolve_histogram_formulation(total_bins) != "native":
         return ("the native histogram kernel is unavailable (chunk-exact "
                 "merges need its integer accumulation)")
     return None
@@ -798,7 +742,8 @@ def _level_histogram_quant(binned, grad_q, hess_q, live, local, width,
       - pallas: exact dequantize (int * pow2 scale) feeding the
         existing Mosaic kernel — int histogramming inside VMEM is a
         measured-on-TPU follow-up, the mirror exists for parity;
-      - XLA: lax.scan over flush-sized row chunks, int32 segment_sum
+      - XLA (per_feature and separate alike, one implementation):
+        lax.scan over flush-sized row chunks, int32 segment_sum
         per chunk folded into an f32 accumulator — the periodic-rescale
         idiom (graftlint GL007 enforces the int32 widening).
     """
@@ -874,24 +819,16 @@ def _level_histogram(binned, grad, hess, live, local, width, f, b,
     ``formulation`` pins a pre-resolved choice (the serial builder
     resolves once per build so its subtraction strategy and histogram
     backend agree); otherwise ``resolve_histogram_formulation`` picks
-    the best available kernel for this backend/caller.
+    the kernel for this backend and caller.
 
-    XLA formulation notes (bench_hist.py measures them): a fori_loop of
-    per-feature segment_sums avoids materializing the (N*F, 3)
-    broadcast and wins ~4x on CPU over the fused scatter, so
-    per_feature is the XLA default outside shard_map. On the v5e it
-    took 0.384 s a level at 2M x 28 against the Pallas kernel's 0.142 s
-    (one host-timed run each, PERF.md, PR 22); the other XLA
-    formulations are unmeasured on the chip, and the A/B that would
-    rank them is still owed (ROADMAP S1, D1). The Pallas kernel itself
-    is two orders of magnitude from its roofline and bound by its own
-    VPU and MXU work, not by bandwidth (hist_pallas.py's cost note), so
-    none of these figures says what the chip allows. Under shard_map
-    the fori_loop carry would need manual varying-axes casts, so those
-    callers use the separate formulation on TPU and keep the fused
-    scatter on CPU (the long-tested path).
-    onehot is the chunked MXU one-hot contraction, insurance for the
-    Pallas kernel.
+    What the chip has said of the XLA formulations: per_feature took
+    0.384 s a level at 2M x 28 on the v5e against the Pallas kernel's
+    0.142 s (one host-timed run each, PERF.md, PR 22); separate is
+    unmeasured there, and the A/B that would rank the two is still owed
+    (ROADMAP S1, D1). The Pallas kernel itself is two orders of
+    magnitude from its roofline and bound by its own VPU and MXU work,
+    not by bandwidth (hist_pallas.py's cost note), so none of these
+    figures says what the chip allows.
     """
     import jax
     import jax.numpy as jnp
@@ -905,11 +842,9 @@ def _level_histogram(binned, grad, hess, live, local, width, f, b,
         allow_native=allow_native)
 
     if choice == "pallas":
-        # Pallas kernel (hist_pallas.py; bench_hist.py measures it
-        # against the XLA formulations below on each backend). Safe
-        # per-shard under shard_map too: the kernel only ever sees this
-        # program's local rows, and the cross-device psum happens on the
-        # returned histogram exactly as for the XLA formulations
+        # Safe per-shard under shard_map too: the kernel only ever sees
+        # this program's local rows, and the cross-device psum happens
+        # on the returned histogram exactly as for the XLA formulations
         # (tests/gbdt/test_hist_pallas.py::test_pallas_under_shard_map_modes)
         return pallas_level_histogram(binned, grad, hess, live, local,
                                       width, f, b)
@@ -919,65 +854,6 @@ def _level_histogram(binned, grad, hess, live, local, width, f, b,
         # program's local rows and the psum happens on the result
         return _native_level_histogram(binned, grad, hess, live, local,
                                        width, f, b)
-
-    if choice == "onehot":
-        # MXU formulation in pure XLA (insurance for the Pallas kernel,
-        # which restructures the same contraction without materializing
-        # the one-hots): rows are chunked; per chunk the bin one-hot
-        # (chunk, F, B) is contracted against the node-expanded stats
-        # (chunk, width*3) in ONE f32 dot — bin accumulation becomes a
-        # (F*B, chunk) @ (chunk, width*3) matmul instead of a scatter.
-        # Sum order differs from segment_sum, so grad/hess match the
-        # other formulations to float tolerance (counts exactly).
-        # On-window tuning knobs (no code edits during a TPU window):
-        # MMLSPARK_TPU_ONEHOT_CHUNK (rows per dot, default 4096) and
-        # MMLSPARK_TPU_ONEHOT_BF16=1 (bf16 operands at 2x MXU rate and
-        # half the one-hot bandwidth; f32 accumulation. Counts stay
-        # exact — 0/1 and the stat values are bf16-representable only
-        # for counts — while grad/hess pick up bf16 input rounding,
-        # ~0.4% relative: an accuracy-vs-speed A/B, not a default).
-        n = binned.shape[0]
-        if n == 0:
-            # ADVICE r5: a zero-row level must return a zero histogram,
-            # not ZeroDivisionError from chunk == 0 in the padding math
-            return jnp.zeros((width, f, b, 3), jnp.float32)
-        # bad values warn once and fall back (core.env contract): they
-        # must not abort — or silently mislabel — a measurement run
-        chunk = env_int("MMLSPARK_TPU_ONEHOT_CHUNK", 4096, minimum=1)
-        chunk = min(chunk, n)
-        op_dtype = (jnp.bfloat16 if env_flag("MMLSPARK_TPU_ONEHOT_BF16")
-                    else jnp.float32)
-        pad = (-n) % chunk
-        data = jnp.stack([grad * live, hess * live, live], axis=-1)
-        bc = jnp.pad(binned, ((0, pad), (0, 0))) if pad else binned
-        dc = jnp.pad(data, ((0, pad), (0, 0))) if pad else data
-        # padded rows carry all-zero stats, so whichever node their
-        # zero-filled local id points at receives nothing
-        lc = jnp.pad(local, (0, pad)) if pad else local
-        nb = jnp.arange(b, dtype=jnp.int32)
-        nw = jnp.arange(width, dtype=jnp.int32)
-
-        def chunk_body(acc, xs):
-            cb, cd, cl = xs
-            b1h = (cb.astype(jnp.int32)[:, :, None] == nb).astype(
-                op_dtype)                               # (chunk, F, B)
-            n1h = (cl[:, None] == nw).astype(jnp.float32)
-            d2 = (n1h[:, :, None] * cd[:, None, :]).reshape(
-                chunk, width * 3).astype(op_dtype)
-            part = jnp.einsum("rfb,rk->fbk", b1h, d2,
-                              preferred_element_type=jnp.float32)
-            return acc + part, None
-
-        xs = (bc.reshape(-1, chunk, f), dc.reshape(-1, chunk, 3),
-              lc.reshape(-1, chunk))
-        acc0 = jnp.zeros((f, b, width * 3), jnp.float32)
-        if in_shard_map:
-            # the scan carry must advertise the same varying axes as
-            # the per-shard data or check_vma rejects the carry update;
-            # folding in a zero-valued data element inherits them
-            acc0 = acc0 + 0.0 * dc.reshape(-1)[0]
-        acc, _ = jax.lax.scan(chunk_body, acc0, xs)
-        return acc.reshape(f, b, width, 3).transpose(2, 0, 1, 3)
 
     if choice == "per_feature":
         data = jnp.stack([grad * live, hess * live, live], axis=-1)
@@ -990,30 +866,23 @@ def _level_histogram(binned, grad, hess, live, local, width, f, b,
         return jax.lax.fori_loop(
             0, f, body, jnp.zeros((width, f, b, 3), jnp.float32))
 
+    if choice != "separate":
+        raise ValueError(
+            f"unknown histogram formulation {choice!r}: expected one of "
+            "pallas|native|per_feature|separate")
+
+    # Three separate scalar segment_sums sharing the index vector
+    # (flat index = (local * F + f) * B + bin): shard_map-safe (no loop
+    # carry), which per_feature is not.
     n = binned.shape[0]
-    # flat index = (local * F + f) * B + bin, shared by the two
-    # remaining formulations
     base = (local[:, None] * f + jnp.arange(f, dtype=jnp.int32)[None, :]) * b
     idx = (base + binned).reshape(-1)
-
-    # Three separate scalar segment_sums sharing the index vector:
-    # shard_map-safe (no loop carry), which per_feature is not.
-    if choice == "separate":
-        outs = []
-        for chan in (grad * live, hess * live, live):
-            flat = jnp.broadcast_to(chan[:, None],
-                                    (n, f)).reshape(-1)
-            outs.append(jax.ops.segment_sum(
-                flat, idx, num_segments=width * f * b))
-        return jnp.stack(outs, axis=-1).reshape(width, f, b, 3)
-
-    data = jnp.stack([
-        jnp.broadcast_to((grad * live)[:, None], (n, f)).reshape(-1),
-        jnp.broadcast_to((hess * live)[:, None], (n, f)).reshape(-1),
-        jnp.broadcast_to(live[:, None], (n, f)).reshape(-1),
-    ], axis=-1)
-    hist = jax.ops.segment_sum(data, idx, num_segments=width * f * b)
-    return hist.reshape(width, f, b, 3)
+    outs = []
+    for chan in (grad * live, hess * live, live):
+        flat = jnp.broadcast_to(chan[:, None], (n, f)).reshape(-1)
+        outs.append(jax.ops.segment_sum(
+            flat, idx, num_segments=width * f * b))
+    return jnp.stack(outs, axis=-1).reshape(width, f, b, 3)
 
 
 def _leaf_objective_impl(g, h, lam1, lam2, extra_l2=0.0):
@@ -1193,7 +1062,7 @@ def make_build_tree(num_features: int, total_bins: int, cfg: TrainConfig,
     # kernel; the compiled-builder cache is keyed on the same env state
     hist_formulation = resolve_histogram_formulation(
         total_bins, in_shard_map=False, allow_pallas=allow_pallas,
-        allow_native=allow_native, warn=False)
+        allow_native=allow_native)
     masked_subtract = subtract and hist_formulation == "native"
     # quantization and EFB are serial single-program paths; the GSPMD /
     # shard_map builders keep f32 full-feature histograms (allow_native
@@ -1859,7 +1728,7 @@ def _get_builder(num_f: int, total_bins: int, cfg: TrainConfig, mode: str,
 
 def resolve_subtract(mode: str, total_bins: int, mesh=None) -> bool:
     """Histogram-subtraction default policy (LightGBM's sibling trick),
-    shared by the builder cache and bench attribution.
+    shared by the builder cache and the out-of-core loop.
 
     MMLSPARK_TPU_HIST_SUB=1/0 forces it on/off. Unset, subtraction is
     ON exactly when the serial single-program builder's histogram
@@ -1881,15 +1750,11 @@ def resolve_subtract(mode: str, total_bins: int, mesh=None) -> bool:
 
 
 def _hist_env_key() -> tuple:
-    """Trace-time histogram-formulation env state; every compiled-step/
-    builder cache key must include it or flipping the env vars between
-    fits in one process is silently ignored (review catch: the
-    onehot-under-shard_map parity test compared a cached default step
-    against itself)."""
-    return (env_str("MMLSPARK_TPU_HIST_FORMULATION", "").strip(),
-            env_str("MMLSPARK_TPU_ONEHOT_CHUNK", "").strip(),
-            env_flag("MMLSPARK_TPU_ONEHOT_BF16"),
-            env_str("MMLSPARK_TPU_HIST_SUB", "").strip(),
+    """Trace-time histogram-policy state; every compiled-step/builder
+    cache key must include it or flipping the env vars (or the native
+    library's availability) between fits in one process is silently
+    ignored and a cached step built under the other policy runs."""
+    return (env_str("MMLSPARK_TPU_HIST_SUB", "").strip(),
             env_str("MMLSPARK_TPU_NATIVE_HIST", "").strip(),
             env_str("MMLSPARK_TPU_HIST_QUANT", "").strip(),
             env_str("MMLSPARK_TPU_HIST_SHARD", "").strip(),
@@ -2142,7 +2007,7 @@ def aot_lower_step(cfg: TrainConfig, n: int, num_f: int,
                    valid_rows: int = 0) -> str:
     """AOT-lower ONE fused boosting step for ``platform`` and return
     its StableHLO text — the exact program ``train()`` dispatches per
-    iteration (bench.py's hot loop), checkable on any host. Used by
+    iteration, checkable on any host. Used by
     tests/parallel/test_mosaic_lowering.py to gate TPU-day risk, and
     handy on TPU day itself to inspect what XLA is given.
 
@@ -2230,9 +2095,10 @@ class TrainResult:
     booster: BoosterArrays
     evals: List[Dict[str, float]] = field(default_factory=list)
     best_iteration: int = -1
-    # histogram-path provenance for this fit (bench.py copies it into
-    # the artifact so a throughput swing is attributable without
-    # rerunning): resolved grow policy, quant mode, EFB bundle counts
+    # histogram-path provenance for this fit (the benchmark's
+    # expect_hist_stats reads it, so a throughput swing is attributable
+    # without rerunning): formulation, grow policy, quant mode, EFB
+    # bundle counts
     hist_stats: Dict[str, object] = field(default_factory=dict)
 
 

@@ -5,7 +5,7 @@ float thresholds (booster/LightGBMBooster.scala:394,520-557). When the
 caller holds the binned matrix, routing can compare uint8 bin ids
 against the stored threshold_bin — results must be IDENTICAL to raw
 scoring because threshold_value is exactly the upper edge of
-threshold_bin (VERDICT r4 #4; tools/bench_scoring.py measures the A/B).
+threshold_bin (VERDICT r4 #4).
 """
 
 import numpy as np

@@ -18,6 +18,7 @@ import pytest
 from mmlspark_tpu.core import faults
 from mmlspark_tpu.core.dataframe import DataFrame
 from mmlspark_tpu.core.faults import FaultInjected
+from mmlspark_tpu.models.gbdt import trainer as trainer_mod
 from mmlspark_tpu.models.gbdt.estimators import LightGBMRegressor
 
 
@@ -162,7 +163,10 @@ def test_level_hist_corruption_reaches_the_model(monkeypatch):
     """Arming corrupt on ``gbdt.level_hist`` must change the trained
     model — proof the injection point sits on the real data path (a
     zeroed histogram kills every split)."""
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_FORMULATION", "native")
+    # the injection point is the native callback; where the library is
+    # missing the bindings fall back to numpy behind the same callback
+    monkeypatch.setattr(trainer_mod, "native_histogram_available",
+                        lambda: True)
     rng = np.random.default_rng(5)
     x = rng.normal(size=(400, 3))
     y = 2.0 * x[:, 0] + rng.normal(size=400) * 0.1

@@ -1,9 +1,9 @@
 """Native C++ level-histogram kernel vs the XLA formulations, and the
-unified best-available dispatch policy (ISSUE 1 tentpole).
+one dispatch policy (``resolve_histogram_formulation``).
 
 The native kernel (native/data_plane.cpp mmls_level_hist_*) is the CPU
 default, so most of the suite exercises it implicitly; these tests pin
-it EXPLICITLY against every XLA formulation — with and without the
+it EXPLICITLY against both XLA formulations — with and without the
 compiled library (numpy fallback), across empty nodes, subtraction
 on/off, and per-shard inside both explicit shard_map tree learners.
 """
@@ -50,44 +50,39 @@ def _fit_data(n=1500, f=6, max_bin=64, seed=11):
     return x, y, mapper.transform(x), mapper.bin_upper_values(max_bin)
 
 
-# the XLA formulations agree exactly with each other (pinned by
-# test_hist_pallas.py::test_formulation_override_agrees), so the shape
-# matrix runs against per_feature only and one case fans out across
-# the other formulations — same coverage, ~half the jit compiles
+# the two XLA formulations agree exactly with each other (pinned by
+# test_hist_pallas.py::test_separate_agrees_with_per_feature), so the
+# shape matrix runs mostly against per_feature and two cases take
+# separate — same coverage, fewer jit compiles
 @pytest.mark.parametrize("n,f,b,width,bin_dtype,xla", [
     (2000, 7, 32, 4, np.uint8, "per_feature"),    # generic
     (2000, 7, 32, 4, np.uint8, "separate"),
-    (2000, 7, 32, 4, np.uint8, "fused"),
+    (1500, 6, 300, 2, np.int32, "separate"),      # more than 256 bins
     (999, 3, 255, 8, np.int32, "per_feature"),    # int32, full bin range
     (100, 5, 16, 16, np.uint8, "per_feature"),    # empty nodes
     (4096, 2, 64, 1, np.uint8, "per_feature"),    # root level
     (3000, 4, 63, 32, np.uint8, "per_feature"),   # wide level, many nodes
 ])
-def test_native_matches_xla_formulations(n, f, b, width, bin_dtype, xla,
-                                         monkeypatch):
+def test_native_matches_xla_formulations(n, f, b, width, bin_dtype, xla):
     case = _case(n, f, b, width, bin_dtype=bin_dtype)
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_FORMULATION", "native")
     got = np.asarray(_level_histogram(*case, width, f, b,
-                                      allow_pallas=False))
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_FORMULATION", xla)
+                                      formulation="native"))
     ref = np.asarray(_level_histogram(*case, width, f, b,
-                                      allow_pallas=False))
+                                      formulation=xla))
     assert got.shape == ref.shape == (width, f, b, 3)
     np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-4)
     # counts are integers: exact
     np.testing.assert_array_equal(got[..., 2], ref[..., 2])
 
 
-def test_bitwise_exact_on_integer_stats(monkeypatch):
+def test_bitwise_exact_on_integer_stats():
     """Integer-valued grad/hess make every f32 add exact, so summation
     order cannot matter: native must be bit-for-bit against XLA."""
     case = _case(3000, 4, 63, 8, integer_stats=True)
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_FORMULATION", "native")
     got = np.asarray(_level_histogram(*case, 8, 4, 63,
-                                      allow_pallas=False))
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_FORMULATION", "fused")
+                                      formulation="native"))
     ref = np.asarray(_level_histogram(*case, 8, 4, 63,
-                                      allow_pallas=False))
+                                      formulation="separate"))
     np.testing.assert_array_equal(got, ref)
 
 
@@ -96,54 +91,59 @@ def test_numpy_fallback_parity(monkeypatch):
     (bincount fallback) and agree with the C++ kernel — the acceptance
     path for compiler-less environments."""
     case = _case(2500, 5, 31, 8, seed=3)
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_FORMULATION", "native")
     native = np.asarray(_level_histogram(*case, 8, 5, 31,
-                                         allow_pallas=False))
+                                         formulation="native"))
     monkeypatch.setattr(bindings_mod, "ensure_built", lambda: False)
     fallback = np.asarray(_level_histogram(*case, 8, 5, 31,
-                                           allow_pallas=False))
+                                           formulation="native"))
     np.testing.assert_allclose(fallback, native, rtol=1e-5, atol=1e-4)
     np.testing.assert_array_equal(fallback[..., 2], native[..., 2])
 
 
-@pytest.mark.parametrize("formulation", ["native", "onehot"])
-def test_empty_input_returns_zero_histogram(formulation, monkeypatch):
-    """ADVICE r5 regression: a zero-row level used to raise
-    ZeroDivisionError in the onehot chunk math; native must handle the
-    degenerate shape too."""
+@pytest.mark.parametrize("formulation",
+                         ["native", "per_feature", "separate"])
+def test_empty_input_returns_zero_histogram(formulation):
+    """A zero-row level must return a zero histogram of the level's
+    shape on every formulation (ADVICE r5: chunk math once divided by
+    the row count)."""
     case = _case(0, 4, 16, 2)
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_FORMULATION", formulation)
     out = np.asarray(_level_histogram(*case, 2, 4, 16,
-                                      allow_pallas=False))
+                                      formulation=formulation))
     assert out.shape == (2, 4, 16, 3)
     assert not out.any()
 
 
-def test_forced_per_feature_warns_under_shard_map(monkeypatch):
-    """ADVICE r5: the forced-per_feature -> separate downgrade inside
-    shard_map must warn once (mistyped values already did), so A/B
-    measurement labels stay honest."""
-    monkeypatch.setattr(trainer_mod, "_WARNED_SHARD_DOWNGRADE", False)
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_FORMULATION", "per_feature")
-    with pytest.warns(UserWarning, match="per_feature"):
-        choice = resolve_histogram_formulation(31, in_shard_map=True,
-                                               allow_pallas=False)
-    assert choice == "separate"
-    # outside shard_map the forced value is honored, no warning
+# every row of the policy, from what the code can observe: backend,
+# whether the native library loaded, shard_map, whether GSPMD would
+# have to partition the call (allow_*), and the bin count
+@pytest.mark.parametrize(
+    "backend,native_lib,in_shard_map,bins,allow,expect", [
+        ("tpu", False, False, 255, True, "pallas"),
+        ("tpu", False, True, 255, True, "pallas"),
+        ("tpu", False, False, 1023, True, "per_feature"),
+        ("tpu", False, True, 1023, True, "separate"),
+        ("tpu", False, False, 255, False, "per_feature"),
+        ("tpu", True, False, 255, False, "per_feature"),
+        ("cpu", True, False, 255, True, "native"),
+        ("cpu", True, True, 255, True, "native"),
+        ("cpu", True, False, 1023, True, "native"),
+        ("cpu", True, False, 255, False, "per_feature"),
+        ("cpu", False, False, 255, True, "per_feature"),
+        ("cpu", False, True, 255, True, "separate"),
+        ("cpu", False, True, 1023, True, "separate"),
+    ])
+def test_resolution_policy_table(backend, native_lib, in_shard_map, bins,
+                                 allow, expect, monkeypatch):
+    import jax
+
+    for name in ("MMLSPARK_TPU_PALLAS_HIST", "MMLSPARK_TPU_NATIVE_HIST"):
+        monkeypatch.delenv(name, raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(trainer_mod, "native_histogram_available",
+                        lambda: native_lib)
     assert resolve_histogram_formulation(
-        31, in_shard_map=False, allow_pallas=False) == "per_feature"
-
-
-def test_forced_native_warns_under_gspmd(monkeypatch):
-    """allow_native=False models the serial-builder-under-mesh (GSPMD)
-    case: a forced native request must downgrade loudly, not silently
-    mislabel an A/B run."""
-    monkeypatch.setattr(trainer_mod, "_WARNED_NATIVE_DOWNGRADE", False)
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_FORMULATION", "native")
-    with pytest.warns(UserWarning, match="native"):
-        choice = resolve_histogram_formulation(31, allow_native=False,
-                                               allow_pallas=False)
-    assert choice in ("per_feature", "separate", "fused")
+        bins, in_shard_map=in_shard_map, allow_pallas=allow,
+        allow_native=allow) == expect
 
 
 def test_default_resolution_policy(monkeypatch):
@@ -158,7 +158,7 @@ def test_default_resolution_policy(monkeypatch):
     assert resolve_subtract("voting", 255) is False
     monkeypatch.setenv("MMLSPARK_TPU_NATIVE_HIST", "0")
     assert resolve_histogram_formulation(255) == "per_feature"
-    assert resolve_histogram_formulation(255, in_shard_map=True) == "fused"
+    assert resolve_histogram_formulation(255, in_shard_map=True) == "separate"
     assert resolve_subtract("serial", 255) is False
     # the explicit env override still forces subtraction on XLA
     monkeypatch.setenv("MMLSPARK_TPU_HIST_SUB", "1")

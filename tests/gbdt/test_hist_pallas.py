@@ -1,8 +1,11 @@
 """Pallas histogram kernel vs the XLA formulations (VERDICT r3 #2).
 
-Interpret mode on CPU; the TPU compile + timing runs through
-``bench_hist.py``'s ``pallas`` variant on real hardware.
+Interpret mode on CPU; the TPU compile runs in
+``tests/parallel/test_mosaic_lowering.py`` and the kernel itself on the
+chip in ``chip_smoke.py`` and the benchmark's fit cell.
 """
+
+from dataclasses import replace
 
 import numpy as np
 import pytest
@@ -195,6 +198,23 @@ def test_trainer_env_flag_routes_to_pallas(monkeypatch):
     np.testing.assert_allclose(p0, p1, rtol=1e-4, atol=1e-4)
 
 
+def _shard_fit_case(tree_learner):
+    """Rows, labels, bins and the config of the shard_map builder
+    parity fits (8 features, 32 bins, 4 trees of depth 4)."""
+    from mmlspark_tpu.models.gbdt.trainer import TrainConfig
+    from mmlspark_tpu.ops.binning import BinMapper
+
+    rng = np.random.default_rng(5)
+    x = rng.normal(size=(512, 8))
+    logit = 1.5 * x[:, 0] - x[:, 1] + 0.5 * x[:, 2]
+    y = (logit + rng.normal(size=512) * 0.3 > 0).astype(np.float64)
+    mapper = BinMapper.fit(x, max_bin=32)
+    cfg = TrainConfig(objective="binary", num_iterations=4, num_leaves=15,
+                      max_depth=4, min_data_in_leaf=5, max_bin=32,
+                      tree_learner=tree_learner, top_k=8)
+    return x, y, mapper.transform(x), mapper.bin_upper_values(32), cfg
+
+
 @pytest.mark.parametrize("tree_learner,mesh_cfg", [
     ("voting", dict(dp=8)),
     ("feature", dict(dp=1, fp=8)),
@@ -205,21 +225,11 @@ def test_pallas_under_shard_map_modes(monkeypatch, tree_learner, mesh_cfg):
     per-shard (local rows only, psum on the returned histogram) and
     reproduce the XLA path's trees exactly (VERDICT r4 weak #3 — without
     this the flagship kernel is single-chip-only)."""
-    from mmlspark_tpu.models.gbdt.trainer import TrainConfig, train
-    from mmlspark_tpu.ops.binning import BinMapper
+    from mmlspark_tpu.models.gbdt.trainer import train
     from mmlspark_tpu.parallel.mesh import MeshConfig, create_mesh
 
     mesh = create_mesh(MeshConfig(**mesh_cfg))
-    rng = np.random.default_rng(5)
-    x = rng.normal(size=(512, 8))
-    logit = 1.5 * x[:, 0] - x[:, 1] + 0.5 * x[:, 2]
-    y = (logit + rng.normal(size=512) * 0.3 > 0).astype(np.float64)
-    mapper = BinMapper.fit(x, max_bin=32)
-    binned = mapper.transform(x)
-    bu = mapper.bin_upper_values(32)
-    cfg = TrainConfig(objective="binary", num_iterations=4, num_leaves=15,
-                      max_depth=4, min_data_in_leaf=5, max_bin=32,
-                      tree_learner=tree_learner, top_k=8)
+    x, y, binned, bu, cfg = _shard_fit_case(tree_learner)
     base = train(binned, y, cfg, bin_upper=bu, mesh=mesh)
 
     monkeypatch.setenv("MMLSPARK_TPU_PALLAS_HIST", "1")
@@ -301,126 +311,55 @@ def test_histogram_subtraction_matches_full(monkeypatch):
             == sub.booster.split_feature[:, 0]).all()
 
 
-@pytest.mark.parametrize("forced", ["per_feature", "separate", "fused"])
-def test_formulation_override_agrees(forced, monkeypatch):
-    """MMLSPARK_TPU_HIST_FORMULATION selects each XLA formulation; all
-    must produce identical histograms (the separate branch is the
-    production default for shard_map on TPU and is otherwise never
-    selected on CPU, so this is its coverage). The unforced default on
-    CPU is now the native kernel (pinned to float tolerance in
-    test_hist_native.py), so the exact-equality reference here is the
-    fused scatter."""
+def test_separate_agrees_with_per_feature():
+    """The two XLA formulations must produce identical histograms:
+    separate is what every shard_map builder runs wherever neither
+    kernel is selectable (the TPU above 256 bins, the CPU without the
+    native library) and is otherwise never selected outside shard_map,
+    so this is its coverage at the level function."""
     binned, grad, hess, live, local = _case(3000, 5, 31, 8, seed=3)
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_FORMULATION", "fused")
     ref = np.asarray(_level_histogram(binned, grad, hess, live, local,
-                                      8, 5, 31, allow_pallas=False))
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_FORMULATION", forced)
+                                      8, 5, 31, formulation="per_feature"))
     out = np.asarray(_level_histogram(binned, grad, hess, live, local,
-                                      8, 5, 31, allow_pallas=False))
+                                      8, 5, 31, formulation="separate"))
     np.testing.assert_array_equal(out, ref)
 
 
-def test_formulation_override_bogus_value_warns_and_uses_default(
-        monkeypatch):
+def test_unknown_formulation_raises():
+    """A pre-resolved name the dispatch does not know must not fall
+    through to some scatter in silence."""
+    binned, grad, hess, live, local = _case(100, 3, 15, 4, seed=4)
+    with pytest.raises(ValueError, match="fused"):
+        _level_histogram(binned, grad, hess, live, local, 4, 3, 15,
+                         formulation="fused")
+
+
+@pytest.mark.parametrize("tree_learner,mesh_cfg,tree_mode", [
+    ("voting", dict(dp=8), "voting"),
+    ("feature", dict(dp=1, fp=8), "feature"),
+    ("serial", dict(dp=8), "data_sharded"),
+])
+def test_separate_under_shard_map_modes(monkeypatch, tree_learner,
+                                        mesh_cfg, tree_mode):
+    """With neither kernel selectable the shard_map builders run the
+    separate formulation (the TPU's multi-chip path above 256 bins);
+    each must reproduce the serial per_feature fit."""
     from mmlspark_tpu.models.gbdt import trainer as trainer_mod
-    monkeypatch.setattr(trainer_mod, "_WARNED_BAD_FORMULATION", False)
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_FORMULATION", "perfeature")
-    binned, grad, hess, live, local = _case(1000, 3, 15, 4, seed=4)
-    with pytest.warns(UserWarning, match="perfeature"):
-        ref = np.asarray(_level_histogram(
-            binned, grad, hess, live, local, 4, 3, 15,
-            allow_pallas=False))
-    monkeypatch.delenv("MMLSPARK_TPU_HIST_FORMULATION")
-    out = np.asarray(_level_histogram(binned, grad, hess, live, local,
-                                      4, 3, 15, allow_pallas=False))
-    np.testing.assert_array_equal(ref, out)
-
-
-def test_onehot_formulation_matches_to_tolerance(monkeypatch):
-    """The MXU one-hot contraction sums in a different order than
-    segment_sum: counts must be exact (integer f32 sums), grad/hess to
-    float tolerance."""
-    binned, grad, hess, live, local = _case(5000, 7, 31, 8, seed=5)
-    ref = np.asarray(_level_histogram(binned, grad, hess, live, local,
-                                      8, 7, 31, allow_pallas=False))
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_FORMULATION", "onehot")
-    out = np.asarray(_level_histogram(binned, grad, hess, live, local,
-                                      8, 7, 31, allow_pallas=False))
-    np.testing.assert_array_equal(out[..., 2], ref[..., 2])
-    np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-4)
-
-
-def test_onehot_formulation_padded_tail(monkeypatch):
-    """n not divisible by the chunk: padded rows must contribute
-    nothing."""
-    binned, grad, hess, live, local = _case(4999, 3, 15, 4, seed=6)
-    ref = np.asarray(_level_histogram(binned, grad, hess, live, local,
-                                      4, 3, 15, allow_pallas=False))
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_FORMULATION", "onehot")
-    out = np.asarray(_level_histogram(binned, grad, hess, live, local,
-                                      4, 3, 15, allow_pallas=False))
-    np.testing.assert_array_equal(out[..., 2], ref[..., 2])
-    np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-4)
-
-
-@pytest.mark.parametrize("extra,rtol", [
-    ({"MMLSPARK_TPU_ONEHOT_CHUNK": "3000"}, 2e-5),  # non-divisor
-    ({"MMLSPARK_TPU_ONEHOT_CHUNK": "zero?"}, 2e-5),  # bad: warn + default
-    ({"MMLSPARK_TPU_ONEHOT_BF16": "1"}, 1e-2),
-])
-def test_onehot_tuning_knobs(monkeypatch, extra, rtol):
-    """Chunk-size and bf16 knobs (on-window A/Bs) keep counts exact and
-    grad/hess within the knob's documented tolerance."""
-    binned, grad, hess, live, local = _case(5000, 7, 31, 8, seed=7)
-    ref = np.asarray(_level_histogram(binned, grad, hess, live, local,
-                                      8, 7, 31, allow_pallas=False))
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_FORMULATION", "onehot")
-    for k, v in extra.items():
-        monkeypatch.setenv(k, v)
-    bad_chunk = not extra.get("MMLSPARK_TPU_ONEHOT_CHUNK",
-                              "1").lstrip("-").isdigit()
-    if bad_chunk:
-        from mmlspark_tpu.core import env as env_mod
-        env_mod.reset_warnings()
-        with pytest.warns(UserWarning, match="ONEHOT_CHUNK"):
-            out = np.asarray(_level_histogram(
-                binned, grad, hess, live, local, 8, 7, 31,
-                allow_pallas=False))
-    else:
-        out = np.asarray(_level_histogram(
-            binned, grad, hess, live, local, 8, 7, 31,
-            allow_pallas=False))
-    np.testing.assert_array_equal(out[..., 2], ref[..., 2])
-    np.testing.assert_allclose(out, ref, rtol=rtol, atol=rtol * 10)
-
-
-@pytest.mark.parametrize("tree_learner,mesh_cfg", [
-    ("voting", dict(dp=8)),
-    ("feature", dict(dp=1, fp=8)),
-])
-def test_onehot_under_shard_map_modes(monkeypatch, tree_learner,
-                                      mesh_cfg):
-    """The onehot formulation is shard_map-safe (the scan carry
-    inherits the per-shard varying axes) so multi-chip training can
-    select it if it wins the TPU microbench."""
-    from mmlspark_tpu.models.gbdt.trainer import TrainConfig, train
-    from mmlspark_tpu.ops.binning import BinMapper
+    from mmlspark_tpu.models.gbdt.trainer import train
     from mmlspark_tpu.parallel.mesh import MeshConfig, create_mesh
 
+    monkeypatch.setattr(trainer_mod, "native_histogram_available",
+                        lambda: False)
     mesh = create_mesh(MeshConfig(**mesh_cfg))
-    rng = np.random.default_rng(5)
-    x = rng.normal(size=(512, 8))
-    logit = 1.5 * x[:, 0] - x[:, 1] + 0.5 * x[:, 2]
-    y = (logit + rng.normal(size=512) * 0.3 > 0).astype(np.float64)
-    mapper = BinMapper.fit(x, max_bin=32)
-    binned = mapper.transform(x)
-    bu = mapper.bin_upper_values(32)
-    cfg = TrainConfig(objective="binary", num_iterations=4, num_leaves=15,
-                      max_depth=4, min_data_in_leaf=5, max_bin=32,
-                      tree_learner=tree_learner, top_k=8)
-    base = train(binned, y, cfg, bin_upper=bu, mesh=mesh)
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_FORMULATION", "onehot")
-    oh = train(binned, y, cfg, bin_upper=bu, mesh=mesh)
-    p0 = np.asarray(base.booster.predict_jit()(x))
-    p1 = np.asarray(oh.booster.predict_jit()(x))
+    x, y, binned, bu, cfg = _shard_fit_case(tree_learner)
+    serial = train(binned, y, replace(cfg, tree_learner="serial"),
+                   bin_upper=bu)
+    assert serial.hist_stats["hist_formulation"] == "per_feature"
+    sharded = train(binned, y, cfg, bin_upper=bu, mesh=mesh)
+    assert sharded.hist_stats["hist_formulation"] == "separate"
+    assert sharded.hist_stats["tree_mode"] == tree_mode
+    p0 = np.asarray(serial.booster.predict_jit()(x))
+    p1 = np.asarray(sharded.booster.predict_jit()(x))
     np.testing.assert_allclose(p0, p1, rtol=1e-4, atol=1e-4)
+
+

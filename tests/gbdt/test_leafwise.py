@@ -48,17 +48,22 @@ def _booster_equal(b1, b2):
                                       err_msg=fld)
 
 
-@pytest.mark.parametrize("formulation", ["", "native", "flat"])
-def test_repeated_fits_bit_identical(formulation):
+@pytest.mark.parametrize("native_lib,expect", [
+    (None, None), (True, "native"), (False, "per_feature")])
+def test_repeated_fits_bit_identical(native_lib, expect, monkeypatch):
     """Same data + seed + policy -> bit-identical booster, for the
-    auto, native-callback, and pure-XLA histogram formulations."""
+    histogram the host resolves by itself, the native callback (numpy
+    fallback where the library is missing) and the XLA formulation."""
     binned, y = _fit_case()
-    with env_override("MMLSPARK_TPU_GROW_POLICY", "leafwise"), \
-            env_override("MMLSPARK_TPU_HIST_FORMULATION",
-                         formulation or None):
+    if native_lib is not None:
+        monkeypatch.setattr(trainer_mod, "native_histogram_available",
+                            lambda: native_lib)
+    with env_override("MMLSPARK_TPU_GROW_POLICY", "leafwise"):
         r1 = train(binned, y, _cfg())
         r2 = train(binned, y, _cfg())
     assert r1.hist_stats["grow_policy"] == "leafwise"
+    if expect is not None:
+        assert trainer_mod.resolve_histogram_formulation(64) == expect
     _booster_equal(r1.booster, r2.booster)
 
 

@@ -51,11 +51,18 @@ def _nan_corrupt(h):
 _KW = dict(numIterations=3, numLeaves=4, maxBin=16)
 
 
+def _native_hist(monkeypatch):
+    """These fits need the histogram callback boundary; where the
+    library is missing the bindings fall back to numpy behind it."""
+    monkeypatch.setattr(trainer_mod, "native_histogram_available",
+                        lambda: True)
+
+
 def test_injected_hist_nan_caught_at_named_boundary(monkeypatch):
     """SAN=1 + armed NaN corruption on the histogram callback must
     abort the fit with a diagnostic naming the jit boundary. jax wraps
     callback exceptions, so match on the message, not the type."""
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_FORMULATION", "native")
+    _native_hist(monkeypatch)
     san.enable()
     with faults.injected("gbdt.level_hist", "corrupt", count=None,
                          corrupt=_nan_corrupt):
@@ -71,7 +78,7 @@ def test_injected_hist_nan_is_silent_with_sanitizer_off(monkeypatch):
     """The control arm: without the sanitizer the NaN histogram is
     absorbed (NaN gain -> -inf -> no split) and the fit completes —
     the silent failure mode the guard closes."""
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_FORMULATION", "native")
+    _native_hist(monkeypatch)
     assert not san.enabled()
     with faults.injected("gbdt.level_hist", "corrupt", count=None,
                          corrupt=_nan_corrupt):
@@ -82,7 +89,7 @@ def test_injected_hist_nan_is_silent_with_sanitizer_off(monkeypatch):
 def test_clean_fit_has_no_false_positives(monkeypatch):
     """SAN=1 over an uncorrupted native-histogram fit: every boundary
     guard (entry, callback, metrics sync, exit) sees finite data."""
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_FORMULATION", "native")
+    _native_hist(monkeypatch)
     san.enable()
     model = LightGBMRegressor(**_KW).fit(_df())
     pred = np.asarray(model.transform(_df())["prediction"])

@@ -243,18 +243,21 @@ def test_vw_sharded_pass_lowers_for_tpu():
     assert len(_lower_tpu(fn, *args)) > 1000
 
 
-@pytest.mark.parametrize("flags", [
-    {},
-    {"MMLSPARK_TPU_PALLAS_HIST": "1",
-     "MMLSPARK_TPU_PALLAS_FORCE_COMPILE": "1"},
-    {"MMLSPARK_TPU_HIST_SUB": "1"},
-    {"MMLSPARK_TPU_HIST_FORMULATION": "onehot"},
+@pytest.mark.parametrize("flags,max_bin", [
+    ({}, 255),
+    ({"MMLSPARK_TPU_PALLAS_HIST": "1",
+      "MMLSPARK_TPU_PALLAS_FORCE_COMPILE": "1"}, 255),
+    ({"MMLSPARK_TPU_HIST_SUB": "1"}, 255),
+    # more than 256 bins: the kernel is enabled as on the chip and the
+    # policy must still leave it for per_feature
+    ({"MMLSPARK_TPU_PALLAS_HIST": "1",
+      "MMLSPARK_TPU_PALLAS_FORCE_COMPILE": "1"}, 1023),
 ])
-def test_full_fused_step_lowers_for_tpu(monkeypatch, flags):
+def test_full_fused_step_lowers_for_tpu(monkeypatch, flags, max_bin):
     """The ENTIRE fused boosting step (gradients -> tree build -> raw
-    update -> metrics) at bench config, in every kernel
+    update -> metrics) at the fit cell's configuration, in every kernel
     configuration a chip run can select — the exact per-iteration
-    program bench.py dispatches."""
+    program ``train()`` dispatches."""
     for kk, vv in flags.items():
         monkeypatch.setenv(kk, vv)
     from mmlspark_tpu.models.gbdt.trainer import (
@@ -263,32 +266,29 @@ def test_full_fused_step_lowers_for_tpu(monkeypatch, flags):
     )
 
     cfg = TrainConfig(objective="binary", num_leaves=63, max_depth=6,
-                      max_bin=255, min_data_in_leaf=20)
+                      max_bin=max_bin, min_data_in_leaf=20)
     txt = aot_lower_step(cfg, n=8192, num_f=28, platform="tpu")
     assert len(txt) > 1000
-    if "MMLSPARK_TPU_PALLAS_HIST" in flags:
-        assert "tpu_custom_call" in txt  # the Mosaic histogram kernel
+    # the Mosaic histogram kernel, exactly where the policy selects it
+    assert ("tpu_custom_call" in txt) == (
+        "MMLSPARK_TPU_PALLAS_HIST" in flags and max_bin <= 256)
 
 
 def test_resnet50_scoring_lowers_for_tpu():
-    """The ONNX->XLA ResNet-50 (bench_onnx's exact graph) lowers for
-    TPU — the converter's conv/BN/pool emission must pass TPU rules."""
-    import os
-    import sys
-
+    """The ONNX->XLA ResNet-50 (the transform cell's graph, at the
+    published stages) lowers for TPU — the converter's conv/BN/pool
+    emission must pass TPU rules."""
     import jax.numpy as jnp
 
-    repo = os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))))
-    sys.path.insert(0, repo)
-    try:
-        from bench_onnx import _resnet50_proto
-    finally:
-        sys.path.pop(0)
+    from benchmark.lookup import load_module
     from mmlspark_tpu.onnx import convert_model
 
+    builder = load_module("builders", "resnet50_onnx")
+    stages = [(3, 64), (4, 128), (6, 256), (3, 512)]
+    payload = builder.make_proto(builder.make_weights(0, stages), stages,
+                                 image=224)
     rng = np.random.default_rng(0)
-    run = convert_model(_resnet50_proto(rng)).convert()
+    run = convert_model(payload).convert()
     x = jnp.asarray(rng.normal(size=(4, 3, 224, 224)).astype(np.float32))
     graph_in = "x"
     txt = _lower_tpu(lambda xx: run({graph_in: xx}), x)
@@ -488,11 +488,13 @@ def test_lowering_check_is_not_vacuous():
         _lower_tpu(bad, jnp.zeros((256, 128), jnp.float32))
 
 
-def test_voting_builder_with_onehot_lowers_for_tpu(monkeypatch):
-    """The onehot formulation inside the voting shard_map builder (the
-    multi-chip fallback if Mosaic rejects the Pallas kernel) passes TPU
-    lowering with check_vma on."""
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_FORMULATION", "onehot")
+def test_voting_builder_with_separate_lowers_for_tpu(monkeypatch):
+    """The separate formulation inside the voting shard_map builder (the
+    chip's multi-chip path above 256 bins, where the policy leaves the
+    enabled Pallas kernel) passes TPU lowering with check_vma on."""
+    monkeypatch.setenv("MMLSPARK_TPU_PALLAS_HIST", "1")
+    monkeypatch.setenv("MMLSPARK_TPU_PALLAS_FORCE_COMPILE", "1")
+    monkeypatch.setenv("MMLSPARK_TPU_NATIVE_HIST", "0")
 
     import jax.numpy as jnp
 
@@ -507,19 +509,20 @@ def test_voting_builder_with_onehot_lowers_for_tpu(monkeypatch):
 
     mesh = create_mesh(MeshConfig(dp=8))
     cfg = _loop_only_normalized(TrainConfig(
-        objective="binary", num_leaves=15, max_depth=4, max_bin=64,
+        objective="binary", num_leaves=15, max_depth=4, max_bin=1023,
         top_k=8))
-    fn = make_build_tree_voting(8, 64, cfg, mesh)
+    fn = make_build_tree_voting(8, 1023, cfg, mesh)
     n, f = 1024, 8
     rng = np.random.default_rng(0)
-    args = (jnp.asarray(rng.integers(0, 64, size=(n, f)).astype(np.uint8)),
+    args = (jnp.asarray(
+                rng.integers(0, 1023, size=(n, f)).astype(np.int32)),
             jnp.asarray(rng.normal(size=n).astype(np.float32)),
             jnp.asarray(rng.uniform(0.1, 1, size=n).astype(np.float32)),
             jnp.ones(n, jnp.float32),
             jnp.ones(f, jnp.float32),
             jnp.int32(15))
     txt = _lower_tpu(fn, *args)
-    assert "dot" in txt or len(txt) > 1000
+    assert len(txt) > 1000 and "tpu_custom_call" not in txt
 
 
 def test_retention_kernels_lower_to_mosaic_at_the_published_widths():
