@@ -25,6 +25,7 @@ from mmlspark_tpu.core.param import (
 from mmlspark_tpu.core.pipeline import Transformer
 from mmlspark_tpu.core.timer import span
 from mmlspark_tpu.onnx.convert import OnnxGraph, load_model
+from mmlspark_tpu.ops.ingest import RowSource
 
 
 class ONNXModel(Transformer):
@@ -100,27 +101,31 @@ class ONNXModel(Transformer):
         fetch = self.get("fetchDict") or {
             "output": graph.output_names[0]}
 
-        # spans (core/timer.py): ``onnx.stack`` is the object column made
-        # one array, ``onnx.cast`` the dtype pass, ``onnx.columns`` the
-        # output columns and post-ops; the engine's own are ``scorer.*``
+        # spans (core/timer.py): ``onnx.stack`` is an object column laid
+        # out as one array, here only checked: the engine lays it out, a
+        # chunk at a time where a group is over a chunk's bytes, each
+        # lay-out an ``onnx.stack`` again. ``onnx.cast`` is the dtype
+        # pass (of an object column: the dtype its lay-outs cast to),
+        # ``onnx.columns`` the output columns and post-ops; the engine's
+        # own are ``scorer.*``
         feeds = {}
         for input_name, col_name in feed.items():
             col = dataset.col(col_name)
             with span("onnx.stack", rows=len(col)) as stack:
-                if col.dtype == object:
-                    batch = np.stack([np.asarray(v) for v in col])
-                else:
-                    batch = col
+                batch = (RowSource(col, "onnx.stack") if col.dtype == object
+                         else col)
                 stack.counts["bytes"] = batch.nbytes
             # honor the graph's declared input dtype; otherwise keep
             # int/bool columns intact and only downcast f64 -> f32
             with span("onnx.cast"):
                 declared = graph.input_dtypes.get(input_name)
+                if declared is None and batch.dtype == np.float64:
+                    declared = np.float32
                 if declared is not None:
-                    batch = np.asarray(batch, declared)
-                elif batch.dtype == np.float64:
-                    batch = batch.astype(np.float32)
-                feeds[input_name] = np.asarray(batch)
+                    batch = (batch.astype(declared)
+                             if isinstance(batch, RowSource)
+                             else np.asarray(batch, declared))
+                feeds[input_name] = batch
         # one engine call: the scorer chunks to miniBatchSize-capped
         # bucket rungs internally and keeps weights resident on-device
         fetched = scorer(feeds)

@@ -5,14 +5,19 @@ The reference streams rows into the native dataset in micro-batches
 marshaling overlaps native ingestion. The TPU analog: ``device_put`` is
 asynchronous, so chunking a large host array overlaps the host-side
 prep of chunk i+1 (dtype narrowing, contiguity copy) with the wire
-transfer of chunk i — double buffering without threads. Binned GBDT
-matrices additionally narrow to uint8 (max_bin <= 256), cutting bytes
-on the wire 4x vs int32; XLA's implicit integer promotion makes the
-narrow dtype free on device (gathers/adds fuse the widening).
+transfer of chunk i. For an ndarray that is double buffering without
+threads; a column of separate row arrays (:class:`RowSource`) is laid
+out a chunk at a time by a few worker threads, into staging buffers
+that are reused, while the calling thread puts the chunk before.
+Binned GBDT matrices additionally narrow to uint8 (max_bin <= 256),
+cutting bytes on the wire 4x vs int32; XLA's implicit integer promotion
+makes the narrow dtype free on device (gathers/adds fuse the widening).
 """
 
 from __future__ import annotations
 
+import copy
+import functools
 import json
 import os
 import struct
@@ -27,32 +32,183 @@ from mmlspark_tpu.core.serialize import DiskFull
 from mmlspark_tpu.core.timer import span
 
 
-def chunked_device_put(arr: np.ndarray, sharding=None,
+# what one ``device_put`` of a chunk carries
+CHUNK_BYTES = 64 << 20
+# staging buffers a row source's chunks take in turn: one being put, one
+# being filled, one whose transfer may not have landed yet
+STAGING_DEPTH = 3
+
+
+class RowSource:
+    """A column whose rows are separate arrays (a DataFrame's object
+    column) standing for the ``(n,) + row shape`` array they would
+    stack to, which is never made on the host in one piece.
+
+    ``shape``, ``dtype`` and ``nbytes`` are that array's;
+    ``lay_out(a, b)`` gives its rows ``[a, b)``, C-contiguous and cast
+    to ``dtype`` as ``astype`` would. Rows of unequal shape, or no rows,
+    raise here what ``np.stack`` raises, before anything is put.
+    ``span_name`` is the span each lay-out is timed under: the front end
+    that owns the column names it.
+    """
+
+    def __init__(self, rows, span_name: str, dtype: Optional[Any] = None):
+        rows = [np.asarray(r) for r in rows]
+        if not rows:
+            raise ValueError("need at least one array to stack")
+        if len({r.shape for r in rows}) != 1:
+            raise ValueError("all input arrays must have the same shape")
+        if dtype is None:
+            dtype = np.result_type(*{r.dtype for r in rows})
+        self._rows, self.span_name = rows, span_name
+        self.shape = (len(rows),) + rows[0].shape
+        self.dtype = np.dtype(dtype)
+
+    @property
+    def ndim(self) -> int:
+        return len(self.shape)
+
+    @property
+    def nbytes(self) -> int:
+        return int(np.prod(self.shape, dtype=np.int64)) * self.dtype.itemsize
+
+    def __len__(self) -> int:
+        return self.shape[0]
+
+    def astype(self, dtype) -> "RowSource":
+        """The same rows, to be laid out in ``dtype``."""
+        new = copy.copy(self)
+        new.dtype = np.dtype(dtype)
+        return new
+
+    def window(self, start: int, rows: int) -> "RowSource":
+        """``rows`` rows from ``start`` on: past the last row, rows of
+        zeros (a scorer's padding of a short last group)."""
+        new = copy.copy(self)
+        new._rows = self._rows[start:start + rows]
+        new.shape = (rows,) + self.shape[1:]
+        return new
+
+    def lay_out(self, a: int, b: int, out: Optional[np.ndarray] = None):
+        """Rows ``[a, b)`` as one array: ``out`` if given, so that no
+        memory is touched for the first time. One copy a row, for each
+        of which numpy releases the GIL: a worker thread can run it."""
+        if out is None:
+            out = np.empty((b - a,) + self.shape[1:], self.dtype)
+        real = self._rows[a:b]
+        for k, row in enumerate(real):
+            out[k] = row
+        out[len(real):] = 0
+        return out
+
+
+def _writable(buf: np.ndarray, left) -> bool:
+    """May ``buf`` be written again, ``left`` being the device array of
+    the chunk that last left it? Not before the transfer has landed; and
+    never where the runtime took the host buffer itself for the array
+    (XLA:CPU does, given an aligned one): the array then reads ``buf``
+    until the concatenate has run."""
+    left.block_until_ready()
+    lo = buf.ctypes.data
+    return not any(lo <= s.data.unsafe_buffer_pointer() < lo + buf.nbytes
+                   for s in left.addressable_shards)
+
+
+def _layout_threads() -> int:
+    """Threads that lay one chunk out, each a share of its rows. A
+    616 MB image column on the chip's 13-core host took 0.098, 0.076,
+    0.075 and 0.086 s with 1, 2, 4 and 8 (PERF.md section 6, PR 32):
+    from two on the transfer sets the pace, and the copies are bound by
+    memory, not by cores."""
+    cores = (len(os.sched_getaffinity(0))
+             if hasattr(os, "sched_getaffinity") else os.cpu_count() or 1)
+    return min(4, cores)
+
+
+def _staged_chunks(source: RowSource, bounds, staging: list, parts: list):
+    """Yield ``source``'s rows for each of ``bounds`` in turn, laid out
+    in one of ``staging``'s buffers by worker threads while the caller
+    puts the chunk before (it appends each chunk's device array to
+    ``parts``). The wait for the workers, and for a buffer to become
+    writable, is the source's span, on the calling thread."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    row_shape, depth = source.shape[1:], len(staging)
+    most = max(b - a for a, b in bounds)
+    threads = _layout_threads()
+
+    def start(j):
+        a, b = bounds[j]
+        buf = staging[j % depth]
+        if (buf is None or buf.shape[1:] != row_shape
+                or buf.dtype != source.dtype or len(buf) < b - a
+                or (j >= depth and not _writable(buf, parts[j - depth]))):
+            buf = staging[j % depth] = np.empty((most,) + row_shape,
+                                                source.dtype)
+        cuts = np.linspace(0, b - a, threads + 1).astype(int)
+        return buf[:b - a], [
+            pool.submit(source.lay_out, a + s, a + e, buf[s:e])
+            for s, e in zip(cuts, cuts[1:]) if e > s]
+
+    with ThreadPoolExecutor(max_workers=threads) as pool:
+        ahead = []
+        for i in range(len(bounds)):
+            with span(source.span_name):
+                # this chunk and the next: the one after would wait for
+                # the last put to land before this one is on the wire
+                while len(ahead) < min(i + depth - 1, len(bounds)):
+                    ahead.append(start(len(ahead)))
+                part, shares = ahead[i]
+                for share in shares:
+                    share.result()
+            yield part
+
+
+@functools.lru_cache(maxsize=None)
+def _concatenate(sharding):
+    """The jitted concatenate of a put's chunks, one a result sharding:
+    made once, so JAX caches its programs by the chunks' shapes."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.jit(lambda *p: jnp.concatenate(p, axis=0),
+                   out_shardings=sharding)
+
+
+def chunked_device_put(arr, sharding=None,
                        dtype: Optional[Any] = None,
-                       chunk_bytes: int = 64 << 20,
-                       row_multiple: int = 1):
-    """Transfer ``arr`` to device in async chunks; returns the device
-    array (concatenated under one jit so the result carries
-    ``sharding``).
+                       chunk_bytes: int = CHUNK_BYTES,
+                       row_multiple: int = 1,
+                       span_name: str = "dataPreparation.transfer",
+                       staging: Optional[list] = None):
+    """Transfer ``arr``, an ndarray or a :class:`RowSource`, to device
+    in async chunks; returns the device array (concatenated under one
+    jit so the result carries ``sharding``).
 
     ``row_multiple``: chunk row counts stay multiples of this (the mesh
-    dp axis size when sharded). Small arrays fall through to one put.
+    dp axis size when sharded). An array of one chunk or less falls
+    through to one put, a row source being laid out whole first.
 
-    The whole of it is the span ``dataPreparation.transfer`` (bytes on
-    the wire, chunks): the host's share of the transfer, which is the
-    narrowing copies and the enqueues. Nothing here waits for the
-    device, so the copies still in flight at the end are paid for by
-    whoever first needs the array.
+    Each chunk's put is a span ``span_name`` (the chunk's bytes on the
+    wire, and ``chunks``, how many the whole put has): the host's share
+    of the transfer, which is an ndarray's narrowing copy and the
+    enqueue. A row source's chunks are laid out under its own span, by
+    worker threads, into ``staging`` (``STAGING_DEPTH`` buffers that
+    the caller keeps from call to call, so that no lay-out touches
+    fresh memory; a list of ``None`` to begin with).
+
+    An ndarray's put waits for nothing, so the copies still in flight at
+    the end are paid for by whoever first needs the array. A row
+    source's waits, in the last chunk's span, until the concatenated
+    array is there: the staging buffers are then the caller's again.
     """
     import jax
     import jax.numpy as jnp
 
-    if dtype is not None and arr.dtype != dtype:
-        row_nbytes = int(np.dtype(dtype).itemsize * np.prod(arr.shape[1:],
-                                                            dtype=np.int64))
-    else:
-        row_nbytes = int(arr.dtype.itemsize * np.prod(arr.shape[1:],
-                                                      dtype=np.int64))
+    source = arr if isinstance(arr, RowSource) else None
+    row_nbytes = int(np.dtype(dtype if dtype is not None
+                              else arr.dtype).itemsize
+                     * np.prod(arr.shape[1:], dtype=np.int64))
     n = arr.shape[0]
     chunk_rows = max(chunk_bytes // max(row_nbytes, 1), 1)
     chunk_rows = max(chunk_rows // row_multiple, 1) * row_multiple
@@ -63,27 +219,39 @@ def chunked_device_put(arr: np.ndarray, sharding=None,
             part = part.astype(dtype, copy=False)
         return part
 
-    with span("dataPreparation.transfer", bytes=n * row_nbytes,
-              chunks=-(-n // chunk_rows)):
-        if chunk_rows >= n:
+    if chunk_rows >= n:
+        if source is not None:
+            with span(source.span_name):
+                arr = source.lay_out(0, n)
+        with span(span_name, bytes=n * row_nbytes, chunks=1):
             full = prep(arr)
             return (jax.device_put(full, sharding) if sharding is not None
                     else jnp.asarray(full))
 
-        parts = []
-        for s in range(0, n, chunk_rows):
+    bounds = [(s, min(s + chunk_rows, n)) for s in range(0, n, chunk_rows)]
+    parts = []
+    if source is None:
+        laid = (arr[a:b] for a, b in bounds)
+    else:
+        laid = _staged_chunks(source, bounds, staging if staging is not None
+                              else [None] * STAGING_DEPTH, parts)
+    for part in laid:
+        with span(span_name, bytes=len(part) * row_nbytes,
+                  chunks=len(bounds)):
             # device_put returns immediately: the next chunk's host prep
             # overlaps this chunk's transfer. Each chunk carries the final
             # sharding (chunk rows are row_multiple-aligned), so shards go
             # straight to their devices — no single-device staging
-            part = prep(arr[s:s + chunk_rows])
+            part = prep(part)
             parts.append(jax.device_put(part, sharding)
                          if sharding is not None
                          and len(part) % row_multiple == 0
                          else jax.device_put(part))
-        concat = jax.jit(lambda *p: jnp.concatenate(p, axis=0),
-                         out_shardings=sharding)
-        return concat(*parts)
+            if len(parts) == len(bounds):
+                whole = _concatenate(sharding)(*parts)
+                if source is not None:
+                    whole.block_until_ready()
+    return whole
 
 
 def binned_ingest_dtype(total_bins: int):

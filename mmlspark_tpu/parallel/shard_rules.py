@@ -410,7 +410,8 @@ class ShardedScorer:
     ``dp``; compile count is bounded by the ladder and counted under
     graftsan. Input buffers are donated on non-CPU backends (XLA:CPU
     device_put aliases host numpy, so donation there could hand the
-    user's buffer to XLA).
+    user's buffer to XLA); of a chunked feed, what is donated is the
+    concatenated array, and the chunks are dropped once it exists.
 
     Ragged sequences take a second ladder, over length
     (``max_length``): ``length_batches(lengths)`` sorts the rows by
@@ -448,6 +449,7 @@ class ShardedScorer:
         self._length_ladder = (length_ladder(int(max_length))
                                if max_length else None)
         self._seen_rungs: set = set()
+        self._staging: Dict[str, list] = {}   # a row source's, by column
         self.autocast = resolve_infer_autocast()
         dtype = param_dtype
         if dtype is None and self.autocast == "bf16":
@@ -497,12 +499,42 @@ class ShardedScorer:
         return jax.sharding.NamedSharding(
             self._mesh, jax.sharding.PartitionSpec(*spec))
 
-    def _put(self, arr: np.ndarray):
+    def _put(self, group: Dict[str, Any]) -> Dict[str, Any]:
+        """One group's columns on the device. The ndarrays take one
+        ``device_put`` each, all under one span ``scorer.put``
+        (``chunks`` 1). A row source goes through
+        ``ops/ingest.chunked_device_put``: over a chunk's bytes its rows
+        are laid out and put a chunk at a time (``chunks`` > 1 on each
+        chunk's span), in staging buffers this scorer keeps from call
+        to call; under them, laid out whole and put once."""
         import jax
 
-        if self._mesh is not None:
-            return jax.device_put(arr, self._row_sharding(arr.ndim))
-        return jax.device_put(arr)
+        from mmlspark_tpu.ops.ingest import (STAGING_DEPTH,
+                                             chunked_device_put)
+
+        def sharding(ndim):
+            return (self._row_sharding(ndim) if self._mesh is not None
+                    else None)
+
+        arrays = {k: v for k, v in group.items()
+                  if isinstance(v, np.ndarray)}
+        placed = {}
+        if arrays:
+            with span("scorer.put", chunks=1,
+                      bytes=sum(v.nbytes for v in arrays.values())):
+                for k, v in arrays.items():
+                    placed[k] = jax.device_put(v, sharding(v.ndim))
+        for k in group.keys() - arrays.keys():
+            # checked out, so that two callers never fill one buffer
+            ring = self._staging.pop(k, None) or [None] * STAGING_DEPTH
+            try:
+                placed[k] = chunked_device_put(
+                    group[k], sharding(group[k].ndim),
+                    row_multiple=self._dp, span_name="scorer.put",
+                    staging=ring)
+            finally:
+                self._staging[k] = ring
+        return placed
 
     def _dispatch(self, group):
         if self._params is not None:
@@ -514,6 +546,10 @@ class ShardedScorer:
         as ``apply_fn``'s output, batch-dim outputs sliced to the true
         row count.
 
+        A column may be an ``ops/ingest.RowSource`` (an object column
+        as it is) in place of an ndarray: a group of it over one chunk's
+        bytes is fed to the device a chunk at a time (``_put``).
+
         Spans (core/timer.py), each group of ``dp x rung`` rows:
         ``scorer.pad`` (slice, zero-fill to the rung), ``scorer.put``
         and ``scorer.dispatch``; then one ``scorer.fetch``. Put and
@@ -521,16 +557,24 @@ class ShardedScorer:
         of each (layout and enqueue) and nothing waits for the device
         until the fetch: ``scorer.fetch`` holds the wait for the copies
         and the program as well as the copy back and the concatenate.
+        A chunked feed differs: each chunk has a span of the row
+        source's own name (its lay-out, the wait for the workers that
+        do it included) and a ``scorer.put``, whose ``chunks`` says
+        how many the group took (1: a single put), and the last
+        ``scorer.put`` waits until the chunks have landed and are one
+        array, so ``scorer.fetch`` there waits for the program and the
+        copy back alone.
         The profiler's trace, on the same clock, is what splits it into
         device-busy and device-idle.
         """
         import jax
 
         from mmlspark_tpu.core import sanitizer
+        from mmlspark_tpu.ops.ingest import RowSource
 
         is_dict = isinstance(x, dict)
-        cols = ({k: np.asarray(v) for k, v in x.items()} if is_dict
-                else {"__x__": np.asarray(x)})
+        cols = {k: v if isinstance(v, RowSource) else np.asarray(v)
+                for k, v in (x.items() if is_dict else [("__x__", x)])}
         n = next(iter(cols.values())).shape[0]
         r = self._rung(n)
         step = self._dp * r
@@ -548,6 +592,9 @@ class ShardedScorer:
             with span("scorer.pad", rows=step):
                 group = {}
                 for k, v in cols.items():
+                    if isinstance(v, RowSource):
+                        group[k] = v.window(g, step)
+                        continue
                     gv = v[g:g + step]
                     if gv.shape[0] < step:
                         fill = np.zeros(
@@ -556,9 +603,7 @@ class ShardedScorer:
                         gv = np.concatenate([gv, fill]) if gv.shape[0] \
                             else fill
                     group[k] = gv
-            with span("scorer.put",
-                      bytes=sum(gv.nbytes for gv in group.values())):
-                group = {k: self._put(gv) for k, gv in group.items()}
+            group = self._put(group)
             with span("scorer.dispatch"):
                 chunks.append(self._dispatch(
                     group if is_dict else group["__x__"]))

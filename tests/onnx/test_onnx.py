@@ -306,6 +306,48 @@ class TestONNXModelTransformer:
         assert np.array_equal(out.col("prediction"),
                               logits.argmax(axis=1).astype(np.float64))
 
+    @pytest.mark.parametrize("dtype", [np.float32, np.float64])
+    def test_object_column_is_fed_in_chunks(self, monkeypatch, dtype):
+        """An object column over a chunk's bytes reaches the device a
+        chunk at a time and gives the column one ndarray gives; the
+        root's spans hold its lay-out and its put once a chunk, and no
+        other span than before."""
+        import functools
+
+        from mmlspark_tpu.core.logging_utils import SINK
+        from mmlspark_tpu.ops import ingest
+
+        # 5 rows of 4 float32 a chunk, through the function's argument
+        monkeypatch.setattr(ingest, "chunked_device_put", functools.partial(
+            ingest.chunked_device_put, chunk_bytes=80))
+        rng = np.random.default_rng(8)
+        data, _ = _mlp_model(rng)
+        x = rng.normal(size=(41, 4)).astype(dtype)
+        col = np.empty(len(x), dtype=object)
+        for i in range(len(x)):
+            col[i] = x[i]
+        model = ONNXModel(modelPayload=data, feedDict={"x": "features"},
+                          fetchDict={"probs": "probs"}, miniBatchSize=16)
+        want = model.transform(DataFrame({"features": x})).col("probs")
+        SINK.drain()
+        got = model.transform(DataFrame({"features": col})).col("probs")
+        assert got.dtype == want.dtype and np.array_equal(got, want)
+
+        (record,) = [e for e in SINK.drain() if "spans" in e]
+        assert {s["parent"] for s in record["spans"]} == {
+            "ONNXModel.transform"}
+        names = [s["name"] for s in record["spans"]]
+        # three groups of 16 rows (the last holds 9 and zeros), each in
+        # chunks of 5, 5, 5 and 1 rows
+        group = ["scorer.pad"] + ["onnx.stack", "scorer.put"] * 4 + [
+            "scorer.dispatch"]
+        assert names == (["onnx.stack", "onnx.cast"] + group * 3
+                         + ["scorer.fetch", "onnx.columns"])
+        puts = [s["counts"] for s in record["spans"]
+                if s["name"] == "scorer.put"]
+        assert puts == [{"bytes": rows * 16, "chunks": 4}
+                        for rows in (5, 5, 5, 1)] * 3
+
     def test_slice_at_output(self):
         rng = np.random.default_rng(5)
         data, params = _mlp_model(rng)

@@ -4,6 +4,7 @@ StreamingPartitionTask.scala:203-277 micro-batch push)."""
 import time
 
 import numpy as np
+import pytest
 
 from mmlspark_tpu.ops.ingest import binned_ingest_dtype, chunked_device_put
 
@@ -81,3 +82,66 @@ def test_overlap_not_slower_than_monolithic(rng):
     b.block_until_ready()
     chunked = time.perf_counter() - t0
     assert chunked < max(mono * 2.0, 0.5), (chunked, mono)
+
+
+def _object_column(x):
+    col = np.empty(len(x), dtype=object)
+    for i in range(len(x)):
+        col[i] = x[i]
+    return col
+
+
+def test_concatenate_is_traced_once_for_equal_shapes(rng):
+    """The chunks' concatenate is one jitted function a sharding, made
+    once (it was a fresh ``jax.jit`` a call: a retrace each)."""
+    from mmlspark_tpu.ops.ingest import _concatenate
+
+    _concatenate.cache_clear()
+    x = rng.integers(0, 255, size=(1_000, 5)).astype(np.int32)
+    y = rng.integers(0, 255, size=(1_000, 5)).astype(np.int32)
+    a = chunked_device_put(x, dtype=np.uint8, chunk_bytes=1_024)
+    concat = _concatenate(None)
+    assert concat._cache_size() == 1
+    b = chunked_device_put(y, dtype=np.uint8, chunk_bytes=1_024)
+    assert _concatenate(None) is concat and concat._cache_size() == 1
+    np.testing.assert_array_equal(np.asarray(a), x.astype(np.uint8))
+    np.testing.assert_array_equal(np.asarray(b), y.astype(np.uint8))
+    chunked_device_put(x[:900], dtype=np.uint8, chunk_bytes=1_024)
+    assert concat._cache_size() == 2     # other shapes: JAX's own cache
+
+
+@pytest.mark.parametrize("rows,dtype", [(1_000, None), (1_000, np.float32),
+                                        (7, None)])
+def test_row_source_matches_monolithic(rng, rows, dtype):
+    """An object column put as a row source, in chunks (1000 rows) or
+    whole (7), is the array ``np.stack`` and one put would have given;
+    ``dtype`` casts as ``astype`` does, a chunk at a time."""
+    import jax
+
+    from mmlspark_tpu.core.timer import span
+    from mmlspark_tpu.ops.ingest import RowSource
+
+    x = rng.normal(size=(rows, 3, 5))
+    source = RowSource(_object_column(x), "rows.stack", dtype)
+    assert source.shape == x.shape and len(source) == rows
+    assert source.dtype == (dtype or np.float64)
+    want = np.stack(list(_object_column(x))).astype(source.dtype)
+    assert source.nbytes == want.nbytes and source.ndim == want.ndim
+    with span("root") as root:
+        got = chunked_device_put(source, chunk_bytes=4_096,
+                                 span_name="rows.put")
+    # (a float64 array is put as float32 unless JAX runs in 64 bits)
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(jax.device_put(want)))
+    chunk_rows = 4_096 // (15 * source.dtype.itemsize)
+    chunks = -(-rows // chunk_rows) if rows > chunk_rows else 1
+    names = [s.name for s in root.spans]
+    assert names == ["rows.stack", "rows.put"] * chunks
+    assert {s.counts["chunks"] for s in root.spans
+            if s.name == "rows.put"} == {chunks}
+    # a window past the last row is rows of zeros (a scorer's padding)
+    tail = source.window(rows - 2, 5)
+    assert tail.shape == (5, 3, 5)
+    np.testing.assert_array_equal(tail.lay_out(0, 5)[:2], want[-2:])
+    assert not tail.lay_out(0, 5)[2:].any()
+    assert not source.window(rows + 3, 4).lay_out(0, 4).any()

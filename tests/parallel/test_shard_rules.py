@@ -282,3 +282,224 @@ def test_recompile_budget_bounded_by_ladder(mesh8, rng):
     finally:
         sanitizer.refresh_from_env()
         sanitizer.reset()
+
+
+# --- the chunked feed of a row source (PR 32) ------------------------------
+
+def _object_column(x):
+    col = np.empty(len(x), dtype=object)
+    for i in range(len(x)):
+        col[i] = x[i]
+    return col
+
+
+def _root_spans(fn):
+    """``fn()`` under a root span: its result and the spans it left."""
+    from mmlspark_tpu.core.timer import span
+
+    with span("root") as root:
+        out = fn()
+    return out, root.spans
+
+
+@pytest.fixture
+def small_chunks(monkeypatch):
+    """``chunked_device_put`` with chunks of 192 bytes (3 rows of 16
+    float32, which no rung is a multiple of), lowered through its own
+    argument."""
+    import functools
+
+    from mmlspark_tpu.ops import ingest
+    monkeypatch.setattr(ingest, "chunked_device_put", functools.partial(
+        ingest.chunked_device_put, chunk_bytes=192))
+
+
+def _linear_scorer(rng, mesh=None, max_batch=64):
+    from mmlspark_tpu.parallel.shard_rules import ShardedScorer
+
+    w = rng.normal(size=(16, 3)).astype(np.float32)
+    return ShardedScorer(lambda p, xb: xb.reshape(len(xb), -1) @ p["w"],
+                         {"w": w}, family="onnx", mesh=mesh,
+                         max_batch=max_batch, label="feedcase")
+
+
+@pytest.mark.parametrize("case", [
+    "rows_not_a_multiple_of_the_chunk", "short_padded_last_group",
+    "mesh_of_4_with_row_multiple", "float64_column_declared_float32"])
+def test_chunked_feed_equals_the_whole_put(case, rng, small_chunks):
+    import jax
+
+    from mmlspark_tpu.ops.ingest import RowSource
+    from mmlspark_tpu.parallel.mesh import MeshConfig, create_mesh
+
+    rows, mesh, dtype = 30, None, np.float32
+    if case == "short_padded_last_group":
+        rows = 64 + 64 + 7               # three groups of 64, the last 7
+    elif case == "mesh_of_4_with_row_multiple":
+        mesh = create_mesh(MeshConfig(dp=4), devices=jax.devices()[:4])
+        rows = 4 * 16 + 4 * 16 + 5       # groups of dp x rung = 64
+    elif case == "float64_column_declared_float32":
+        dtype = np.float64
+    x = rng.normal(size=(rows, 2, 8)).astype(dtype)
+    scorer = _linear_scorer(rng, mesh, max_batch=16 if mesh else 64)
+
+    want = scorer(x.astype(np.float32))
+    source = RowSource(_object_column(x), "rows.stack").astype(np.float32)
+    got, spans = _root_spans(lambda: scorer(source))
+
+    assert got.dtype == want.dtype and np.array_equal(got, want)
+    puts = [s for s in spans if s.name == "scorer.put"]
+    groups = -(-rows // 64)
+    # a group is 64 rows of 64 bytes (30 rows: the 32 rung) in chunks of
+    # 3 rows, the last one short; of 4 = dp rows under the mesh
+    group_rows = 32 if rows == 30 else 64
+    chunks = -(-group_rows // (3 if mesh is None else 4))
+    assert len(puts) == groups * chunks
+    assert {s.counts["chunks"] for s in puts} == {chunks}
+    assert sum(s.counts["bytes"] for s in puts) == groups * group_rows * 64
+    assert [s.name for s in spans].count("rows.stack") == len(puts)
+
+
+def test_chunked_feed_three_frames_in_turn_keep_their_own_rows(
+        rng, small_chunks):
+    """The staging buffers are written again on every call and, within a
+    call, by every third chunk: each call must still return its own
+    frame's rows, and the second call on must find the buffers there."""
+    from mmlspark_tpu.ops.ingest import RowSource
+
+    scorer = _linear_scorer(rng)
+    frames = [rng.normal(size=(50, 16)).astype(np.float32)
+              for _ in range(3)]
+    want = [scorer(f) for f in frames]
+    kept = None
+    for turn in range(2):
+        for frame, expect in zip(frames, want):
+            got = scorer(RowSource(_object_column(frame), "rows.stack"))
+            assert np.array_equal(got, expect)
+            ring = scorer._staging["__x__"]
+            assert all(b is not None and b.shape == (3, 16) for b in ring)
+            kept = kept or list(ring)
+    # a buffer is replaced only where the runtime took it for the device
+    # array (XLA:CPU, if it happens to be aligned): else all are reused
+    if all(b.ctypes.data % 64 for b in kept):
+        assert all(a is b for a, b in zip(kept, ring))
+
+
+
+
+@pytest.mark.parametrize("aligned", [True, False])
+def test_staging_buffer_the_runtime_took_is_not_written_again(rng, aligned):
+    """XLA:CPU takes a 64-byte-aligned host buffer as the device array
+    itself. A chunk put from such a staging buffer reads it until the
+    concatenate has run, so the feed must take a new buffer for the
+    chunk that would follow it there; an unaligned one it may reuse."""
+    import jax
+
+    from mmlspark_tpu.ops.ingest import (RowSource, _writable,
+                                         chunked_device_put)
+
+    def buffer():
+        raw = np.zeros(4 * 16 * 4 + 128, np.uint8)
+        off = (-raw.ctypes.data) % 64 + (0 if aligned else 4)
+        return raw[off:off + 4 * 16 * 4].view(np.float32).reshape(4, 16)
+
+    probe = buffer()
+    on_device = jax.device_put(probe)
+    on_device.block_until_ready()
+    probe[0, 0] = 7.0                    # seen on the device: one memory
+    took = float(on_device[0, 0]) == 7.0
+    if aligned and not took:
+        pytest.skip("this backend copied an aligned host buffer")
+    assert took == aligned
+    assert _writable(probe, on_device) == (not took)
+
+    x = rng.normal(size=(50, 16)).astype(np.float32)
+    staging = [buffer() for _ in range(3)]
+    before = list(staging)
+    got = chunked_device_put(RowSource(_object_column(x), "rows.stack"),
+                             chunk_bytes=256, staging=staging)
+    assert np.array_equal(np.asarray(got), x)
+    reused = [a is b for a, b in zip(before, staging)]
+    assert reused == ([False] * 3 if aligned else [True] * 3)
+
+
+def test_ragged_column_raises_before_anything_is_put(rng, small_chunks):
+    from mmlspark_tpu.onnx.model import ONNXModel
+    from mmlspark_tpu.ops.ingest import RowSource
+    from tests.onnx.test_onnx import _mlp_model
+
+    col = _object_column(rng.normal(size=(9, 4)).astype(np.float32))
+    col[5] = np.zeros(3, np.float32)
+    with pytest.raises(ValueError, match="same shape"):
+        RowSource(col, "rows.stack")
+    with pytest.raises(ValueError, match="need at least one array"):
+        RowSource([], "rows.stack")
+
+    proto, _ = _mlp_model(rng)
+    model = ONNXModel(modelPayload=proto, miniBatchSize=8)
+    with pytest.raises(ValueError, match="same shape") as raised:
+        _root_spans(lambda: model.transform(DataFrame({"features": col})))
+    with pytest.raises(ValueError, match="same shape"):
+        np.stack(list(col))              # what the front end raised before
+    assert raised.type is ValueError
+
+
+def test_scorer_put_counts_its_chunks(rng, small_chunks):
+    from mmlspark_tpu.ops.ingest import RowSource
+
+    scorer = _linear_scorer(rng)
+    x = rng.normal(size=(40, 16)).astype(np.float32)
+
+    def chunks_of(batch):
+        _, spans = _root_spans(lambda: scorer(batch))
+        return [s.counts["chunks"] for s in spans if s.name == "scorer.put"]
+
+    # an ndarray, whatever its size, and a row source of one chunk or
+    # less: today's single put
+    assert chunks_of(x) == [1]
+    assert chunks_of(RowSource(_object_column(x[:2]), "rows.stack")) == [1]
+    # 40 rows on the 64 rung, 3 rows a chunk
+    assert chunks_of(RowSource(_object_column(x), "rows.stack")) == [22] * 22
+
+
+def test_chunked_feed_callers_on_many_threads_keep_their_own_rows(
+        rng, small_chunks):
+    """One scorer, more callers than cores, each feeding its own frame
+    through the chunked feed again and again: a staging ring is checked
+    out for a call, so no caller may ever read rows another one laid."""
+    import os
+    import sys
+    import threading
+
+    from mmlspark_tpu.ops.ingest import RowSource
+
+    scorer = _linear_scorer(rng)
+    callers = 2 * (os.cpu_count() or 4)
+    frames = [rng.normal(size=(40, 16)).astype(np.float32)
+              for _ in range(callers)]
+    want = [scorer(f) for f in frames]
+    wrong, errors = [], []
+
+    def call(i):
+        try:
+            for _ in range(5):
+                got = scorer(RowSource(_object_column(frames[i]),
+                                       "rows.stack"))
+                if not np.array_equal(got, want[i]):
+                    wrong.append(i)
+        except Exception as e:               # surfaced below, not lost
+            errors.append(repr(e))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=call, args=(i,))
+                   for i in range(callers)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors and not wrong
