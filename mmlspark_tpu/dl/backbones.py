@@ -4,10 +4,14 @@ The reference fine-tunes torchvision/HF checkpoints pulled from the
 network (dl/DeepVisionClassifier.py backbone param). This environment is
 zero-egress, so the zoo is built in-repo: a compact ResNet family and a
 transformer encoder, both TPU-shaped (NHWC convs, bf16-friendly widths,
-optional ring attention for long sequences), and a causal decoder
-(:class:`RetentionLM`: RMSNorm, rotary positions, grouped heads, SwiGLU)
-whose layers mix the sequence by power retention and carry a state
-from one forward pass to the next.
+optional ring attention for long sequences), and two causal decoders
+that carry a state from one forward pass to the next:
+:class:`RetentionLM` (RMSNorm, rotary positions, grouped heads, SwiGLU;
+every layer mixes the sequence by power retention) and
+:class:`HybridLM` (layers that mix by a gated delta rule or by latent
+attention over a cache, over a dense SwiGLU or sparse experts, chosen
+by the layer's index). ``LM_MODELS`` maps a config's ``model_type`` to
+its class.
 """
 
 from __future__ import annotations
@@ -247,32 +251,54 @@ class DecoderBlock(nn.Module):
         return h, state
 
 
-def lm_init_state(config: Mapping[str, Any], batch: int):
+def lm_module(config: Mapping[str, Any]):
+    """The language model a config names (``model_type``; a config
+    without the key is a :class:`RetentionLM`'s)."""
+    kind = config.get("model_type", "brumby")
+    if kind not in LM_MODELS:
+        raise ValueError(f"model_type {kind!r} is not one of "
+                         f"{sorted(LM_MODELS)}")
+    return LM_MODELS[kind](dict(config))
+
+
+def lm_init_state(config: Mapping[str, Any], batch: int, capacity: int = 0):
     """An empty state for ``batch`` sequences: no token absorbed, every
-    layer's retention state zero (float32, whatever the model's dtype)."""
-    from mmlspark_tpu.parallel import retention
-
-    return {"pos": jnp.zeros((batch,), jnp.int32),
-            "layers": [retention.init_state(
-                batch, config["num_key_value_heads"], config["head_dim"])
-                for _ in range(config["num_hidden_layers"])]}
+    recurrent state zero (float32, whatever the model's dtype), every
+    cache empty with room for ``capacity`` positions."""
+    return type(lm_module(config)).init_state(config, batch, capacity)
 
 
-def lm_state_bytes(config: Mapping[str, Any], batch: int) -> int:
-    """Bytes of ``lm_init_state(config, batch)``."""
-    from mmlspark_tpu.parallel import retention
+def lm_state_bytes(config: Mapping[str, Any], batch: int,
+                   capacity: int = 0) -> Mapping[str, int]:
+    """Bytes of ``lm_init_state(config, batch, capacity)``, by kind:
+    ``{"state": the fixed recurrent part, "cache": the part that grows
+    with ``capacity``}``."""
+    state = jax.eval_shape(
+        lambda: lm_init_state(config, batch, capacity))
+    empty = jax.eval_shape(lambda: lm_init_state(config, batch, 0))
 
-    shapes = retention.state_shapes(batch, config["num_key_value_heads"],
-                                    config["head_dim"])
-    per_layer = sum(4 * int(np.prod(shape)) for shape in shapes.values())
-    return config["num_hidden_layers"] * per_layer + 4 * batch
+    def nbytes(tree):
+        return sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                   for x in jax.tree_util.tree_leaves(tree))
+
+    return {"state": nbytes(empty), "cache": nbytes(state) - nbytes(empty)}
+
+
+def lm_hidden(module, params, ids, lengths, state):
+    """``module.apply(params, ids, lengths, state, method="hidden")``,
+    through the model's own way of bounding a long stretch's
+    activations where it has one (``HybridLM.hidden_in_groups``)."""
+    grouped = getattr(type(module), "hidden_in_groups", None)
+    if grouped is not None:
+        return grouped(module, params, ids, lengths, state)
+    return module.apply(params, ids, lengths, state, method="hidden")
 
 
 def lm_init_params(config: Mapping[str, Any], seed: int = 0):
-    """Freshly initialised parameters of ``RetentionLM(config)``."""
-    return RetentionLM(dict(config)).init(
+    """Freshly initialised parameters of the config's model."""
+    return lm_module(config).init(
         jax.random.PRNGKey(seed), jnp.zeros((1, 2), jnp.int32),
-        jnp.full((1,), 2, jnp.int32), lm_init_state(config, 1))
+        jnp.full((1,), 2, jnp.int32), lm_init_state(config, 1, 2))
 
 
 def lm_param_shapes(config: Mapping[str, Any]):
@@ -296,6 +322,17 @@ class RetentionLM(nn.Module):
     pays for the head once."""
 
     config: Any
+
+    @staticmethod
+    def init_state(config, batch, capacity=0):
+        """Every layer's retention state, zero; no cache (``capacity``
+        means nothing to a model whose state does not grow)."""
+        from mmlspark_tpu.parallel import retention
+
+        return {"pos": jnp.zeros((batch,), jnp.int32),
+                "layers": [retention.init_state(
+                    batch, config["num_key_value_heads"], config["head_dim"])
+                    for _ in range(config["num_hidden_layers"])]}
 
     def setup(self):
         c = self.config
@@ -331,3 +368,381 @@ class RetentionLM(nn.Module):
     def __call__(self, ids, lengths, state, every=False):
         h, state = self.hidden(ids, lengths, state, every)
         return self.head(h), state
+
+
+# ---------------------------------------------------------------------
+# hybrid decoder: delta-rule and latent-attention layers over a dense
+# SwiGLU or sparse experts (config keys of ``model_type`` gigachat3_5)
+
+
+def gated_norm(x, weight, eps):
+    """RMSNorm whose scale is ``2 sigmoid(weight)``: ``weight`` is
+    stored centred at zero, where the scale is 1
+    (``ZeroCenteredGatedNorm``, ``layernorm_gating_weight`` 2)."""
+    x = x.astype(jnp.float32)
+    return (x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+            * 2.0 * jax.nn.sigmoid(weight.astype(jnp.float32)))
+
+
+def _centred(module, name, width):
+    return module.param(name, nn.initializers.zeros, (width,), jnp.float32)
+
+
+def _rounded(x, dtype):
+    """``x`` at the model's precision, kept float32 (see
+    ``DecoderBlock``: the rounding XLA may not drop)."""
+    info = jnp.finfo(dtype)
+    return jax.lax.reduce_precision(x, info.nexp, info.nmant)
+
+
+def experts_held(config: Mapping[str, Any]):
+    """``(first, count, router width)``: the run of the published
+    experts this chip holds (``experts_held``; all ``n_routed_experts``
+    where the key is absent) and how many the router scores
+    (``router_experts``)."""
+    count = config["n_routed_experts"]
+    first, stop = config.get("experts_held", (0, count))
+    return first, stop - first, config.get("router_experts", count)
+
+
+class DeltaMixer(nn.Module):
+    """Gated delta rule over grouped heads (``parallel/delta_rule.py``):
+    a causal depth-wise convolution and SiLU on q, k and v, L2-normed q
+    and k, a decay and a write strength a value head, and a gated norm
+    on the output. State: ``{"s": (B, value heads, d_k, d_v)`` float32,
+    ``"conv": (B, width - 1, channels)`` the last tokens' channels
+    before the convolution``}``."""
+
+    config: Any
+
+    @staticmethod
+    def init_state(config, batch, capacity=0):
+        from mmlspark_tpu.parallel import delta_rule
+
+        c = config
+        kh, vh = c["linear_num_key_heads"], c["linear_num_value_heads"]
+        d_k, d_v = c["linear_key_head_dim"], c["linear_value_head_dim"]
+        return {"s": delta_rule.init_state(batch, vh, d_k, d_v),
+                "conv": jnp.zeros((batch, c["linear_conv_kernel_dim"] - 1,
+                                   2 * kh * d_k + vh * d_v), jnp.float32)}
+
+    @nn.compact
+    def __call__(self, x, positions, lengths, state):
+        from mmlspark_tpu.parallel import delta_rule
+
+        c = self.config
+        kh, vh = c["linear_num_key_heads"], c["linear_num_value_heads"]
+        d_k, d_v = c["linear_key_head_dim"], c["linear_value_head_dim"]
+        width, dtype = c["linear_conv_kernel_dim"], lm_dtype(c)
+        b, t, _ = x.shape
+        mixed = jnp.concatenate(
+            [Linear(kh * d_k, dtype, name="q_proj")(x),
+             Linear(kh * d_k, dtype, name="k_proj")(x),
+             Linear(vh * d_v, dtype, name="v_proj")(x)], axis=-1)
+        z = Linear(vh * d_v, dtype, name="z_proj")(x)
+        beta = jax.nn.sigmoid(Linear(vh, dtype, name="b_proj")(x))
+        log_g = -jnp.exp(self.param(
+            "A_log", nn.initializers.zeros, (vh,), jnp.float32).astype(
+                jnp.float32)) * jax.nn.softplus(
+            Linear(vh, dtype, name="a_proj")(x) + self.param(
+                "dt_bias", nn.initializers.zeros, (vh,), jnp.float32))
+        taps = self.param("conv", nn.initializers.normal(0.5),
+                          (width, mixed.shape[-1]), jnp.float32)
+        # the convolution reaches width - 1 tokens back: the state's tail
+        # before the stretch; the new tail is the last real tokens' rows
+        seen = jnp.concatenate([state["conv"], mixed], axis=1)
+        tail = jax.vmap(lambda rows, at: jax.lax.dynamic_slice_in_dim(
+            rows, at, width - 1, axis=0))(seen, lengths)
+        mixed = nn.silu(sum(
+            taps[j].astype(jnp.float32) * seen[:, j:j + t]
+            for j in range(width)))
+        q, k, v = jnp.split(mixed, [kh * d_k, 2 * kh * d_k], axis=-1)
+
+        def unit(a):
+            a = a.reshape(b, t, kh, d_k)
+            return a * jax.lax.rsqrt(
+                jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+
+        q = _rounded(unit(q) * d_k ** -0.5, dtype)
+        k = _rounded(unit(k), dtype)
+        v = _rounded(v.reshape(b, t, vh, d_v), dtype)
+        if t == 1:
+            real = (lengths > 0)[:, None]          # padding: g = 1, beta = 0
+            o, s = delta_rule.delta_step(
+                q[:, 0], k[:, 0], v[:, 0], jnp.where(real, log_g[:, 0], 0.0),
+                jnp.where(real, beta[:, 0], 0.0), state["s"])
+            o = o[:, None]
+        else:
+            o, s = delta_rule.delta_prefill(q, k, v, log_g, beta, lengths,
+                                            state["s"])
+        o = gated_norm(o, _centred(self, "o_norm", d_v),
+                       c["linear_attn_o_norm_eps"])
+        o = o * 2.0 * jax.nn.sigmoid(z.reshape(b, t, vh, d_v))
+        return (Linear(x.shape[-1], dtype, name="o_proj")(
+            o.reshape(b, t, vh * d_v)), {"s": s, "conv": tail})
+
+
+class LatentMixer(nn.Module):
+    """Latent attention (``parallel/latent.py``): low-rank queries,
+    keys and values compressed to one latent and one rotated key a
+    position, a sigmoid gate a head channel on the output. State: the
+    cache ``{"c", "r"}`` in the model's dtype."""
+
+    config: Any
+
+    @staticmethod
+    def init_state(config, batch, capacity=0):
+        from mmlspark_tpu.parallel import latent
+
+        return latent.init_cache(batch, capacity, config["kv_lora_rank"],
+                                 config["qk_rope_head_dim"],
+                                 lm_dtype(config))
+
+    @nn.compact
+    def __call__(self, x, positions, lengths, state):
+        from mmlspark_tpu.parallel import latent
+
+        c = self.config
+        heads, rank = c["num_attention_heads"], c["kv_lora_rank"]
+        nope, rope = c["qk_nope_head_dim"], c["qk_rope_head_dim"]
+        d_v, eps, dtype = c["v_head_dim"], c["rms_norm_eps"], lm_dtype(c)
+        b, t, _ = x.shape
+        scaling = c.get("rope_scaling") or {}
+        freq = latent.yarn_frequencies(rope, c["rope_theta"], scaling)
+        scale = latent.softmax_scale(
+            nope + rope, scaling, c.get("use_mla_scaling_factor", False))
+
+        c_q = gated_norm(Linear(c["q_lora_rank"], dtype, name="q_a_proj")(x),
+                         _centred(self, "q_a_norm", c["q_lora_rank"]), eps)
+        q = Linear(heads * (nope + rope), dtype, name="q_b_proj")(c_q)
+        q = q.reshape(b, t, heads, nope + rope)
+        q_n = q[..., :nope]
+        q_r = latent.rotary_interleaved(q[..., nope:], positions, freq)
+        down = Linear(rank + rope, dtype, name="kv_a_proj")(x)
+        c_kv = gated_norm(down[..., :rank],
+                          _centred(self, "kv_a_norm", rank), eps)
+        k_r = latent.rotary_interleaved(down[..., rank:], positions, freq)
+        up = self.param("kv_b_proj", nn.initializers.normal(0.02),
+                        (rank, heads, nope + d_v), dtype)
+        w_uk, w_uv = up[..., :nope], up[..., nope:]
+        pos = positions[:, 0]
+        cache = latent.cache_write(state, c_kv, k_r, pos, lengths)
+        if t == 1:
+            o = latent.latent_decode(q_n[:, 0], q_r[:, 0], cache, w_uk, w_uv,
+                                     pos, scale=scale, dtype=dtype)[:, None]
+        else:
+            o = latent.latent_prefill(q_n, q_r, cache, w_uk, w_uv, pos,
+                                      lengths, scale=scale, dtype=dtype)
+        o = o.reshape(b, t, heads * d_v)
+        if c.get("gated_attention", False):
+            o = o * jax.nn.sigmoid(Linear(heads * d_v, dtype,
+                                          name="g_proj")(x))
+        return Linear(x.shape[-1], dtype, name="o_proj")(o), cache
+
+
+class DenseFeedForward(nn.Module):
+    config: Any
+
+    @nn.compact
+    def __call__(self, x, valid):
+        from mmlspark_tpu.parallel.experts import swiglu
+
+        c = self.config
+        h, w, dtype = x.shape[-1], c["intermediate_size"], lm_dtype(c)
+        shape = nn.initializers.normal(0.02)
+        return swiglu(x, self.param("gate_proj", shape, (h, w), dtype),
+                      self.param("up_proj", shape, (h, w), dtype),
+                      self.param("down_proj", shape, (w, h), dtype),
+                      dtype=dtype, limit=c.get("swiglu_limit")), None
+
+
+class ExpertFeedForward(nn.Module):
+    """Sparse experts (``parallel/experts.py``): the router scores all
+    published experts, the experts held here serve their pairs, the
+    shared expert every token. Also returns ``(pairs a held expert,
+    dropped pairs)``."""
+
+    config: Any
+
+    @nn.compact
+    def __call__(self, x, valid):
+        from mmlspark_tpu.parallel import experts
+
+        c = self.config
+        first, count, routed = experts_held(c)
+        h, w, dtype = x.shape[-1], c["moe_intermediate_size"], lm_dtype(c)
+        limit, shape = c.get("swiglu_limit"), nn.initializers.normal(0.02)
+        tokens = x.reshape(-1, h)
+        with jax.named_scope("lm.moe.route"):
+            routing = experts.route(
+                tokens, self.param("router", shape, (h, routed), jnp.float32),
+                self.param("router_bias", nn.initializers.zeros, (routed,),
+                           jnp.float32),
+                top_k=c["num_experts_per_tok"],
+                scale=c["routed_scaling_factor"])
+        y, pairs, dropped = experts.grouped_experts(
+            tokens, routing, valid.reshape(-1),
+            self.param("experts_gate", shape, (count, h, w), dtype),
+            self.param("experts_up", shape, (count, h, w), dtype),
+            self.param("experts_down", shape, (count, w, h), dtype),
+            held=(first, count), dtype=dtype, limit=limit,
+            tile=experts.tile_rows(tokens.shape[0],
+                                   c["num_experts_per_tok"], routed))
+        with jax.named_scope("lm.moe.shared"):
+            ws = w * c.get("n_shared_experts", 1)
+            y = y + experts.swiglu(
+                tokens, self.param("shared_gate", shape, (h, ws), dtype),
+                self.param("shared_up", shape, (h, ws), dtype),
+                self.param("shared_down", shape, (ws, h), dtype),
+                dtype=dtype, limit=limit)
+        return y.reshape(x.shape), (pairs, dropped)
+
+
+class HybridBlock(nn.Module):
+    """One layer: ``h += post(mixer(pre(h)))``, then the same around the
+    feed-forward. The layer's index chooses both: latent attention where
+    ``full_attention_layers`` lists it, else the delta rule; a dense
+    SwiGLU under ``first_k_dense_replace``, else the experts."""
+
+    config: Any
+    index: int
+
+    def latent(self) -> bool:
+        return self.index in tuple(self.config["full_attention_layers"])
+
+    def sparse(self) -> bool:
+        return self.index >= self.config["first_k_dense_replace"]
+
+    @nn.compact
+    def __call__(self, h, positions, lengths, state):
+        c = self.config
+        width, eps = h.shape[-1], c["rms_norm_eps"]
+        mixer = LatentMixer if self.latent() else DeltaMixer
+        with jax.named_scope("lm.mla" if self.latent() else "lm.gdn"):
+            y, state = mixer(c, name="mixer")(
+                gated_norm(h, _centred(self, "mixer_pre", width), eps),
+                positions, lengths, state)
+            h = h + gated_norm(y, _centred(self, "mixer_post", width), eps)
+        ffn = ExpertFeedForward if self.sparse() else DenseFeedForward
+        with jax.named_scope("lm.moe" if self.sparse() else "lm.mlp"):
+            valid = jnp.arange(h.shape[1])[None, :] < lengths[:, None]
+            y, served = ffn(c, name="ffn")(
+                gated_norm(h, _centred(self, "ffn_pre", width), eps), valid)
+            h = h + gated_norm(y, _centred(self, "ffn_post", width), eps)
+        return h, state, served
+
+
+class HybridLM(nn.Module):
+    """Causal language model of :class:`HybridBlock` layers, with
+    :class:`RetentionLM`'s contract (``apply``, ``method="hidden"``,
+    ``method="head"``). Its state holds two kinds side by side: a
+    delta-rule layer's fixed recurrent state and a latent layer's
+    cache, whose capacity ``lm_init_state`` is given; and ``experts``,
+    what the expert layers served since the state was empty: ``pairs``
+    ``(expert layers, experts held)`` and ``dropped``."""
+
+    config: Any
+
+    @staticmethod
+    def _blocks(config):
+        return [HybridBlock(config, i, name=f"layers_{i}")
+                for i in range(config["num_hidden_layers"])]
+
+    @staticmethod
+    def init_state(config, batch, capacity=0):
+        blocks = HybridLM._blocks(config)
+        sparse = sum(block.sparse() for block in blocks)
+        return {"pos": jnp.zeros((batch,), jnp.int32),
+                "layers": [(LatentMixer if block.latent() else DeltaMixer)
+                           .init_state(config, batch, capacity)
+                           for block in blocks],
+                "experts": {"pairs": jnp.zeros(
+                    (sparse, experts_held(config)[1]), jnp.int32),
+                    "dropped": jnp.zeros((), jnp.int32)}}
+
+    @staticmethod
+    def hidden_in_groups(module, params, ids, lengths, state):
+        """``method="hidden"`` a group of rows at a time, so that a
+        stretch of many tokens (a prefill step of a wide batch) holds
+        the activations of ``GROUP_TOKENS`` tokens and not of all: the
+        state is the loop's carry, a group's rows are cut from it and
+        written back in place, and the experts' counters pass from
+        group to group. One group (a decode step) is the plain call."""
+        rows, t = ids.shape
+        groups = 1
+        while (rows * t > groups * HybridLM.GROUP_TOKENS
+               and rows % (2 * groups) == 0):
+            groups *= 2
+        if groups == 1:
+            return module.apply(params, ids, lengths, state, method="hidden")
+        per = rows // groups
+
+        def body(g, carry):
+            state, out = carry
+
+            def cut(x):
+                return jax.lax.dynamic_slice_in_dim(x, g * per, per, axis=0)
+
+            def paste(whole, part):
+                return jax.lax.dynamic_update_slice_in_dim(
+                    whole, part, g * per, axis=0)
+
+            mine = {k: v if k == "experts" else jax.tree_util.tree_map(cut, v)
+                    for k, v in state.items()}
+            h, mine = module.apply(params, cut(ids), cut(lengths), mine,
+                                   method="hidden")
+            state = {k: mine[k] if k == "experts" else
+                     jax.tree_util.tree_map(paste, v, mine[k])
+                     for k, v in state.items()}
+            return state, paste(out, h)
+
+        hidden = module.config["hidden_size"]
+        return jax.lax.fori_loop(
+            0, groups, body,
+            (state, jnp.zeros((rows, hidden), jnp.float32)))[::-1]
+
+    GROUP_TOKENS = 4096
+
+    def setup(self):
+        c = self.config
+        self.embedding = self.param(
+            "embedding", nn.initializers.normal(0.02),
+            (c["vocab_size"], c["hidden_size"]), lm_dtype(c))
+        self.layers = self._blocks(c)
+        self.final_norm = self.param("final_norm", nn.initializers.zeros,
+                                     (c["hidden_size"],), jnp.float32)
+        self.lm_head = Linear(c["vocab_size"], lm_dtype(c))
+
+    def hidden(self, ids, lengths, state, every=False):
+        with jax.named_scope("lm.embed"):
+            h = jnp.take(self.embedding, ids, axis=0).astype(jnp.float32)
+        positions = state["pos"][:, None] + jnp.arange(ids.shape[1])
+        layers, pairs = [], []
+        dropped = state["experts"]["dropped"]
+        for block, layer_state in zip(self.layers, state["layers"]):
+            h, layer_state, served = block(h, positions, lengths,
+                                           layer_state)
+            layers.append(layer_state)
+            if served is not None:
+                pairs.append(served[0])
+                dropped = dropped + served[1]
+        if not every:
+            last = jnp.clip(lengths - 1, 0, ids.shape[1] - 1)
+            h = jnp.take_along_axis(h, last[:, None, None], axis=1)[:, 0]
+        served = state["experts"]["pairs"]
+        if pairs:
+            served = served + jnp.stack(pairs)
+        return h, {"pos": state["pos"] + lengths, "layers": layers,
+                   "experts": {"pairs": served, "dropped": dropped}}
+
+    def head(self, h):
+        with jax.named_scope("lm.head"):
+            return self.lm_head(gated_norm(h, self.final_norm,
+                                           self.config["rms_norm_eps"]))
+
+    def __call__(self, ids, lengths, state, every=False):
+        h, state = self.hidden(ids, lengths, state, every)
+        return self.head(h), state
+
+
+LM_MODELS = {"brumby": RetentionLM, "gigachat3_5": HybridLM}

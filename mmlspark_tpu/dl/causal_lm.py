@@ -3,10 +3,16 @@ in, a column of greedy completions (and their log-probabilities) out.
 
 Parity: SynapseML's ``HuggingFaceCausalLM`` (a ``Transformer`` from a
 prompt column to a completion column, batched generation behind it).
-The model is :class:`~mmlspark_tpu.dl.backbones.RetentionLM`, whose
-layers keep a recurrent state and no key-value cache, so a sequence
-costs the same device memory whatever its length, and a device batch is
-sized by state bytes.
+The model is the one ``modelConfig["model_type"]`` names in
+``backbones.LM_MODELS``: :class:`~mmlspark_tpu.dl.backbones.RetentionLM`
+(``brumby``, and a config without the key), whose layers keep a
+recurrent state and no cache, or
+:class:`~mmlspark_tpu.dl.backbones.HybridLM` (``gigachat3_5``), whose
+delta-rule layers keep a recurrent state and whose latent-attention
+layers a cache of one compressed entry a position, over sparse experts
+of which this chip holds a share. A device batch is sized by the bytes
+of both: the state, and the cache at the longest prompt plus
+``maxNewTokens``.
 
 One ``transform()``: the ragged prompts are sorted by length and cut
 into device batches (``ShardedScorer.length_batches``: the row ladder
@@ -16,8 +22,9 @@ and fetches (``scorer.*``). A batch runs two programs: ``lm_prefill``
 absorbs the prompt a chunk of tokens at a time with the state as the
 scan's carry; ``lm_generate`` takes that state donated and decodes
 ``maxNewTokens`` greedy tokens in one ``lax.scan``. Padding never
-touches the state, so a row's output does not depend on its rungs or
-its neighbours.
+touches the state, writes nothing to a cache (whose rows are indexed by
+each row's own position) and is masked out of every softmax, so a
+row's output does not depend on its rungs or its neighbours.
 """
 
 from __future__ import annotations
@@ -44,16 +51,22 @@ def _free_device_bytes() -> Optional[int]:
 
 
 class CausalLM(Transformer, HasInputCol, HasOutputCol):
-    modelConfig = Param("modelConfig", "the model's config.json as a dict "
+    modelConfig = Param("modelConfig", "the model's config.json as a dict; "
+                        "model_type names the model (backbones.LM_MODELS): "
+                        "brumby, also taken where the key is absent "
                         "(hidden_size, num_attention_heads, "
                         "num_key_value_heads, head_dim, intermediate_size, "
                         "vocab_size, num_hidden_layers, rms_norm_eps, "
-                        "rope_theta, torch_dtype)", is_complex=True)
+                        "rope_theta, torch_dtype), or gigachat3_5 (the keys "
+                        "of its config.json, with experts_held and "
+                        "router_experts where this chip holds a share of "
+                        "the experts)", is_complex=True)
     maxNewTokens = Param("maxNewTokens", "tokens generated a row (greedy, "
                          "no early stop)", to_int, gt(0), default=32)
     batchSize = Param("batchSize", "rows a device batch; unset, the "
-                      "largest power of two whose retention state fits "
-                      "half the device's free memory", to_int, gt(0))
+                      "largest power of two whose state and cache (at "
+                      "maxLength's rung plus maxNewTokens) fit half the "
+                      "device's free memory", to_int, gt(0))
     maxLength = Param("maxLength", "longest prompt in tokens (longer ones "
                       "keep their last maxLength tokens)", to_int, gt(0),
                       default=1024)
@@ -114,19 +127,23 @@ class CausalLM(Transformer, HasInputCol, HasOutputCol):
         free = _free_device_bytes()
         if free is None:                     # no memory statistics: the CPU
             return 8
+        from mmlspark_tpu.parallel.inference import length_ladder
+
+        capacity = (length_ladder(self.get("maxLength"))[-1]
+                    + self.get("maxNewTokens"))
         rows = 1
-        while (rows < 1024 and
-               lm_state_bytes(self._config(), rows * 2) <= free // 2):
+        while (rows < 1024 and sum(lm_state_bytes(
+                self._config(), rows * 2, capacity).values()) <= free // 2):
             rows *= 2
         return rows
 
     def _ensure_scorer(self):
-        from mmlspark_tpu.dl.backbones import RetentionLM, lm_dtype
+        from mmlspark_tpu.dl.backbones import lm_dtype, lm_module
         from mmlspark_tpu.parallel.shard_rules import ShardedScorer
 
         if self._scorer is None:
             config = self._config()
-            self._module = RetentionLM(config)
+            self._module = lm_module(config)
             self._scorer = ShardedScorer(
                 self._generate, self._ensure_weights(), family="dl",
                 max_batch=self._batch_rows(), label="causal_lm",
@@ -138,7 +155,7 @@ class CausalLM(Transformer, HasInputCol, HasOutputCol):
         """The two jitted programs of a device batch, built once a
         setting: ``lm_prefill`` and ``lm_generate`` (their names are what
         a profiler's module line shows). Ids, the last hidden state and
-        the retention state are donated."""
+        the model's state are donated."""
         import jax
 
         programs = self.__dict__.setdefault("_programs", {})
@@ -158,11 +175,12 @@ class CausalLM(Transformer, HasInputCol, HasOutputCol):
     def _lm_prefill(self, params, ids, lengths):
         """``(hidden after each row's last prompt token, state)``: the
         prompt absorbed ``prefillChunk`` tokens a row at a time, the
-        state the scan's carry."""
+        state the scan's carry. A cache in it has room for the batch's
+        length rung and ``maxNewTokens`` more."""
         import jax
         import jax.numpy as jnp
 
-        from mmlspark_tpu.dl.backbones import lm_init_state
+        from mmlspark_tpu.dl.backbones import lm_hidden, lm_init_state
 
         config = self._config()
         rows, length = ids.shape
@@ -175,11 +193,12 @@ class CausalLM(Transformer, HasInputCol, HasOutputCol):
             state, last = carry
             chunk_ids, start = xs
             real = jnp.clip(lengths - start, 0, chunk)
-            h, state = self._module.apply(params, chunk_ids, real, state,
-                                          method="hidden")
+            h, state = lm_hidden(self._module, params, chunk_ids, real,
+                                 state)
             return (state, jnp.where((real > 0)[:, None], h, last)), None
 
-        first = (lm_init_state(config, rows),
+        first = (lm_init_state(config, rows,
+                               length + self.get("maxNewTokens")),
                  jnp.zeros((rows, config["hidden_size"]), jnp.float32))
         (state, last), _ = jax.lax.scan(
             step, first, (ids, jnp.arange(steps) * chunk))
@@ -187,7 +206,10 @@ class CausalLM(Transformer, HasInputCol, HasOutputCol):
 
     def _decode(self, params, last, state, with_logits):
         """``maxNewTokens`` greedy tokens a row in one scan, the state its
-        carry: ``({"tokens", "logprobs"[, "logits"]}, state)``."""
+        carry: ``({"tokens", "logprobs"[, "logits"]}, state)``; with
+        expert layers, also what they served since the state was empty,
+        counted on the device: ``expert_pairs`` (one row: a count an
+        expert layer and held expert) and ``dropped_pairs``."""
         import jax
         import jax.numpy as jnp
 
@@ -213,6 +235,10 @@ class CausalLM(Transformer, HasInputCol, HasOutputCol):
         outs = {k: jnp.concatenate([jnp.moveaxis(v, 0, 1),
                                     final[k][:, None]], axis=1)
                 for k, v in outs.items()}
+        if "experts" in state:
+            outs.update(
+                expert_pairs=state["experts"]["pairs"].reshape(1, -1),
+                dropped_pairs=state["experts"]["dropped"])
         # the state goes out again so that the donated buffers have an
         # output to alias: the scan then updates them in place, and a
         # second copy of the state (which would not fit) is never made
@@ -280,11 +306,21 @@ class CausalLM(Transformer, HasInputCol, HasOutputCol):
                 out = out.with_column(name, column)
         if root is not None:
             rows = max((len(index) for index, _ in batches), default=0)
+            rung = max((b["ids"].shape[1] for _, b in batches), default=0)
+            held = lm_state_bytes(self._config(), rows, rung + new)
             root.counts.update(
                 new_tokens=int(new * len(prompts)),
-                state_bytes=int(lm_state_bytes(self._config(), rows)),
-                length_rung=int(max((b["ids"].shape[1] for _, b in batches),
-                                    default=0)))
+                state_bytes=int(held["state"]),
+                cache_bytes=int(held["cache"]), length_rung=int(rung))
+            served = [scored for _, scored in outputs
+                      if "expert_pairs" in scored]
+            if served:
+                pairs = sum(s["expert_pairs"] for s in served)
+                root.counts.update(
+                    expert_pairs=int(pairs.sum()),
+                    expert_pairs_max=int(pairs.max()),
+                    dropped_pairs=int(sum(s["dropped_pairs"]
+                                          for s in served)))
         return out
 
     # -- persistence (as DeepModel: leaves in order) -------------------

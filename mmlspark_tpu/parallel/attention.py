@@ -37,21 +37,31 @@ _NEG_INF = -1e30
 
 
 def _block_attend(q, k, v, out, row_max, row_sum, q_offset, k_offset,
-                  causal: bool, scale: float):
+                  causal: bool, scale: float, *, q_positions=None,
+                  kv_lengths=None):
     """One online-softmax accumulation step.
 
     q: (b, nq, h, d); k/v: (b, nk, h, d); out/row_max/row_sum are the
     running accumulators. Returns updated (out, row_max, row_sum).
+    ``q_positions`` (b, nq) gives each row's own query positions in
+    place of ``q_offset``; ``kv_lengths`` (b,) masks each row's keys
+    from that index on (a cache filled so far).
     """
     import jax.numpy as jnp
 
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-    if causal:
-        nq, nk = q.shape[1], k.shape[1]
+    nq, nk = q.shape[1], k.shape[1]
+    k_pos = k_offset + jnp.arange(nk)
+    if causal and q_positions is not None:
+        mask = q_positions[:, None, :, None] >= k_pos
+        scores = jnp.where(mask, scores, _NEG_INF)
+    elif causal:
         q_pos = q_offset + jnp.arange(nq)
-        k_pos = k_offset + jnp.arange(nk)
         mask = q_pos[:, None] >= k_pos[None, :]
         scores = jnp.where(mask[None, None, :, :], scores, _NEG_INF)
+    if kv_lengths is not None:
+        scores = jnp.where(k_pos < kv_lengths[:, None, None, None], scores,
+                           _NEG_INF)
 
     blk_max = jnp.max(scores, axis=-1)                      # (b, h, q)
     new_max = jnp.maximum(row_max, blk_max)
@@ -110,14 +120,23 @@ def _streamed_attend(q, k, v, out, row_max, row_sum, q_offset, k_offset,
 
 
 def blockwise_attention(q, k, v, block_size: int = 512,
-                        causal: bool = False):
-    """Memory-efficient attention via lax.scan over KV blocks."""
+                        causal: bool = False, *, scale=None,
+                        q_positions=None, kv_lengths=None, kv_map=None):
+    """Memory-efficient attention via lax.scan over KV blocks.
+
+    ``scale`` replaces ``1 / sqrt(d)``; ``q_positions`` and
+    ``kv_lengths`` are ``_block_attend``'s (a ragged batch over a
+    cache). With ``kv_map``, ``k`` and ``v`` are any ``(b, nk, ...)``
+    arrays and ``kv_map(k block, v block)`` gives the block's ``(b,
+    block, h, d)`` keys and ``(b, block, h, d_v)`` values inside the
+    scan: compressed keys and values are then expanded a block at a
+    time and never whole."""
     import jax
     import jax.numpy as jnp
 
     b, n, h, d = q.shape
     nk = k.shape[1]
-    scale = 1.0 / (d ** 0.5)
+    scale = 1.0 / (d ** 0.5) if scale is None else scale
     # largest divisor of nk that fits the requested block: any kv
     # length streams (the scan needs equal blocks; a 704-long sequence
     # gets 352-wide blocks rather than a ValueError). Awkward lengths
@@ -129,25 +148,31 @@ def blockwise_attention(q, k, v, block_size: int = 512,
     if block < min(block_size, nk) // 4:
         block = nk
     n_blocks = nk // block
-    k_blocks = k.reshape(b, n_blocks, block, h, d).transpose(1, 0, 2, 3, 4)
-    v_blocks = v.reshape(b, n_blocks, block, h, d).transpose(1, 0, 2, 3, 4)
+    k_blocks = jnp.moveaxis(
+        k.reshape((b, n_blocks, block) + k.shape[2:]), 1, 0)
+    v_blocks = jnp.moveaxis(
+        v.reshape((b, n_blocks, block) + v.shape[2:]), 1, 0)
+    d_v = (v.shape[-1] if kv_map is None else
+           jax.eval_shape(kv_map, k_blocks[0], v_blocks[0])[1].shape[-1])
 
     def step(carry, blk):
         out, row_max, row_sum, blk_i = carry
-        kb, vb = blk
+        kb, vb = blk if kv_map is None else kv_map(*blk)
         out, row_max, row_sum = _block_attend(
             q, kb, vb, out, row_max, row_sum,
-            q_offset=0, k_offset=blk_i * block, causal=causal, scale=scale)
+            q_offset=0, k_offset=blk_i * block, causal=causal, scale=scale,
+            q_positions=q_positions, kv_lengths=kv_lengths)
         return (out, row_max, row_sum, blk_i + 1), None
 
-    stats0 = (jnp.full((b, h, n), _NEG_INF, q.dtype),
+    stats0 = (jnp.zeros((b, n, h, d_v), q.dtype),
+              jnp.full((b, h, n), _NEG_INF, q.dtype),
               jnp.zeros((b, h, n), q.dtype))
     # inside a shard_map (e.g. the Ulysses inner attention) the inputs
     # vary over the sp axis, so the freshly-created accumulators must be
     # promoted to the same varying type or the scan carry mismatches
     from mmlspark_tpu.core.jax_compat import operand_vma, pcast_varying
     stats0 = pcast_varying(stats0, tuple(sorted(operand_vma(q, k, v))))
-    init = (jnp.zeros_like(q), *stats0, jnp.asarray(0))
+    init = (*stats0, jnp.asarray(0))
     (out, row_max, row_sum, _), _ = jax.lax.scan(
         step, init, (k_blocks, v_blocks))
     return out / jnp.maximum(row_sum, 1e-30).transpose(0, 2, 1)[..., None]

@@ -86,6 +86,11 @@ ONNX_RULES: List[Tuple[str, Tuple]] = [
 DL_RULES: List[Tuple[str, Tuple]] = [
     (r".*embedding.*", (MODEL_AXIS, None)),
     (r".*kernel$", (None, MODEL_AXIS)),
+    # stacked expert weights (experts, in, out): the expert axis over
+    # mp. No scorer runs such a mesh yet (the experts' exchange across
+    # chips is not written); without an mp axis the leaf replicates,
+    # placed once at construction like every other
+    (r".*experts_(gate|up|down)$", (MODEL_AXIS, None, None)),
     (r".*", ()),
 ]
 

@@ -560,3 +560,20 @@ def test_retention_kernels_lower_to_mosaic_at_the_published_widths():
                      jax.ShapeDtypeStruct((b, 2 * c, kv), f32),
                      jax.ShapeDtypeStruct((b,), jnp.int32), state)
     assert "tpu_custom_call" in txt and "retention_prefill" in txt
+
+
+def test_gdn_decode_kernel_lowers_to_mosaic():
+    """The delta rule's one-token step at the published head sizes (64
+    value heads of 128 x 128 over 32 key heads) goes through Mosaic."""
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.parallel import delta_rule
+
+    b, kh, heads, d = 2, 32, 64, 128
+    f32 = jnp.float32
+    args = (jnp.zeros((b, kh, d), f32), jnp.zeros((b, kh, d), f32),
+            jnp.zeros((b, heads, d), f32), jnp.zeros((b, heads), f32),
+            jnp.zeros((b, heads), f32), jnp.zeros((b, heads, d, d), f32))
+    txt = _lower_tpu(functools.partial(delta_rule.delta_step, pallas=True),
+                     *args)
+    assert "tpu_custom_call" in txt and "gdn_decode" in txt

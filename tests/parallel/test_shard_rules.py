@@ -503,3 +503,23 @@ def test_chunked_feed_callers_on_many_threads_keep_their_own_rows(
         sys.setswitchinterval(interval)
     assert not any(t.is_alive() for t in threads)
     assert not errors and not wrong
+
+
+def test_dl_rules_name_the_expert_axis_of_stacked_expert_weights():
+    """``(experts, in, out)`` leaves shard their expert axis over mp
+    where the mesh has one that divides it, and replicate otherwise."""
+    from mmlspark_tpu.parallel import shard_rules as sr
+    from mmlspark_tpu.parallel.mesh import MeshConfig, create_mesh
+    params = {"ffn": {"experts_gate": np.zeros((16, 64, 32), np.float32),
+                      "experts_down": np.zeros((16, 32, 64), np.float32),
+                      "shared_gate": np.zeros((64, 32), np.float32),
+                      "router": np.zeros((64, 256), np.float32)}}
+    mesh = create_mesh(MeshConfig(dp=4, mp=2))
+    specs = sr.match_partition_rules(sr.DL_RULES, params, mesh=mesh,
+                                     small_numel=0)
+    assert specs["ffn"]["experts_gate"] == (sr.MODEL_AXIS, None, None)
+    assert specs["ffn"]["experts_down"] == (sr.MODEL_AXIS, None, None)
+    assert specs["ffn"]["shared_gate"] == () == specs["ffn"]["router"]
+    alone = sr.match_partition_rules(sr.DL_RULES, params, mesh=None,
+                                     small_numel=0)
+    assert alone["ffn"]["experts_gate"] == ()
