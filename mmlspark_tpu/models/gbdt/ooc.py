@@ -658,6 +658,8 @@ def train_ooc(spill: SpillReader, labels, cfg: TrainConfig, *,
         # (trainer._ooc_supported screens everything else out)
         "hist_formulation": "native", "tree_mode": "serial",
         "pallas_interpret": None, "hist_feed": None,
+        # levels are routed on the host, in NumPy (_route_level)
+        "route": None,
         # chunks are streamed and keep no slot from the level loop, so
         # the carry walks each finished tree (_carry_step)
         "raw_update": "tree_walk",

@@ -130,6 +130,7 @@ def make_build_tree_voting(num_features: int, total_bins: int, cfg,
     from jax.sharding import PartitionSpec as P
 
     from mmlspark_tpu.core.jax_compat import shard_map
+    from mmlspark_tpu.models.gbdt.trainer import route_level
 
     depth = cfg.effective_depth
     num_slots = 2 ** (depth + 1) - 1
@@ -233,14 +234,8 @@ def make_build_tree_voting(num_features: int, total_bins: int, cfg,
                 jnp.where(do_split, right_stats[:, 2], 0.0))
 
             # ---- route local rows (all features present locally) -------
-            nfeat = best_feat[local]
-            nbin = jnp.take_along_axis(binned, nfeat[:, None], 1)[:, 0]
-            nsplit = do_split[local]
-            go_left = nbin <= best_bin[local]
-            child = jnp.where(go_left, 2 * node + 1, 2 * node + 2)
-            newly_done = ~nsplit & ~done
-            node = jnp.where(done | ~nsplit, node, child)
-            done = done | newly_done
+            node, done = route_level(binned, node, done, local, do_split,
+                                     best_feat, best_bin)
 
         return split_feature, threshold_bin, node_value, node_count, node
 
@@ -317,6 +312,7 @@ def make_build_tree_data_parallel(num_features: int, total_bins: int,
     from jax.sharding import PartitionSpec as P
 
     from mmlspark_tpu.core.jax_compat import shard_map
+    from mmlspark_tpu.models.gbdt.trainer import route_level
     from mmlspark_tpu.parallel.mesh import axis_size
 
     depth = cfg.effective_depth
@@ -491,14 +487,8 @@ def make_build_tree_data_parallel(num_features: int, total_bins: int,
                 jnp.where(do_split, right_stats[:, 2], 0.0))
 
             # ---- route local rows (all features present locally) -------
-            nfeat = best_feat[local]
-            nbin = jnp.take_along_axis(binned, nfeat[:, None], 1)[:, 0]
-            nsplit = do_split[local]
-            go_left = nbin <= best_bin[local]
-            child = jnp.where(go_left, 2 * node + 1, 2 * node + 2)
-            newly_done = ~nsplit & ~done
-            node = jnp.where(done | ~nsplit, node, child)
-            done = done | newly_done
+            node, done = route_level(binned, node, done, local, do_split,
+                                     best_feat, best_bin)
 
         # every shard computed identical tree state (all cross-shard
         # values went through psum/all_gather); pmax is an identity that

@@ -29,7 +29,14 @@ allreduce). Design:
     builder routed (validation sets, out-of-core chunks, the eager
     loop) walk the finished tree (``_make_predict_tree``). A fit's
     ``hist_stats["raw_update"]`` says which: ``builder_leaf`` or
-    ``tree_walk``.
+    ``tree_walk``;
+  - a level sends its rows to their children through one function,
+    ``route_level``, in one of two forms that give every row the same
+    node: compare-and-select over the level's nodes where a per-row
+    index is a serial gather (the TPU, up to 512 nodes), the gather on
+    the CPU. A fit's
+    ``hist_stats["route"]`` counts a tree's levels by form
+    (``{"select": 6, "gather": 0}`` at depth 6 on the chip).
 
 GOSS / bagging / feature-fraction / DART semantics follow
 params/LightGBMParams.scala; voting/feature/data-parallel builders live
@@ -927,6 +934,104 @@ def _derive_sibling_hist(hist_small, prev_hist, prev_split, prev_ss):
     return hist
 
 
+ROUTE_FORMS = ("select", "gather")
+# Widest level the select form routes on an accelerator. One level at
+# 20M x 28 on a TPU v5e, seconds a call (tools/route_level_ab.py; my
+# chip runs, PR 34), select | gather:
+#   width   1: 0.0024 | 0.345      width 128: 0.153 | 0.690
+#   width   8: 0.0110 | 0.267      width 256: 0.305 | 0.711
+#   width  32: 0.0396 | 0.255      width 512: 0.609 | 0.711
+# The select's time follows the nodes (1.2 ms each: a column's slice
+# and compare, two ORs of pred[N]); the gather's does not grow past
+# 0.71 s. By that arithmetic they cross near 600 nodes; 1024 was not
+# measured.
+ROUTE_SELECT_MAX_WIDTH = 512
+
+
+def route_form(width: int) -> str:
+    """The form the routing of a level of ``width`` nodes takes
+    (``route_level``), read from the backend as
+    ``resolve_histogram_formulation`` reads it and from the static
+    width; nothing a user sets.
+
+    On the TPU a per-row index lowers to a serial gather, 0.26-0.71 s a
+    level for one byte a row at 20M x 28, while what the index chooses
+    among is few, the level's nodes: ``select``, up to
+    ``ROUTE_SELECT_MAX_WIDTH``; 0.076 s a tree of depth 6 against 3.16
+    (the traced fit; PERF.md §6, PR 34). The CPU backend gathers in
+    a few cycles a row and selecting is ``width`` strided passes over
+    the row-major matrix (3.6 times the gather at width 32, 2M rows):
+    ``gather``."""
+    import jax
+
+    if jax.default_backend() == "cpu" or width > ROUTE_SELECT_MAX_WIDTH:
+        return "gather"
+    return "select"
+
+
+def route_by_form(widths, tree_mode: str = "serial") -> dict:
+    """How many of a tree's levels, of these widths, route in each form:
+    what ``hist_stats["route"]`` records. The feature-parallel builder
+    holds a slice of the columns a device and keeps its own routing, a
+    gathered bin and a vote."""
+    forms = ["gather" if tree_mode == "feature" else route_form(w)
+             for w in widths]
+    return {name: forms.count(name) for name in ROUTE_FORMS}
+
+
+def route_level(binned, node, done, local, do_split, best_feat, best_bin,
+                left_mask=None, form=None):
+    """Send each live row of one level to its child, or settle it in a
+    leaf: ``(node, done)`` after the level. The one routing of the
+    serial builder and both ``shard_map`` builders.
+
+    ``local`` (N,) is each row's node within the level (every entry in
+    ``[0, width)``), ``do_split``, ``best_feat`` and ``best_bin``
+    (width,) the level's splits. A row goes left where the bin of its
+    node's split feature is at most the node's ``best_bin``; with
+    categorical splits ``left_mask`` (width, B) bool says instead which
+    bins of each node go left.
+
+    ``form`` is ``route_form``'s choice unless a test pins one; both
+    give every row the same node. ``select`` reads no per-row index: a
+    loop over the level's nodes takes node ``w``'s column of ``binned``
+    (one ``dynamic_slice``: the device keeps the matrix column-major)
+    and keeps its verdict for the rows with ``local == w``. The loop's
+    carry starts from node 0's verdict, so inside ``shard_map`` it
+    varies over the axes its inputs vary over with no cast. Only the
+    categorical table is still looked up a row, by the bin so chosen."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope("gbdt.route"):
+        if form is None:
+            form = route_form(do_split.shape[0])
+        if form == "gather":
+            nbin = jnp.take_along_axis(
+                binned, best_feat[local][:, None], 1)[:, 0]
+            picked = nbin <= best_bin[local] if left_mask is None else nbin
+            nsplit = do_split[local]
+        else:
+            def of_node(w):
+                here = local == w
+                col = jax.lax.dynamic_slice_in_dim(
+                    binned, best_feat[w], 1, axis=1)[:, 0]
+                # numeric: whether the row goes left; else its bin
+                pick = (here & (col <= best_bin[w]) if left_mask is None
+                        else jnp.where(here, col, 0))
+                return pick, here & do_split[w]
+
+            # every row has exactly one node, so OR-ing the nodes'
+            # verdicts is choosing among them
+            picked, nsplit = jax.lax.fori_loop(
+                1, do_split.shape[0],
+                lambda w, acc: tuple(a | v for a, v in zip(acc, of_node(w))),
+                of_node(0))
+        go_left = picked if left_mask is None else left_mask[local, picked]
+        child = jnp.where(go_left, 2 * node + 1, 2 * node + 2)
+        return jnp.where(done | ~nsplit, node, child), done | ~nsplit
+
+
 def _find_numeric_splits(hist, feat_mask, remaining, parent_value, *, b,
                          lam1, lam2, min_child, min_hess, min_gain,
                          path_smooth, max_delta_step):
@@ -1012,8 +1117,11 @@ def make_build_tree(num_features: int, total_bins: int, cfg: TrainConfig,
     full-layout arrays described in booster.py; ``bin_go_left`` is a
     (num_slots, B) bool mask — for every internal slot, which bin ids
     route left. Numerical splits fill it with ``bin <= threshold``;
-    categorical splits with the chosen category subset, so row routing
-    and binned prediction are a single gather regardless of split type.
+    categorical splits with the chosen category subset, so binned
+    prediction is a single gather regardless of split type. A level's
+    row routing is ``route_level``: numeric fits compare the row's bin
+    with its node's threshold, and only categorical fits look the bin
+    up in the level's masks.
     ``node`` is int32 (N,): the slot every row of ``binned`` settled in
     after the last level's routing, bagged-out and padded rows included
     — the slot ``_make_predict_tree``'s walk of the finished tree
@@ -1228,18 +1336,6 @@ def make_build_tree(num_features: int, total_bins: int, cfg: TrainConfig,
 
         remaining = remaining_leaves - 1  # root is one leaf
 
-        def route(node, done, local, do_split, best_feat, left_mask):
-            """Send each live row to its child, or settle it in a leaf."""
-            with jax.named_scope("gbdt.route"):
-                nfeat = best_feat[local]
-                nbin = jnp.take_along_axis(binned, nfeat[:, None], 1)[:, 0]
-                nsplit = do_split[local]
-                go_left = left_mask[local, nbin]
-                child = jnp.where(go_left, 2 * node + 1, 2 * node + 2)
-                newly_done = ~nsplit & ~done
-                node = jnp.where(done | ~nsplit, node, child)
-                return node, done | newly_done
-
         for d in range(depth):
             level_start = 2 ** d - 1
             width = 2 ** d
@@ -1332,8 +1428,8 @@ def make_build_tree(num_features: int, total_bins: int, cfg: TrainConfig,
                 if subtract:
                     prev_split = do_split
                     prev_ss = small_side
-                node, done = route(node, done, local, do_split,
-                                   best_feat, left_mask)
+                node, done = route_level(binned, node, done, local,
+                                         do_split, best_feat, best_bin)
                 continue
 
             # --- numerical split finding: ordered cumulative scan -------
@@ -1538,8 +1634,11 @@ def make_build_tree(num_features: int, total_bins: int, cfg: TrainConfig,
                     left_stats[:, 2] <= right_stats[:, 2], 0, 1
                 ).astype(jnp.int32)
 
-            node, done = route(node, done, local, do_split, best_feat,
-                               left_mask)
+            # a numeric left_mask is ``bin <= best_bin`` by construction;
+            # only categorical membership needs the table
+            node, done = route_level(binned, node, done, local, do_split,
+                                     best_feat, best_bin,
+                                     left_mask if has_cat else None)
 
         return (split_feature, threshold_bin, node_value, node_count,
                 decision_type, bin_go_left, node)
@@ -1814,9 +1913,12 @@ def _make_step_fn(num_f: int, total_bins: int, cfg: TrainConfig, k: int,
     of the compiled program says in its ``op_name`` which stage it came
     from: ``gbdt.sample``, ``gbdt.grad``, then inside the builder a
     level ``gbdt.hist`` (with the Pallas feed as ``gbdt.hist.feed``),
-    ``gbdt.split``, ``gbdt.leaf``, ``gbdt.route``, and ``gbdt.predict``
-    (the raw updates: the leaf gather and, for validation rows, the
-    walk), ``gbdt.metric``. Where scopes nest, the innermost names the op.
+    ``gbdt.split``, ``gbdt.leaf``, ``gbdt.route`` (``route_level``: on an
+    accelerator a loop over the level's nodes that selects each row's
+    verdict with no per-row index, on the CPU a gather), and
+    ``gbdt.predict`` (the raw updates: the leaf gather and, for
+    validation rows, the walk), ``gbdt.metric``. Where scopes nest, the
+    innermost names the op.
     They are metadata: the program and its compile-cache key are as
     without them. The jitted function stays named ``step``.
     """
@@ -2381,6 +2483,13 @@ def train(binned: np.ndarray, labels: np.ndarray, cfg: TrainConfig,
                 [1] if grow_policy == "leafwise"
                 else [2 ** d for d in range(cfg.effective_depth)])
                 if hist_formulation == "pallas" else None),
+            # a tree's levels by the form their routing takes
+            # (route_form): compare-and-select over the level's nodes,
+            # or a gather a row. Leaf-wise growth routes on the host
+            "route": (None if grow_policy == "leafwise"
+                      else route_by_form(
+                          [2 ** d for d in range(cfg.effective_depth)],
+                          tree_mode)),
             # rows of the binned matrix resident on each device: N/dp
             # apiece when the ingest sharded them, nothing staged whole
             "binned_rows_per_device": _rows_per_device(binned_d),

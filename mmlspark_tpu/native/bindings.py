@@ -2,7 +2,9 @@
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
+import fcntl
 import os
 import subprocess
 import threading
@@ -26,6 +28,24 @@ _build_done = threading.Event()
 _quant_symbols = False
 
 
+@contextlib.contextmanager
+def _build_flock():
+    """One PROCESS at a time builds and loads: an exclusive ``flock`` on
+    a file beside the target, held across ``make`` and the load. Test
+    workers and fit processes that start together on a fresh checkout
+    otherwise all run ``make`` at once, and one loads (or has mapped) a
+    library another is still writing. Where the directory cannot be
+    written nothing can be built there either, and no lock is taken."""
+    try:
+        fh = open(os.path.join(_NATIVE_DIR, ".build.lock"), "a")
+    except OSError:
+        yield
+        return
+    with fh:                      # closing the file drops the lock
+        fcntl.flock(fh, fcntl.LOCK_EX)
+        yield
+
+
 def ensure_built() -> bool:
     """Compile the shared library if missing; returns availability.
 
@@ -33,7 +53,11 @@ def ensure_built() -> bool:
     is elected builder under the lock, concurrent callers park on
     ``_build_done`` — holding a lock across a subprocess would stall
     every thread that merely wants the cached availability answer
-    (GL012, blocking-under-lock)."""
+    (GL012, blocking-under-lock). Across processes ``_build_flock``
+    serialises the build and the load, and ``make`` renames a finished
+    library onto the target, so no process ever maps a partial file.
+    A load the loader refused is not remembered: the next call tries
+    again."""
     global _lib, _build_failed, _building
     with _lock:
         if _lib is not None:
@@ -54,36 +78,37 @@ def ensure_built() -> bool:
         with _lock:
             return _lib is not None
     lib: Optional[ctypes.CDLL] = None
-    failed = False
+    failed = load_again = False
     try:
-        # always run make: it is a no-op when the .so is fresh and
-        # rebuilds when data_plane.cpp is newer (a stale library would
-        # silently miss symbols added since it was built)
-        try:
-            subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
-                           capture_output=True, timeout=120)
-        except Exception as e:
-            if not os.path.exists(_SO_PATH):
-                logger.warning("native build failed (%s); using numpy "
-                               "fallbacks", e)
-                failed = True
-            else:
-                logger.warning("native rebuild failed (%s); loading "
-                               "the existing library", e)
-        if not failed:
+        with _build_flock():
+            # always run make: it is a no-op when the .so is fresh and
+            # rebuilds when data_plane.cpp is newer (a stale library
+            # would silently miss symbols added since it was built)
             try:
-                loaded = ctypes.CDLL(_SO_PATH)
-            except OSError as e:
-                logger.warning("native load failed (%s); using numpy "
-                               "fallbacks", e)
-                failed = True
-            else:
-                _configure(loaded)
-                lib = loaded    # published only once fully configured
+                subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
+                               capture_output=True, timeout=120)
+            except Exception as e:
+                if not os.path.exists(_SO_PATH):
+                    logger.warning("native build failed (%s); using "
+                                   "numpy fallbacks", e)
+                    failed = True
+                else:
+                    logger.warning("native rebuild failed (%s); loading "
+                                   "the existing library", e)
+            if not failed:
+                try:
+                    loaded = ctypes.CDLL(_SO_PATH)
+                except OSError as e:
+                    logger.warning("native load failed (%s); numpy "
+                                   "fallbacks for this call", e)
+                    load_again = True
+                else:
+                    _configure(loaded)
+                    lib = loaded    # published only once configured
     finally:
         with _lock:
             _lib = lib
-            _build_failed = failed or lib is None
+            _build_failed = lib is None and not load_again
             _building = False
         _build_done.set()
     return lib is not None

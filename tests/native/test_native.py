@@ -1,6 +1,13 @@
 """C++ data-plane tests: native results must equal the Python
 reference implementations exactly."""
 
+import os
+import shutil
+import subprocess
+import sys
+import time
+from pathlib import Path
+
 import numpy as np
 import pytest
 
@@ -163,3 +170,54 @@ class TestIntegration:
         python = mapper.transform(x)
         assert np.array_equal(np.asarray(native), np.asarray(python))
         assert (np.asarray(python)[::17, 1] == 0).all()
+
+
+class TestBuildAcrossProcesses:
+    def test_processes_starting_on_a_fresh_tree_all_load(self, tmp_path):
+        """Several processes reach ``ensure_built`` on a library-less
+        copy of ``native/`` while the first of them is still compiling
+        (six test workers on a fresh checkout): each must end with the
+        library loaded and working. ``make`` used to link onto the
+        target in place, so a process whose ``make`` found the target
+        there loaded a file another was still writing, and remembered
+        the failure for its life."""
+        repo = Path(__file__).resolve().parents[2]
+        native = tmp_path / "native"
+        native.mkdir()
+        for name in ("Makefile", "data_plane.cpp"):
+            shutil.copy(repo / "native" / name, native / name)
+        code = (
+            "import sys\n"
+            "from mmlspark_tpu.native import bindings\n"
+            "bindings._NATIVE_DIR = sys.argv[1]\n"
+            "bindings._SO_PATH = sys.argv[1] + '/libmmlspark_native.so'\n"
+            "assert bindings.ensure_built(), 'library not loaded'\n"
+            "print(int(bindings.murmur3_batch(['age'], 42)[0]))\n")
+        env = dict(os.environ, PYTHONPATH=str(repo))
+
+        def start():
+            return subprocess.Popen(
+                [sys.executable, "-c", code, str(native)], env=env,
+                stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+
+        def check(procs):
+            want = str(murmur3_32("age", 42))
+            for i, p in enumerate(procs):
+                out, err = p.communicate(timeout=300)
+                assert p.returncode == 0, f"process {i}: {err[-800:]}"
+                assert out.strip() == want, f"process {i}: {out!r}"
+            assert [f.name for f in native.glob("*.tmp.*")] == []
+
+        procs = []
+        for _ in range(6):
+            procs.append(start())
+            time.sleep(0.4)     # starts spread over the first's compile
+        check(procs)
+        # a rebuild replaces the library by rename: the file a process
+        # has mapped is never truncated and rewritten under it
+        target = native / "libmmlspark_native.so"
+        before = target.stat().st_ino
+        later = time.time() + 5
+        os.utime(native / "data_plane.cpp", (later, later))
+        check([start()])
+        assert target.stat().st_ino != before
