@@ -10,8 +10,9 @@ that carry a state from one forward pass to the next:
 every layer mixes the sequence by power retention) and
 :class:`HybridLM` (layers that mix by a gated delta rule or by latent
 attention over a cache, over a dense SwiGLU or sparse experts, chosen
-by the layer's index). ``LM_MODELS`` maps a config's ``model_type`` to
-its class.
+by the layer's index; the norm, its placement, the attention's gate
+and the SwiGLU's limit read from the config). ``LM_MODELS`` maps a
+config's ``model_type`` to its class.
 """
 
 from __future__ import annotations
@@ -372,7 +373,8 @@ class RetentionLM(nn.Module):
 
 # ---------------------------------------------------------------------
 # hybrid decoder: delta-rule and latent-attention layers over a dense
-# SwiGLU or sparse experts (config keys of ``model_type`` gigachat3_5)
+# SwiGLU or sparse experts (config keys of ``model_type`` gigachat3_5
+# and kimi_k2)
 
 
 def gated_norm(x, weight, eps):
@@ -386,6 +388,42 @@ def gated_norm(x, weight, eps):
 
 def _centred(module, name, width):
     return module.param(name, nn.initializers.zeros, (width,), jnp.float32)
+
+
+# what a family's code does where its config.json may be silent; a
+# config without these keys and of another family has plain RMSNorm
+# before each sub-layer and none after
+FAMILY_DEFAULTS = {"gigachat3_5": {"norm_type": "ZeroCenteredGatedNorm",
+                                   "layernorm_type": "pre_post"}}
+
+
+def _setting(config, key):
+    return config.get(key, FAMILY_DEFAULTS.get(
+        config.get("model_type"), {}).get(key))
+
+
+def _gated(config) -> bool:
+    return _setting(config, "norm_type") == "ZeroCenteredGatedNorm"
+
+
+def norm_weight(module, name, width, config):
+    """The weight of one of the model's norms: centred at zero for the
+    gated norm, a scale about one for plain RMSNorm."""
+    return (_centred if _gated(config) else _scale)(module, name, width)
+
+
+def model_norm(x, weight, config):
+    """The model's norm over ``x``'s last axis: the gated norm where
+    the config names it (``norm_type`` ``ZeroCenteredGatedNorm``), else
+    plain RMSNorm."""
+    return (gated_norm if _gated(config) else rms_norm)(
+        x, weight, config["rms_norm_eps"])
+
+
+def block_norm(module, name, x, config):
+    """``model_norm`` with the weight ``name`` declared on ``module``."""
+    return model_norm(x, norm_weight(module, name, x.shape[-1], config),
+                      config)
 
 
 def _rounded(x, dtype):
@@ -485,8 +523,12 @@ class DeltaMixer(nn.Module):
 class LatentMixer(nn.Module):
     """Latent attention (``parallel/latent.py``): low-rank queries,
     keys and values compressed to one latent and one rotated key a
-    position, a sigmoid gate a head channel on the output. State: the
-    cache ``{"c", "r"}`` in the model's dtype."""
+    position, and where the config asks (``gated_attention``) a sigmoid
+    gate a head channel on the output. Under YaRN the softmax scale
+    carries ``mscale_all_dim`` where ``use_mla_scaling_factor`` says
+    so, and where the key is absent whenever ``mscale_all_dim`` is set
+    (the DeepSeek-V3 family's code). State: the cache ``{"c", "r"}`` in
+    the model's dtype."""
 
     config: Any
 
@@ -505,31 +547,34 @@ class LatentMixer(nn.Module):
         c = self.config
         heads, rank = c["num_attention_heads"], c["kv_lora_rank"]
         nope, rope = c["qk_nope_head_dim"], c["qk_rope_head_dim"]
-        d_v, eps, dtype = c["v_head_dim"], c["rms_norm_eps"], lm_dtype(c)
+        d_v, dtype = c["v_head_dim"], lm_dtype(c)
         b, t, _ = x.shape
         scaling = c.get("rope_scaling") or {}
         freq = latent.yarn_frequencies(rope, c["rope_theta"], scaling)
         scale = latent.softmax_scale(
-            nope + rope, scaling, c.get("use_mla_scaling_factor", False))
+            nope + rope, scaling, c.get("use_mla_scaling_factor",
+                                        bool(scaling.get("mscale_all_dim"))))
 
-        c_q = gated_norm(Linear(c["q_lora_rank"], dtype, name="q_a_proj")(x),
-                         _centred(self, "q_a_norm", c["q_lora_rank"]), eps)
+        c_q = block_norm(self, "q_a_norm", Linear(
+            c["q_lora_rank"], dtype, name="q_a_proj")(x), c)
         q = Linear(heads * (nope + rope), dtype, name="q_b_proj")(c_q)
         q = q.reshape(b, t, heads, nope + rope)
         q_n = q[..., :nope]
         q_r = latent.rotary_interleaved(q[..., nope:], positions, freq)
         down = Linear(rank + rope, dtype, name="kv_a_proj")(x)
-        c_kv = gated_norm(down[..., :rank],
-                          _centred(self, "kv_a_norm", rank), eps)
+        c_kv = block_norm(self, "kv_a_norm", down[..., :rank], c)
         k_r = latent.rotary_interleaved(down[..., rank:], positions, freq)
         up = self.param("kv_b_proj", nn.initializers.normal(0.02),
                         (rank, heads, nope + d_v), dtype)
         w_uk, w_uv = up[..., :nope], up[..., nope:]
         pos = positions[:, 0]
-        cache = latent.cache_write(state, c_kv, k_r, pos, lengths)
+        with jax.named_scope("lm.mla.write"):
+            cache = latent.cache_write(state, c_kv, k_r, pos, lengths)
         if t == 1:
-            o = latent.latent_decode(q_n[:, 0], q_r[:, 0], cache, w_uk, w_uv,
-                                     pos, scale=scale, dtype=dtype)[:, None]
+            with jax.named_scope("lm.mla.decode"):
+                o = latent.latent_decode(
+                    q_n[:, 0], q_r[:, 0], cache, w_uk, w_uv, pos,
+                    scale=scale, dtype=dtype)[:, None]
         else:
             o = latent.latent_prefill(q_n, q_r, cache, w_uk, w_uv, pos,
                                       lengths, scale=scale, dtype=dtype)
@@ -599,16 +644,20 @@ class ExpertFeedForward(nn.Module):
 
 
 class HybridBlock(nn.Module):
-    """One layer: ``h += post(mixer(pre(h)))``, then the same around the
-    feed-forward. The layer's index chooses both: latent attention where
-    ``full_attention_layers`` lists it, else the delta rule; a dense
-    SwiGLU under ``first_k_dense_replace``, else the experts."""
+    """One layer: ``h += mixer(pre(h))``, then the same around the
+    feed-forward; where the config says ``layernorm_type`` ``pre_post``
+    a sub-layer's output is normed too before it is added. The layer's
+    index chooses both sub-layers: latent attention where
+    ``full_attention_layers`` lists it (or in every layer where the
+    config has no such key), else the delta rule; a dense SwiGLU under
+    ``first_k_dense_replace``, else the experts."""
 
     config: Any
     index: int
 
     def latent(self) -> bool:
-        return self.index in tuple(self.config["full_attention_layers"])
+        listed = self.config.get("full_attention_layers")
+        return listed is None or self.index in tuple(listed)
 
     def sparse(self) -> bool:
         return self.index >= self.config["first_k_dense_replace"]
@@ -616,19 +665,23 @@ class HybridBlock(nn.Module):
     @nn.compact
     def __call__(self, h, positions, lengths, state):
         c = self.config
-        width, eps = h.shape[-1], c["rms_norm_eps"]
+        post = _setting(c, "layernorm_type") == "pre_post"
+
+        def added(y, name):
+            return h + (block_norm(self, name, y, c) if post else y)
+
         mixer = LatentMixer if self.latent() else DeltaMixer
         with jax.named_scope("lm.mla" if self.latent() else "lm.gdn"):
             y, state = mixer(c, name="mixer")(
-                gated_norm(h, _centred(self, "mixer_pre", width), eps),
-                positions, lengths, state)
-            h = h + gated_norm(y, _centred(self, "mixer_post", width), eps)
+                block_norm(self, "mixer_pre", h, c), positions, lengths,
+                state)
+            h = added(y, "mixer_post")
         ffn = ExpertFeedForward if self.sparse() else DenseFeedForward
         with jax.named_scope("lm.moe" if self.sparse() else "lm.mlp"):
             valid = jnp.arange(h.shape[1])[None, :] < lengths[:, None]
             y, served = ffn(c, name="ffn")(
-                gated_norm(h, _centred(self, "ffn_pre", width), eps), valid)
-            h = h + gated_norm(y, _centred(self, "ffn_post", width), eps)
+                block_norm(self, "ffn_pre", h, c), valid)
+            h = added(y, "ffn_post")
         return h, state, served
 
 
@@ -709,8 +762,8 @@ class HybridLM(nn.Module):
             "embedding", nn.initializers.normal(0.02),
             (c["vocab_size"], c["hidden_size"]), lm_dtype(c))
         self.layers = self._blocks(c)
-        self.final_norm = self.param("final_norm", nn.initializers.zeros,
-                                     (c["hidden_size"],), jnp.float32)
+        self.final_norm = norm_weight(self, "final_norm", c["hidden_size"],
+                                      c)
         self.lm_head = Linear(c["vocab_size"], lm_dtype(c))
 
     def hidden(self, ids, lengths, state, every=False):
@@ -737,12 +790,13 @@ class HybridLM(nn.Module):
 
     def head(self, h):
         with jax.named_scope("lm.head"):
-            return self.lm_head(gated_norm(h, self.final_norm,
-                                           self.config["rms_norm_eps"]))
+            return self.lm_head(model_norm(h, self.final_norm,
+                                           self.config))
 
     def __call__(self, ids, lengths, state, every=False):
         h, state = self.hidden(ids, lengths, state, every)
         return self.head(h), state
 
 
-LM_MODELS = {"brumby": RetentionLM, "gigachat3_5": HybridLM}
+LM_MODELS = {"brumby": RetentionLM, "gigachat3_5": HybridLM,
+             "kimi_k2": HybridLM}

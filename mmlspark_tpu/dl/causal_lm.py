@@ -10,9 +10,10 @@ recurrent state and no cache, or
 :class:`~mmlspark_tpu.dl.backbones.HybridLM` (``gigachat3_5``), whose
 delta-rule layers keep a recurrent state and whose latent-attention
 layers a cache of one compressed entry a position, over sparse experts
-of which this chip holds a share. A device batch is sized by the bytes
-of both: the state, and the cache at the longest prompt plus
-``maxNewTokens``.
+of which this chip holds a share; ``kimi_k2`` is the same class with
+latent attention in every layer, so its whole per-sequence state is
+cache. A device batch is sized by the bytes of both: the state, and the
+cache at the longest prompt plus ``maxNewTokens``.
 
 One ``transform()``: the ragged prompts are sorted by length and cut
 into device batches (``ShardedScorer.length_batches``: the row ladder
@@ -57,10 +58,18 @@ class CausalLM(Transformer, HasInputCol, HasOutputCol):
                         "(hidden_size, num_attention_heads, "
                         "num_key_value_heads, head_dim, intermediate_size, "
                         "vocab_size, num_hidden_layers, rms_norm_eps, "
-                        "rope_theta, torch_dtype), or gigachat3_5 (the keys "
-                        "of its config.json, with experts_held and "
-                        "router_experts where this chip holds a share of "
-                        "the experts)", is_complex=True)
+                        "rope_theta, torch_dtype), or gigachat3_5 or "
+                        "kimi_k2 (the keys of the model's config.json: "
+                        "for kimi_k2 hidden_size, num_hidden_layers, "
+                        "first_k_dense_replace, intermediate_size, "
+                        "moe_intermediate_size, n_routed_experts, "
+                        "n_shared_experts, num_experts_per_tok, "
+                        "routed_scaling_factor, q_lora_rank, kv_lora_rank, "
+                        "qk_nope_head_dim, qk_rope_head_dim, v_head_dim, "
+                        "num_attention_heads, rope_theta, rope_scaling, "
+                        "rms_norm_eps, vocab_size, torch_dtype; with "
+                        "experts_held and router_experts where this chip "
+                        "holds a share of the experts)", is_complex=True)
     maxNewTokens = Param("maxNewTokens", "tokens generated a row (greedy, "
                          "no early stop)", to_int, gt(0), default=32)
     batchSize = Param("batchSize", "rows a device batch; unset, the "
@@ -209,7 +218,10 @@ class CausalLM(Transformer, HasInputCol, HasOutputCol):
         carry: ``({"tokens", "logprobs"[, "logits"]}, state)``; with
         expert layers, also what they served since the state was empty,
         counted on the device: ``expert_pairs`` (one row: a count an
-        expert layer and held expert) and ``dropped_pairs``."""
+        expert layer and held expert) and ``dropped_pairs``; with
+        caches, each row's ``cache_positions`` (the positions it has
+        filled, summed over the layers that cache) and
+        ``cache_capacity`` (the same at capacity)."""
         import jax
         import jax.numpy as jnp
 
@@ -239,6 +251,12 @@ class CausalLM(Transformer, HasInputCol, HasOutputCol):
             outs.update(
                 expert_pairs=state["experts"]["pairs"].reshape(1, -1),
                 dropped_pairs=state["experts"]["dropped"])
+        caches = [layer["c"].shape[1] for layer in state["layers"]
+                  if "c" in layer]
+        if caches:
+            outs.update(
+                cache_positions=len(caches) * state["pos"],
+                cache_capacity=jnp.full_like(state["pos"], sum(caches)))
         # the state goes out again so that the donated buffers have an
         # output to alias: the scan then updates them in place, and a
         # second copy of the state (which would not fit) is never made
@@ -321,6 +339,10 @@ class CausalLM(Transformer, HasInputCol, HasOutputCol):
                     expert_pairs_max=int(pairs.max()),
                     dropped_pairs=int(sum(s["dropped_pairs"]
                                           for s in served)))
+            for key in ("cache_positions", "cache_capacity"):
+                if outputs and key in outputs[0][1]:
+                    root.counts[key] = int(sum(
+                        scored[key].sum() for _, scored in outputs))
         return out
 
     # -- persistence (as DeepModel: leaves in order) -------------------

@@ -121,7 +121,8 @@ def _streamed_attend(q, k, v, out, row_max, row_sum, q_offset, k_offset,
 
 def blockwise_attention(q, k, v, block_size: int = 512,
                         causal: bool = False, *, scale=None,
-                        q_positions=None, kv_lengths=None, kv_map=None):
+                        q_positions=None, kv_lengths=None, kv_map=None,
+                        kv_limit=None):
     """Memory-efficient attention via lax.scan over KV blocks.
 
     ``scale`` replaces ``1 / sqrt(d)``; ``q_positions`` and
@@ -130,7 +131,10 @@ def blockwise_attention(q, k, v, block_size: int = 512,
     arrays and ``kv_map(k block, v block)`` gives the block's ``(b,
     block, h, d)`` keys and ``(b, block, h, d_v)`` values inside the
     scan: compressed keys and values are then expanded a block at a
-    time and never whole."""
+    time and never whole. ``kv_limit`` (a traced scalar) says that no
+    row attends to a position from there on (a cache filled so far):
+    the blocks beyond are not visited, which changes no result, only
+    the work (forward only: the loop's length is then not static)."""
     import jax
     import jax.numpy as jnp
 
@@ -173,8 +177,17 @@ def blockwise_attention(q, k, v, block_size: int = 512,
     from mmlspark_tpu.core.jax_compat import operand_vma, pcast_varying
     stats0 = pcast_varying(stats0, tuple(sorted(operand_vma(q, k, v))))
     init = (*stats0, jnp.asarray(0))
-    (out, row_max, row_sum, _), _ = jax.lax.scan(
-        step, init, (k_blocks, v_blocks))
+    if kv_limit is None:
+        (out, row_max, row_sum, _), _ = jax.lax.scan(
+            step, init, (k_blocks, v_blocks))
+    else:
+        def visit(i, carry):
+            return step(carry, tuple(jax.lax.dynamic_index_in_dim(
+                x, i, axis=0, keepdims=False)
+                for x in (k_blocks, v_blocks)))[0]
+
+        out, row_max, row_sum, _ = jax.lax.fori_loop(
+            0, jnp.minimum(-(-kv_limit // block), n_blocks), visit, init)
     return out / jnp.maximum(row_sum, 1e-30).transpose(0, 2, 1)[..., None]
 
 

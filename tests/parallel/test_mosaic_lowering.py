@@ -577,3 +577,33 @@ def test_gdn_decode_kernel_lowers_to_mosaic():
     txt = _lower_tpu(functools.partial(delta_rule.delta_step, pallas=True),
                      *args)
     assert "tpu_custom_call" in txt and "gdn_decode" in txt
+
+
+@pytest.mark.parametrize("rows,capacity", [(512, 1280), (128, 768)],
+                         ids=["kimi_k2_6.reason", "gigachat.generate"])
+def test_latent_decode_kernel_lowers_to_mosaic(rows, capacity):
+    """The absorbed decode over the latent cache at the published widths
+    (64 heads, latent 512, rotated key 64, bfloat16) and the two cells'
+    batches and capacities: the scalar-prefetched positions in the block
+    index, the transposed products and the 64-wide key block go through
+    Mosaic."""
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.parallel import latent
+
+    bf = jnp.bfloat16
+
+    def decode(q_n, q_r, c, r, w_uk, w_uv, pos):
+        return latent.latent_decode(q_n, q_r, {"c": c, "r": r}, w_uk, w_uv,
+                                    pos, scale=0.1, dtype=bf, pallas=True)
+
+    txt = _lower_tpu(
+        decode, jax.ShapeDtypeStruct((rows, 64, 128), jnp.float32),
+        jax.ShapeDtypeStruct((rows, 64, 64), jnp.float32),
+        jax.ShapeDtypeStruct((rows, capacity, 512), bf),
+        jax.ShapeDtypeStruct((rows, capacity, 64), bf),
+        jax.ShapeDtypeStruct((512, 64, 128), bf),
+        jax.ShapeDtypeStruct((512, 64, 128), bf),
+        jax.ShapeDtypeStruct((rows,), jnp.int32))
+    assert "tpu_custom_call" in txt and "latent_decode" in txt
