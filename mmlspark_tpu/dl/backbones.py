@@ -295,6 +295,21 @@ def lm_hidden(module, params, ids, lengths, state):
     return module.apply(params, ids, lengths, state, method="hidden")
 
 
+def lm_hidden_visits(module, lengths, t):
+    """``(visits, run)`` of one ``lm_hidden`` over ``t`` tokens a row of
+    which ``lengths`` (a ``numpy`` array) are real: the groups of rows
+    the model would absorb them in, and those inside its loop's bounds.
+    A model with no group loop, and a stretch of one group, is one
+    visit, run."""
+    model = type(module)
+    groups = (model.row_groups(len(lengths), t)
+              if hasattr(model, "hidden_in_groups") else 1)
+    if groups == 1:
+        return 1, 1
+    first, stop = model.active_groups(lengths, groups)
+    return groups, int(stop - first)
+
+
 def lm_init_params(config: Mapping[str, Any], seed: int = 0):
     """Freshly initialised parameters of the config's model."""
     return lm_module(config).init(
@@ -714,18 +729,43 @@ class HybridLM(nn.Module):
                     "dropped": jnp.zeros((), jnp.int32)}}
 
     @staticmethod
+    def row_groups(rows, t):
+        """How many groups of rows a stretch of ``t`` tokens a row is
+        absorbed in: the fewest (a power of two that divides the rows)
+        that hold ``GROUP_TOKENS`` tokens each, or as near as the rows
+        divide."""
+        groups = 1
+        while (rows * t > groups * HybridLM.GROUP_TOKENS
+               and rows % (2 * groups) == 0):
+            groups *= 2
+        return groups
+
+    @staticmethod
+    def active_groups(lengths, groups):
+        """``(first, stop)``: the range of the ``groups`` groups of
+        consecutive rows from the first to the last that has a row with
+        a real token (``lengths > 0``); ``(0, 0)`` where none has.
+        ``lengths`` is a ``numpy`` array or a traced one, and so are
+        the bounds."""
+        active = (lengths.reshape(groups, -1) > 0).any(axis=1)
+        return active.argmax(), (active * np.arange(1, groups + 1)).max()
+
+    @staticmethod
     def hidden_in_groups(module, params, ids, lengths, state):
         """``method="hidden"`` a group of rows at a time, so that a
         stretch of many tokens (a prefill step of a wide batch) holds
         the activations of ``GROUP_TOKENS`` tokens and not of all: the
         state is the loop's carry, a group's rows are cut from it and
         written back in place, and the experts' counters pass from
-        group to group. One group (a decode step) is the plain call."""
+        group to group. The loop runs from the first group with a real
+        token to the last (``active_groups``): a group outside it has
+        nothing to absorb, so its state stays the carry's and its
+        hidden rows zero. The result is the same for rows in any
+        order; sorted by length (``length_batches``), the rows that
+        have ended are whole groups at one end. One group (a decode
+        step) is the plain call."""
         rows, t = ids.shape
-        groups = 1
-        while (rows * t > groups * HybridLM.GROUP_TOKENS
-               and rows % (2 * groups) == 0):
-            groups *= 2
+        groups = HybridLM.row_groups(rows, t)
         if groups == 1:
             return module.apply(params, ids, lengths, state, method="hidden")
         per = rows // groups
@@ -750,8 +790,9 @@ class HybridLM(nn.Module):
             return state, paste(out, h)
 
         hidden = module.config["hidden_size"]
+        first, stop = HybridLM.active_groups(lengths, groups)
         return jax.lax.fori_loop(
-            0, groups, body,
+            first, stop, body,
             (state, jnp.zeros((rows, hidden), jnp.float32)))[::-1]
 
     GROUP_TOKENS = 4096

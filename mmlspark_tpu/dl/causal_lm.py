@@ -193,8 +193,7 @@ class CausalLM(Transformer, HasInputCol, HasOutputCol):
 
         config = self._config()
         rows, length = ids.shape
-        chunk = min(self.get("prefillChunk"), length)
-        steps = -(-length // chunk)
+        chunk, steps = self._prefill_steps(length)
         ids = jnp.pad(ids, ((0, 0), (0, steps * chunk - length)))
         ids = jnp.moveaxis(ids.reshape(rows, steps, chunk), 1, 0)
 
@@ -212,6 +211,25 @@ class CausalLM(Transformer, HasInputCol, HasOutputCol):
         (state, last), _ = jax.lax.scan(
             step, first, (ids, jnp.arange(steps) * chunk))
         return last, state
+
+    def _prefill_steps(self, length: int):
+        """``(chunk, steps)``: the tokens a row a prefill step absorbs
+        and the steps a length rung takes."""
+        chunk = min(self.get("prefillChunk"), length)
+        return chunk, -(-length // chunk)
+
+    def _prefill_visits(self, lengths: np.ndarray, length: int):
+        """``[visits, run]`` of one ``lm_prefill`` over these rows'
+        lengths (the device batch's, zero rows included) at a length
+        rung: a visit is a group of rows in a step
+        (``backbones.lm_hidden_visits``); those run are the ones inside
+        the bounds of the model's group loop."""
+        from mmlspark_tpu.dl.backbones import lm_hidden_visits
+
+        chunk, steps = self._prefill_steps(length)
+        return np.sum([lm_hidden_visits(
+            self._module, np.clip(lengths - start, 0, chunk), chunk)
+            for start in np.arange(steps) * chunk], axis=0)
 
     def _decode(self, params, last, state, with_logits):
         """``maxNewTokens`` greedy tokens a row in one scan, the state its
@@ -326,10 +344,16 @@ class CausalLM(Transformer, HasInputCol, HasOutputCol):
             rows = max((len(index) for index, _ in batches), default=0)
             rung = max((b["ids"].shape[1] for _, b in batches), default=0)
             held = lm_state_bytes(self._config(), rows, rung + new)
+            visits = sum((self._prefill_visits(
+                np.pad(b["lengths"], (0, scorer.padded_rows(len(index))
+                                      - len(index))), b["ids"].shape[1])
+                for index, b in batches), np.zeros(2, int))
             root.counts.update(
                 new_tokens=int(new * len(prompts)),
                 state_bytes=int(held["state"]),
-                cache_bytes=int(held["cache"]), length_rung=int(rung))
+                cache_bytes=int(held["cache"]), length_rung=int(rung),
+                prefill_visits=int(visits[0]),
+                prefill_visits_run=int(visits[1]))
             served = [scored for _, scored in outputs
                       if "expert_pairs" in scored]
             if served:

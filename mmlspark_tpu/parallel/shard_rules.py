@@ -483,6 +483,10 @@ class ShardedScorer:
 
         return bucket_for(max(n, 1), self._ladder)
 
+    def padded_rows(self, n: int) -> int:
+        """The rows a batch of ``n`` is padded to: ``dp x`` its rung."""
+        return self._dp * self._rung(n)
+
     def length_batches(self, lengths) -> List[Tuple[np.ndarray, int]]:
         """``[(row indices, length rung)]``: the device batches of a
         ragged column (``parallel/inference.length_batches``), ``dp x``

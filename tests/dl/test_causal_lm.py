@@ -159,6 +159,9 @@ def test_save_load_spans_and_counts(params, tmp_path):
     assert counts["new_tokens"] == 5 * NEW and counts["length_rung"] == 128
     # 4 rows x 2 layers x 2 kv heads x 9 rows of phi x 16 x (16 + 1) x 4 B
     assert counts["state_bytes"] == 4 * 2 * 2 * 9 * 16 * 17 * 4 + 4 * 4
+    # no group loop: a visit a prefill step, all run (two device batches
+    # of 16 steps of 8 tokens on the 128 rung)
+    assert counts["prefill_visits"] == counts["prefill_visits_run"] == 32
 
     stage.save(str(tmp_path / "lm"))
     loaded = PipelineStage.load(str(tmp_path / "lm"))
@@ -167,6 +170,20 @@ def test_save_load_spans_and_counts(params, tmp_path):
                           np.asarray(first.col("completion")))
     assert np.array_equal(np.asarray(again.col("logprobs")),
                           np.asarray(first.col("logprobs")))
+
+
+def test_the_prefill_has_no_loop_with_traced_bounds(params):
+    """``RetentionLM`` has no group loop: its ``lm_prefill`` is the scan
+    over the chunks and nothing in it is a ``while`` (what a
+    ``fori_loop`` over the groups that have a token left would be)."""
+    import jax
+    import jax.numpy as jnp
+
+    stage = _stage(params)
+    stage._ensure_scorer()
+    text = str(jax.make_jaxpr(stage._lm_prefill)(
+        params, jnp.zeros((4, 128), jnp.int32), jnp.full((4,), 9, jnp.int32)))
+    assert "scan[" in text and "while[" not in text
 
 
 def test_text_prompts_and_missing_weights(params):

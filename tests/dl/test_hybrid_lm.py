@@ -10,6 +10,7 @@ import pytest
 from benchmark.lookup import load_module
 from mmlspark_tpu.core.dataframe import DataFrame
 from mmlspark_tpu.core.logging_utils import SINK
+from tests.dl import group_loop
 
 # layer 0: delta rule over the dense SwiGLU; 1: latent attention; 2, 3:
 # delta rule; 1-3 over the experts 4..7 of 16. swiglu_limit 0.1 so that
@@ -201,6 +202,33 @@ def test_a_row_does_not_change_with_its_rungs_or_its_neighbours(
     assert np.array_equal(np.asarray(out.col("completion"))[0], tokens[2])
     assert np.abs(np.asarray(out.col("logprobs"))[0]
                   - logprobs[2]).max() < 2e-5
+
+
+@pytest.fixture(scope="module")
+def group_loops(params):
+    return group_loop.compile_loops(CFG, params)
+
+
+@pytest.mark.parametrize("ended", sorted(group_loop.ENDED))
+@pytest.mark.parametrize("order", group_loop.ORDERS)
+def test_the_bounded_group_loop_equals_the_loop_over_all_groups(
+        group_loops, order, ended):
+    """A prefill step's rows in any order, with zero-length rows at the
+    end or in the middle, in steps where no, some and all groups have
+    ended: state, counters and hidden rows to the bit."""
+    group_loop.check(group_loops, CFG, order, ended)
+
+
+def test_a_prefill_that_skips_ended_groups_equals_the_one_group_stage(
+        params, monkeypatch):
+    """Tokens, log-probabilities and the root's ``prefill_visits`` and
+    ``prefill_visits_run`` for hand-made lengths."""
+    group_loop.check_stage(lambda **kw: _stage(params, **kw), _prompts,
+                           monkeypatch)
+
+
+def test_one_group_is_the_plain_call_with_no_loop(params):
+    group_loop.check_one_group(CFG, params)
 
 
 def test_save_load_spans_and_counts(params, tmp_path):

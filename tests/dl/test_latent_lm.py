@@ -12,6 +12,7 @@ import pytest
 from benchmark.lookup import load_module
 from mmlspark_tpu.core.dataframe import DataFrame
 from mmlspark_tpu.core.logging_utils import SINK
+from tests.dl import group_loop
 
 # three layers: 0 over the dense SwiGLU, 1 and 2 over the experts
 # 6..11 of 24 (a count that is no power of two)
@@ -285,6 +286,33 @@ def test_the_shares_add_up_to_the_uncut_layer(reference):
     # and one share alone is not the layer
     assert np.abs(parts[0] - np.asarray(want)).max() > 0.05 * np.abs(
         want).max()
+
+
+@pytest.fixture(scope="module")
+def group_loops(params):
+    return group_loop.compile_loops(CFG, params)
+
+
+@pytest.mark.parametrize("ended", sorted(group_loop.ENDED))
+@pytest.mark.parametrize("order", group_loop.ORDERS)
+def test_the_bounded_group_loop_equals_the_loop_over_all_groups(
+        group_loops, order, ended):
+    """A prefill step's rows in any order, with zero-length rows at the
+    end or in the middle, in steps where no, some and all groups have
+    ended: state, counters and hidden rows to the bit."""
+    group_loop.check(group_loops, CFG, order, ended)
+
+
+def test_a_prefill_that_skips_ended_groups_equals_the_one_group_stage(
+        params, monkeypatch):
+    """Tokens, log-probabilities and the root's ``prefill_visits`` and
+    ``prefill_visits_run`` for hand-made lengths."""
+    group_loop.check_stage(lambda **kw: _stage(params, **kw), _prompts,
+                           monkeypatch)
+
+
+def test_one_group_is_the_plain_call_with_no_loop(params):
+    group_loop.check_one_group(CFG, params)
 
 
 def test_save_load_spans_and_counts(params, tmp_path):

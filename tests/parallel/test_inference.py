@@ -120,6 +120,29 @@ def test_length_ladder_and_batches_group_rows_of_like_length():
         range(len(lengths)))
 
 
+@pytest.mark.parametrize("rows", [1, 3, 8, 64])
+def test_a_length_batch_has_its_rows_ascending_by_length(rows):
+    """Inside every batch the rows stand in ascending order of length
+    (ties in the column's order), so the rows of a device batch that
+    have ended are its first ones. A prefill that skips the groups of
+    rows with no token left (``HybridLM.hidden_in_groups``) depends on
+    this for its gain, not for its result."""
+    from mmlspark_tpu.parallel.inference import (
+        length_batches,
+        length_ladder,
+    )
+
+    lengths = np.random.default_rng(rows).integers(1, 1025, 50)
+    batches = length_batches(lengths, rows, length_ladder(1024))
+    for index, rung in batches:
+        assert (np.diff(lengths[index]) >= 0).all()
+        assert lengths[index[-1]] <= rung
+        ties = np.diff(lengths[index]) == 0
+        assert (np.diff(index)[ties] > 0).all()
+    assert [len(i) for i, _ in batches] == [rows] * (50 // rows) + (
+        [50 % rows] if 50 % rows else [])
+
+
 def test_scorer_places_params_in_the_dtype_the_model_states(monkeypatch):
     import jax.numpy as jnp
 
