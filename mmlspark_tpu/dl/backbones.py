@@ -372,8 +372,10 @@ class RetentionLM(nn.Module):
             h, layer_state = block(h, positions, lengths, layer_state)
             layers.append(layer_state)
         if not every:
-            last = jnp.clip(lengths - 1, 0, ids.shape[1] - 1)
-            h = jnp.take_along_axis(h, last[:, None, None], axis=1)[:, 0]
+            with jax.named_scope("lm.last"):
+                last = jnp.clip(lengths - 1, 0, ids.shape[1] - 1)
+                h = jnp.take_along_axis(h, last[:, None, None],
+                                        axis=1)[:, 0]
         return h, {"pos": state["pos"] + lengths, "layers": layers}
 
     def head(self, h):
@@ -763,7 +765,8 @@ class HybridLM(nn.Module):
         hidden rows zero. The result is the same for rows in any
         order; sorted by length (``length_batches``), the rows that
         have ended are whole groups at one end. One group (a decode
-        step) is the plain call."""
+        step) is the plain call. The cuts, the pastes and the loop's
+        bounds stand under the scope ``lm.group``."""
         rows, t = ids.shape
         groups = HybridLM.row_groups(rows, t)
         if groups == 1:
@@ -780,20 +783,24 @@ class HybridLM(nn.Module):
                 return jax.lax.dynamic_update_slice_in_dim(
                     whole, part, g * per, axis=0)
 
-            mine = {k: v if k == "experts" else jax.tree_util.tree_map(cut, v)
-                    for k, v in state.items()}
-            h, mine = module.apply(params, cut(ids), cut(lengths), mine,
+            with jax.named_scope("lm.group"):
+                mine = {k: v if k == "experts" else
+                        jax.tree_util.tree_map(cut, v)
+                        for k, v in state.items()}
+                group_ids, group_lengths = cut(ids), cut(lengths)
+            h, mine = module.apply(params, group_ids, group_lengths, mine,
                                    method="hidden")
-            state = {k: mine[k] if k == "experts" else
-                     jax.tree_util.tree_map(paste, v, mine[k])
-                     for k, v in state.items()}
-            return state, paste(out, h)
+            with jax.named_scope("lm.group"):
+                state = {k: mine[k] if k == "experts" else
+                         jax.tree_util.tree_map(paste, v, mine[k])
+                         for k, v in state.items()}
+                return state, paste(out, h)
 
         hidden = module.config["hidden_size"]
-        first, stop = HybridLM.active_groups(lengths, groups)
-        return jax.lax.fori_loop(
-            first, stop, body,
-            (state, jnp.zeros((rows, hidden), jnp.float32)))[::-1]
+        with jax.named_scope("lm.group"):
+            first, stop = HybridLM.active_groups(lengths, groups)
+            out = jnp.zeros((rows, hidden), jnp.float32)
+        return jax.lax.fori_loop(first, stop, body, (state, out))[::-1]
 
     GROUP_TOKENS = 4096
 
@@ -821,8 +828,10 @@ class HybridLM(nn.Module):
                 pairs.append(served[0])
                 dropped = dropped + served[1]
         if not every:
-            last = jnp.clip(lengths - 1, 0, ids.shape[1] - 1)
-            h = jnp.take_along_axis(h, last[:, None, None], axis=1)[:, 0]
+            with jax.named_scope("lm.last"):
+                last = jnp.clip(lengths - 1, 0, ids.shape[1] - 1)
+                h = jnp.take_along_axis(h, last[:, None, None],
+                                        axis=1)[:, 0]
         served = state["experts"]["pairs"]
         if pairs:
             served = served + jnp.stack(pairs)

@@ -34,6 +34,7 @@ from typing import Optional
 
 import numpy as np
 
+from mmlspark_tpu.core import scopes
 from mmlspark_tpu.core.dataframe import DataFrame
 from mmlspark_tpu.core.param import (
     HasInputCol, HasOutputCol, Param, gt, to_bool, to_int, to_str,
@@ -49,6 +50,103 @@ def _free_device_bytes() -> Optional[int]:
     if "bytes_limit" not in stats:
         return None
     return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+
+
+def prefill_steps(prefill_chunk: int, length: int):
+    """``(chunk, steps)``: the tokens a row a prefill step absorbs and
+    the steps a length rung takes."""
+    chunk = min(prefill_chunk, length)
+    return chunk, -(-length // chunk)
+
+
+def lm_prefill_program(module, prefill_chunk: int, new_tokens: int):
+    """``lm_prefill(params, ids, lengths) -> (hidden after each row's
+    last prompt token, state)``: the prompt absorbed ``prefill_chunk``
+    tokens a row at a time, the state the scan's carry. A cache in it
+    has room for the batch's length rung and ``new_tokens`` more."""
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.dl.backbones import lm_hidden, lm_init_state
+
+    config = module.config
+
+    def lm_prefill(params, ids, lengths):
+        rows, length = ids.shape
+        chunk, steps = prefill_steps(prefill_chunk, length)
+        ids = jnp.pad(ids, ((0, 0), (0, steps * chunk - length)))
+        ids = jnp.moveaxis(ids.reshape(rows, steps, chunk), 1, 0)
+
+        def step(carry, xs):
+            state, last = carry
+            chunk_ids, start = xs
+            real = jnp.clip(lengths - start, 0, chunk)
+            h, state = lm_hidden(module, params, chunk_ids, real, state)
+            with jax.named_scope("lm.last"):
+                last = jnp.where((real > 0)[:, None], h, last)
+            return (state, last), None
+
+        first = (lm_init_state(config, rows, length + new_tokens),
+                 jnp.zeros((rows, config["hidden_size"]), jnp.float32))
+        (state, last), _ = jax.lax.scan(
+            step, first, (ids, jnp.arange(steps) * chunk))
+        return last, state
+
+    return lm_prefill
+
+
+def lm_generate_program(module, new_tokens: int, with_logits: bool):
+    """``lm_generate(params, last, state)``: ``new_tokens`` greedy
+    tokens a row in one scan, the state its carry: ``({"tokens",
+    "logprobs"[, "logits"]}, state)``; with expert layers, also what
+    they served since the state was empty, counted on the device:
+    ``expert_pairs`` (one row: a count an expert layer and held expert)
+    and ``dropped_pairs``; with caches, each row's ``cache_positions``
+    (the positions it has filled, summed over the layers that cache)
+    and ``cache_capacity`` (the same at capacity)."""
+    import jax
+    import jax.numpy as jnp
+
+    def emit(logits):
+        with jax.named_scope("lm.sample"):
+            token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            chosen = jnp.take_along_axis(logits, token[:, None], axis=1)
+            logprob = chosen[:, 0] - jax.nn.logsumexp(logits, axis=-1)
+        out = {"tokens": token, "logprobs": logprob}
+        return dict(out, logits=logits) if with_logits else out
+
+    def lm_generate(params, last, state):
+        def step(carry, _):
+            logits, state = carry
+            out = emit(logits)
+            token = out["tokens"]
+            logits, state = module.apply(
+                params, token[:, None], jnp.ones_like(token), state)
+            return (logits, state), out
+
+        logits = module.apply(params, last, method="head")
+        (logits, state), outs = jax.lax.scan(
+            step, (logits, state), None, length=new_tokens - 1)
+        final = emit(logits)
+        outs = {k: jnp.concatenate([jnp.moveaxis(v, 0, 1),
+                                    final[k][:, None]], axis=1)
+                for k, v in outs.items()}
+        if "experts" in state:
+            outs.update(
+                expert_pairs=state["experts"]["pairs"].reshape(1, -1),
+                dropped_pairs=state["experts"]["dropped"])
+        caches = [layer["c"].shape[1] for layer in state["layers"]
+                  if "c" in layer]
+        if caches:
+            outs.update(
+                cache_positions=len(caches) * state["pos"],
+                cache_capacity=jnp.full_like(state["pos"], sum(caches)))
+        # the state goes out again so that the donated buffers have an
+        # output to alias: the scan then updates them in place, and a
+        # second copy of the state (which would not fit) is never made
+        return outs, state
+
+    return lm_generate
 
 
 class CausalLM(Transformer, HasInputCol, HasOutputCol):
@@ -96,6 +194,7 @@ class CausalLM(Transformer, HasInputCol, HasOutputCol):
     _weights = None          # the model's parameter pytree, when given
     _module = None
     _scorer = None
+    _programs = None         # the module's jitted programs by setting
 
     def set_weights(self, params) -> "CausalLM":
         """Use this parameter pytree (the structure of
@@ -153,6 +252,7 @@ class CausalLM(Transformer, HasInputCol, HasOutputCol):
         if self._scorer is None:
             config = self._config()
             self._module = lm_module(config)
+            self._programs = {}     # they close over the module
             self._scorer = ShardedScorer(
                 self._generate, self._ensure_weights(), family="dl",
                 max_batch=self._batch_rows(), label="causal_lm",
@@ -163,60 +263,23 @@ class CausalLM(Transformer, HasInputCol, HasOutputCol):
     def _program(self, name: str, with_logits: bool = False):
         """The two jitted programs of a device batch, built once a
         setting: ``lm_prefill`` and ``lm_generate`` (their names are what
-        a profiler's module line shows). Ids, the last hidden state and
-        the model's state are donated."""
+        a profiler's module line shows), and again for a new module
+        (``_ensure_scorer``). Ids, the last hidden state and the model's
+        state are donated. A program closes over the module
+        and numbers, not over this stage or its weights, so that
+        ``core.scopes`` may keep it past the stage's life."""
         import jax
 
-        programs = self.__dict__.setdefault("_programs", {})
-        key = (name, with_logits, self.get("maxNewTokens"),
-               self.get("prefillChunk"))
+        programs = self._programs
+        new, chunk = self.get("maxNewTokens"), self.get("prefillChunk")
+        key = (name, with_logits, new, chunk)
         if key not in programs:
-            if name == "lm_prefill":
-                fn = self._lm_prefill
-            else:
-                def lm_generate(params, last, state):
-                    return self._decode(params, last, state, with_logits)
-                fn = lm_generate
+            fn = (lm_prefill_program(self._module, chunk, new)
+                  if name == "lm_prefill" else
+                  lm_generate_program(self._module, new, with_logits))
             donate = () if jax.default_backend() == "cpu" else (1, 2)
             programs[key] = jax.jit(fn, donate_argnums=donate)
         return programs[key]
-
-    def _lm_prefill(self, params, ids, lengths):
-        """``(hidden after each row's last prompt token, state)``: the
-        prompt absorbed ``prefillChunk`` tokens a row at a time, the
-        state the scan's carry. A cache in it has room for the batch's
-        length rung and ``maxNewTokens`` more."""
-        import jax
-        import jax.numpy as jnp
-
-        from mmlspark_tpu.dl.backbones import lm_hidden, lm_init_state
-
-        config = self._config()
-        rows, length = ids.shape
-        chunk, steps = self._prefill_steps(length)
-        ids = jnp.pad(ids, ((0, 0), (0, steps * chunk - length)))
-        ids = jnp.moveaxis(ids.reshape(rows, steps, chunk), 1, 0)
-
-        def step(carry, xs):
-            state, last = carry
-            chunk_ids, start = xs
-            real = jnp.clip(lengths - start, 0, chunk)
-            h, state = lm_hidden(self._module, params, chunk_ids, real,
-                                 state)
-            return (state, jnp.where((real > 0)[:, None], h, last)), None
-
-        first = (lm_init_state(config, rows,
-                               length + self.get("maxNewTokens")),
-                 jnp.zeros((rows, config["hidden_size"]), jnp.float32))
-        (state, last), _ = jax.lax.scan(
-            step, first, (ids, jnp.arange(steps) * chunk))
-        return last, state
-
-    def _prefill_steps(self, length: int):
-        """``(chunk, steps)``: the tokens a row a prefill step absorbs
-        and the steps a length rung takes."""
-        chunk = min(self.get("prefillChunk"), length)
-        return chunk, -(-length // chunk)
 
     def _prefill_visits(self, lengths: np.ndarray, length: int):
         """``[visits, run]`` of one ``lm_prefill`` over these rows'
@@ -226,66 +289,21 @@ class CausalLM(Transformer, HasInputCol, HasOutputCol):
         the bounds of the model's group loop."""
         from mmlspark_tpu.dl.backbones import lm_hidden_visits
 
-        chunk, steps = self._prefill_steps(length)
+        chunk, steps = prefill_steps(self.get("prefillChunk"), length)
         return np.sum([lm_hidden_visits(
             self._module, np.clip(lengths - start, 0, chunk), chunk)
             for start in np.arange(steps) * chunk], axis=0)
 
-    def _decode(self, params, last, state, with_logits):
-        """``maxNewTokens`` greedy tokens a row in one scan, the state its
-        carry: ``({"tokens", "logprobs"[, "logits"]}, state)``; with
-        expert layers, also what they served since the state was empty,
-        counted on the device: ``expert_pairs`` (one row: a count an
-        expert layer and held expert) and ``dropped_pairs``; with
-        caches, each row's ``cache_positions`` (the positions it has
-        filled, summed over the layers that cache) and
-        ``cache_capacity`` (the same at capacity)."""
-        import jax
-        import jax.numpy as jnp
-
-        def emit(logits):
-            token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            chosen = jnp.take_along_axis(logits, token[:, None], axis=1)
-            logprob = chosen[:, 0] - jax.nn.logsumexp(logits, axis=-1)
-            out = {"tokens": token, "logprobs": logprob}
-            return dict(out, logits=logits) if with_logits else out
-
-        def step(carry, _):
-            logits, state = carry
-            out = emit(logits)
-            token = out["tokens"]
-            logits, state = self._module.apply(
-                params, token[:, None], jnp.ones_like(token), state)
-            return (logits, state), out
-
-        logits = self._module.apply(params, last, method="head")
-        (logits, state), outs = jax.lax.scan(
-            step, (logits, state), None, length=self.get("maxNewTokens") - 1)
-        final = emit(logits)
-        outs = {k: jnp.concatenate([jnp.moveaxis(v, 0, 1),
-                                    final[k][:, None]], axis=1)
-                for k, v in outs.items()}
-        if "experts" in state:
-            outs.update(
-                expert_pairs=state["experts"]["pairs"].reshape(1, -1),
-                dropped_pairs=state["experts"]["dropped"])
-        caches = [layer["c"].shape[1] for layer in state["layers"]
-                  if "c" in layer]
-        if caches:
-            outs.update(
-                cache_positions=len(caches) * state["pos"],
-                cache_capacity=jnp.full_like(state["pos"], sum(caches)))
-        # the state goes out again so that the donated buffers have an
-        # output to alias: the scan then updates them in place, and a
-        # second copy of the state (which would not fit) is never made
-        return outs, state
-
     def _generate(self, params, batch):
-        """What the engine calls a device batch with: two programs."""
-        last, state = self._program("lm_prefill")(
-            params, batch["ids"], batch["lengths"])
-        out, _ = self._program("lm_generate", self.is_set("logitsCol"))(
-            params, last, state)
+        """What the engine calls a device batch with: two programs,
+        each registered with ``core.scopes`` before it takes its
+        donated arguments."""
+        prefill = self._program("lm_prefill")
+        scopes.register(prefill, params, batch["ids"], batch["lengths"])
+        last, state = prefill(params, batch["ids"], batch["lengths"])
+        generate = self._program("lm_generate", self.is_set("logitsCol"))
+        scopes.register(generate, params, last, state)
+        out, _ = generate(params, last, state)
         return out
 
     # -- the stage -----------------------------------------------------

@@ -54,7 +54,7 @@ import numpy as np
 
 from mmlspark_tpu.core.env import (env_flag, env_int, env_override,
                                    env_raw, env_str)
-from mmlspark_tpu.core import sanitizer
+from mmlspark_tpu.core import sanitizer, scopes
 from mmlspark_tpu.core.faults import fault_point
 from mmlspark_tpu.parallel import resilience
 from mmlspark_tpu.models.gbdt import metrics as metrics_mod
@@ -2844,6 +2844,8 @@ def _train_scan(cfg, k, num_f, total_bins, binned_d, labels_d, weights_d,
 
     it = 0
     _clear_callback_failure()
+    # what the loop dispatches, for whoever asks core.scopes.hlo_texts()
+    scopes.register(step_fn, data, carry, iteration_offset)
     while it < total:
         # per-iteration injection point (host side, outside the jitted
         # step): arming a raise here is the deterministic stand-in for

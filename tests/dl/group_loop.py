@@ -95,7 +95,7 @@ def check(loops, config, order, ended):
     hidden rows equal to the bit. A row with no token in the step has
     no hidden row to compare: the loop over all groups returns what it
     made of the padding, the bounded one may leave zeros, and
-    ``_lm_prefill`` keeps the row's last either way."""
+    ``lm_prefill`` keeps the row's last either way."""
     whole, bounded = loops
     started, real = arrange(order, ended)
     ids = np.random.default_rng(5).integers(

@@ -181,9 +181,29 @@ def test_the_prefill_has_no_loop_with_traced_bounds(params):
 
     stage = _stage(params)
     stage._ensure_scorer()
-    text = str(jax.make_jaxpr(stage._lm_prefill)(
+    text = str(jax.make_jaxpr(stage._program("lm_prefill"))(
         params, jnp.zeros((4, 128), jnp.int32), jnp.full((4,), 9, jnp.int32)))
     assert "scan[" in text and "while[" not in text
+
+
+def test_a_stage_that_ran_takes_a_new_config_with_its_new_weights(
+        builder, params):
+    """The two programs close over the module, so a new ``modelConfig``
+    with ``set_weights`` builds them anew: the same shapes under
+    another ``rope_theta`` and norm give what a fresh stage gives, and
+    another depth runs at all."""
+    frame = DataFrame({"prompt": _prompts()})
+    stage = _stage(params)
+    first = np.asarray(stage.transform(frame).col("logprobs"))
+    for cfg in (dict(CFG, rope_theta=1e2, rms_norm_eps=1e-1),
+                dict(CFG, num_hidden_layers=3)):
+        weights = builder.make_weights(7, cfg)
+        stage.set("modelConfig", cfg).set_weights(weights)
+        again = np.asarray(stage.transform(frame).col("logprobs"))
+        fresh = np.asarray(_stage(weights).set("modelConfig", cfg).transform(
+            frame).col("logprobs"))
+        assert np.array_equal(again, fresh)
+        assert not np.allclose(again, first)
 
 
 def test_text_prompts_and_missing_weights(params):
