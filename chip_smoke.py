@@ -237,8 +237,9 @@ def kernel_parity(binned, formulation: str) -> None:
     exact, grad and hess to float-sum tolerance — the contract
     tests/gbdt/test_hist_pallas.py pins in interpret mode, here on
     whatever compiled the kernel. The Pallas kernel has two paths told
-    apart by the level's width (hist_pallas.level_feed): one width on
-    each side of the bound is checked."""
+    apart by the level's width and the feature count
+    (hist_pallas.level_feed): one width on each side of the bound is
+    checked."""
     import jax
     import jax.numpy as jnp
 
@@ -281,7 +282,8 @@ def kernel_parity(binned, formulation: str) -> None:
               f"kernel: grad/hess off by up to {float(err.max()):.3e} at "
               f"width {width} (rtol 1e-5, atol 1e-4)")
         facts[width] = {
-            "feed": level_feed(width) if formulation == "pallas" else None,
+            "feed": (level_feed(width, f) if formulation == "pallas"
+                     else None),
             "max_abs_err": float(err.max()),
             **{f"{name}_first_s": s[0] for name, s in seconds.items()},
             **{f"{name}_second_s": s[1] for name, s in seconds.items()}}

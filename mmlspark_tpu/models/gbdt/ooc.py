@@ -657,7 +657,7 @@ def train_ooc(spill: SpillReader, labels, cfg: TrainConfig, *,
         # the chunked loop only ever runs the native integer kernel
         # (trainer._ooc_supported screens everything else out)
         "hist_formulation": "native", "tree_mode": "serial",
-        "pallas_interpret": None, "hist_feed": None,
+        "pallas_interpret": None, "hist_feed": None, "hist_product": None,
         # levels are routed on the host, in NumPy (_route_level)
         "route": None,
         # chunks are streamed and keep no slot from the level loop, so

@@ -829,13 +829,13 @@ def _level_histogram(binned, grad, hess, live, local, width, f, b,
     the kernel for this backend and caller.
 
     What the chip has said of the XLA formulations: per_feature took
-    0.384 s a level at 2M x 28 on the v5e against the Pallas kernel's
-    0.142 s (one host-timed run each, PERF.md, PR 22); separate is
-    unmeasured there, and the A/B that would rank the two is still owed
-    (ROADMAP S1, D1). The Pallas kernel itself is two orders of
-    magnitude from its roofline and bound by its own VPU and MXU work,
-    not by bandwidth (hist_pallas.py's cost note), so none of these
-    figures says what the chip allows.
+    0.385 s a level at 2M x 28 x 32 nodes on the v5e against the Pallas
+    kernel's 0.037 s (chip_smoke.py's kernel phase, PERF.md, PR 38;
+    0.384 against 0.142 s in PR 22); separate is unmeasured there, and
+    the A/B that would rank the two is still owed (ROADMAP S1, D1). The
+    Pallas kernel itself is 30 to 80 times from its roofline and bound
+    by its own VPU and MXU work, not by bandwidth (hist_pallas.py's
+    cost note), so none of these figures says what the chip allows.
     """
     import jax
     import jax.numpy as jnp
@@ -2453,13 +2453,26 @@ def train(binned: np.ndarray, labels: np.ndarray, cfg: TrainConfig,
         hist_formulation = resolve_fit_formulation(total_bins, tree_mode,
                                                    mesh)
         from mmlspark_tpu.models.gbdt.hist_pallas import (
-            feed_by_path, resolve_pallas_interpret)
+            HIST_PRODUCT, feed_by_path, resolve_pallas_interpret)
         # the eager loop: DART's dropped-tree set and a custom
         # objective's host code fit no fixed-shape step, and the
         # leaf-wise frontier is grown on the host
         eager_loop = (cfg.boosting_type == "dart"
                       or custom_objective is not None
                       or grow_policy == "leafwise")
+
+        def hist_feed(f_call):
+            # a feature-parallel shard holds its share of the columns;
+            # leaf-wise growth asks for one node at a time
+            if hist_formulation != "pallas":
+                return None
+            if feature_mode:
+                from mmlspark_tpu.parallel.mesh import FEATURE_AXIS
+                f_call //= mesh.shape[FEATURE_AXIS]
+            return feed_by_path(
+                [1] if grow_policy == "leafwise"
+                else [2 ** d for d in range(cfg.effective_depth)], f_call)
+
         hist_stats: Dict[str, object] = {
             "grow_policy": grow_policy, "hist_quant": "off",
             # how the training rows' raw scores take each new tree: from
@@ -2476,13 +2489,14 @@ def train(binned: np.ndarray, labels: np.ndarray, cfg: TrainConfig,
                                  if hist_formulation == "pallas"
                                  else None),
             # the levels of a tree by the path the Pallas kernel takes
-            # at their width (hist_pallas.level_feed): in place over the
-            # rows as they lie, or through the sort by node. Leaf-wise
-            # growth asks for one node at a time
-            "hist_feed": (feed_by_path(
-                [1] if grow_policy == "leafwise"
-                else [2 ** d for d in range(cfg.effective_depth)])
-                if hist_formulation == "pallas" else None),
+            # at their width and the feature count of one call
+            # (hist_pallas.level_feed): in place over the rows as they
+            # lie, or through the sort by node
+            "hist_feed": hist_feed(num_f),
+            # the Pallas kernel's product of stats and one-hot: one bf16
+            # pass over float32 stats in three bf16 parts
+            "hist_product": (HIST_PRODUCT if hist_formulation == "pallas"
+                             else None),
             # a tree's levels by the form their routing takes
             # (route_form): compare-and-select over the level's nodes,
             # or a gather a row. Leaf-wise growth routes on the host
@@ -2536,6 +2550,8 @@ def train(binned: np.ndarray, labels: np.ndarray, cfg: TrainConfig,
                                                    dtype=ing_dtype)
             hist_stats["hist_quant"] = resolve_hist_quant(warn=True)
             if efb_plan is not None:
+                # the kernel runs over the bundled columns
+                hist_stats["hist_feed"] = hist_feed(efb_plan.n_cols)
                 hist_stats["efb_bundles"] = len(efb_plan.bundles)
                 hist_stats["efb_bundled_features"] = (
                     efb_plan.n_bundled_features)

@@ -351,7 +351,8 @@ def test_the_lowered_step_carries_every_scope_and_the_kernels_name(
 
     monkeypatch.setenv("MMLSPARK_TPU_PALLAS_HIST", "1")
     monkeypatch.setenv("MMLSPARK_TPU_PALLAS_FORCE_COMPILE", "1")
-    from mmlspark_tpu.models.gbdt.hist_pallas import pallas_level_histogram
+    from mmlspark_tpu.models.gbdt.hist_pallas import (
+        IN_PLACE_MAX_WIDTH, pallas_level_histogram)
     from mmlspark_tpu.models.gbdt.trainer import TrainConfig, aot_lower_step
 
     cfg = TrainConfig(objective="binary", num_leaves=7, max_depth=3,
@@ -367,7 +368,9 @@ def test_the_lowered_step_carries_every_scope_and_the_kernels_name(
     # hist_kernel_roofline finds the kernel by its result shape,
     # f32[·,·,8,256], on either path: (F, 3·width/8, 8, 256) in place,
     # (width, F, 8, 256) sorted
-    for width, shape in ((4, "f32[28,2,8,256]"), (64, "f32[64,28,8,256]")):
+    wide = 2 * IN_PLACE_MAX_WIDTH
+    for width, shape in ((4, "f32[28,2,8,256]"),
+                         (wide, f"f32[{wide},28,8,256]")):
         jaxpr = str(jax.make_jaxpr(
             lambda *a: pallas_level_histogram(*a, width, 28, 255))(
                 jnp.zeros((n, 28), jnp.uint8), jnp.ones(n), jnp.ones(n),

@@ -68,7 +68,7 @@ def test_matches_xla_histogram(n, f, b, width):
 def test_bitwise_exact_on_integer_stats(width, path):
     """With integer-valued grad/hess every f32 add is exact, so block
     order cannot matter: the kernel must be bit-for-bit, on both paths."""
-    assert hist_pallas.level_feed(width) == path
+    assert hist_pallas.level_feed(width, 4) == path
     binned, grad, hess, live, local = _case(3000, 4, 63, width,
                                             integer_stats=True)
     ref = np.asarray(_level_histogram(binned, grad, hess, live, local,
@@ -77,6 +77,74 @@ def test_bitwise_exact_on_integer_stats(width, path):
                                             local, width, 4, 63,
                                             interpret=True))
     np.testing.assert_array_equal(got, ref)
+
+
+# float32 values over the range a fit produces: logistic grad in (-1, 1)
+# and hess in (0, 0.25], large regression residuals, zero, negatives,
+# tiny magnitudes, and values that use all 24 bits of the significand
+_SPLIT_CASES = {
+    "logistic_grad": lambda rng: rng.uniform(-1, 1, 4096),
+    "logistic_hess": lambda rng: rng.uniform(1e-7, 0.25, 4096),
+    "regression_residuals": lambda rng: rng.normal(scale=3e6, size=4096),
+    "zero_and_signs": lambda rng: np.array([0.0, 1.0, -1.0, 0.5, -0.25]),
+    "tiny": lambda rng: np.array([1e-30, -1e-30, 3.3e-27, 1e-20]),
+    "full_significand": lambda rng: np.array(
+        [1 + 2.0 ** -23, -(2.0 ** 20 + 1), 2.0 ** 24 - 1, 1 - 2.0 ** -24,
+         -(1 + 2.0 ** -8 + 2.0 ** -16 + 2.0 ** -23)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SPLIT_CASES))
+def test_split3_is_exact(case):
+    """The three bf16 parts of a float32 add back to it to the bit, and
+    each part is a value bfloat16 holds: what makes the one-pass product
+    against a 0/1 one-hot exact."""
+    import jax.numpy as jnp
+
+    x = _SPLIT_CASES[case](np.random.default_rng(2)).astype(np.float32)
+    parts = [np.asarray(p) for p in hist_pallas.split3(jnp.asarray(x))]
+    for p in parts:
+        assert p.dtype == np.float32
+        np.testing.assert_array_equal(
+            np.asarray(jnp.asarray(p).astype(jnp.bfloat16)
+                       .astype(jnp.float32)), p)
+    hi, mid, lo = parts
+    np.testing.assert_array_equal((hi + mid + lo).view(np.uint32),
+                                  x.view(np.uint32))
+    # two parts are not enough for a full significand
+    if case == "full_significand":
+        assert np.any(hi + mid != x)
+
+
+@pytest.mark.parametrize("width", [1, 8, _WIDE])
+def test_full_significand_stats_survive(width):
+    """Stats that use all 24 bits of a float32's significand, one row a
+    (node, bin): the histogram is the input, to the bit, on both paths.
+    A two-part split (or a bf16 cast of the stats) fails this."""
+    import jax.numpy as jnp
+
+    f, b = 2, 255
+    n = width * b
+    values = np.array([1 + 2.0 ** -23, -(2.0 ** 20 + 1), 2.0 ** 24 - 1,
+                       -(1 + 2.0 ** -8 + 2.0 ** -16 + 2.0 ** -23),
+                       0.1, 1e-30], np.float32)
+    rng = np.random.default_rng(9)
+    grad = values[rng.integers(0, len(values), n)]
+    hess = np.abs(values[rng.integers(0, len(values), n)])
+    # row i is alone in bin (i % b) of node (i // b), in every feature
+    binned = np.broadcast_to((np.arange(n) % b).astype(np.uint8)[:, None],
+                             (n, f))
+    local = (np.arange(n) // b).astype(np.int32)
+    got = np.asarray(pallas_level_histogram(
+        jnp.asarray(binned), jnp.asarray(grad), jnp.asarray(hess),
+        jnp.ones(n, jnp.float32), jnp.asarray(local), width, f, b,
+        interpret=True))
+    for fi in range(f):
+        np.testing.assert_array_equal(
+            got[:, fi, :, 0].reshape(n).view(np.uint32), grad.view(np.uint32))
+        np.testing.assert_array_equal(
+            got[:, fi, :, 1].reshape(n).view(np.uint32), hess.view(np.uint32))
+    assert np.all(got[..., 2] == 1.0)
 
 
 @pytest.mark.parametrize("width", [8, _WIDE])
@@ -107,14 +175,44 @@ def test_skewed_node_distribution(width):
         assert not np.any(got[w])
 
 
-def test_path_is_chosen_by_width_alone(monkeypatch):
-    """One algorithm, two paths, told apart by a static shape: no
-    environment variable is read on the way, and each path is the one
-    ``level_feed`` names."""
+@pytest.mark.parametrize("f,widest", [
+    # the crossing of the two paths: f x (width - 32) up to 28 x 96
+    (1, 128), (28, 128),    # the cell's matrix: the bound itself
+    (29, 64), (64, 64), (84, 64),
+    (85, 32), (136, 32),    # an MSLR-wide matrix
+    (200, 32), (554, 32),
+    # at 32 nodes and under, what the accumulator asks of VMEM
+    (555, 16), (1109, 16), (1110, 8), (2218, 8), (2219, 4),
+    (6657, 0),              # no level in place at all
+])
+def test_wide_feature_sets_leave_the_in_place_path_sooner(f, widest):
+    """In place pays ``f`` times the level's nodes past 32 where the
+    sorted path pays a feed that does not grow with ``f``, and its
+    accumulator holds the whole level, (f, rows, 256) float32:
+    ``level_feed`` sees the feature count with the width, the widest
+    level in place falls as ``f`` grows, and no level in place asks for
+    more VMEM than a v5e has."""
+    widths = [1 << d for d in range(9)]
+    in_place = [w for w in widths
+                if hist_pallas.level_feed(w, f) == "in_place"]
+    assert in_place == [w for w in widths if w <= widest]
+    for w in in_place:
+        asked = hist_pallas._in_place_vmem(
+            f, hist_pallas._in_place_rows(w)[0])
+        assert asked <= hist_pallas.IN_PLACE_VMEM_BUDGET <= 128 << 20
+    assert hist_pallas.feed_by_path(widths, f) == {
+        "in_place": len(in_place), "sorted": 9 - len(in_place)}
+
+
+def test_path_is_chosen_by_static_shapes_alone(monkeypatch):
+    """One algorithm, two paths, told apart by the call's static shapes
+    (the level's width and the feature count): no environment variable
+    is read on the way, and each path is the one ``level_feed`` names."""
     bound = hist_pallas.IN_PLACE_MAX_WIDTH
-    assert [hist_pallas.level_feed(w) for w in (1, bound, bound + 1)] == [
+    assert [hist_pallas.level_feed(w, 2)
+            for w in (1, bound, bound + 1)] == [
         "in_place", "in_place", "sorted"]
-    assert hist_pallas.feed_by_path([1, 2, 4, bound, 2 * bound]) == {
+    assert hist_pallas.feed_by_path([1, 2, 4, bound, 2 * bound], 2) == {
         "in_place": 4, "sorted": 1}
 
     taken = []
@@ -138,12 +236,30 @@ def test_path_is_chosen_by_width_alone(monkeypatch):
             binned, grad, hess, live, local, width=width, f=2, b=15,
             block_rows=512, interpret=True)
     assert taken == ["_in_place_level_histogram", "_sorted_level_histogram"]
+    # a budget that holds 2 features x 8 nodes and not 16: the same
+    # call goes sorted below the bound, and is as right there
+    monkeypatch.setattr(hist_pallas, "IN_PLACE_VMEM_BUDGET",
+                        hist_pallas._in_place_vmem(2, 24))
+    for width in (8, 16):
+        binned, grad, hess, live, local = _case(600, 2, 15, width)
+        got = hist_pallas._pallas_level_histogram(
+            binned, grad, hess, live, local, width=width, f=2, b=15,
+            block_rows=512, interpret=True)
+        np.testing.assert_allclose(
+            np.asarray(got),
+            np.asarray(_level_histogram(binned, grad, hess, live, local,
+                                        width, 2, 15,
+                                        formulation="per_feature")),
+            rtol=1e-5, atol=1e-4)
+    assert taken[2:] == ["_in_place_level_histogram",
+                         "_sorted_level_histogram"]
     assert not reads
 
 
 def test_fit_records_hist_feed(monkeypatch):
     """``hist_stats["hist_feed"]``: the levels of a tree by path, beside
-    ``raw_update``; nothing for a fit that runs another formulation."""
+    ``raw_update``, and ``["hist_product"]``, the kernel's product;
+    nothing for a fit that runs another formulation."""
     from mmlspark_tpu.models.gbdt.trainer import TrainConfig, train
     from mmlspark_tpu.ops.binning import BinMapper
 
@@ -155,12 +271,56 @@ def test_fit_records_hist_feed(monkeypatch):
     cfg = TrainConfig(objective="binary", num_iterations=2, num_leaves=8,
                       max_depth=3, min_data_in_leaf=5, max_bin=16)
     bu = mapper.bin_upper_values(16)
-    assert train(binned, y, cfg, bin_upper=bu).hist_stats["hist_feed"] is None
+    stats = train(binned, y, cfg, bin_upper=bu).hist_stats
+    assert stats["hist_feed"] is None and stats["hist_product"] is None
     monkeypatch.setenv("MMLSPARK_TPU_PALLAS_HIST", "1")
     stats = train(binned, y, cfg, bin_upper=bu).hist_stats
     assert stats["hist_formulation"] == "pallas"
     assert stats["hist_feed"] == {"in_place": 3, "sorted": 0}
+    assert stats["hist_product"] == "bf16x3"
     assert stats["raw_update"] == "builder_leaf"
+
+
+def test_fit_reckons_hist_feed_with_its_feature_count(monkeypatch):
+    """``hist_feed`` is what ``level_feed`` says of the fit's own
+    matrix: with the crossing moved down to where 5 features meet it
+    between 2 and 4 nodes, a depth-3 fit records its widest level as
+    sorted, runs it there, and is the model the in-place fit is."""
+    from mmlspark_tpu.models.gbdt.trainer import TrainConfig, train
+    from mmlspark_tpu.ops.binning import BinMapper
+
+    rng = np.random.default_rng(5)
+    x = rng.normal(size=(400, 5))
+    y = (x[:, 0] - 0.5 * x[:, 1] > 0).astype(np.float64)
+    mapper = BinMapper.fit(x, max_bin=16)
+    binned = mapper.transform(x)
+    cfg = TrainConfig(objective="binary", num_iterations=2, num_leaves=8,
+                      max_depth=3, min_data_in_leaf=5, max_bin=16)
+    bu = mapper.bin_upper_values(16)
+    monkeypatch.setenv("MMLSPARK_TPU_PALLAS_HIST", "1")
+    monkeypatch.setattr(hist_pallas, "IN_PLACE_FREE_WIDTH", 1)
+    monkeypatch.setattr(hist_pallas, "IN_PLACE_MAX_EXTRA", 5)
+    taken = []
+    orig = hist_pallas._sorted_level_histogram
+    monkeypatch.setattr(
+        hist_pallas, "_sorted_level_histogram",
+        lambda *a, **k: taken.append(k["width"]) or orig(*a, **k))
+    moved = train(binned, y, cfg, bin_upper=bu)
+    assert moved.hist_stats["hist_feed"] == {"in_place": 2, "sorted": 1}
+    assert set(taken) == {4}
+    monkeypatch.undo()
+    monkeypatch.setenv("MMLSPARK_TPU_PALLAS_HIST", "1")
+    hist_pallas._JIT_CACHE.clear()
+    from mmlspark_tpu.models.gbdt import trainer
+    trainer._BUILDER_CACHE.clear()
+    trainer._CHUNK_CACHE.clear()
+    base = train(binned, y, cfg, bin_upper=bu)
+    assert base.hist_stats["hist_feed"] == {"in_place": 3, "sorted": 0}
+    np.testing.assert_array_equal(base.booster.split_feature,
+                                  moved.booster.split_feature)
+    np.testing.assert_allclose(base.booster.node_value,
+                               moved.booster.node_value,
+                               rtol=1e-6, atol=1e-7)
 
 
 def test_trainer_env_flag_routes_to_pallas(monkeypatch):
@@ -253,12 +413,16 @@ def test_pallas_under_shard_map_modes(monkeypatch, tree_learner, mesh_cfg):
     np.testing.assert_allclose(p0, p1, rtol=1e-4, atol=1e-4)
 
 
-def test_dp_serial_with_flag_bypasses_pallas(monkeypatch, rng):
-    """The serial builder under a mesh runs via GSPMD, which cannot
-    partition Mosaic kernels — with MMLSPARK_TPU_PALLAS_HIST=1 it must
-    silently take the XLA formulation (identical trees to flag-off),
-    not crash at TPU compile (pinned at lowering level in
-    test_mosaic_lowering.py; this is the execution-level twin)."""
+def test_dp_serial_with_flag_runs_pallas_per_shard(monkeypatch, rng):
+    """The serial learner under a dp mesh fits through the data-sharded
+    shard_map builder (GSPMD cannot partition a Mosaic kernel; the
+    serial builder's own bypass is pinned at lowering level in
+    test_mosaic_lowering.py), and there MMLSPARK_TPU_PALLAS_HIST
+    chooses the formulation a shard; ``hist_stats`` says which one ran.
+    Flag off twice is one formulation: the same model to the bit. Flag
+    on runs the Pallas kernel against the XLA path: the same trees,
+    leaf values to float-sum tolerance (the kernel adds a bin's three
+    bf16 parts in its own order)."""
     from mmlspark_tpu.models.gbdt.trainer import TrainConfig, train
     from mmlspark_tpu.ops.binning import BinMapper
     from mmlspark_tpu.parallel.mesh import MeshConfig, create_mesh
@@ -271,15 +435,28 @@ def test_dp_serial_with_flag_bypasses_pallas(monkeypatch, rng):
     bu = mapper.bin_upper_values(32)
     cfg = TrainConfig(objective="binary", num_iterations=3, num_leaves=7,
                       max_depth=3, min_data_in_leaf=5, max_bin=32)
-    base = train(binned, y, cfg, bin_upper=bu, mesh=mesh)
-    monkeypatch.setenv("MMLSPARK_TPU_PALLAS_HIST", "1")
-    flagged = train(binned, y, cfg, bin_upper=bu, mesh=mesh)
-    np.testing.assert_array_equal(base.booster.split_feature,
-                                  flagged.booster.split_feature)
-    np.testing.assert_array_equal(base.booster.threshold_bin,
-                                  flagged.booster.threshold_bin)
+
+    def fit(flag):
+        monkeypatch.setenv("MMLSPARK_TPU_PALLAS_HIST", flag)
+        res = train(binned, y, cfg, bin_upper=bu, mesh=mesh)
+        assert res.hist_stats["tree_mode"] == "data_sharded"
+        return res, res.hist_stats["hist_formulation"]
+
+    (base, base_form), (again, again_form) = fit("0"), fit("0")
+    flagged, flagged_form = fit("1")
+    assert base_form == again_form != "pallas"
+    assert flagged_form == "pallas"
+    assert flagged.hist_stats["hist_product"] == hist_pallas.HIST_PRODUCT
+    for other in (again, flagged):
+        np.testing.assert_array_equal(base.booster.split_feature,
+                                      other.booster.split_feature)
+        np.testing.assert_array_equal(base.booster.threshold_bin,
+                                      other.booster.threshold_bin)
     np.testing.assert_array_equal(base.booster.node_value,
-                                  flagged.booster.node_value)
+                                  again.booster.node_value)
+    np.testing.assert_allclose(base.booster.node_value,
+                               flagged.booster.node_value,
+                               rtol=1e-6, atol=1e-7)
 
 
 def test_histogram_subtraction_matches_full(monkeypatch):

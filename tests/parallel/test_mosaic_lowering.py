@@ -25,34 +25,149 @@ def _lower_tpu(fn, *args) -> str:
         lowering_platforms=("tpu",)).as_text()
 
 
-@pytest.mark.parametrize("path,width", [("in_place", 8), ("in_place", 32),
-                                        ("sorted", 64)])
-def test_hist_kernel_lowers_to_mosaic(path, width):
-    """Both paths of the level histogram go through Mosaic; the in-place
-    one asks XLA for no sort and no gather (it has no feed beyond the
-    stats' element-wise writes)."""
+def _kernel_calls(fn, *args):
+    """The ``pallas_call`` equations of ``fn``."""
+    import jax
+
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            else:
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from calls(sub)
+
+    return list(calls(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+def _kernel_dots(fn, *args):
+    """The ``dot_general`` equations inside ``fn``'s ``pallas_call``."""
+    import jax
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+
+    return [eqn for call in _kernel_calls(fn, *args)
+            for eqn in walk(call.params["jaxpr"])]
+
+
+def _hist_cases():
+    """(path, width, features). At the cell's 28 features: widths 1, 8
+    and 32 in place and one width on each side of the bound between the
+    two paths, wherever a measurement puts it. At 64, 136 and 200
+    features (an MSLR-wide matrix and past it) the paths cross at
+    narrower levels, and at 600 the level's accumulator, which grows
+    with the feature count, no longer fits the kernel's share of VMEM
+    at 32 nodes."""
+    from mmlspark_tpu.models.gbdt.hist_pallas import IN_PLACE_MAX_WIDTH
+
+    in_place = sorted({1, 8, 32, IN_PLACE_MAX_WIDTH})
+    return ([("in_place", w, 28) for w in in_place]
+            + [("sorted", 2 * IN_PLACE_MAX_WIDTH, 28),
+               ("in_place", 64, 64), ("sorted", 128, 64),
+               ("in_place", 32, 136), ("sorted", 64, 136),
+               ("in_place", 32, 200), ("sorted", 128, 200),
+               ("in_place", 16, 600), ("sorted", 32, 600)])
+
+
+def _hist_level(width, f, n=4100, b=255):
+    """One level at bench-like dims (255 bins, N no multiple of the
+    block): the traced function and its arguments' shapes."""
+    import jax
     import jax.numpy as jnp
 
     from mmlspark_tpu.models.gbdt import hist_pallas
 
-    assert hist_pallas.level_feed(width) == path
-    # bench-like dims: 255 bins, 28 features; N no multiple of the block
-    n, f, b = 4100, 28, 255
-    rng = np.random.default_rng(0)
-    args = (jnp.asarray(rng.integers(0, b, size=(n, f)).astype(np.uint8)),
-            jnp.asarray(rng.normal(size=n).astype(np.float32)),
-            jnp.asarray(rng.uniform(0.1, 1, size=n).astype(np.float32)),
-            jnp.ones(n, jnp.float32),
-            jnp.asarray(rng.integers(0, width, size=n).astype(np.int32)))
-    txt = _lower_tpu(
-        functools.partial(hist_pallas._pallas_level_histogram, width=width,
-                          f=f, b=b, block_rows=512, interpret=False), *args)
+    fn = functools.partial(hist_pallas._pallas_level_histogram, width=width,
+                           f=f, b=b, block_rows=512, interpret=False)
+    vec = jax.ShapeDtypeStruct((n,), jnp.float32)
+    return fn, (jax.ShapeDtypeStruct((n, f), jnp.uint8), vec, vec, vec,
+                jax.ShapeDtypeStruct((n,), jnp.int32))
+
+
+@pytest.mark.parametrize("path,width,f", _hist_cases())
+def test_hist_kernel_lowers_to_mosaic(path, width, f):
+    """Both paths of the level histogram go through Mosaic with their
+    bf16 operands (the stats' three parts against the one-hot, one
+    default-precision product); the in-place one asks XLA for no sort
+    and no gather (it has no feed beyond the stats' element-wise
+    writes), and asks Mosaic for no more VMEM than its budget, its
+    accumulator counted twice."""
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.models.gbdt import hist_pallas
+
+    assert hist_pallas.level_feed(width, f) == path
+    fn, args = _hist_level(width, f)
+    txt = _lower_tpu(fn, *args)
     assert "tpu_custom_call" in txt  # the serialized Mosaic module
+    # the kernel's products: one a feature, bf16 x bf16 -> f32, no
+    # precision asked for (a float32 product at HIGHEST is six passes)
+    dots = _kernel_dots(fn, *args)
+    assert len(dots) == f
+    for eqn in dots:
+        assert [v.aval.dtype for v in eqn.invars] == [jnp.bfloat16] * 2
+        assert eqn.outvars[0].aval.dtype == jnp.float32
+        assert eqn.params["precision"] is None
     has_feed = "stablehlo.sort" in txt and "stablehlo.gather" in txt
     assert has_feed == (path == "sorted")
     if path == "in_place":
         assert "sort" not in txt and "gather" not in txt
         assert "scatter" not in txt
+        (call,) = _kernel_calls(fn, *args)
+        asked = call.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+        acc = call.outvars[0].aval
+        assert acc.shape[0] == f and acc.shape[2:] == (8, 256)
+        assert (2 * acc.size * acc.dtype.itemsize < asked
+                <= hist_pallas.IN_PLACE_VMEM_BUDGET)
+
+
+@pytest.fixture(scope="module")
+def one_v5e():
+    """A described (not attached) v5e chip to compile for; built inside
+    the fixture so that only the worker that runs this file loads the
+    TPU's library."""
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # and cannot be read back without one
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("width,f", [(128, 28), (64, 84)])
+def test_widest_in_place_levels_compile_for_v5e(one_v5e, width, f):
+    """The TPU's own compiler takes the in-place kernel at the widest
+    levels the rule admits past 32 nodes: 128 at 28 features and 64 at
+    84 (45 and 54 MiB of VMEM asked). A compile that passes is not a
+    chip run; the chip's are beside ``IN_PLACE_MAX_WIDTH``."""
+    import jax
+
+    from mmlspark_tpu.models.gbdt import hist_pallas
+
+    assert hist_pallas.level_feed(width, f) == "in_place"
+    assert hist_pallas.level_feed(2 * width, f) == "sorted"
+    fn, args = _hist_level(width, f, n=100_000)
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_v5e)
+            for a in args]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_flash_kernel_lowers_to_mosaic():

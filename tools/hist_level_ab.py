@@ -1,13 +1,18 @@
 """One level histogram on the chip, both Pallas paths by width: the
 measurement that sets ``hist_pallas.IN_PLACE_MAX_WIDTH`` (PERF.md §6,
-PR 30).
+PR 30; again at PR 38, when the kernel's product became one bf16 pass).
 
-usage: python3 tools/hist_level_ab.py [--rows N] [--widths 1,8,32,...]
-           [--paths in_place,sorted] [--block-rows 512] [--reps 3]
-           [--no-check] [--truth] [--out chiprun_out/hist_level_ab.jsonl]
+usage: python3 tools/hist_level_ab.py [--rows N] [--features F]
+           [--widths 1,8,32,...] [--paths in_place,sorted,chosen]
+           [--block-rows 512] [--reps 3] [--no-check] [--truth]
+           [--out chiprun_out/hist_level_ab.jsonl]
 
 Arrays are made once from a seed and passed as arguments to each jitted
-path (nothing is a compile-time constant). One JSON line a (path, width,
+path (nothing is a compile-time constant). ``in_place`` and ``sorted``
+run that path whatever ``level_feed`` would choose (a width or a feature
+count past its bounds included: that is how the bounds are read);
+``chosen`` runs the kernel's entry and says which path it took (``feed``).
+One JSON line a (path, width,
 block_rows): the call's host-clock seconds (block_until_ready), the
 device seconds of the kernel and of everything else in the program from
 one traced call, and the parity against ``per_feature`` (counts exact,
@@ -123,11 +128,17 @@ def main(argv=None) -> int:
                     truth = float64_level(*host, local_h, width, b)
             for path in args.paths.split(","):
                 for r in [int(x) for x in args.block_rows.split(",")]:
+                    feed = (hist_pallas.level_feed(width, f)
+                            if path == "chosen" else path)
                     fn = jax.jit(functools.partial(
-                        getattr(hist_pallas, f"_{path}_level_histogram"),
+                        hist_pallas._pallas_level_histogram
+                        if path == "chosen"
+                        else getattr(hist_pallas,
+                                     f"_{path}_level_histogram"),
                         width=width, f=f, b=b, block_rows=r,
                         interpret=not on_tpu))
-                    row = {"path": path, "width": width, "block_rows": r,
+                    row = {"path": path, "feed": feed, "width": width,
+                           "block_rows": r,
                            "rows": n, "features": f, "bins": b,
                            "platform": dev.platform,
                            "device_kind": dev.device_kind}
