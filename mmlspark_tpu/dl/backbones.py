@@ -390,8 +390,9 @@ class RetentionLM(nn.Module):
 
 # ---------------------------------------------------------------------
 # hybrid decoder: delta-rule and latent-attention layers over a dense
-# SwiGLU or sparse experts (config keys of ``model_type`` gigachat3_5
-# and kimi_k2)
+# SwiGLU or sparse experts (config keys of ``model_type`` gigachat3_5,
+# kimi_k2 and xing4_0), the residual one vector a token or, with
+# ``hc_mult``, that many streams (``parallel/hyper.py``)
 
 
 def gated_norm(x, weight, eps):
@@ -660,14 +661,54 @@ class ExpertFeedForward(nn.Module):
         return y.reshape(x.shape), (pairs, dropped)
 
 
+class HyperConnection(nn.Module):
+    """One sub-layer's leaves of the hyper-connection residual path
+    (``parallel/hyper.py``) and its first pass over the streams ``x``
+    (``hc_mult`` arrays ``(B, T, hidden)``): ``(what the sub-layer
+    reads (B, T, hidden), (H_post, H_res))``, the pair being what
+    ``hyper.hc_write`` takes with the sub-layer's output. The stream's
+    own RMSNorm has no learned scale and the model's ``rms_norm_eps``;
+    the leaves are declared float32 and multiplied in float32
+    whatever dtype the engine placed them in."""
+
+    config: Any
+
+    @nn.compact
+    def __call__(self, x):
+        from mmlspark_tpu.parallel import hyper
+
+        c = self.config
+        n, width = len(x), x[0].shape[-1]
+        zeros = nn.initializers.zeros
+        h_pre, h_post, h_res = hyper.hc_coefficients(
+            x, self.param("phi", nn.initializers.normal(0.02),
+                          (n * width, 2 * n + n * n), jnp.float32),
+            self.param("alpha", nn.initializers.constant(0.01), (3,),
+                       jnp.float32),
+            self.param("b_pre", zeros, (n,), jnp.float32),
+            self.param("b_post", zeros, (n,), jnp.float32),
+            self.param("b_res", zeros, (n, n), jnp.float32),
+            norm_eps=c["rms_norm_eps"], iters=c["hc_sinkhorn_iters"],
+            eps=c["hc_eps"], clamp=(c["mhc_h_res_clamp_min"],
+                                    c["mhc_h_res_clamp_max"]))
+        return hyper.hc_read(x, h_pre), (h_post, h_res)
+
+
 class HybridBlock(nn.Module):
-    """One layer: ``h += mixer(pre(h))``, then the same around the
+    """One layer: ``h = h + mixer(pre(h))``, then the same around the
     feed-forward; where the config says ``layernorm_type`` ``pre_post``
     a sub-layer's output is normed too before it is added. The layer's
     index chooses both sub-layers: latent attention where
     ``full_attention_layers`` lists it (or in every layer where the
     config has no such key), else the delta rule; a dense SwiGLU under
-    ``first_k_dense_replace``, else the experts."""
+    ``first_k_dense_replace``, else the experts.
+
+    Where the config has ``hc_mult`` the residual is that many streams
+    (``h``: a tuple of ``(B, T, hidden)`` arrays) and the sum is a
+    :class:`HyperConnection`'s: the sub-layer reads a mix of the
+    streams and its output is written back into each beside a mix of
+    them all (``parallel/hyper.py``), under the scope ``lm.hc``, which
+    stands outside the sub-layer's own."""
 
     config: Any
     index: int
@@ -681,24 +722,40 @@ class HybridBlock(nn.Module):
 
     @nn.compact
     def __call__(self, h, positions, lengths, state):
+        from mmlspark_tpu.parallel import hyper
+
         c = self.config
         post = _setting(c, "layernorm_type") == "pre_post"
+        streams = "hc_mult" in c
 
-        def added(y, name):
-            return h + (block_norm(self, name, y, c) if post else y)
+        def around(h, scope, name, sub, *args):
+            """``(h after the sub-layer, what it returned beside its
+            output)``."""
+            x, mix = h, None
+            if streams:
+                with jax.named_scope("lm.hc"):
+                    x, mix = HyperConnection(c, name=f"{name}_hc")(h)
+            with jax.named_scope(scope):
+                y, out = sub(c, name=name)(
+                    block_norm(self, f"{name}_pre", x, c), *args)
+                if post:
+                    y = block_norm(self, f"{name}_post", y, c)
+                if not streams:
+                    return h + y, out
+            with jax.named_scope("lm.hc"):
+                return hyper.hc_write(h, y, *mix), out
 
-        mixer = LatentMixer if self.latent() else DeltaMixer
-        with jax.named_scope("lm.mla" if self.latent() else "lm.gdn"):
-            y, state = mixer(c, name="mixer")(
-                block_norm(self, "mixer_pre", h, c), positions, lengths,
-                state)
-            h = added(y, "mixer_post")
-        ffn = ExpertFeedForward if self.sparse() else DenseFeedForward
-        with jax.named_scope("lm.moe" if self.sparse() else "lm.mlp"):
-            valid = jnp.arange(h.shape[1])[None, :] < lengths[:, None]
-            y, served = ffn(c, name="ffn")(
-                block_norm(self, "ffn_pre", h, c), valid)
-            h = added(y, "ffn_post")
+        h, state = around(
+            h, "lm.mla" if self.latent() else "lm.gdn", "mixer",
+            LatentMixer if self.latent() else DeltaMixer, positions,
+            lengths, state)
+        scope = "lm.moe" if self.sparse() else "lm.mlp"
+        with jax.named_scope(scope):
+            valid = (jnp.arange(positions.shape[1])[None, :]
+                     < lengths[:, None])
+        h, served = around(
+            h, scope, "ffn",
+            ExpertFeedForward if self.sparse() else DenseFeedForward, valid)
         return h, state, served
 
 
@@ -709,7 +766,14 @@ class HybridLM(nn.Module):
     delta-rule layer's fixed recurrent state and a latent layer's
     cache, whose capacity ``lm_init_state`` is given; and ``experts``,
     what the expert layers served since the state was empty: ``pairs``
-    ``(expert layers, experts held)`` and ``dropped``."""
+    ``(expert layers, experts held)`` and ``dropped``.
+
+    With ``hc_mult`` in the config (``model_type`` ``xing4_0``) the
+    residual between the layers is that many streams a token: the
+    embedding is copied into each before the first layer and they are
+    summed after the last (of each row's last real token alone, unless
+    ``every``), so what ``hidden`` returns, and ``hidden_in_groups``
+    with it, has the one shape."""
 
     config: Any
 
@@ -817,6 +881,9 @@ class HybridLM(nn.Module):
     def hidden(self, ids, lengths, state, every=False):
         with jax.named_scope("lm.embed"):
             h = jnp.take(self.embedding, ids, axis=0).astype(jnp.float32)
+        streams = "hc_mult" in self.config
+        if streams:
+            h = (h,) * self.config["hc_mult"]
         positions = state["pos"][:, None] + jnp.arange(ids.shape[1])
         layers, pairs = [], []
         dropped = state["experts"]["dropped"]
@@ -830,8 +897,15 @@ class HybridLM(nn.Module):
         if not every:
             with jax.named_scope("lm.last"):
                 last = jnp.clip(lengths - 1, 0, ids.shape[1] - 1)
-                h = jnp.take_along_axis(h, last[:, None, None],
-                                        axis=1)[:, 0]
+
+                def at_last(x):
+                    return jnp.take_along_axis(x, last[:, None, None],
+                                               axis=1)[:, 0]
+
+                h = jax.tree_util.tree_map(at_last, h)
+        if streams:
+            with jax.named_scope("lm.hc"):
+                h = sum(h)
         served = state["experts"]["pairs"]
         if pairs:
             served = served + jnp.stack(pairs)
@@ -849,4 +923,4 @@ class HybridLM(nn.Module):
 
 
 LM_MODELS = {"brumby": RetentionLM, "gigachat3_5": HybridLM,
-             "kimi_k2": HybridLM}
+             "kimi_k2": HybridLM, "xing4_0": HybridLM}

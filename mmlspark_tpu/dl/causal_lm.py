@@ -12,8 +12,10 @@ delta-rule layers keep a recurrent state and whose latent-attention
 layers a cache of one compressed entry a position, over sparse experts
 of which this chip holds a share; ``kimi_k2`` is the same class with
 latent attention in every layer, so its whole per-sequence state is
-cache. A device batch is sized by the bytes of both: the state, and the
-cache at the longest prompt plus ``maxNewTokens``.
+cache, and ``xing4_0`` that layer again inside a residual path of
+``hc_mult`` streams a token (``parallel/hyper.py``). A device batch
+is sized by the bytes of both: the state, and the cache at the longest
+prompt plus ``maxNewTokens``.
 
 One ``transform()``: the ragged prompts are sorted by length and cut
 into device batches (``ShardedScorer.length_batches``: the row ladder
@@ -103,7 +105,10 @@ def lm_generate_program(module, new_tokens: int, with_logits: bool):
     ``expert_pairs`` (one row: a count an expert layer and held expert)
     and ``dropped_pairs``; with caches, each row's ``cache_positions``
     (the positions it has filled, summed over the layers that cache)
-    and ``cache_capacity`` (the same at capacity)."""
+    and ``cache_capacity`` (the same at capacity); with a residual of
+    several streams (``hc_mult``), each row's ``hc_sublayer_tokens``:
+    the tokens it has absorbed, prompt and generated, times the
+    sub-layers they went through that path around."""
     import jax
     import jax.numpy as jnp
 
@@ -141,6 +146,9 @@ def lm_generate_program(module, new_tokens: int, with_logits: bool):
             outs.update(
                 cache_positions=len(caches) * state["pos"],
                 cache_capacity=jnp.full_like(state["pos"], sum(caches)))
+        if "hc_mult" in module.config:
+            outs.update(
+                hc_sublayer_tokens=2 * len(state["layers"]) * state["pos"])
         # the state goes out again so that the donated buffers have an
         # output to alias: the scan then updates them in place, and a
         # second copy of the state (which would not fit) is never made
@@ -167,7 +175,11 @@ class CausalLM(Transformer, HasInputCol, HasOutputCol):
                         "num_attention_heads, rope_theta, rope_scaling, "
                         "rms_norm_eps, vocab_size, torch_dtype; with "
                         "experts_held and router_experts where this chip "
-                        "holds a share of the experts)", is_complex=True)
+                        "holds a share of the experts), or xing4_0 "
+                        "(kimi_k2's keys and the residual path's: hc_mult "
+                        "streams a token, hc_sinkhorn_iters, hc_eps, "
+                        "mhc_h_res_clamp_min, mhc_h_res_clamp_max)",
+                        is_complex=True)
     maxNewTokens = Param("maxNewTokens", "tokens generated a row (greedy, "
                          "no early stop)", to_int, gt(0), default=32)
     batchSize = Param("batchSize", "rows a device batch; unset, the "
@@ -381,10 +393,14 @@ class CausalLM(Transformer, HasInputCol, HasOutputCol):
                     expert_pairs_max=int(pairs.max()),
                     dropped_pairs=int(sum(s["dropped_pairs"]
                                           for s in served)))
-            for key in ("cache_positions", "cache_capacity"):
+            for key in ("cache_positions", "cache_capacity",
+                        "hc_sublayer_tokens"):
                 if outputs and key in outputs[0][1]:
                     root.counts[key] = int(sum(
                         scored[key].sum() for _, scored in outputs))
+            if "hc_mult" in self._module.config:
+                root.counts["hc_streams"] = int(
+                    self._module.config["hc_mult"])
         return out
 
     # -- persistence (as DeepModel: leaves in order) -------------------
