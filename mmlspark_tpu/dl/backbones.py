@@ -11,12 +11,15 @@ every layer mixes the sequence by power retention) and
 :class:`HybridLM` (layers that mix by a gated delta rule or by latent
 attention over a cache, over a dense SwiGLU or sparse experts, chosen
 by the layer's index; the norm, its placement, the attention's gate
-and the SwiGLU's limit read from the config). ``LM_MODELS`` maps a
-config's ``model_type`` to its class.
+and the SwiGLU's limit read from the config; for ``Motif`` grouped
+differential attention on the latent cache, window layers on a ring,
+PolyNorm MLPs). ``LM_MODELS`` maps a config's ``model_type`` to its
+class.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Mapping, Optional, Sequence
 
 import flax.linen as nn
@@ -254,19 +257,22 @@ class DecoderBlock(nn.Module):
 
 def lm_module(config: Mapping[str, Any]):
     """The language model a config names (``model_type``; a config
-    without the key is a :class:`RetentionLM`'s)."""
+    without the key is a :class:`RetentionLM`'s), given the config with
+    the keys its code reads (``family_config``)."""
     kind = config.get("model_type", "brumby")
     if kind not in LM_MODELS:
         raise ValueError(f"model_type {kind!r} is not one of "
                          f"{sorted(LM_MODELS)}")
-    return LM_MODELS[kind](dict(config))
+    return LM_MODELS[kind](family_config(config))
 
 
 def lm_init_state(config: Mapping[str, Any], batch: int, capacity: int = 0):
     """An empty state for ``batch`` sequences: no token absorbed, every
     recurrent state zero (float32, whatever the model's dtype), every
-    cache empty with room for ``capacity`` positions."""
-    return type(lm_module(config)).init_state(config, batch, capacity)
+    cache empty with room for ``capacity`` positions (a window layer's
+    ring: its window's, whatever ``capacity``)."""
+    module = lm_module(config)
+    return type(module).init_state(module.config, batch, capacity)
 
 
 def lm_state_bytes(config: Mapping[str, Any], batch: int,
@@ -410,9 +416,41 @@ def _centred(module, name, width):
 
 # what a family's code does where its config.json may be silent; a
 # config without these keys and of another family has plain RMSNorm
-# before each sub-layer and none after
+# before each sub-layer and none after, a selection bias on the router
+# and, with a residual of several streams, xing4_0's epsilon and clamp
 FAMILY_DEFAULTS = {"gigachat3_5": {"norm_type": "ZeroCenteredGatedNorm",
-                                   "layernorm_type": "pre_post"}}
+                                   "layernorm_type": "pre_post"},
+                   "Motif": {"hc_eps": 1e-6, "mhc_h_res_clamp_min": -30,
+                             "mhc_h_res_clamp_max": 30,
+                             "selection_bias": False}}
+
+
+def _motif_keys(c):
+    return {"n_routed_experts": c["num_experts"],
+            "num_experts_per_tok": c["experts_top_k"],
+            "routed_scaling_factor": c["route_scale"],
+            "first_k_dense_replace": c["n_dense_first_layers"],
+            "n_shared_experts": c["num_shared_experts"],
+            "qk_nope_head_dim": c["head_dim"] - c["qk_rope_head_dim"],
+            "gated_attention": c["elementwise_attn_output_gate"],
+            "hc_mult": c["mhc_expansion_rate"],
+            "hc_sinkhorn_iters": c["mhc_sinkhorn_iters"]}
+
+
+# the keys this module's code reads, from a family's config.json where
+# it names them otherwise (Motif: no qk_nope_head_dim, head_dim the
+# query's and key's whole width)
+FAMILY_KEYS = {"Motif": _motif_keys}
+
+
+def family_config(config: Mapping[str, Any]) -> dict:
+    """``config`` with the keys ``FAMILY_KEYS`` derives for its family
+    where it lacks them; another family's config as it is."""
+    c = dict(config)
+    derive = FAMILY_KEYS.get(c.get("model_type"))
+    for key, value in (derive(c) if derive else {}).items():
+        c.setdefault(key, value)
+    return c
 
 
 def _setting(config, key):
@@ -538,6 +576,39 @@ class DeltaMixer(nn.Module):
             o.reshape(b, t, vh * d_v)), {"s": s, "conv": tail})
 
 
+def latent_inputs(module, x, positions, key_heads):
+    """What a latent mixer makes of its input, its leaves declared on
+    ``module``: ``(q_n, q_r, c_kv, k_r, w_uk, w_uv, softmax scale)``,
+    the ``num_attention_heads`` queries (their rotated part turned),
+    the normed latent and the rotated key a position caches, and the up
+    projection to ``key_heads`` keys and values."""
+    from mmlspark_tpu.parallel import latent
+
+    c = module.config
+    heads, rank = c["num_attention_heads"], c["kv_lora_rank"]
+    nope, rope = c["qk_nope_head_dim"], c["qk_rope_head_dim"]
+    d_v, dtype = c["v_head_dim"], lm_dtype(c)
+    b, t, _ = x.shape
+    scaling = c.get("rope_scaling") or {}
+    freq = latent.yarn_frequencies(rope, c["rope_theta"], scaling)
+    scale = latent.softmax_scale(
+        nope + rope, scaling, c.get("use_mla_scaling_factor",
+                                    bool(scaling.get("mscale_all_dim"))))
+
+    c_q = block_norm(module, "q_a_norm", Linear(
+        c["q_lora_rank"], dtype, name="q_a_proj")(x), c)
+    q = Linear(heads * (nope + rope), dtype, name="q_b_proj")(c_q)
+    q = q.reshape(b, t, heads, nope + rope)
+    q_n = q[..., :nope]
+    q_r = latent.rotary_interleaved(q[..., nope:], positions, freq)
+    down = Linear(rank + rope, dtype, name="kv_a_proj")(x)
+    c_kv = block_norm(module, "kv_a_norm", down[..., :rank], c)
+    k_r = latent.rotary_interleaved(down[..., rank:], positions, freq)
+    up = module.param("kv_b_proj", nn.initializers.normal(0.02),
+                      (rank, key_heads, nope + d_v), dtype)
+    return q_n, q_r, c_kv, k_r, up[..., :nope], up[..., nope:], scale
+
+
 class LatentMixer(nn.Module):
     """Latent attention (``parallel/latent.py``): low-rank queries,
     keys and values compressed to one latent and one rotated key a
@@ -563,28 +634,11 @@ class LatentMixer(nn.Module):
         from mmlspark_tpu.parallel import latent
 
         c = self.config
-        heads, rank = c["num_attention_heads"], c["kv_lora_rank"]
-        nope, rope = c["qk_nope_head_dim"], c["qk_rope_head_dim"]
-        d_v, dtype = c["v_head_dim"], lm_dtype(c)
+        heads, d_v, dtype = c["num_attention_heads"], c["v_head_dim"], \
+            lm_dtype(c)
         b, t, _ = x.shape
-        scaling = c.get("rope_scaling") or {}
-        freq = latent.yarn_frequencies(rope, c["rope_theta"], scaling)
-        scale = latent.softmax_scale(
-            nope + rope, scaling, c.get("use_mla_scaling_factor",
-                                        bool(scaling.get("mscale_all_dim"))))
-
-        c_q = block_norm(self, "q_a_norm", Linear(
-            c["q_lora_rank"], dtype, name="q_a_proj")(x), c)
-        q = Linear(heads * (nope + rope), dtype, name="q_b_proj")(c_q)
-        q = q.reshape(b, t, heads, nope + rope)
-        q_n = q[..., :nope]
-        q_r = latent.rotary_interleaved(q[..., nope:], positions, freq)
-        down = Linear(rank + rope, dtype, name="kv_a_proj")(x)
-        c_kv = block_norm(self, "kv_a_norm", down[..., :rank], c)
-        k_r = latent.rotary_interleaved(down[..., rank:], positions, freq)
-        up = self.param("kv_b_proj", nn.initializers.normal(0.02),
-                        (rank, heads, nope + d_v), dtype)
-        w_uk, w_uv = up[..., :nope], up[..., nope:]
+        q_n, q_r, c_kv, k_r, w_uk, w_uv, scale = latent_inputs(
+            self, x, positions, heads)
         pos = positions[:, 0]
         with jax.named_scope("lm.mla.write"):
             cache = latent.cache_write(state, c_kv, k_r, pos, lengths)
@@ -603,6 +657,115 @@ class LatentMixer(nn.Module):
         return Linear(x.shape[-1], dtype, name="o_proj")(o), cache
 
 
+class DiffLatentMixer(nn.Module):
+    """Grouped differential attention on the latent cache (GDLA,
+    ``attention_cls`` ``gdla``): :class:`LatentMixer`'s queries and
+    cache, the ``num_attention_heads`` query heads grouped over
+    ``num_key_value_heads`` key and value heads expanded from the
+    latent (``W_uk``, ``W_uv``: ``(rank, key heads, .)``). Of a group's
+    consecutive query heads the first is its noise head
+    (``num_noise_heads``, one a group), the others its signal heads;
+    a signal head's output is its own attention map less
+    ``lambda = sigmoid(x W_lambda)`` (a token and signal head:
+    ``diff_v2``) times its group's noise map, applied to the group's
+    values, then the sigmoid gate a head channel and ``W_o``. The maps
+    are linear in the output, so each head attends as it would alone
+    and the difference is taken after (``lm.mla.diff``).
+
+    With ``window`` the layer sees the ``sliding_window`` most recent
+    positions alone, its own included, and its state is a ring of that
+    many slots (``{"ring": {"c", "r"}}``, position ``p`` in slot ``p mod
+    window``; ``latent.ring_write``, ``latent.window_prefill``); a
+    decode step reads the ring with the cache's decode, through which
+    the slots' order does not matter. The core and the ring's write
+    stand under ``lm.mla.window``."""
+
+    config: Any
+    window: bool = False
+
+    @staticmethod
+    def ring(config, batch):
+        """A window layer's empty state; a full layer's is
+        :class:`LatentMixer`'s cache."""
+        from mmlspark_tpu.parallel import latent
+
+        return {"ring": latent.init_cache(
+            batch, config["sliding_window"], config["kv_lora_rank"],
+            config["qk_rope_head_dim"], lm_dtype(config))}
+
+    @nn.compact
+    def __call__(self, x, positions, lengths, state):
+        from mmlspark_tpu.parallel import latent
+
+        c = self.config
+        heads, groups = c["num_attention_heads"], c["num_key_value_heads"]
+        signal = heads - c["num_noise_heads"]
+        d_v, dtype = c["v_head_dim"], lm_dtype(c)
+        b, t, _ = x.shape
+        q_n, q_r, c_kv, k_r, w_uk, w_uv, scale = latent_inputs(
+            self, x, positions, groups)
+        pos = positions[:, 0]
+
+        def decode(cache, filled):
+            # the absorbed decode a query head: its group's W_uk, W_uv
+            def each(w):
+                return jnp.repeat(w, heads // groups, axis=1)
+            return latent.latent_decode(
+                q_n[:, 0], q_r[:, 0], cache, each(w_uk), each(w_uv), filled,
+                scale=scale, dtype=dtype)[:, None]
+
+        if self.window:
+            with jax.named_scope("lm.mla.window"):
+                ring = state["ring"]
+                width = ring["c"].shape[1]
+                if t == 1:
+                    ring = latent.ring_write(ring, c_kv, k_r, pos, lengths)
+                    o = decode(ring, jnp.minimum(pos, width - 1))
+                else:
+                    o = latent.window_prefill(
+                        q_n, q_r, ring, c_kv, k_r, w_uk, w_uv, pos, lengths,
+                        scale=scale, dtype=dtype)
+                    ring = latent.ring_write(ring, c_kv, k_r, pos, lengths)
+                state = {"ring": ring}
+        else:
+            with jax.named_scope("lm.mla.write"):
+                state = latent.cache_write(state, c_kv, k_r, pos, lengths)
+            if t == 1:
+                with jax.named_scope("lm.mla.decode"):
+                    o = decode(state, pos)
+            else:
+                o = latent.latent_prefill(q_n, q_r, state, w_uk, w_uv, pos,
+                                          lengths, scale=scale, dtype=dtype)
+        with jax.named_scope("lm.mla.diff"):
+            o = o.reshape(b, t, groups, heads // groups, d_v)
+            lam = jax.nn.sigmoid(Linear(signal, dtype, name="lambda_proj")(
+                x)).reshape(b, t, groups, -1, 1)
+            o = (o[..., 1:, :] - lam * o[..., :1, :]).reshape(
+                b, t, signal * d_v)
+            if c.get("gated_attention", False):
+                o = o * jax.nn.sigmoid(Linear(signal * d_v, dtype,
+                                              name="g_proj")(x))
+        return Linear(x.shape[-1], dtype, name="o_proj")(o), state
+
+
+def _poly_norm(module, config, prefix="", stack=()):
+    """The :class:`~mmlspark_tpu.parallel.experts.PolyNorm` of one MLP
+    (or of ``stack`` experts) where the config's ``hidden_act`` is
+    ``poly_norm``, its leaves declared on ``module``; else ``None``
+    (SiLU). The weights start at a third each, the bias at zero."""
+    from mmlspark_tpu.parallel.experts import PolyNorm
+
+    if config.get("hidden_act") != "poly_norm":
+        return None
+    return PolyNorm(
+        module.param(prefix + "poly_weight", nn.initializers.constant(1 / 3),
+                     stack + (3,), jnp.float32),
+        module.param(prefix + "poly_bias", nn.initializers.zeros,
+                     stack + (1,), jnp.float32),
+        scale=config["polynorm_output_scale"],
+        clamp=config["polynorm_bias_clamp"], eps=config["rms_norm_eps"])
+
+
 class DenseFeedForward(nn.Module):
     config: Any
 
@@ -616,7 +779,8 @@ class DenseFeedForward(nn.Module):
         return swiglu(x, self.param("gate_proj", shape, (h, w), dtype),
                       self.param("up_proj", shape, (h, w), dtype),
                       self.param("down_proj", shape, (w, h), dtype),
-                      dtype=dtype, limit=c.get("swiglu_limit")), None
+                      dtype=dtype, limit=c.get("swiglu_limit"),
+                      poly=_poly_norm(self, c)), None
 
 
 class ExpertFeedForward(nn.Module):
@@ -637,12 +801,14 @@ class ExpertFeedForward(nn.Module):
         limit, shape = c.get("swiglu_limit"), nn.initializers.normal(0.02)
         tokens = x.reshape(-1, h)
         with jax.named_scope("lm.moe.route"):
-            routing = experts.route(
-                tokens, self.param("router", shape, (h, routed), jnp.float32),
-                self.param("router_bias", nn.initializers.zeros, (routed,),
-                           jnp.float32),
-                top_k=c["num_experts_per_tok"],
-                scale=c["routed_scaling_factor"])
+            router = self.param("router", shape, (h, routed), jnp.float32)
+            bias = (jnp.zeros((routed,), jnp.float32)
+                    if _setting(c, "selection_bias") is False else
+                    self.param("router_bias", nn.initializers.zeros,
+                               (routed,), jnp.float32))
+            routing = experts.route(tokens, router, bias,
+                                    top_k=c["num_experts_per_tok"],
+                                    scale=c["routed_scaling_factor"])
         y, pairs, dropped = experts.grouped_experts(
             tokens, routing, valid.reshape(-1),
             self.param("experts_gate", shape, (count, h, w), dtype),
@@ -650,14 +816,15 @@ class ExpertFeedForward(nn.Module):
             self.param("experts_down", shape, (count, w, h), dtype),
             held=(first, count), n=routed, dtype=dtype, limit=limit,
             tile=experts.tile_rows(tokens.shape[0],
-                                   c["num_experts_per_tok"], routed))
+                                   c["num_experts_per_tok"], routed),
+            poly=_poly_norm(self, c, "experts_", (count,)))
         with jax.named_scope("lm.moe.shared"):
             ws = w * c.get("n_shared_experts", 1)
             y = y + experts.swiglu(
                 tokens, self.param("shared_gate", shape, (h, ws), dtype),
                 self.param("shared_up", shape, (h, ws), dtype),
                 self.param("shared_down", shape, (ws, h), dtype),
-                dtype=dtype, limit=limit)
+                dtype=dtype, limit=limit, poly=_poly_norm(self, c, "shared_"))
         return y.reshape(x.shape), (pairs, dropped)
 
 
@@ -689,8 +856,9 @@ class HyperConnection(nn.Module):
             self.param("b_post", zeros, (n,), jnp.float32),
             self.param("b_res", zeros, (n, n), jnp.float32),
             norm_eps=c["rms_norm_eps"], iters=c["hc_sinkhorn_iters"],
-            eps=c["hc_eps"], clamp=(c["mhc_h_res_clamp_min"],
-                                    c["mhc_h_res_clamp_max"]))
+            eps=_setting(c, "hc_eps"),
+            clamp=(_setting(c, "mhc_h_res_clamp_min"),
+                   _setting(c, "mhc_h_res_clamp_max")))
         return hyper.hc_read(x, h_pre), (h_post, h_res)
 
 
@@ -720,6 +888,38 @@ class HybridBlock(nn.Module):
     def sparse(self) -> bool:
         return self.index >= self.config["first_k_dense_replace"]
 
+    def window(self) -> bool:
+        """A layer of window attention (``use_sliding_window``): by the
+        published index the layer stands for (``published_layers``, a
+        cut's; its own where absent), from ``max_window_layers`` on
+        every layer but each ``sliding_window_period``-th ("interleave":
+        published index + 1 a multiple of the period is full)."""
+        c = self.config
+        if not c.get("use_sliding_window"):
+            return False
+        index = c.get("published_layers",
+                      range(c["num_hidden_layers"]))[self.index]
+        return (index >= c["max_window_layers"]
+                and (index + 1) % c["sliding_window_period"] != 0)
+
+    def mixer(self):
+        """The mixer: the delta rule, latent attention, or where the
+        config says ``attention_cls`` ``gdla`` its grouped differential
+        form, over a window where ``window()``."""
+        if not self.latent():
+            return DeltaMixer
+        if self.config.get("attention_cls") != "gdla":
+            return LatentMixer
+        return functools.partial(DiffLatentMixer, window=self.window())
+
+    def init_state(self, batch, capacity):
+        """The mixer's empty state: a window layer's ring, else the
+        cache or the recurrent state."""
+        if self.window():
+            return DiffLatentMixer.ring(self.config, batch)
+        return (LatentMixer if self.latent() else DeltaMixer).init_state(
+            self.config, batch, capacity)
+
     @nn.compact
     def __call__(self, h, positions, lengths, state):
         from mmlspark_tpu.parallel import hyper
@@ -741,14 +941,21 @@ class HybridBlock(nn.Module):
                 if post:
                     y = block_norm(self, f"{name}_post", y, c)
                 if not streams:
-                    return h + y, out
+                    return clamped(h + y), out
             with jax.named_scope("lm.hc"):
-                return hyper.hc_write(h, y, *mix), out
+                return clamped(hyper.hc_write(h, y, *mix)), out
+
+        def clamped(h):
+            # the residual held to +-hidden_clamp after each sub-layer
+            bound = c.get("hidden_clamp")
+            if bound is None:
+                return h
+            return jax.tree_util.tree_map(
+                lambda s: jnp.clip(s, -bound, bound), h)
 
         h, state = around(
             h, "lm.mla" if self.latent() else "lm.gdn", "mixer",
-            LatentMixer if self.latent() else DeltaMixer, positions,
-            lengths, state)
+            self.mixer(), positions, lengths, state)
         scope = "lm.moe" if self.sparse() else "lm.mlp"
         with jax.named_scope(scope):
             valid = (jnp.arange(positions.shape[1])[None, :]
@@ -792,8 +999,7 @@ class HybridLM(nn.Module):
         sparse = sum(block.sparse() for block in blocks)
         _, count, routed = experts_held(config)
         state = {"pos": jnp.zeros((batch,), jnp.int32),
-                 "layers": [(LatentMixer if block.latent() else DeltaMixer)
-                            .init_state(config, batch, capacity)
+                 "layers": [block.init_state(batch, capacity)
                             for block in blocks],
                  "experts": {"pairs": jnp.zeros((sparse, count), jnp.int32),
                              "dropped": jnp.zeros((), jnp.int32)}}
@@ -933,4 +1139,4 @@ class HybridLM(nn.Module):
 
 
 LM_MODELS = {"brumby": RetentionLM, "gigachat3_5": HybridLM,
-             "kimi_k2": HybridLM, "xing4_0": HybridLM}
+             "kimi_k2": HybridLM, "xing4_0": HybridLM, "Motif": HybridLM}

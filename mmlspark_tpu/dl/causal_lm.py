@@ -12,10 +12,12 @@ delta-rule layers keep a recurrent state and whose latent-attention
 layers a cache of one compressed entry a position, over sparse experts
 of which this chip holds a share; ``kimi_k2`` is the same class with
 latent attention in every layer, so its whole per-sequence state is
-cache, and ``xing4_0`` that layer again inside a residual path of
-``hc_mult`` streams a token (``parallel/hyper.py``). A device batch
-is sized by the bytes of both: the state, and the cache at the longest
-prompt plus ``maxNewTokens``.
+cache, ``xing4_0`` that layer again inside a residual path of
+``hc_mult`` streams a token (``parallel/hyper.py``), and ``Motif``
+grouped differential attention on the latent cache in that path, its
+window layers holding a ring of the window's slots in place of a cache.
+A device batch is sized by the bytes of both: the state (rings
+included), and the cache at the longest prompt plus ``maxNewTokens``.
 
 One ``transform()``: the ragged prompts are sorted by length and cut
 into device batches (``ShardedScorer.length_batches``: the row ladder
@@ -97,6 +99,17 @@ def lm_prefill_program(module, prefill_chunk: int, new_tokens: int):
     return lm_prefill
 
 
+def window_read(pos, window: int):
+    """Cached positions a window layer's attention has read for a row
+    that has absorbed ``pos`` tokens, prompt and generated: position
+    ``p`` reads the ``min(p + 1, window)`` most recent, its own
+    included."""
+    import jax.numpy as jnp
+
+    return jnp.where(pos <= window, pos * (pos + 1) // 2,
+                     window * (window + 1) // 2 + (pos - window) * window)
+
+
 def lm_generate_program(module, new_tokens: int, with_logits: bool):
     """``lm_generate(params, last, state)``: ``new_tokens`` greedy
     tokens a row in one scan, the state its carry: ``({"tokens",
@@ -106,8 +119,11 @@ def lm_generate_program(module, new_tokens: int, with_logits: bool):
     and ``dropped_pairs``, and ``combine_rows`` where the experts
     combine in one pass (the rows that pass read back); with caches,
     each row's ``cache_positions``
-    (the positions it has filled, summed over the layers that cache)
-    and ``cache_capacity`` (the same at capacity); with a residual of
+    (the positions it has filled, summed over the layers that cache;
+    a window layer's ring is no such cache) and ``cache_capacity``
+    (the same at capacity); with window layers, each row's
+    ``window_positions`` (``window_read``, summed over them); with a
+    residual of
     several streams (``hc_mult``), each row's ``hc_sublayer_tokens``:
     the tokens it has absorbed, prompt and generated, times the
     sub-layers they went through that path around."""
@@ -150,6 +166,11 @@ def lm_generate_program(module, new_tokens: int, with_logits: bool):
             outs.update(
                 cache_positions=len(caches) * state["pos"],
                 cache_capacity=jnp.full_like(state["pos"], sum(caches)))
+        rings = [layer["ring"]["c"].shape[1] for layer in state["layers"]
+                 if "ring" in layer]
+        if rings:
+            outs["window_positions"] = sum(
+                window_read(state["pos"], width) for width in rings)
         if "hc_mult" in module.config:
             outs.update(
                 hc_sublayer_tokens=2 * len(state["layers"]) * state["pos"])
@@ -182,7 +203,12 @@ class CausalLM(Transformer, HasInputCol, HasOutputCol):
                         "holds a share of the experts), or xing4_0 "
                         "(kimi_k2's keys and the residual path's: hc_mult "
                         "streams a token, hc_sinkhorn_iters, hc_eps, "
-                        "mhc_h_res_clamp_min, mhc_h_res_clamp_max)",
+                        "mhc_h_res_clamp_min, mhc_h_res_clamp_max), or "
+                        "Motif (its own config.json's keys, read through "
+                        "backbones.FAMILY_KEYS: attention_cls gdla, "
+                        "num_noise_heads, sliding_window and its pattern, "
+                        "hidden_act poly_norm, mhc_*; published_layers "
+                        "where a cut keeps some of the published layers)",
                         is_complex=True)
     maxNewTokens = Param("maxNewTokens", "tokens generated a row (greedy, "
                          "no early stop)", to_int, gt(0), default=32)
@@ -400,7 +426,7 @@ class CausalLM(Transformer, HasInputCol, HasOutputCol):
                     combine_rows=int(sum(s.get("combine_rows", 0)
                                          for s in served)))
             for key in ("cache_positions", "cache_capacity",
-                        "hc_sublayer_tokens"):
+                        "hc_sublayer_tokens", "window_positions"):
                 if outputs and key in outputs[0][1]:
                     root.counts[key] = int(sum(
                         scored[key].sum() for _, scored in outputs))

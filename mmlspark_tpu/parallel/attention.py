@@ -45,11 +45,20 @@ def _block_attend(q, k, v, out, row_max, row_sum, q_offset, k_offset,
     running accumulators. Returns updated (out, row_max, row_sum).
     ``q_positions`` (b, nq) gives each row's own query positions in
     place of ``q_offset``; ``kv_lengths`` (b,) masks each row's keys
-    from that index on (a cache filled so far).
+    from that index on (a cache filled so far). Where ``k`` has fewer
+    heads than ``q`` the query heads are grouped: ``h / kv heads``
+    consecutive query heads a key and value head.
     """
     import jax.numpy as jnp
 
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    b, nq, h, _ = q.shape
+    groups = k.shape[2]
+    if groups != h:
+        scores = jnp.einsum("bqgjd,bkgd->bgjqk",
+                            q.reshape(b, nq, groups, h // groups, -1),
+                            k).reshape(b, h, nq, k.shape[1]) * scale
+    else:
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     nq, nk = q.shape[1], k.shape[1]
     k_pos = k_offset + jnp.arange(nk)
     if causal and q_positions is not None:
@@ -69,7 +78,12 @@ def _block_attend(q, k, v, out, row_max, row_sum, q_offset, k_offset,
     correction = jnp.exp(row_max - new_max)
     p = jnp.exp(scores - new_max[..., None])                # (b, h, q, k)
     new_sum = row_sum * correction + jnp.sum(p, axis=-1)
-    pv = jnp.einsum("bhqk,bkhd->bqhd", p, v)
+    if groups != h:
+        pv = jnp.einsum("bgjqk,bkgd->bqgjd",
+                        p.reshape(b, groups, h // groups, nq, nk),
+                        v).reshape(b, nq, h, v.shape[-1])
+    else:
+        pv = jnp.einsum("bhqk,bkhd->bqhd", p, v)
     new_out = out * correction.transpose(0, 2, 1)[..., None] + pv
     return new_out, new_max, new_sum
 

@@ -39,6 +39,42 @@ class Routing(NamedTuple):
     weights: "jax.Array"   # noqa: F821  (N, top_k) float32
 
 
+class PolyNorm(NamedTuple):
+    """The PolyNorm activation (arXiv:2411.03884) in place of SiLU on
+    the gate: ``scale (w0 n(u^3) + w1 n(u^2) + w2 n(u) + clip(b,
+    +-clamp))``, ``n(v) = v / sqrt(mean(v^2) + eps)`` over the
+    intermediate width. ``weight`` ``(..., 3)`` and ``bias`` ``(...,
+    1)``: one MLP's, or stacked an expert (``grouped_experts`` takes
+    expert ``e``'s)."""
+    weight: "jax.Array"    # noqa: F821
+    bias: "jax.Array"      # noqa: F821
+    scale: float
+    clamp: float
+    eps: float
+
+    def __call__(self, u):
+        import jax
+        import jax.numpy as jnp
+
+        w = self.weight.astype(jnp.float32)
+        b = jnp.clip(self.bias.astype(jnp.float32), -self.clamp, self.clamp)
+
+        def n(v):
+            return v * jax.lax.rsqrt(jnp.mean(v * v, axis=-1, keepdims=True)
+                                     + self.eps)
+
+        return self.scale * (w[0] * n(u * u * u) + w[1] * n(u * u)
+                             + w[2] * n(u) + b[0])
+
+    def expert(self, e):
+        """Expert ``e``'s of a stacked one."""
+        import jax
+
+        return self._replace(**{k: jax.lax.dynamic_index_in_dim(
+            getattr(self, k), e, axis=0, keepdims=False)
+            for k in ("weight", "bias")})
+
+
 def route(x, router, bias, *, top_k: int, scale: float) -> Routing:
     """``x``: ``(N, hidden)`` float32; ``router``: ``(hidden, n)``;
     ``bias``: ``(n,)``, added to the scores for the choice only."""
@@ -54,9 +90,11 @@ def route(x, router, bias, *, top_k: int, scale: float) -> Routing:
     return Routing(experts.astype(jnp.int32), weights)
 
 
-def swiglu(x, gate, up, down, *, dtype, limit=None):
+def swiglu(x, gate, up, down, *, dtype, limit=None, poly=None):
     """``(silu(min(x gate, limit)) * clip(x up, +-limit)) down``:
-    operands in ``dtype``, float32 accumulation and element-wise."""
+    operands in ``dtype``, float32 accumulation and element-wise. With
+    ``poly`` (a :class:`PolyNorm`) the gate's activation is that in
+    place of SiLU."""
     import jax
     import jax.numpy as jnp
 
@@ -69,7 +107,7 @@ def swiglu(x, gate, up, down, *, dtype, limit=None):
     g, u = product(x, gate), product(x, up)
     if limit is not None:
         g, u = jnp.minimum(g, limit), jnp.clip(u, -limit, limit)
-    return product(jax.nn.silu(g) * u, down)
+    return product((jax.nn.silu(g) if poly is None else poly(g)) * u, down)
 
 
 def tile_rows(tokens: int, top_k: int, n: int) -> int:
@@ -103,18 +141,19 @@ def one_pass_combine(count: int, n: int) -> bool:
 
 def grouped_experts(x, routing: Routing, valid, gate, up, down, *,
                     held: Tuple[int, int], n: int, tile: int, dtype,
-                    limit=None):
+                    limit=None, poly=None):
     """The held experts' part of the layer's output.
 
     ``x``: ``(N, hidden)`` float32; ``valid``: ``(N,)`` bool, false at
     padded tokens (which route nowhere); ``gate``, ``up``: ``(count,
     hidden, width)``; ``down``: ``(count, width, hidden)``; ``n``: the
     router's width, which with ``count`` chooses the combine
-    (``one_pass_combine``); ``tile``: pairs a tile (``tile_rows``). Returns
-    ``(y, pairs, dropped)``: ``y`` ``(N, hidden)`` float32; ``pairs``
-    ``(count,)`` int32, the pairs each held expert served; ``dropped``
-    the pairs routed here that no tile served (0: the loop covers every
-    tile in use).
+    (``one_pass_combine``); ``tile``: pairs a tile (``tile_rows``);
+    ``poly``: a :class:`PolyNorm` stacked an expert, the experts'
+    activation in place of SiLU. Returns ``(y, pairs, dropped)``: ``y``
+    ``(N, hidden)`` float32; ``pairs`` ``(count,)`` int32, the pairs
+    each held expert served; ``dropped`` the pairs routed here that no
+    tile served (0: the loop covers every tile in use).
     """
     import jax
     import jax.numpy as jnp
@@ -149,7 +188,8 @@ def grouped_experts(x, routing: Routing, valid, gate, up, down, *,
         with jax.named_scope("lm.moe.experts"):
             out = swiglu(rows, *(jax.lax.dynamic_index_in_dim(
                 w, e, axis=0, keepdims=False) for w in (gate, up, down)),
-                dtype=dtype, limit=limit)
+                dtype=dtype, limit=limit,
+                poly=None if poly is None else poly.expert(e))
         with jax.named_scope("lm.moe.combine"):
             weight = jnp.where(real, flat_weights[pair], 0.0)
             return e, offset, real, token, weight[:, None] * out

@@ -22,6 +22,15 @@ two forms of one formula:
   ``jax.numpy`` twin runs (``use_pallas``), which reads the whole
   capacity.
 
+A window layer (``window_prefill``) sees only the ``window`` most
+recent positions and keeps them in a ring of ``window`` slots, position
+``p`` in slot ``p mod window`` (``ring_write``): a stretch's queries are
+cut into blocks of ``window`` and each reads two blocks of keys, the
+ring or the stretch's block before and its own; a decode step reads
+the ring through ``latent_decode`` as a cache of ``window`` positions
+(a softmax does not care about the slots' order, and each key was
+rotated when it was written).
+
 The cache is ``{"c": (B, capacity, rank), "r": (B, capacity, rope)}``
 in the model's dtype. ``cache_write`` puts a stretch's entries at each
 row's own position (one token a row: one scatter); a padded position
@@ -149,15 +158,33 @@ def latent_prefill(q_n, q_r, cache, w_uk, w_uv, pos, lengths, *,
                    scale: float, dtype):
     """``q_n``: ``(B, T, heads, nope)``; ``q_r``: ``(B, T, heads, rope)``
     rotated; ``cache`` already holds the stretch (``cache_write``);
-    ``w_uk``: ``(rank, heads, nope)``; ``w_uv``: ``(rank, heads, d_v)``;
-    ``pos``: ``(B,)`` the stretch's first position. Returns ``(B, T,
-    heads, d_v)`` float32."""
+    ``w_uk``: ``(rank, heads, nope)``; ``w_uv``: ``(rank, heads, d_v)``,
+    or of fewer key heads over which the query heads are grouped
+    (``attention._block_attend``); ``pos``: ``(B,)`` the stretch's first
+    position. Returns ``(B, T, heads, d_v)`` float32."""
     import jax.numpy as jnp
 
     from mmlspark_tpu.parallel.attention import blockwise_attention
+
+    t = q_n.shape[1]
+    expand = _expander(w_uk, w_uv, dtype)
+    q = _operand(jnp.concatenate([q_n, q_r], axis=-1), dtype)
+    return blockwise_attention(
+        q, cache["c"], cache["r"], block_size=KV_BLOCK, causal=True,
+        scale=scale, q_positions=pos[:, None] + jnp.arange(t),
+        kv_lengths=pos + lengths, kv_map=expand,
+        kv_limit=jnp.max(pos + lengths))
+
+
+def _expander(w_uk, w_uv, dtype):
+    """``expand(c, r)``: the keys ``(B, L, key heads, nope + rope)`` and
+    values ``(B, L, key heads, d_v)`` of cached entries, both operands
+    in ``dtype``."""
+    import jax.numpy as jnp
+
     from mmlspark_tpu.parallel.shard_rules import placement_cast
 
-    t, heads = q_n.shape[1:3]
+    heads = w_uk.shape[1]
     both = placement_cast(jnp.concatenate([w_uk, w_uv], axis=-1), dtype)
 
     def expand(c, r):
@@ -169,12 +196,98 @@ def latent_prefill(q_n, q_r, cache, w_uk, w_uv, pos, lengths, *,
         return (_operand(jnp.concatenate([k_n, k_r], axis=-1), dtype),
                 _operand(v, dtype))
 
-    q = _operand(jnp.concatenate([q_n, q_r], axis=-1), dtype)
-    return blockwise_attention(
-        q, cache["c"], cache["r"], block_size=KV_BLOCK, causal=True,
-        scale=scale, q_positions=pos[:, None] + jnp.arange(t),
-        kv_lengths=pos + lengths, kv_map=expand,
-        kv_limit=jnp.max(pos + lengths))
+    return expand
+
+
+def ring_slots(pos, window: int):
+    """``(B, window)``: the position each slot of a ring holds before a
+    stretch that starts at ``pos`` ``(B,)``, the latest below ``pos`` of
+    the slot's class mod ``window``; negative where the slot is empty."""
+    import jax.numpy as jnp
+
+    last = pos[:, None] - 1
+    return last - (last - jnp.arange(window)[None, :]) % window
+
+
+def ring_write(ring, c, r, pos, lengths):
+    """A stretch's entries ``c``, ``r`` ``(B, T, ...)`` from ``pos``
+    into a ring of ``window`` slots (``{"c": (B, window, rank), "r":
+    (B, window, rope)}``): position ``p`` goes to slot ``p mod window``,
+    so of a stretch longer than the ring its last ``window`` real
+    positions stay. One token a row is one scatter (``cache_write`` at
+    the slot); a longer stretch a gather, a slot, of the stretch's
+    latest real entry of the slot's class where it has one."""
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.parallel.shard_rules import placement_cast
+
+    t, window = c.shape[1], ring["c"].shape[1]
+    if t == 1:
+        return cache_write(ring, c, r, pos % window, lengths)
+    end = (pos + lengths - 1)[:, None]                 # last real position
+    index = (lengths[:, None] - 1
+             - (end - jnp.arange(window)[None, :]) % window)
+
+    def put(old, new):
+        got = jnp.take_along_axis(
+            new, jnp.clip(index, 0, t - 1)[..., None], axis=1)
+        return jnp.where((index >= 0)[..., None],
+                         placement_cast(got, old.dtype), old)
+
+    return {"c": put(ring["c"], c), "r": put(ring["r"], r)}
+
+
+def window_prefill(q_n, q_r, ring, c, r, w_uk, w_uv, pos, lengths, *,
+                   scale: float, dtype):
+    """A stretch under a window of ``window`` positions (the ring's
+    slots), each query's own included: the stretch's queries against
+    the ring as it was before the stretch (``ring_write`` comes after)
+    and the stretch's own entries ``c``, ``r`` ``(B, T, ...)``. The
+    queries are cut into blocks of ``window``; block ``i`` reads two
+    blocks of keys, the ring (``i`` = 0) or the stretch's block ``i -
+    1``, and the stretch's block ``i``: a block outside every one of its
+    queries' windows is never read. Keys and values are expanded once
+    (``_expander``; the query heads grouped over the key heads as in
+    ``latent_prefill``). Returns ``(B, T, heads, d_v)`` float32."""
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.parallel.shard_rules import placement_cast
+
+    b, t, heads = q_n.shape[:3]
+    window, groups = ring["c"].shape[1], w_uk.shape[1]
+    blocks = -(-t // window)
+    span = blocks * window
+
+    def padded(x):
+        return jnp.pad(x, ((0, 0), (0, span - t)) + ((0, 0),) * (x.ndim - 2))
+
+    def band(x):
+        # (B, window + span, ...) -> (B, blocks, 2 window, ...): the two
+        # blocks of keys of each block of queries
+        def cut(y):
+            return y.reshape((b, blocks, window) + y.shape[2:])
+        return jnp.concatenate([cut(x[:, :span]), cut(x[:, window:])], axis=2)
+
+    q = padded(_operand(jnp.concatenate([q_n, q_r], axis=-1), dtype))
+    q = q.reshape(b, blocks, window, groups, heads // groups, -1)
+    k, v = _expander(w_uk, w_uv, dtype)(
+        *(jnp.concatenate([ring[name], padded(placement_cast(
+            x, ring[name].dtype))], axis=1)
+          for name, x in (("c", c), ("r", r))))
+    stretch = jnp.arange(span)[None, :]
+    slots = ring_slots(pos, window)
+    k_pos = band(jnp.concatenate([slots, pos[:, None] + stretch], axis=1))
+    k_real = band(jnp.concatenate([slots >= 0, stretch < lengths[:, None]],
+                                  axis=1))
+    q_pos = (pos[:, None] + stretch).reshape(b, blocks, window, 1)
+    seen = (k_real[:, :, None, :] & (k_pos[:, :, None, :] <= q_pos)
+            & (k_pos[:, :, None, :] > q_pos - window))  # (B, blocks, q, k)
+    scores = jnp.einsum("bnqgjd,bnkgd->bngjqk", q, band(k)) * scale
+    p = jax.nn.softmax(jnp.where(seen[:, :, None, None], scores, -1e30),
+                       axis=-1)
+    o = jnp.einsum("bngjqk,bnkgd->bnqgjd", _operand(p, dtype), band(v))
+    return o.reshape(b, span, heads, -1)[:, :t]
 
 
 def _product(spec, a, b, dtype):

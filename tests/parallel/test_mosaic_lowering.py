@@ -694,14 +694,18 @@ def test_gdn_decode_kernel_lowers_to_mosaic():
     assert "tpu_custom_call" in txt and "gdn_decode" in txt
 
 
-@pytest.mark.parametrize("rows,capacity", [(512, 1280), (128, 768)],
-                         ids=["kimi_k2_6.reason", "gigachat.generate"])
-def test_latent_decode_kernel_lowers_to_mosaic(rows, capacity):
+@pytest.mark.parametrize(
+    "rows,capacity,heads",
+    [(512, 1280, 64), (128, 768, 64), (16, 128, 80), (16, 16512, 80)],
+    ids=["kimi_k2_6.reason", "gigachat.generate", "motif_3_beta.ring",
+         "motif_3_beta.full"])
+def test_latent_decode_kernel_lowers_to_mosaic(rows, capacity, heads):
     """The absorbed decode over the latent cache at the published widths
-    (64 heads, latent 512, rotated key 64, bfloat16) and the two cells'
-    batches and capacities: the scalar-prefetched positions in the block
-    index, the transposed products and the 64-wide key block go through
-    Mosaic."""
+    (64 heads, or Motif's 80 query heads, latent 512, rotated key 64,
+    bfloat16) and the cells' batches and capacities (a window layer's
+    ring of 128 slots among them): the scalar-prefetched positions in
+    the block index, the transposed products and the 64-wide key block
+    go through Mosaic."""
     import jax
     import jax.numpy as jnp
 
@@ -714,11 +718,11 @@ def test_latent_decode_kernel_lowers_to_mosaic(rows, capacity):
                                     pos, scale=0.1, dtype=bf, pallas=True)
 
     txt = _lower_tpu(
-        decode, jax.ShapeDtypeStruct((rows, 64, 128), jnp.float32),
-        jax.ShapeDtypeStruct((rows, 64, 64), jnp.float32),
+        decode, jax.ShapeDtypeStruct((rows, heads, 128), jnp.float32),
+        jax.ShapeDtypeStruct((rows, heads, 64), jnp.float32),
         jax.ShapeDtypeStruct((rows, capacity, 512), bf),
         jax.ShapeDtypeStruct((rows, capacity, 64), bf),
-        jax.ShapeDtypeStruct((512, 64, 128), bf),
-        jax.ShapeDtypeStruct((512, 64, 128), bf),
+        jax.ShapeDtypeStruct((512, heads, 128), bf),
+        jax.ShapeDtypeStruct((512, heads, 128), bf),
         jax.ShapeDtypeStruct((rows,), jnp.int32))
     assert "tpu_custom_call" in txt and "latent_decode" in txt
