@@ -316,6 +316,23 @@ def lm_hidden_visits(module, lengths, t):
     return groups, int(stop - first)
 
 
+def lm_fused_prefill_layers(module, t: int) -> int:
+    """The layers whose prefill of ``t`` tokens a row runs the latent
+    prefill kernel (``latent.prefill_kernel``): full latent layers whose
+    key heads have enough query rows a step, on the TPU."""
+    from mmlspark_tpu.parallel import latent
+
+    if not isinstance(module, HybridLM) or t == 1:
+        return 0
+    c = module.config
+    heads = c["num_attention_heads"]
+    key_heads = (c["num_key_value_heads"]
+                 if c.get("attention_cls") == "gdla" else heads)
+    return sum(block.latent() and not block.window()
+               and latent.prefill_kernel(t, heads, key_heads)
+               for block in HybridLM._blocks(c))
+
+
 def lm_init_params(config: Mapping[str, Any], seed: int = 0):
     """Freshly initialised parameters of the config's model."""
     return lm_module(config).init(
@@ -678,7 +695,8 @@ class DiffLatentMixer(nn.Module):
     window``; ``latent.ring_write``, ``latent.window_prefill``); a
     decode step reads the ring with the cache's decode, through which
     the slots' order does not matter. The core and the ring's write
-    stand under ``lm.mla.window``."""
+    stand under ``lm.mla.window``; a full layer's prefill attention
+    (``latent.latent_prefill``) under ``lm.mla.full``."""
 
     config: Any
     window: bool = False
@@ -734,8 +752,10 @@ class DiffLatentMixer(nn.Module):
                 with jax.named_scope("lm.mla.decode"):
                     o = decode(state, pos)
             else:
-                o = latent.latent_prefill(q_n, q_r, state, w_uk, w_uv, pos,
-                                          lengths, scale=scale, dtype=dtype)
+                with jax.named_scope("lm.mla.full"):
+                    o = latent.latent_prefill(q_n, q_r, state, w_uk, w_uv,
+                                              pos, lengths, scale=scale,
+                                              dtype=dtype)
         with jax.named_scope("lm.mla.diff"):
             o = o.reshape(b, t, groups, heads // groups, d_v)
             lam = jax.nn.sigmoid(Linear(signal, dtype, name="lambda_proj")(

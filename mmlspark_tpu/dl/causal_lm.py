@@ -324,17 +324,22 @@ class CausalLM(Transformer, HasInputCol, HasOutputCol):
         return programs[key]
 
     def _prefill_visits(self, lengths: np.ndarray, length: int):
-        """``[visits, run]`` of one ``lm_prefill`` over these rows'
+        """``[visits, run, fused]`` of one ``lm_prefill`` over these rows'
         lengths (the device batch's, zero rows included) at a length
         rung: a visit is a group of rows in a step
         (``backbones.lm_hidden_visits``); those run are the ones inside
-        the bounds of the model's group loop."""
-        from mmlspark_tpu.dl.backbones import lm_hidden_visits
+        the bounds of the model's group loop; ``fused`` counts a run
+        visit once for each layer whose prefill runs the latent prefill
+        kernel (``backbones.lm_fused_prefill_layers``)."""
+        from mmlspark_tpu.dl.backbones import (lm_fused_prefill_layers,
+                                               lm_hidden_visits)
 
         chunk, steps = prefill_steps(self.get("prefillChunk"), length)
-        return np.sum([lm_hidden_visits(
+        visits = np.sum([lm_hidden_visits(
             self._module, np.clip(lengths - start, 0, chunk), chunk)
             for start in np.arange(steps) * chunk], axis=0)
+        return np.append(visits, visits[1] * lm_fused_prefill_layers(
+            self._module, chunk))
 
     def _generate(self, params, batch):
         """What the engine calls a device batch with: two programs,
@@ -407,13 +412,14 @@ class CausalLM(Transformer, HasInputCol, HasOutputCol):
             visits = sum((self._prefill_visits(
                 np.pad(b["lengths"], (0, scorer.padded_rows(len(index))
                                       - len(index))), b["ids"].shape[1])
-                for index, b in batches), np.zeros(2, int))
+                for index, b in batches), np.zeros(3, int))
             root.counts.update(
                 new_tokens=int(new * len(prompts)),
                 state_bytes=int(held["state"]),
                 cache_bytes=int(held["cache"]), length_rung=int(rung),
                 prefill_visits=int(visits[0]),
-                prefill_visits_run=int(visits[1]))
+                prefill_visits_run=int(visits[1]),
+                fused_prefill_visits=int(visits[2]))
             served = [scored for _, scored in outputs
                       if "expert_pairs" in scored]
             if served:

@@ -10,7 +10,13 @@ two forms of one formula:
   expanded from the cache a block of positions at a time inside
   ``attention.blockwise_attention``'s loop (never whole, and only the
   blocks some row has filled), and the stretch's queries attend to
-  every cached position up to their own;
+  every cached position up to their own. Where a key head has many
+  query rows a step (``prefill_kernel``: the TPU, and ``t × heads /
+  key heads`` from ``PREFILL_KERNEL_MIN_ROWS``) one Pallas kernel
+  (``name="latent_prefill"``) does the same a query tile at a time:
+  the block's keys and values expanded in VMEM from the latent, the
+  tile's scores, running maximum and sum and float32 accumulator kept
+  there, the blocks past a row's last real query not read;
 - ``latent_decode`` (one token): ``W_uk`` is absorbed into the query
   and ``W_uv`` applied to the attended latent, so a step reads the
   cache's latents alone. The latent is key and value at once (a
@@ -53,6 +59,19 @@ from mmlspark_tpu.parallel.retention import use_pallas
 KV_BLOCK = 128       # cached positions expanded a step of the scan
 DECODE_ROWS = 8      # rows a grid step of the decode kernel
 DECODE_BLOCK = 256   # cached positions a grid step of the decode kernel
+# One Motif full layer's group (4 rows x 1,024 queries, 80 heads over 16
+# key heads, rank 512) on a TPU v5e, ms a call, fills 4-8k / 8-15k
+# (tools/latent_decode_ab.py --form prefill): the scan 147 / 259; block x
+# tile 512x512 16.5 / 27.6, 512x1024 17.4 / 29.8, 512x256 19.6 / 33.3,
+# 384x1024 27.1 / 47.2, 256x1024 33.5 / 58.7, 256x256 33.1 / 57.9,
+# 1024x1024 38.4 / 44.3
+PREFILL_BLOCK = 512  # cached positions a grid step of the prefill kernel
+PREFILL_TILE = 512   # queries a row a grid step of the prefill kernel
+# query rows a key head a step (t x heads / key heads) from which the
+# prefill takes the kernel; below, the scan. The kernel also won at 128
+# and 256 rows (the other latent models' steps: 24.0 -> 10.9, 15.8 ->
+# 7.0, 10.4 -> 4.5 ms a group), which have not been measured end to end
+PREFILL_KERNEL_MIN_ROWS = 1024
 
 
 def yarn_frequencies(dim: int, theta: float, scaling: Mapping) -> np.ndarray:
@@ -154,19 +173,36 @@ def _operand(x, dtype):
     return jax.lax.reduce_precision(x, info.nexp, info.nmant)
 
 
+def prefill_kernel(t: int, heads: int, key_heads: int) -> bool:
+    """Whether a prefill of ``t`` queries a row over ``heads`` query heads
+    grouped on ``key_heads`` takes the kernel: on the TPU, where a key
+    head has at least ``PREFILL_KERNEL_MIN_ROWS`` query rows a step.
+    Fewer, and a block's expansion (which the kernel makes once a query
+    tile and key head) costs as much as its attention."""
+    return use_pallas() and t * heads // key_heads >= PREFILL_KERNEL_MIN_ROWS
+
+
 def latent_prefill(q_n, q_r, cache, w_uk, w_uv, pos, lengths, *,
-                   scale: float, dtype):
+                   scale: float, dtype, pallas: Optional[bool] = None,
+                   interpret: bool = False):
     """``q_n``: ``(B, T, heads, nope)``; ``q_r``: ``(B, T, heads, rope)``
     rotated; ``cache`` already holds the stretch (``cache_write``);
     ``w_uk``: ``(rank, heads, nope)``; ``w_uv``: ``(rank, heads, d_v)``,
     or of fewer key heads over which the query heads are grouped
     (``attention._block_attend``); ``pos``: ``(B,)`` the stretch's first
-    position. Returns ``(B, T, heads, d_v)`` float32."""
+    position. Returns ``(B, T, heads, d_v)`` float32. The kernel where
+    ``prefill_kernel`` says so (``pallas`` pins a form): it gives a row
+    without a real token in the stretch zeros, the scan the softmax of
+    whatever it may see; the real queries' are the same."""
     import jax.numpy as jnp
 
     from mmlspark_tpu.parallel.attention import blockwise_attention
 
-    t = q_n.shape[1]
+    t, heads = q_n.shape[1:3]
+    if (prefill_kernel(t, heads, w_uk.shape[1]) if pallas is None
+            else pallas):
+        return _prefill_pallas(q_n, q_r, cache, w_uk, w_uv, pos, lengths,
+                               scale, dtype, interpret)
     expand = _expander(w_uk, w_uv, dtype)
     q = _operand(jnp.concatenate([q_n, q_r], axis=-1), dtype)
     return blockwise_attention(
@@ -197,6 +233,174 @@ def _expander(w_uk, w_uv, dtype):
                 _operand(v, dtype))
 
     return expand
+
+
+def _prefill_kernel(pos_ref, fill_ref, last_ref, qn_ref, qr_ref, c_ref,
+                    r_ref, wk_ref, wv_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                    tile: int, block: int, scale: float):
+    """One row's tile of ``tile`` queries, for the ``J`` query heads of
+    one key head, against one block of ``block`` cached positions: the
+    block's latents expanded to the key head's keys and values (float32
+    out, rounded to the operands' dtype), the scores as the nope and rope
+    parts' two products, running maximum ``m``, sum ``l`` and output
+    ``acc`` in float32, ``p`` rounded to the operands' dtype for its
+    product with the values. A block past the tile's last real query is
+    not read (its index is held at the last block in use, which is not
+    fetched again) and does no work; a block wholly before the tile's
+    first query and the row's fill is not masked."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    tiles = pl.num_programs(2)
+    last, fill = last_ref[b * tiles + i], fill_ref[b]
+    first = pos_ref[b] + i * tile                  # the tile's first query
+    dtype = qn_ref.dtype
+    contract = (((1,), (1,)), ((), ()))
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, -1e30, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def attend(masked: bool):
+        c = c_ref[...].astype(dtype)
+        k_n = jnp.dot(c, wk_ref[...],
+                      preferred_element_type=jnp.float32).astype(dtype)
+        v = jnp.dot(c, wv_ref[...], preferred_element_type=jnp.float32)
+        r = r_ref[...].astype(dtype)
+        if masked:
+            # values of positions not filled are zero, so that what lies
+            # there (anything: a partial last block reads beyond the
+            # array) meets its zero weight as zero
+            down = j * block + jax.lax.broadcasted_iota(
+                jnp.int32, (block, 1), 0)
+            v = jnp.where(down < fill, v, 0.0)
+            along = j * block + jax.lax.broadcasted_iota(
+                jnp.int32, (1, block), 1)
+            q_pos = first + jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
+            seen = (along <= q_pos) & (along < fill)       # (tile, block)
+        v = v.astype(dtype)
+        for h in range(qn_ref.shape[0]):
+            s = (jax.lax.dot_general(qn_ref[h], k_n, contract,
+                                     preferred_element_type=jnp.float32)
+                 + jax.lax.dot_general(qr_ref[h], r, contract,
+                                       preferred_element_type=jnp.float32)
+                 ) * scale
+            if masked:
+                s = jnp.where(seen, s, -1e30)
+            m_old = m_ref[h]                                 # lane-equal
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
+            shrink = jnp.exp(m_old - m_new)
+            p = jnp.exp(s - m_new[:, :1])
+            l_ref[h] = shrink * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[h] = shrink[:, :1] * acc_ref[h] + jnp.dot(
+                p.astype(dtype), v, preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
+
+    whole = ((j + 1) * block <= first + 1) & ((j + 1) * block <= fill)
+
+    @pl.when((j <= last) & whole)
+    def _():
+        attend(masked=False)
+
+    @pl.when((j <= last) & jnp.logical_not(whole))
+    def _():
+        attend(masked=True)
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _():
+        for h in range(qn_ref.shape[0]):
+            # a tile that did no work has l = 0 and acc = 0: zeros
+            o_ref[h] = acc_ref[h] / jnp.maximum(l_ref[h][:, :1], 1e-30)
+
+
+def _prefill_pallas(q_n, q_r, cache, w_uk, w_uv, pos, lengths, scale, dtype,
+                    interpret: bool):
+    """``latent_prefill`` as one kernel over (row, key head, query tile)
+    and the cache's blocks: the queries laid out a key head's ``J`` heads
+    at a time, ``(B, key heads, J, T, .)``, and the weights a key head
+    at a time, ``(key heads, rank, .)``. A tile's last block is that of
+    its last real query; a tile with none (a row without a real token in
+    the stretch, or past a row's real ones) reads nothing and gives
+    zeros."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from mmlspark_tpu.parallel.shard_rules import placement_cast
+
+    c, r = cache["c"], cache["r"]
+    b, t, heads, nope = q_n.shape
+    rope, (rank, groups, d_v) = q_r.shape[-1], w_uv.shape
+    per = heads // groups
+    capacity = c.shape[1]
+    tile = min(PREFILL_TILE, -(-t // 8) * 8)
+    tiles = -(-t // tile)
+    block = min(PREFILL_BLOCK, capacity)
+
+    def by_head(x):
+        # (B, T, heads, d) -> (B, key heads, J, tiles x tile, d)
+        x = placement_cast(x, dtype).reshape(b, t, groups, per, -1)
+        x = jnp.pad(x, ((0, 0), (0, tiles * tile - t)) + ((0, 0),) * 3)
+        return jnp.transpose(x, (0, 2, 3, 1, 4))
+
+    pos = pos.astype(jnp.int32)
+    fill = pos + lengths.astype(jnp.int32)
+    start = jnp.arange(tiles, dtype=jnp.int32)[None, :] * tile
+    real = jnp.minimum(start + tile, lengths[:, None]) - 1   # last real query
+    last = jnp.where(real >= start, (pos[:, None] + real) // block,
+                     -1).reshape(-1)
+
+    def by_tile(d):
+        return pl.BlockSpec((None, None, per, tile, d),
+                            lambda g0, g, i, j, *_: (g0, g, 0, i, 0))
+
+    def by_block(d):
+        return pl.BlockSpec(
+            (None, block, d),
+            lambda g0, g, i, j, pos, fill, last: (
+                g0, jnp.minimum(j, jnp.maximum(last[g0 * tiles + i], 0)), 0))
+
+    def by_key_head(d):
+        return pl.BlockSpec((None, rank, d), lambda g0, g, i, j, *_: (g, 0, 0))
+
+    # what a grid step holds, in bytes: the scratch, the output's and the
+    # queries' two buffers, the cache's and the weights' two, and a few
+    # (tile, block) float32 scores alive at once
+    item = jnp.dtype(dtype).itemsize
+    vmem = (4 * per * tile * (2 * 128 + 3 * d_v)
+            + 2 * item * (per * tile * (nope + 128) + block * (rank + 128)
+                          + rank * (nope + d_v))
+            + 4 * 4 * tile * block)
+    out = pl.pallas_call(
+        functools.partial(_prefill_kernel, tile=tile, block=block,
+                          scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, groups, tiles, pl.cdiv(capacity, block)),
+            in_specs=[by_tile(nope), by_tile(rope), by_block(rank),
+                      by_block(rope), by_key_head(nope), by_key_head(d_v)],
+            out_specs=by_tile(d_v),
+            scratch_shapes=[pltpu.VMEM((per, tile, 128), jnp.float32),
+                            pltpu.VMEM((per, tile, 128), jnp.float32),
+                            pltpu.VMEM((per, tile, d_v), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, groups, per, tiles * tile, d_v),
+                                       jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=min(vmem + (16 << 20), 100 << 20)),
+        interpret=interpret,
+        name="latent_prefill",
+    )(pos, fill, last, by_head(q_n), by_head(q_r), c, r,
+      jnp.transpose(placement_cast(w_uk, dtype), (1, 0, 2)),
+      jnp.transpose(placement_cast(w_uv, dtype), (1, 0, 2)))
+    out = jnp.transpose(out, (0, 3, 1, 2, 4))[:, :t]
+    return out.reshape(b, t, heads, d_v)
 
 
 def ring_slots(pos, window: int):

@@ -277,6 +277,7 @@ def test_spans_counts_and_scopes(params):
     assert counts["hc_streams"] == 4
     assert counts["hc_sublayer_tokens"] == 2 * 5 * sum(through)
     assert counts["dropped_pairs"] == 0
+    assert counts["fused_prefill_visits"] == 0     # the scan, off the TPU
     for t in (1, 16):
         text = jax.jit(lm_module(CFG).apply).lower(
             params, jnp.zeros((2, t), jnp.int32), jnp.full((2,), t,
@@ -287,6 +288,38 @@ def test_spans_counts_and_scopes(params):
         assert "layers_0/lm.mla/mixer/lm.mla.window" not in text
         for layer in range(5):
             assert f"layers_{layer}/lm.mla/mixer/lm.mla.diff" in text
+        # a full layer's prefill attention, and nothing else's
+        full = [f"layers_{layer}/lm.mla/mixer/lm.mla.full" in text
+                for layer in range(5)]
+        assert full == ([True, False, False, False, True] if t > 1
+                        else [False] * 5)
+
+
+def test_the_prefill_kernel_in_the_stage_equals_the_scan(params,
+                                                        monkeypatch):
+    """The whole stage with the full layers' prefill through the Pallas
+    kernel, interpreted (the rule's bound lowered to this size), and the
+    decode kernel: the tokens and log-probabilities of the scan's, and
+    each run visit counted once for each of the two full layers."""
+    from mmlspark_tpu.parallel import latent
+
+    col = _prompts()
+    base = _stage(params).transform(DataFrame({"prompt": col}))
+    monkeypatch.setattr(latent, "use_pallas", lambda: True)
+    monkeypatch.setattr(latent, "PREFILL_KERNEL_MIN_ROWS", 16)
+    for name in ("_decode_pallas", "_prefill_pallas"):
+        monkeypatch.setattr(latent, name, lambda *a, _real=getattr(
+            latent, name): _real(*a[:-1], True))
+    before = len(SINK.events)
+    out = _stage(params).transform(DataFrame({"prompt": col}))
+    assert np.array_equal(np.asarray(out.col("completion")),
+                          np.asarray(base.col("completion")))
+    assert np.abs(np.asarray(out.col("logprobs"))
+                  - np.asarray(base.col("logprobs"))).max() < 2e-5
+    counts = [r for r in SINK.events[before:]
+              if r.get("className") == "CausalLM"][-1]["counts"]
+    assert counts["fused_prefill_visits"] == 2 * counts[
+        "prefill_visits_run"] > 0
 
 
 def test_the_shares_add_up_to_the_uncut_layer():

@@ -236,3 +236,119 @@ def test_a_bounded_prefill_visits_only_the_blocks_in_use():
                                              **kw))
     whole = np.asarray(blockwise_attention(q, k, np.nan_to_num(v), **kw))
     assert np.array_equal(bounded, whole)
+
+
+# -- the prefill kernel (interpreted) against the scan ------------------
+
+PREFILL_T, PREFILL_CAPACITY = 24, 100     # blocks of 16, the last partial
+# row 0 a first step; row 1 a later one whose fill (50) ends inside a
+# block; row 2 without a real token; row 3 a later step, all real, a
+# tile of it starting one short of a block's last position
+PREFILL_POS, PREFILL_LENGTHS = [0, 37, 50, 62], [24, 13, 0, 24]
+
+
+def _prefill_inputs(heads, groups, dtype, seed=7):
+    rng = np.random.default_rng(seed)
+
+    def normal(*shape, std=1.0):
+        return jnp.asarray(rng.standard_normal(shape) * std, dtype)
+
+    b = len(PREFILL_POS)
+    cache = {"c": normal(b, PREFILL_CAPACITY, WIDE_RANK),
+             "r": normal(b, PREFILL_CAPACITY, WIDE_ROPE)}
+    return (normal(b, PREFILL_T, heads, 128),
+            normal(b, PREFILL_T, heads, WIDE_ROPE), cache,
+            normal(WIDE_RANK, groups, 128, std=0.1),
+            normal(WIDE_RANK, groups, 128, std=0.1))
+
+
+def _prefill(inputs, pallas, dtype, **kw):
+    q_n, q_r, cache, w_uk, w_uv = inputs
+    return np.asarray(L.latent_prefill(
+        q_n, q_r, cache, w_uk, w_uv, jnp.array(PREFILL_POS),
+        jnp.array(PREFILL_LENGTHS), scale=0.1, dtype=dtype, pallas=pallas,
+        interpret=pallas, **kw), np.float32)
+
+
+@pytest.fixture
+def small_blocks(monkeypatch):
+    """Blocks of 16 positions and tiles of 8 queries: three tiles a row,
+    seven blocks a cache, the causal edge inside a block."""
+    monkeypatch.setattr(L, "PREFILL_BLOCK", 16)
+    monkeypatch.setattr(L, "PREFILL_TILE", 8)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("heads,groups", [(10, 2), (4, 4)],
+                         ids=["grouped", "ungrouped"])
+def test_prefill_kernel_equals_the_scan_and_reads_nothing_past_a_row(
+        small_blocks, heads, groups, dtype):
+    """Every real query's output is the scan's, with the cache past each
+    row's fill poisoned for the kernel (the scan would read the NaN into
+    a zero weight); a row without a real token gives zeros. In bfloat16
+    the two round the same operands and differ by where ``p`` is
+    rounded (the scan's product on the CPU keeps it float32) and by the
+    order of the sums."""
+    inputs = _prefill_inputs(heads, groups, dtype)
+    want = _prefill(inputs, False, dtype)
+    poisoned = {k: np.array(v, np.float32) for k, v in inputs[2].items()}
+    for row, (p, n) in enumerate(zip(PREFILL_POS, PREFILL_LENGTHS)):
+        for v in poisoned.values():
+            v[row, p + n:] = np.nan
+    got = _prefill(inputs[:2] + ({k: jnp.asarray(v, dtype)
+                                  for k, v in poisoned.items()},)
+                   + inputs[3:], True, dtype)
+    scale = np.abs(want).max()
+    tol = 5 * TOL if dtype == jnp.float32 else 0.01
+    for row, n in enumerate(PREFILL_LENGTHS):
+        assert np.abs(got[row, :n] - want[row, :n]).max(initial=0) < (
+            tol * scale), row
+    assert np.isfinite(got).all()
+    assert not got[2].any()                 # no real token: zeros
+
+
+def test_prefill_kernel_attends_to_the_diagonal_and_to_the_fill(
+        small_blocks):
+    """The key at a query's own position and the last filled one are
+    seen: a kernel that masked either (one block too few, or the causal
+    edge off by one) moves a query's output by far more than rounding."""
+    inputs = _prefill_inputs(10, 2, jnp.float32, seed=8)
+    q_n, q_r, cache, w_uk, w_uv = inputs
+    want = _prefill(inputs, False, jnp.float32)
+    got = _prefill(inputs, True, jnp.float32)
+    # row 0 from position 0: its first query sees one key alone, itself
+    assert np.abs(got[0, 0] - want[0, 0]).max() < 5 * TOL
+    v0 = np.einsum("c,chd->hd", np.asarray(cache["c"])[0, 0],
+                   np.asarray(w_uv))
+    assert np.abs(got[0, 0] - np.repeat(v0, 5, axis=0)).max() < 5 * TOL
+    # every query whose own position opens a block, or whose row's last
+    # filled position does
+    for row, (p, n) in enumerate(zip(PREFILL_POS, PREFILL_LENGTHS)):
+        for q in range(n):
+            if (p + q) % 16 == 0 or q == n - 1:
+                assert np.abs(got[row, q] - want[row, q]).max() < 5 * TOL
+
+
+def test_the_rule_takes_the_kernel_at_motifs_shape_alone(monkeypatch):
+    """On the TPU the kernel is taken by the shapes alone: Motif's full
+    layers (1,024 queries a step, 80 heads over 16 key heads) take it;
+    Kimi's, Xing's and GigaChat's latent layers (128-256 queries, no
+    grouping) keep the scan; window layers never run the latent
+    prefill. Off the TPU nothing takes it."""
+    from benchmark.lookup import load_json
+    from mmlspark_tpu.dl.backbones import lm_fused_prefill_layers, lm_module
+
+    def fused(name):
+        file = load_json("configs", name + ".json")
+        module = lm_module({k: file[k] for k in file["model_keys"]})
+        return lm_fused_prefill_layers(module, file["prefillChunk"])
+
+    names = ("motif_3_beta", "kimi_k2_6", "xing4_0_29b_a4b",
+             "gigachat3_5_432b", "brumby_14b")
+    assert [fused(n) for n in names] == [0] * 5
+    monkeypatch.setattr(L, "use_pallas", lambda: True)
+    assert [fused(n) for n in names] == [2, 0, 0, 0, 0]
+    assert L.prefill_kernel(1024, 80, 16)
+    for t, heads in ((128, 64), (256, 32)):
+        assert not L.prefill_kernel(t, heads, heads)

@@ -726,3 +726,39 @@ def test_latent_decode_kernel_lowers_to_mosaic(rows, capacity, heads):
         jax.ShapeDtypeStruct((512, heads, 128), bf),
         jax.ShapeDtypeStruct((rows,), jnp.int32))
     assert "tpu_custom_call" in txt and "latent_decode" in txt
+
+
+@pytest.mark.parametrize(
+    "rows,t,capacity",
+    [(4, 1024, 16512), (4, 128, 1024)],
+    ids=["motif_3_beta.long_read", "a short step"])
+def test_latent_prefill_kernel_lowers_to_mosaic(rows, t, capacity):
+    """The full layers' prefill kernel at Motif's widths (80 query heads
+    over 16 key heads of 128 + 64 rotated, latent 512, values of 128,
+    bfloat16): a group of the cell's step of 1,024 queries against its
+    cache of 16,512 positions (a partial last block), and a short step:
+    the three scalar-prefetched vectors in the block index, the
+    expansion of a block by the key head's weights, the transposed
+    products and the 64-wide rotated parts go through Mosaic."""
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.parallel import latent
+
+    bf = jnp.bfloat16
+
+    def prefill(q_n, q_r, c, r, w_uk, w_uv, pos, lengths):
+        return latent.latent_prefill(q_n, q_r, {"c": c, "r": r}, w_uk, w_uv,
+                                     pos, lengths, scale=0.1, dtype=bf,
+                                     pallas=True)
+
+    txt = _lower_tpu(
+        prefill, jax.ShapeDtypeStruct((rows, t, 80, 128), bf),
+        jax.ShapeDtypeStruct((rows, t, 80, 64), bf),
+        jax.ShapeDtypeStruct((rows, capacity, 512), bf),
+        jax.ShapeDtypeStruct((rows, capacity, 64), bf),
+        jax.ShapeDtypeStruct((512, 16, 128), bf),
+        jax.ShapeDtypeStruct((512, 16, 128), bf),
+        jax.ShapeDtypeStruct((rows,), jnp.int32),
+        jax.ShapeDtypeStruct((rows,), jnp.int32))
+    assert "tpu_custom_call" in txt and "latent_prefill" in txt

@@ -1,22 +1,41 @@
-"""The latent decode and the one-token cache write on the chip, kernel
+"""The latent attention on the chip by form. ``--form decode`` (the
+default): the latent decode and the one-token cache write, kernel
 against ``jax.numpy`` twin and scatter against slices, by shape: the
 measurement behind ``latent.DECODE_ROWS`` and ``DECODE_BLOCK`` (PERF.md
-section 6, PR 35).
+section 6, PR 35). ``--form prefill``: one layer's ``latent_prefill``,
+the scan against the kernel by block and tile: the measurement behind
+``latent.PREFILL_BLOCK``, ``PREFILL_TILE`` and
+``PREFILL_KERNEL_MIN_ROWS`` (PERF.md section 6, PR 42).
 
 usage: python3 tools/latent_decode_ab.py [--shapes 512x1280,128x768]
            [--tiles 8x128,8x256,4x256,16x128] [--fill 0.5] [--steps 20]
            [--out chiprun_out/latent_decode_ab.jsonl]
+       python3 tools/latent_decode_ab.py --form prefill
+           [--shapes 4x1024x80x16x16512,32x128x64x64x1280]
+           [--tiles 512x512,512x1024,512x256] [--fill 0.5] [--steps 5]
 
-A shape is rows x capacity at the published widths (64 heads, rank 512,
-rope 64, bfloat16). Rows' positions are a sorted ramp about ``fill`` of
-the capacity (a length-sorted batch in the middle of a call). Each
-variant runs ``steps`` steps inside one ``jax.jit`` over a ``lax.scan``
-whose carry is the cache, as ``lm_generate`` does. One JSON line a
-variant: seconds a step (host clock around the whole scan, less one
-step's), the floor of ``benchmark/opcount_latent_lm.latent_decode``,
-and the largest difference from the twin inside that scan. Exits 2 off
-the TPU unless JAX_PLATFORMS=cpu is set (a rehearsal: Pallas
-interpreted, times meaningless)."""
+Decode: a shape is rows x capacity at the published widths (64 heads,
+rank 512, rope 64, bfloat16). Rows' positions are a sorted ramp about
+``fill`` of the capacity (a length-sorted batch in the middle of a
+call). Each variant runs ``steps`` steps inside one ``jax.jit`` over a
+``lax.scan`` whose carry is the cache, as ``lm_generate`` does. One
+JSON line a variant: seconds a step (host clock around the whole scan,
+less one step's), the floor of
+``benchmark/opcount_latent_lm.latent_decode``, and the largest
+difference from the twin inside that scan.
+
+Prefill: a shape is rows x queries x heads x key heads x capacity
+(rank 512, nope and d_v 128, rope 64, bfloat16), a tile block x
+queries. Every row's stretch is real; its first position a sorted ramp
+from half to all of ``fill`` of what the capacity leaves, a multiple
+of the stretch (a group of a length-sorted batch at a later step).
+One JSON line a variant: the least of ``steps`` timed calls of one
+jitted layer (host clock, each waited for), the floor of its (query,
+key) pairs in the expanded form at the bf16 peak, and the largest
+difference from the scan.
+
+Exits 2 off the TPU unless JAX_PLATFORMS=cpu is set (a rehearsal:
+Pallas interpreted, times meaningless)."""
 import argparse
 import json
 import os
@@ -32,17 +51,24 @@ HEADS, RANK, ROPE, NOPE, DV = 64, 512, 64, 128, 128
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--shapes", default="512x1280,128x768")
-    ap.add_argument("--tiles", default="8x128,8x256,4x256,16x128")
+    ap.add_argument("--form", choices=("decode", "prefill"),
+                    default="decode")
+    ap.add_argument("--shapes")
+    ap.add_argument("--tiles")
     ap.add_argument("--fill", type=float, default=0.5)
-    ap.add_argument("--steps", type=int, default=20)
-    ap.add_argument("--out", default="chiprun_out/latent_decode_ab.jsonl")
+    ap.add_argument("--steps", type=int)
+    ap.add_argument("--out")
     args = ap.parse_args(argv)
+    prefill = args.form == "prefill"
+    args.shapes = args.shapes or (
+        "4x1024x80x16x16512" if prefill else "512x1280,128x768")
+    args.tiles = args.tiles or (
+        "512x512,512x1024,512x256" if prefill
+        else "8x128,8x256,4x256,16x128")
+    args.steps = args.steps or (5 if prefill else 20)
+    args.out = args.out or f"chiprun_out/latent_{args.form}_ab.jsonl"
 
     import jax
-    import jax.numpy as jnp
-
-    from mmlspark_tpu.parallel import latent
 
     rehearsal = jax.default_backend() != "tpu"
     if rehearsal and os.environ.get("JAX_PLATFORMS") != "cpu":
@@ -50,6 +76,19 @@ def main(argv=None) -> int:
               "rehearsal)", file=sys.stderr)
         return 2
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+    lines = (_prefill_ab if prefill else _decode_ab)(args, rehearsal)
+    with open(args.out, "w") as f:
+        for line in lines:
+            f.write(json.dumps(line) + "\n")
+    return 0
+
+
+def _decode_ab(args, rehearsal):
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.parallel import latent
+
     bf = jnp.bfloat16
     lines = []
 
@@ -135,10 +174,73 @@ def main(argv=None) -> int:
                           "step_s": s})
         for line in lines[-(3 + len(args.tiles.split(","))):]:
             print(json.dumps(line), flush=True)
-    with open(args.out, "w") as f:
-        for line in lines:
-            f.write(json.dumps(line) + "\n")
-    return 0
+    return lines
+
+
+def _prefill_ab(args, rehearsal):
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import opcount
+    from mmlspark_tpu.parallel import latent
+
+    bf = jnp.bfloat16
+    lines = []
+    for shape in args.shapes.split(","):
+        b, t, heads, groups, cap = (int(v) for v in shape.split("x"))
+        key = jax.random.split(jax.random.PRNGKey(b + t + cap), 6)
+        cache = {"c": jax.random.normal(key[0], (b, cap, RANK), bf),
+                 "r": jax.random.normal(key[1], (b, cap, ROPE), bf)}
+        q_n = jax.random.normal(key[2], (b, t, heads, NOPE), bf)
+        q_r = jax.random.normal(key[3], (b, t, heads, ROPE), bf)
+        w_uk = (jax.random.normal(key[4], (RANK, groups, NOPE)) * .05
+                ).astype(bf)
+        w_uv = (jax.random.normal(key[5], (RANK, groups, DV)) * .05
+                ).astype(bf)
+        room = (cap - t) // t
+        pos = np.round(np.linspace(0.5, 1.0, b) * args.fill * room) * t
+        pos = jnp.asarray(pos, jnp.int32)
+        lengths = jnp.full((b,), t, jnp.int32)
+
+        def layer(pallas):
+            return jax.jit(lambda q_n, q_r, cache, pos: latent.latent_prefill(
+                q_n, q_r, cache, w_uk, w_uv, pos, lengths, scale=0.1,
+                dtype=bf, pallas=pallas, interpret=rehearsal and pallas))
+
+        def timed(fn):
+            out = jax.block_until_ready(fn(q_n, q_r, cache, pos))  # compiles
+            best = float("inf")
+            for _ in range(args.steps):
+                start = time.perf_counter()
+                jax.block_until_ready(fn(q_n, q_r, cache, pos))
+                best = min(best, time.perf_counter() - start)
+            return best, out
+
+        pairs = float(sum(np.asarray(pos, np.int64) * t
+                          + t * (t + 1) // 2))
+        flops = pairs * heads * 2.0 * (NOPE + ROPE + DV)
+        floor = (None if rehearsal else opcount.least_seconds(
+            flops, 0.0, opcount.peaks(jax.devices()[0].device_kind))[0])
+        base = {"shape": shape, "pos": np.asarray(pos).tolist(),
+                "rows_a_key_head": t * heads // groups, "floor_s": floor}
+        scan_s, scan = timed(layer(False))
+        lines.append(dict(base, variant="scan", call_s=scan_s))
+        for tile in args.tiles.split(","):
+            latent.PREFILL_BLOCK, latent.PREFILL_TILE = (
+                int(v) for v in tile.split("x"))
+            try:
+                s, got = timed(layer(True))
+            except Exception as e:            # what Mosaic refused
+                lines.append(dict(base, variant="kernel " + tile,
+                                  refused=str(e)[:300]))
+                continue
+            lines.append(dict(
+                base, variant="kernel " + tile, call_s=s,
+                max_abs_diff_from_scan=float(jnp.abs(got - scan).max()),
+                scan_scale=float(jnp.abs(scan).max())))
+        for line in lines[-(1 + len(args.tiles.split(","))):]:
+            print(json.dumps(line), flush=True)
+    return lines
 
 
 if __name__ == "__main__":
