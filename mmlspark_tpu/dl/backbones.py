@@ -634,7 +634,9 @@ class LatentMixer(nn.Module):
     carries ``mscale_all_dim`` where ``use_mla_scaling_factor`` says
     so, and where the key is absent whenever ``mscale_all_dim`` is set
     (the DeepSeek-V3 family's code). State: the cache ``{"c", "r"}`` in
-    the model's dtype."""
+    the model's dtype. A prefill's attention (``latent.latent_prefill``)
+    stands under ``lm.mla.full``, as a full layer's of
+    :class:`DiffLatentMixer`."""
 
     config: Any
 
@@ -665,8 +667,9 @@ class LatentMixer(nn.Module):
                     q_n[:, 0], q_r[:, 0], cache, w_uk, w_uv, pos,
                     scale=scale, dtype=dtype)[:, None]
         else:
-            o = latent.latent_prefill(q_n, q_r, cache, w_uk, w_uv, pos,
-                                      lengths, scale=scale, dtype=dtype)
+            with jax.named_scope("lm.mla.full"):
+                o = latent.latent_prefill(q_n, q_r, cache, w_uk, w_uv, pos,
+                                          lengths, scale=scale, dtype=dtype)
         o = o.reshape(b, t, heads * d_v)
         if c.get("gated_attention", False):
             o = o * jax.nn.sigmoid(Linear(heads * d_v, dtype,

@@ -10,13 +10,16 @@ two forms of one formula:
   expanded from the cache a block of positions at a time inside
   ``attention.blockwise_attention``'s loop (never whole, and only the
   blocks some row has filled), and the stretch's queries attend to
-  every cached position up to their own. Where a key head has many
-  query rows a step (``prefill_kernel``: the TPU, and ``t × heads /
-  key heads`` from ``PREFILL_KERNEL_MIN_ROWS``) one Pallas kernel
+  every cached position up to their own. On the TPU, from
+  ``PREFILL_KERNEL_MIN_ROWS`` query rows a key head a step
+  (``prefill_kernel``: every latent model's prefill here, Motif's full
+  layers' 5,120 and the ungrouped models' 128-256) one Pallas kernel
   (``name="latent_prefill"``) does the same a query tile at a time:
   the block's keys and values expanded in VMEM from the latent, the
   tile's scores, running maximum and sum and float32 accumulator kept
-  there, the blocks past a row's last real query not read;
+  there, the blocks past a row's last real query not read; its block
+  and the key heads a grid step follow the step's shapes
+  (``prefill_form``);
 - ``latent_decode`` (one token): ``W_uk`` is absorbed into the query
   and ``W_uv`` applied to the attended latent, so a step reads the
   cache's latents alone. The latent is key and value at once (a
@@ -65,13 +68,23 @@ DECODE_BLOCK = 256   # cached positions a grid step of the decode kernel
 # tile 512x512 16.5 / 27.6, 512x1024 17.4 / 29.8, 512x256 19.6 / 33.3,
 # 384x1024 27.1 / 47.2, 256x1024 33.5 / 58.7, 256x256 33.1 / 57.9,
 # 1024x1024 38.4 / 44.3
-PREFILL_BLOCK = 512  # cached positions a grid step of the prefill kernel
+PREFILL_BLOCK = 512  # most cached positions a grid step of the prefill kernel
 PREFILL_TILE = 512   # queries a row a grid step of the prefill kernel
+# One layer's group of ungrouped heads (one query head a key head), ms
+# summed over a cell's steps weighted by its run visits a step, TPU v5e
+# (tools/latent_decode_ab.py --form prefill --at, PERF.md section 6):
+# block x key heads a grid step, against the scan's 259 / 78 / 347
+# for 32 rows x 128 queries x 64 heads over 1,280 and 768 positions and
+# 16 x 256 x 32 over 2,080: 256x8 174 / 49 / 202, 512x8 189 / 54 / 167,
+# 128x8 181 / 51 / 277, 256x4 182 / 52 / 207, 512x4 195 / 55 / 172,
+# 256x2 210 / 58 / 220, 256x1 262 / 70 / 253, 512x1 248 / 67 / 219: a
+# block of twice the tile (the step's 128 or 256 queries), 8 key heads
+PREFILL_KEY_HEADS = 8
 # query rows a key head a step (t x heads / key heads) from which the
-# prefill takes the kernel; below, the scan. The kernel also won at 128
-# and 256 rows (the other latent models' steps: 24.0 -> 10.9, 15.8 ->
-# 7.0, 10.4 -> 4.5 ms a group), which have not been measured end to end
-PREFILL_KERNEL_MIN_ROWS = 1024
+# prefill takes the kernel; below, the scan. Measured from 128 up (in
+# the chosen form the kernel won at every step of the three cells
+# above); fewer rows were never measured
+PREFILL_KERNEL_MIN_ROWS = 128
 
 
 def yarn_frequencies(dim: int, theta: float, scaling: Mapping) -> np.ndarray:
@@ -176,10 +189,22 @@ def _operand(x, dtype):
 def prefill_kernel(t: int, heads: int, key_heads: int) -> bool:
     """Whether a prefill of ``t`` queries a row over ``heads`` query heads
     grouped on ``key_heads`` takes the kernel: on the TPU, where a key
-    head has at least ``PREFILL_KERNEL_MIN_ROWS`` query rows a step.
-    Fewer, and a block's expansion (which the kernel makes once a query
-    tile and key head) costs as much as its attention."""
+    head has at least ``PREFILL_KERNEL_MIN_ROWS`` query rows a step."""
     return use_pallas() and t * heads // key_heads >= PREFILL_KERNEL_MIN_ROWS
+
+
+def prefill_form(t: int, per: int, groups: int, capacity: int):
+    """``(block, tile, key heads a grid step)`` of the prefill kernel for
+    ``t`` queries a row and ``per`` query heads on each of ``groups`` key
+    heads, over a cache of ``capacity`` positions: a block of at most
+    twice the tile (``t`` rounded up to 8), of which a first step, from
+    position 0, fills at most ``t``; and where a key head has one query
+    head, ``PREFILL_KEY_HEADS`` key heads a grid step (the most of them
+    that divide ``groups``), so that a block is expanded to their keys
+    and values in one wider product and read once for all."""
+    tile = min(PREFILL_TILE, -(-t // 8) * 8)
+    each = math.gcd(groups, PREFILL_KEY_HEADS) if per == 1 else 1
+    return min(PREFILL_BLOCK, 2 * tile, capacity), tile, each
 
 
 def latent_prefill(q_n, q_r, cache, w_uk, w_uv, pos, lengths, *,
@@ -237,17 +262,18 @@ def _expander(w_uk, w_uv, dtype):
 
 def _prefill_kernel(pos_ref, fill_ref, last_ref, qn_ref, qr_ref, c_ref,
                     r_ref, wk_ref, wv_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                    tile: int, block: int, scale: float):
-    """One row's tile of ``tile`` queries, for the ``J`` query heads of
-    one key head, against one block of ``block`` cached positions: the
-    block's latents expanded to the key head's keys and values (float32
-    out, rounded to the operands' dtype), the scores as the nope and rope
-    parts' two products, running maximum ``m``, sum ``l`` and output
-    ``acc`` in float32, ``p`` rounded to the operands' dtype for its
-    product with the values. A block past the tile's last real query is
-    not read (its index is held at the last block in use, which is not
-    fetched again) and does no work; a block wholly before the tile's
-    first query and the row's fill is not masked."""
+                    tile: int, block: int, scale: float, per: int):
+    """One row's tile of ``tile`` queries, for the query heads of ``E``
+    key heads (``per`` a key head), against one block of ``block`` cached
+    positions: the block's latents expanded to the ``E`` key heads' keys
+    and values in one product each (float32 out, rounded to the operands'
+    dtype), the scores as the nope and rope parts' two products, running
+    maximum ``m``, sum ``l`` and output ``acc`` in float32, ``p`` rounded
+    to the operands' dtype for its product with the values. A block past
+    the tile's last real query is not read (its index is held at the last
+    block in use, which is not fetched again) and does no work; a block
+    wholly before the tile's first query and the row's fill is not
+    masked."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -283,8 +309,12 @@ def _prefill_kernel(pos_ref, fill_ref, last_ref, qn_ref, qr_ref, c_ref,
             q_pos = first + jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
             seen = (along <= q_pos) & (along < fill)       # (tile, block)
         v = v.astype(dtype)
+        each = qn_ref.shape[0] // per           # key heads a grid step
+        nope, d_v = k_n.shape[1] // each, v.shape[1] // each
         for h in range(qn_ref.shape[0]):
-            s = (jax.lax.dot_general(qn_ref[h], k_n, contract,
+            g = h // per
+            k_h = k_n if each == 1 else k_n[:, g * nope:(g + 1) * nope]
+            s = (jax.lax.dot_general(qn_ref[h], k_h, contract,
                                      preferred_element_type=jnp.float32)
                  + jax.lax.dot_general(qr_ref[h], r, contract,
                                        preferred_element_type=jnp.float32)
@@ -297,7 +327,9 @@ def _prefill_kernel(pos_ref, fill_ref, last_ref, qn_ref, qr_ref, c_ref,
             p = jnp.exp(s - m_new[:, :1])
             l_ref[h] = shrink * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
             acc_ref[h] = shrink[:, :1] * acc_ref[h] + jnp.dot(
-                p.astype(dtype), v, preferred_element_type=jnp.float32)
+                p.astype(dtype),
+                v if each == 1 else v[:, g * d_v:(g + 1) * d_v],
+                preferred_element_type=jnp.float32)
             m_ref[h] = m_new
 
     whole = ((j + 1) * block <= first + 1) & ((j + 1) * block <= fill)
@@ -319,13 +351,14 @@ def _prefill_kernel(pos_ref, fill_ref, last_ref, qn_ref, qr_ref, c_ref,
 
 def _prefill_pallas(q_n, q_r, cache, w_uk, w_uv, pos, lengths, scale, dtype,
                     interpret: bool):
-    """``latent_prefill`` as one kernel over (row, key head, query tile)
-    and the cache's blocks: the queries laid out a key head's ``J`` heads
-    at a time, ``(B, key heads, J, T, .)``, and the weights a key head
-    at a time, ``(key heads, rank, .)``. A tile's last block is that of
-    its last real query; a tile with none (a row without a real token in
-    the stretch, or past a row's real ones) reads nothing and gives
-    zeros."""
+    """``latent_prefill`` as one kernel over (row, key heads, query tile)
+    and the cache's blocks, in the form ``prefill_form`` gives: ``E`` key
+    heads a grid step, the queries laid out their ``E × J`` heads at a
+    time, ``(B, key heads / E, E × J, T, .)``, and the weights ``E`` key
+    heads side by side, ``(key heads / E, rank, E × .)``. A tile's last
+    block is that of its last real query; a tile with none (a row without
+    a real token in the stretch, or past a row's real ones) reads nothing
+    and gives zeros."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -338,15 +371,23 @@ def _prefill_pallas(q_n, q_r, cache, w_uk, w_uv, pos, lengths, scale, dtype,
     rope, (rank, groups, d_v) = q_r.shape[-1], w_uv.shape
     per = heads // groups
     capacity = c.shape[1]
-    tile = min(PREFILL_TILE, -(-t // 8) * 8)
+    block, tile, each = prefill_form(t, per, groups, capacity)
     tiles = -(-t // tile)
-    block = min(PREFILL_BLOCK, capacity)
+    steps, width = groups // each, each * per     # grid steps, query heads
 
     def by_head(x):
-        # (B, T, heads, d) -> (B, key heads, J, tiles x tile, d)
-        x = placement_cast(x, dtype).reshape(b, t, groups, per, -1)
+        # (B, T, heads, d) -> (B, key heads / E, E x J, tiles x tile, d)
+        x = placement_cast(x, dtype).reshape(b, t, steps, width, -1)
         x = jnp.pad(x, ((0, 0), (0, tiles * tile - t)) + ((0, 0),) * 3)
         return jnp.transpose(x, (0, 2, 3, 1, 4))
+
+    def side_by_side(w):
+        # (rank, key heads, d) -> (key heads / E, rank, E x d)
+        w = jnp.transpose(placement_cast(w, dtype), (1, 0, 2))
+        if each == 1:
+            return w
+        return jnp.transpose(w.reshape(steps, each, rank, -1),
+                             (0, 2, 1, 3)).reshape(steps, rank, -1)
 
     pos = pos.astype(jnp.int32)
     fill = pos + lengths.astype(jnp.int32)
@@ -356,7 +397,7 @@ def _prefill_pallas(q_n, q_r, cache, w_uk, w_uv, pos, lengths, scale, dtype,
                      -1).reshape(-1)
 
     def by_tile(d):
-        return pl.BlockSpec((None, None, per, tile, d),
+        return pl.BlockSpec((None, None, width, tile, d),
                             lambda g0, g, i, j, *_: (g0, g, 0, i, 0))
 
     def by_block(d):
@@ -366,29 +407,30 @@ def _prefill_pallas(q_n, q_r, cache, w_uk, w_uv, pos, lengths, scale, dtype,
                 g0, jnp.minimum(j, jnp.maximum(last[g0 * tiles + i], 0)), 0))
 
     def by_key_head(d):
-        return pl.BlockSpec((None, rank, d), lambda g0, g, i, j, *_: (g, 0, 0))
+        return pl.BlockSpec((None, rank, each * d),
+                            lambda g0, g, i, j, *_: (g, 0, 0))
 
     # what a grid step holds, in bytes: the scratch, the output's and the
     # queries' two buffers, the cache's and the weights' two, and a few
     # (tile, block) float32 scores alive at once
     item = jnp.dtype(dtype).itemsize
-    vmem = (4 * per * tile * (2 * 128 + 3 * d_v)
-            + 2 * item * (per * tile * (nope + 128) + block * (rank + 128)
-                          + rank * (nope + d_v))
+    vmem = (4 * width * tile * (2 * 128 + 3 * d_v)
+            + 2 * item * (width * tile * (nope + 128) + block * (rank + 128)
+                          + each * rank * (nope + d_v))
             + 4 * 4 * tile * block)
     out = pl.pallas_call(
         functools.partial(_prefill_kernel, tile=tile, block=block,
-                          scale=scale),
+                          scale=scale, per=per),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(b, groups, tiles, pl.cdiv(capacity, block)),
+            grid=(b, steps, tiles, pl.cdiv(capacity, block)),
             in_specs=[by_tile(nope), by_tile(rope), by_block(rank),
                       by_block(rope), by_key_head(nope), by_key_head(d_v)],
             out_specs=by_tile(d_v),
-            scratch_shapes=[pltpu.VMEM((per, tile, 128), jnp.float32),
-                            pltpu.VMEM((per, tile, 128), jnp.float32),
-                            pltpu.VMEM((per, tile, d_v), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((b, groups, per, tiles * tile, d_v),
+            scratch_shapes=[pltpu.VMEM((width, tile, 128), jnp.float32),
+                            pltpu.VMEM((width, tile, 128), jnp.float32),
+                            pltpu.VMEM((width, tile, d_v), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, steps, width, tiles * tile, d_v),
                                        jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
@@ -397,8 +439,7 @@ def _prefill_pallas(q_n, q_r, cache, w_uk, w_uv, pos, lengths, scale, dtype,
         interpret=interpret,
         name="latent_prefill",
     )(pos, fill, last, by_head(q_n), by_head(q_r), c, r,
-      jnp.transpose(placement_cast(w_uk, dtype), (1, 0, 2)),
-      jnp.transpose(placement_cast(w_uv, dtype), (1, 0, 2)))
+      side_by_side(w_uk), side_by_side(w_uv))
     out = jnp.transpose(out, (0, 3, 1, 2, 4))[:, :t]
     return out.reshape(b, t, heads, d_v)
 

@@ -241,6 +241,36 @@ def test_the_decode_kernel_in_the_stage_equals_the_twin(params, monkeypatch):
                   - np.asarray(base.col("logprobs"))).max() < 2e-5
 
 
+def test_the_prefill_kernel_in_the_stage_equals_the_scan(params,
+                                                        monkeypatch):
+    """The whole stage with every latent layer's prefill through the
+    Pallas kernel, interpreted, in the form the rule gives this size (the
+    rule's bound lowered to it: 8 queries a step, blocks of 16 positions,
+    all 4 key heads a grid step), and the decode kernel: the scan's
+    tokens and log-probabilities, and each run visit counted once for
+    each of the three latent layers."""
+    from mmlspark_tpu.parallel import latent
+
+    col = _prompts()
+    base = _stage(params).transform(DataFrame({"prompt": col}))
+    monkeypatch.setattr(latent, "use_pallas", lambda: True)
+    monkeypatch.setattr(latent, "PREFILL_KERNEL_MIN_ROWS", 8)
+    assert latent.prefill_form(8, 1, 4, 128 + NEW) == (16, 8, 4)
+    for name in ("_decode_pallas", "_prefill_pallas"):
+        monkeypatch.setattr(latent, name, lambda *a, _real=getattr(
+            latent, name): _real(*a[:-1], True))
+    before = len(SINK.events)
+    out = _stage(params).transform(DataFrame({"prompt": col}))
+    assert np.array_equal(np.asarray(out.col("completion")),
+                          np.asarray(base.col("completion")))
+    assert np.abs(np.asarray(out.col("logprobs"))
+                  - np.asarray(base.col("logprobs"))).max() < 2e-5
+    counts = [r for r in SINK.events[before:]
+              if r.get("className") == "CausalLM"][-1]["counts"]
+    assert counts["fused_prefill_visits"] == 3 * counts[
+        "prefill_visits_run"] > 0
+
+
 def test_the_shares_add_up_to_the_uncut_layer(reference):
     """24 experts in 2 shares of 12: what the two chips compute, with
     the shared expert (which both compute alike) counted once, is the
@@ -369,7 +399,25 @@ def test_the_scopes_name_the_decode_and_the_write(params):
         lm_init_state(CFG, 2, 16)).as_text(debug_info=True)
     assert "lm.mla/mixer/lm.mla.decode" in text
     assert "lm.mla/mixer/lm.mla.write" in text
+    assert "lm.mla.full" not in text
     assert "lm.gdn" not in text
+
+
+def test_the_prefill_attention_stands_under_its_own_scope(params):
+    """A prefill's attention, kernel or scan, stands under
+    ``lm.mla.full`` inside ``lm.mla`` in every latent layer, as a full
+    layer's of grouped differential attention does."""
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.dl.backbones import lm_init_state, lm_module
+
+    text = jax.jit(lm_module(CFG).apply).lower(
+        params, jnp.zeros((2, 8), jnp.int32), jnp.full((2,), 8, jnp.int32),
+        lm_init_state(CFG, 2, 16)).as_text(debug_info=True)
+    for layer in range(CFG["num_hidden_layers"]):
+        assert f"layers_{layer}/lm.mla/mixer/lm.mla.full" in text
+    assert "lm.mla.decode" not in text
 
 
 def test_a_bfloat16_model_keeps_its_cache_bfloat16(params):

@@ -409,3 +409,47 @@ def test_the_configs_before_trace_the_programs_they_traced(name):
         for logits in (False, True)]
     assert tuple(hashlib.sha256(t.encode()).hexdigest()[:16]
                  for t in texts) == PROGRAMS[name]
+
+
+# sha256 (first 16 hex digits) of the jaxpr text of the programs the
+# latent prefill kernel's rule leaves as they were, at the cells' own
+# sizes (the config file's batchSize x maxLength in steps of
+# prefillChunk, the cell's new tokens) with every kernel's switch giving
+# the TPU's answer: Motif's lm_prefill (its full layers' kernel in the
+# form it had: blocks and tiles of 512, one key head a grid step) and
+# every cell's lm_generate without and with logits (a decode step is
+# latent_decode's), as the code traced them before the ungrouped latent
+# layers' prefill took the kernel
+CELL_PROGRAMS = {
+    "motif_3_beta": (128, "5ba59556abe6af39", "99d641fa2d4fe404",
+                     "74d47670e05272fe"),
+    "kimi_k2_6": (768, None, "ea5537023024c7d8", "a71070541adc28c9"),
+    "xing4_0_29b_a4b": (32, None, "d5c724cd1b9531fb", "1b8593b3c306758e"),
+    "gigachat3_5_432b": (256, None, "d4129d9ce8906c68", "e74ed20992374554"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CELL_PROGRAMS))
+def test_the_programs_the_prefill_rule_leaves_alone_trace_as_before(
+        name, monkeypatch):
+    from mmlspark_tpu.dl import causal_lm
+    from mmlspark_tpu.dl.backbones import lm_module, lm_param_shapes
+    from mmlspark_tpu.parallel import delta_rule, latent, retention
+
+    for kernels in (latent, retention, delta_rule):
+        monkeypatch.setattr(kernels, "use_pallas", lambda: True)
+    new, *pins = CELL_PROGRAMS[name]
+    file = load_json("configs", name + ".json")
+    config = {k: file[k] for k in file["model_keys"]}
+    module, shapes = lm_module(config), lm_param_shapes(config)
+    ids = jax.ShapeDtypeStruct((file["batchSize"], file["maxLength"]),
+                               jnp.int32)
+    lengths = jax.ShapeDtypeStruct((file["batchSize"],), jnp.int32)
+    prefill = causal_lm.lm_prefill_program(module, file["prefillChunk"], new)
+    last, state = jax.eval_shape(prefill, shapes, ids, lengths)
+    texts = [str(jax.make_jaxpr(prefill)(shapes, ids, lengths))] + [
+        str(jax.make_jaxpr(causal_lm.lm_generate_program(
+            module, new, logits))(shapes, last, state))
+        for logits in (False, True)]
+    got = [hashlib.sha256(t.encode()).hexdigest()[:16] for t in texts]
+    assert [g if pin else None for g, pin in zip(got, pins)] == pins

@@ -262,10 +262,10 @@ def _prefill_inputs(heads, groups, dtype, seed=7):
             normal(WIDE_RANK, groups, 128, std=0.1))
 
 
-def _prefill(inputs, pallas, dtype, **kw):
+def _prefill(inputs, pallas, dtype, pos=PREFILL_POS, **kw):
     q_n, q_r, cache, w_uk, w_uv = inputs
     return np.asarray(L.latent_prefill(
-        q_n, q_r, cache, w_uk, w_uv, jnp.array(PREFILL_POS),
+        q_n, q_r, cache, w_uk, w_uv, jnp.array(pos),
         jnp.array(PREFILL_LENGTHS), scale=0.1, dtype=dtype, pallas=pallas,
         interpret=pallas, **kw), np.float32)
 
@@ -280,25 +280,43 @@ def small_blocks(monkeypatch):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("heads,groups", [(10, 2), (4, 4)],
-                         ids=["grouped", "ungrouped"])
+@pytest.mark.parametrize("heads,groups,each,first", [
+    (10, 2, 1, False), (4, 4, 4, False), (4, 4, 4, True), (8, 8, 2, False)],
+    ids=["grouped", "ungrouped", "ungrouped first step",
+         "two key heads a step"])
 def test_prefill_kernel_equals_the_scan_and_reads_nothing_past_a_row(
-        small_blocks, heads, groups, dtype):
+        small_blocks, monkeypatch, heads, groups, each, first, dtype):
     """Every real query's output is the scan's, with the cache past each
     row's fill poisoned for the kernel (the scan would read the NaN into
     a zero weight); a row without a real token gives zeros. In bfloat16
     the two round the same operands and differ by where ``p`` is
     rounded (the scan's product on the CPU keeps it float32) and by the
-    order of the sums."""
+    order of the sums. Ungrouped heads go ``PREFILL_KEY_HEADS`` key heads
+    a grid step (``prefill_form``: all four, or two of eight), a block
+    expanded for them in one product and each head's keys and values a
+    slice of it; a first step puts
+    every row at position 0 in the rule's own form, one tile of 24
+    queries and one block of 48 positions, of which a row fills 24, 13
+    or none."""
+    pos = PREFILL_POS
+    monkeypatch.setattr(L, "PREFILL_KEY_HEADS", each)
+    if first:
+        monkeypatch.setattr(L, "PREFILL_BLOCK", 512)
+        monkeypatch.setattr(L, "PREFILL_TILE", 512)
+        pos = [0] * len(PREFILL_POS)
+        assert L.prefill_form(PREFILL_T, 1, groups, PREFILL_CAPACITY)[:2] == (
+            2 * PREFILL_T, PREFILL_T)
+    assert L.prefill_form(PREFILL_T, heads // groups, groups,
+                          PREFILL_CAPACITY)[2] == each
     inputs = _prefill_inputs(heads, groups, dtype)
-    want = _prefill(inputs, False, dtype)
+    want = _prefill(inputs, False, dtype, pos)
     poisoned = {k: np.array(v, np.float32) for k, v in inputs[2].items()}
-    for row, (p, n) in enumerate(zip(PREFILL_POS, PREFILL_LENGTHS)):
+    for row, (p, n) in enumerate(zip(pos, PREFILL_LENGTHS)):
         for v in poisoned.values():
             v[row, p + n:] = np.nan
     got = _prefill(inputs[:2] + ({k: jnp.asarray(v, dtype)
                                   for k, v in poisoned.items()},)
-                   + inputs[3:], True, dtype)
+                   + inputs[3:], True, dtype, pos)
     scale = np.abs(want).max()
     tol = 5 * TOL if dtype == jnp.float32 else 0.01
     for row, n in enumerate(PREFILL_LENGTHS):
@@ -331,11 +349,15 @@ def test_prefill_kernel_attends_to_the_diagonal_and_to_the_fill(
 
 
 def test_the_rule_takes_the_kernel_at_motifs_shape_alone(monkeypatch):
-    """On the TPU the kernel is taken by the shapes alone: Motif's full
-    layers (1,024 queries a step, 80 heads over 16 key heads) take it;
-    Kimi's, Xing's and GigaChat's latent layers (128-256 queries, no
-    grouping) keep the scan; window layers never run the latent
-    prefill. Off the TPU nothing takes it."""
+    """On the TPU the kernel is taken by the shapes alone, now at every
+    latent cell's: Motif's full layers (1,024 queries a step, 80 heads
+    over 16 key heads: 5,120 rows a key head), Kimi's, Xing's and
+    GigaChat's latent layers (128 and 256 queries, no grouping); window
+    layers never run the latent prefill, and under 128 rows a key head
+    the scan stays. Off the TPU nothing takes it. The form follows the
+    step's shapes: Motif's is the one it had, blocks and tiles of 512
+    and one key head a grid step; the ungrouped shapes take blocks of
+    twice their queries and eight key heads a grid step."""
     from benchmark.lookup import load_json
     from mmlspark_tpu.dl.backbones import lm_fused_prefill_layers, lm_module
 
@@ -348,7 +370,14 @@ def test_the_rule_takes_the_kernel_at_motifs_shape_alone(monkeypatch):
              "gigachat3_5_432b", "brumby_14b")
     assert [fused(n) for n in names] == [0] * 5
     monkeypatch.setattr(L, "use_pallas", lambda: True)
-    assert [fused(n) for n in names] == [2, 0, 0, 0, 0]
+    assert [fused(n) for n in names] == [2, 5, 6, 1, 0]
     assert L.prefill_kernel(1024, 80, 16)
     for t, heads in ((128, 64), (256, 32)):
-        assert not L.prefill_kernel(t, heads, heads)
+        assert L.prefill_kernel(t, heads, heads)
+    assert not L.prefill_kernel(64, 64, 64)
+    assert not L.prefill_kernel(16, 80, 16)
+    # (t, query heads a key head, key heads, capacity) -> form
+    assert L.prefill_form(1024, 5, 16, 16512) == (512, 512, 1)
+    assert L.prefill_form(128, 1, 64, 1280) == (256, 128, 8)
+    assert L.prefill_form(128, 1, 64, 768) == (256, 128, 8)
+    assert L.prefill_form(256, 1, 32, 2080) == (512, 256, 8)

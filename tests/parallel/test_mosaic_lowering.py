@@ -729,17 +729,25 @@ def test_latent_decode_kernel_lowers_to_mosaic(rows, capacity, heads):
 
 
 @pytest.mark.parametrize(
-    "rows,t,capacity",
-    [(4, 1024, 16512), (4, 128, 1024)],
-    ids=["motif_3_beta.long_read", "a short step"])
-def test_latent_prefill_kernel_lowers_to_mosaic(rows, t, capacity):
-    """The full layers' prefill kernel at Motif's widths (80 query heads
-    over 16 key heads of 128 + 64 rotated, latent 512, values of 128,
-    bfloat16): a group of the cell's step of 1,024 queries against its
-    cache of 16,512 positions (a partial last block), and a short step:
-    the three scalar-prefetched vectors in the block index, the
-    expansion of a block by the key head's weights, the transposed
-    products and the 64-wide rotated parts go through Mosaic."""
+    "rows,t,heads,key_heads,capacity",
+    [(4, 1024, 80, 16, 16512), (4, 128, 80, 16, 1024),
+     (32, 128, 64, 64, 1280), (32, 128, 64, 64, 768),
+     (16, 256, 32, 32, 2080)],
+    ids=["motif_3_beta.long_read", "a short step", "kimi_k2_6.reason",
+         "gigachat3_5_432b.generate", "xing4_0_29b_a4b.extract"])
+def test_latent_prefill_kernel_lowers_to_mosaic(rows, t, heads, key_heads,
+                                                capacity):
+    """The prefill kernel, in the form the rule gives each shape, at the
+    cells' widths (latent 512, 128 + 64 rotated a query and key head,
+    values of 128, bfloat16): Motif's full layers (80 query heads over
+    16 key heads; a group of the cell's step of 1,024 queries against its
+    cache of 16,512 positions, a partial last block, and a short step),
+    and a group of the other latent cells' steps, one query head a key
+    head (64 heads and 128 queries against 1,280 and 768 positions, 32
+    heads and 256 queries against 2,080): the three scalar-prefetched
+    vectors in the block index, the expansion of a block by its key
+    heads' weights, the transposed products and the 64-wide rotated
+    parts go through Mosaic."""
     import jax
     import jax.numpy as jnp
 
@@ -753,12 +761,12 @@ def test_latent_prefill_kernel_lowers_to_mosaic(rows, t, capacity):
                                      pallas=True)
 
     txt = _lower_tpu(
-        prefill, jax.ShapeDtypeStruct((rows, t, 80, 128), bf),
-        jax.ShapeDtypeStruct((rows, t, 80, 64), bf),
+        prefill, jax.ShapeDtypeStruct((rows, t, heads, 128), bf),
+        jax.ShapeDtypeStruct((rows, t, heads, 64), bf),
         jax.ShapeDtypeStruct((rows, capacity, 512), bf),
         jax.ShapeDtypeStruct((rows, capacity, 64), bf),
-        jax.ShapeDtypeStruct((512, 16, 128), bf),
-        jax.ShapeDtypeStruct((512, 16, 128), bf),
+        jax.ShapeDtypeStruct((512, key_heads, 128), bf),
+        jax.ShapeDtypeStruct((512, key_heads, 128), bf),
         jax.ShapeDtypeStruct((rows,), jnp.int32),
         jax.ShapeDtypeStruct((rows,), jnp.int32))
     assert "tpu_custom_call" in txt and "latent_prefill" in txt

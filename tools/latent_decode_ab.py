@@ -4,15 +4,16 @@ against ``jax.numpy`` twin and scatter against slices, by shape: the
 measurement behind ``latent.DECODE_ROWS`` and ``DECODE_BLOCK`` (PERF.md
 section 6, PR 35). ``--form prefill``: one layer's ``latent_prefill``,
 the scan against the kernel by block and tile: the measurement behind
-``latent.PREFILL_BLOCK``, ``PREFILL_TILE`` and
-``PREFILL_KERNEL_MIN_ROWS`` (PERF.md section 6, PR 42).
+``latent.prefill_form`` and ``PREFILL_KERNEL_MIN_ROWS`` (PERF.md
+section 6).
 
 usage: python3 tools/latent_decode_ab.py [--shapes 512x1280,128x768]
            [--tiles 8x128,8x256,4x256,16x128] [--fill 0.5] [--steps 20]
            [--out chiprun_out/latent_decode_ab.jsonl]
        python3 tools/latent_decode_ab.py --form prefill
            [--shapes 4x1024x80x16x16512,32x128x64x64x1280]
-           [--tiles 512x512,512x1024,512x256] [--fill 0.5] [--steps 5]
+           [--tiles 512x512,512x1024x1,128x128x8,rule] [--fill 0.5]
+           [--at 0,1,2,3] [--steps 5]
 
 Decode: a shape is rows x capacity at the published widths (64 heads,
 rank 512, rope 64, bfloat16). Rows' positions are a sorted ramp about
@@ -25,14 +26,18 @@ less one step's), the floor of
 difference from the twin inside that scan.
 
 Prefill: a shape is rows x queries x heads x key heads x capacity
-(rank 512, nope and d_v 128, rope 64, bfloat16), a tile block x
-queries. Every row's stretch is real; its first position a sorted ramp
-from half to all of ``fill`` of what the capacity leaves, a multiple
-of the stretch (a group of a length-sorted batch at a later step).
-One JSON line a variant: the least of ``steps`` timed calls of one
-jitted layer (host clock, each waited for), the floor of its (query,
-key) pairs in the expanded form at the bf16 peak, and the largest
-difference from the scan.
+(rank 512, nope and d_v 128, rope 64, bfloat16), a form of the kernel
+block x queries, and x key heads a grid step where given (else 1);
+``rule`` is the form ``latent.prefill_form`` chooses. Every row's
+stretch is real; its first position a sorted ramp from half to all of
+``fill`` of what the capacity leaves, a multiple of the stretch (a
+group of a length-sorted batch at a later step), or with ``--at`` the
+same for every row, ``k`` stretches in, once for each ``k`` listed (a
+cell's step ``k + 1``: ``--at 0`` is a first step, from position 0).
+One JSON line a variant and start: the least of ``steps`` timed calls
+of one jitted layer (host clock, each waited for), the floor of its
+(query, key) pairs in the expanded form at the bf16 peak, and the
+largest difference from the scan.
 
 Exits 2 off the TPU unless JAX_PLATFORMS=cpu is set (a rehearsal:
 Pallas interpreted, times meaningless)."""
@@ -56,6 +61,7 @@ def main(argv=None) -> int:
     ap.add_argument("--shapes")
     ap.add_argument("--tiles")
     ap.add_argument("--fill", type=float, default=0.5)
+    ap.add_argument("--at")
     ap.add_argument("--steps", type=int)
     ap.add_argument("--out")
     args = ap.parse_args(argv)
@@ -185,6 +191,7 @@ def _prefill_ab(args, rehearsal):
     from mmlspark_tpu.parallel import latent
 
     bf = jnp.bfloat16
+    rule = latent.prefill_form
     lines = []
     for shape in args.shapes.split(","):
         b, t, heads, groups, cap = (int(v) for v in shape.split("x"))
@@ -197,17 +204,21 @@ def _prefill_ab(args, rehearsal):
                 ).astype(bf)
         w_uv = (jax.random.normal(key[5], (RANK, groups, DV)) * .05
                 ).astype(bf)
-        room = (cap - t) // t
-        pos = np.round(np.linspace(0.5, 1.0, b) * args.fill * room) * t
-        pos = jnp.asarray(pos, jnp.int32)
         lengths = jnp.full((b,), t, jnp.int32)
+        if args.at:              # every row at the same step of the cell
+            starts = [np.full(b, int(k) * t) for k in args.at.split(",")]
+        else:
+            room = (cap - t) // t
+            starts = [np.round(np.linspace(0.5, 1.0, b) * args.fill * room)
+                      * t]
+        starts = [jnp.asarray(pos, jnp.int32) for pos in starts]
 
         def layer(pallas):
             return jax.jit(lambda q_n, q_r, cache, pos: latent.latent_prefill(
                 q_n, q_r, cache, w_uk, w_uv, pos, lengths, scale=0.1,
                 dtype=bf, pallas=pallas, interpret=rehearsal and pallas))
 
-        def timed(fn):
+        def timed(fn, pos):
             out = jax.block_until_ready(fn(q_n, q_r, cache, pos))  # compiles
             best = float("inf")
             for _ in range(args.steps):
@@ -216,29 +227,47 @@ def _prefill_ab(args, rehearsal):
                 best = min(best, time.perf_counter() - start)
             return best, out
 
-        pairs = float(sum(np.asarray(pos, np.int64) * t
-                          + t * (t + 1) // 2))
-        flops = pairs * heads * 2.0 * (NOPE + ROPE + DV)
-        floor = (None if rehearsal else opcount.least_seconds(
-            flops, 0.0, opcount.peaks(jax.devices()[0].device_kind))[0])
-        base = {"shape": shape, "pos": np.asarray(pos).tolist(),
-                "rows_a_key_head": t * heads // groups, "floor_s": floor}
-        scan_s, scan = timed(layer(False))
-        lines.append(dict(base, variant="scan", call_s=scan_s))
-        for tile in args.tiles.split(","):
-            latent.PREFILL_BLOCK, latent.PREFILL_TILE = (
-                int(v) for v in tile.split("x"))
-            try:
-                s, got = timed(layer(True))
-            except Exception as e:            # what Mosaic refused
-                lines.append(dict(base, variant="kernel " + tile,
-                                  refused=str(e)[:300]))
-                continue
-            lines.append(dict(
-                base, variant="kernel " + tile, call_s=s,
-                max_abs_diff_from_scan=float(jnp.abs(got - scan).max()),
-                scan_scale=float(jnp.abs(scan).max())))
-        for line in lines[-(1 + len(args.tiles.split(","))):]:
+        def base(pos):
+            pairs = float(sum(np.asarray(pos, np.int64) * t
+                              + t * (t + 1) // 2))
+            flops = pairs * heads * 2.0 * (NOPE + ROPE + DV)
+            floor = (None if rehearsal else opcount.least_seconds(
+                flops, 0.0, opcount.peaks(jax.devices()[0].device_kind))[0])
+            return {"shape": shape, "first": int(pos.min()),
+                    "last": int(pos.max()), "rows_a_key_head":
+                    t * heads // groups, "floor_s": floor}
+
+        scan_fn, scans, done = layer(False), [], len(lines)
+        for pos in starts:
+            s, out = timed(scan_fn, pos)
+            scans.append(out)
+            lines.append(dict(base(pos), variant="scan", call_s=s))
+        # block x tile [x key heads a grid step]; "rule": prefill_form's
+        for form in args.tiles.split(","):
+            if form == "rule":
+                latent.prefill_form = rule
+            else:
+                block, tile, each = (int(v) for v in (form + "x1").split(
+                    "x")[:3])
+                if groups % each:
+                    continue
+                latent.prefill_form = lambda t_, per, groups_, cap_, f=(
+                    block, tile, each): (min(f[0], cap_),
+                                         min(f[1], -(-t_ // 8) * 8), f[2])
+            fn = layer(True)
+            for pos, scan in zip(starts, scans):
+                try:
+                    s, got = timed(fn, pos)
+                except Exception as e:        # what Mosaic refused
+                    lines.append(dict(base(pos), variant="kernel " + form,
+                                      refused=str(e)[:300]))
+                    break
+                lines.append(dict(
+                    base(pos), variant="kernel " + form, call_s=s,
+                    max_abs_diff_from_scan=float(jnp.abs(got - scan).max()),
+                    scan_scale=float(jnp.abs(scan).max())))
+        latent.prefill_form = rule
+        for line in lines[done:]:
             print(json.dumps(line), flush=True)
     return lines
 
