@@ -4,17 +4,44 @@ The reference fine-tunes torchvision/HF checkpoints pulled from the
 network (dl/DeepVisionClassifier.py backbone param). This environment is
 zero-egress, so the zoo is built in-repo: a compact ResNet family and a
 transformer encoder, both TPU-shaped (NHWC convs, bf16-friendly widths,
-optional ring attention for long sequences), and two causal decoders
-that carry a state from one forward pass to the next:
-:class:`RetentionLM` (RMSNorm, rotary positions, grouped heads, SwiGLU;
-every layer mixes the sequence by power retention) and
-:class:`HybridLM` (layers that mix by a gated delta rule or by latent
-attention over a cache, over a dense SwiGLU or sparse experts, chosen
-by the layer's index; the norm, its placement, the attention's gate
-and the SwiGLU's limit read from the config; for ``Motif`` grouped
-differential attention on the latent cache, window layers on a ring,
-PolyNorm MLPs). ``LM_MODELS`` maps a config's ``model_type`` to its
-class.
+optional ring attention for long sequences), and one causal decoder
+that carries a state from one forward pass to the next,
+:class:`HybridLM`, whose layers the config chooses.
+
+A decoder's config is the model's own ``config.json`` as a dict;
+``model_type`` names its family (``FAMILY_DEFAULTS``; a config without
+the key is ``brumby``'s), and a family reads these keys:
+
+- ``brumby``: every layer a :class:`DecoderBlock` (power retention over
+  grouped heads, then SwiGLU): ``hidden_size``, ``num_attention_heads``,
+  ``num_key_value_heads``, ``head_dim``, ``intermediate_size``,
+  ``vocab_size``, ``num_hidden_layers``, ``rms_norm_eps``,
+  ``rope_theta``, ``torch_dtype``.
+- ``gigachat3_5`` and ``kimi_k2``: :class:`HybridBlock` layers, latent
+  attention where ``full_attention_layers`` lists the layer (every
+  layer where the key is absent), else the gated delta rule (its
+  ``linear_*`` keys), over a dense SwiGLU under
+  ``first_k_dense_replace``, else sparse experts: ``hidden_size``,
+  ``num_hidden_layers``, ``intermediate_size``,
+  ``moe_intermediate_size``, ``n_routed_experts``,
+  ``n_shared_experts``, ``num_experts_per_tok``,
+  ``routed_scaling_factor``, ``q_lora_rank``, ``kv_lora_rank``,
+  ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``,
+  ``num_attention_heads``, ``rope_theta``, ``rope_scaling``,
+  ``rms_norm_eps``, ``vocab_size``, ``torch_dtype``; where the config
+  states them, ``norm_type``, ``layernorm_type``, ``gated_attention``,
+  ``swiglu_limit``, ``hidden_clamp``; and ``experts_held`` and
+  ``router_experts`` where this chip holds a share of the experts.
+- ``xing4_0``: ``kimi_k2``'s keys and the residual path's
+  (``parallel/hyper.py``): ``hc_mult`` streams a token,
+  ``hc_sinkhorn_iters``, ``hc_eps``, ``mhc_h_res_clamp_min``,
+  ``mhc_h_res_clamp_max``.
+- ``Motif``: its own ``config.json``'s keys, read through
+  ``FAMILY_KEYS``: ``attention_cls`` ``gdla`` (grouped differential
+  attention on the latent cache), ``num_noise_heads``,
+  ``sliding_window`` and its pattern (window layers on a ring),
+  ``hidden_act`` ``poly_norm``, ``mhc_*``; ``published_layers`` where a
+  cut keeps some of the published layers.
 """
 
 from __future__ import annotations
@@ -191,11 +218,27 @@ def _scale(module, name, width):
 
 
 class DecoderBlock(nn.Module):
-    """One decoder layer: pre-norm power retention over grouped heads
+    """One ``brumby`` layer: pre-norm power retention over grouped heads
     (``parallel/retention.py``), then pre-norm SwiGLU. ``config`` holds
-    the keys of the model's ``config.json``."""
+    the keys of the model's ``config.json``; ``index`` is the layer's
+    (every layer of the family is alike). Its leaves keep the flat
+    names of the family's checkpoint (``attn_norm``, ``q_proj/kernel``,
+    ``gate_proj/kernel``, ...)."""
 
     config: Any
+    index: int = 0
+
+    def sparse(self) -> bool:
+        return False
+
+    def init_state(self, batch, capacity):
+        """The layer's retention state, zero; no cache (``capacity``
+        means nothing to a state that does not grow)."""
+        from mmlspark_tpu.parallel import retention
+
+        c = self.config
+        return retention.init_state(batch, c["num_key_value_heads"],
+                                    c["head_dim"])
 
     @nn.compact
     def __call__(self, h, positions, lengths, state):
@@ -205,14 +248,6 @@ class DecoderBlock(nn.Module):
         heads, kv = c["num_attention_heads"], c["num_key_value_heads"]
         d, eps, dtype = c["head_dim"], c["rms_norm_eps"], lm_dtype(c)
         b, t, _ = h.shape
-
-        def rounded(x):
-            # q, k, v carry the model's precision but stay float32 for
-            # the state's arithmetic; reduce_precision is the rounding
-            # that XLA may not drop (a float32 -> bfloat16 -> float32
-            # pair of converts it may, as excess precision)
-            info = jnp.finfo(dtype)
-            return jax.lax.reduce_precision(x, info.nexp, info.nmant)
 
         with jax.named_scope("lm.retention"):
             x = rms_norm(h, _scale(self, "attn_norm", h.shape[-1]), eps)
@@ -230,7 +265,7 @@ class DecoderBlock(nn.Module):
                 Linear(kv, dtype, name="g_proj")(x)
                 + self.param("g_bias", nn.initializers.constant(4.0),
                              (kv,), jnp.float32))
-            q, k, v = rounded(q), rounded(k), rounded(v)
+            q, k, v = (_rounded(a, dtype) for a in (q, k, v))
             scale = c.get("retention_scale", d ** -0.5)
             ret_eps = c.get("retention_eps", retention.EPS)
             if t == 1:
@@ -252,18 +287,18 @@ class DecoderBlock(nn.Module):
             up = Linear(c["intermediate_size"], dtype, name="up_proj")(x)
             h = h + Linear(h.shape[-1], dtype, name="down_proj")(
                 nn.silu(gate) * up)
-        return h, state
+        return h, state, None
 
 
 def lm_module(config: Mapping[str, Any]):
-    """The language model a config names (``model_type``; a config
-    without the key is a :class:`RetentionLM`'s), given the config with
-    the keys its code reads (``family_config``)."""
+    """The language model of a config's family (``model_type``, one of
+    ``FAMILY_DEFAULTS``; a config without the key is ``brumby``'s),
+    given the config with the keys its code reads (``family_config``)."""
     kind = config.get("model_type", "brumby")
-    if kind not in LM_MODELS:
+    if kind not in FAMILY_DEFAULTS:
         raise ValueError(f"model_type {kind!r} is not one of "
-                         f"{sorted(LM_MODELS)}")
-    return LM_MODELS[kind](family_config(config))
+                         f"{sorted(FAMILY_DEFAULTS)}")
+    return HybridLM(family_config(config))
 
 
 def lm_init_state(config: Mapping[str, Any], batch: int, capacity: int = 0):
@@ -272,7 +307,7 @@ def lm_init_state(config: Mapping[str, Any], batch: int, capacity: int = 0):
     cache empty with room for ``capacity`` positions (a window layer's
     ring: its window's, whatever ``capacity``)."""
     module = lm_module(config)
-    return type(module).init_state(module.config, batch, capacity)
+    return module.init_state(module.config, batch, capacity)
 
 
 def lm_state_bytes(config: Mapping[str, Any], batch: int,
@@ -291,48 +326,6 @@ def lm_state_bytes(config: Mapping[str, Any], batch: int,
     return {"state": nbytes(empty), "cache": nbytes(state) - nbytes(empty)}
 
 
-def lm_hidden(module, params, ids, lengths, state):
-    """``module.apply(params, ids, lengths, state, method="hidden")``,
-    through the model's own way of bounding a long stretch's
-    activations where it has one (``HybridLM.hidden_in_groups``)."""
-    grouped = getattr(type(module), "hidden_in_groups", None)
-    if grouped is not None:
-        return grouped(module, params, ids, lengths, state)
-    return module.apply(params, ids, lengths, state, method="hidden")
-
-
-def lm_hidden_visits(module, lengths, t):
-    """``(visits, run)`` of one ``lm_hidden`` over ``t`` tokens a row of
-    which ``lengths`` (a ``numpy`` array) are real: the groups of rows
-    the model would absorb them in, and those inside its loop's bounds.
-    A model with no group loop, and a stretch of one group, is one
-    visit, run."""
-    model = type(module)
-    groups = (model.row_groups(len(lengths), t)
-              if hasattr(model, "hidden_in_groups") else 1)
-    if groups == 1:
-        return 1, 1
-    first, stop = model.active_groups(lengths, groups)
-    return groups, int(stop - first)
-
-
-def lm_fused_prefill_layers(module, t: int) -> int:
-    """The layers whose prefill of ``t`` tokens a row runs the latent
-    prefill kernel (``latent.prefill_kernel``): full latent layers whose
-    key heads have enough query rows a step, on the TPU."""
-    from mmlspark_tpu.parallel import latent
-
-    if not isinstance(module, HybridLM) or t == 1:
-        return 0
-    c = module.config
-    heads = c["num_attention_heads"]
-    key_heads = (c["num_key_value_heads"]
-                 if c.get("attention_cls") == "gdla" else heads)
-    return sum(block.latent() and not block.window()
-               and latent.prefill_kernel(t, heads, key_heads)
-               for block in HybridLM._blocks(c))
-
-
 def lm_init_params(config: Mapping[str, Any], seed: int = 0):
     """Freshly initialised parameters of the config's model."""
     return lm_module(config).init(
@@ -345,76 +338,9 @@ def lm_param_shapes(config: Mapping[str, Any]):
     return jax.eval_shape(lambda: lm_init_params(config))
 
 
-class RetentionLM(nn.Module):
-    """Causal language model of :class:`DecoderBlock` layers.
-
-    ``apply(params, ids, lengths, state) -> (logits_last, state)``:
-    ``ids`` ``(B, T)`` are the next ``T`` tokens of each sequence,
-    ``lengths`` ``(B,)`` how many of them are real (padding follows
-    them and leaves the state alone); ``state`` is
-    ``lm_init_state(config, B)`` or what an earlier call returned (``pos``: tokens absorbed so far;
-    ``layers``: each layer's retention state, float32). ``logits_last``
-    ``(B, vocab)`` float32 are the logits after each row's last real
-    token of this call (undefined for a row with none).
-    ``method="hidden"`` stops before the head, ``method="head"`` is the
-    final norm and the head alone, so a prompt fed in several calls
-    pays for the head once."""
-
-    config: Any
-
-    @staticmethod
-    def init_state(config, batch, capacity=0):
-        """Every layer's retention state, zero; no cache (``capacity``
-        means nothing to a model whose state does not grow)."""
-        from mmlspark_tpu.parallel import retention
-
-        return {"pos": jnp.zeros((batch,), jnp.int32),
-                "layers": [retention.init_state(
-                    batch, config["num_key_value_heads"], config["head_dim"])
-                    for _ in range(config["num_hidden_layers"])]}
-
-    def setup(self):
-        c = self.config
-        self.embedding = self.param(
-            "embedding", nn.initializers.normal(0.02),
-            (c["vocab_size"], c["hidden_size"]), lm_dtype(c))
-        self.layers = [DecoderBlock(c, name=f"layers_{i}")
-                       for i in range(c["num_hidden_layers"])]
-        self.final_norm = self.param("final_norm", nn.initializers.ones,
-                                     (c["hidden_size"],), jnp.float32)
-        self.lm_head = Linear(c["vocab_size"], lm_dtype(c))
-
-    def hidden(self, ids, lengths, state, every=False):
-        """Residual after the last layer at each row's last real token
-        ``(B, hidden)``, or with ``every`` at all ``T`` positions."""
-        with jax.named_scope("lm.embed"):
-            h = jnp.take(self.embedding, ids, axis=0).astype(jnp.float32)
-        positions = state["pos"][:, None] + jnp.arange(ids.shape[1])
-        layers = []
-        for block, layer_state in zip(self.layers, state["layers"]):
-            h, layer_state = block(h, positions, lengths, layer_state)
-            layers.append(layer_state)
-        if not every:
-            with jax.named_scope("lm.last"):
-                last = jnp.clip(lengths - 1, 0, ids.shape[1] - 1)
-                h = jnp.take_along_axis(h, last[:, None, None],
-                                        axis=1)[:, 0]
-        return h, {"pos": state["pos"] + lengths, "layers": layers}
-
-    def head(self, h):
-        with jax.named_scope("lm.head"):
-            return self.lm_head(rms_norm(h, self.final_norm,
-                                         self.config["rms_norm_eps"]))
-
-    def __call__(self, ids, lengths, state, every=False):
-        h, state = self.hidden(ids, lengths, state, every)
-        return self.head(h), state
-
-
 # ---------------------------------------------------------------------
-# hybrid decoder: delta-rule and latent-attention layers over a dense
-# SwiGLU or sparse experts (config keys of ``model_type`` gigachat3_5,
-# kimi_k2 and xing4_0), the residual one vector a token or, with
+# the hybrid layer: delta-rule and latent-attention mixers over a dense
+# SwiGLU or sparse experts, the residual one vector a token or, with
 # ``hc_mult``, that many streams (``parallel/hyper.py``)
 
 
@@ -431,12 +357,16 @@ def _centred(module, name, width):
     return module.param(name, nn.initializers.zeros, (width,), jnp.float32)
 
 
-# what a family's code does where its config.json may be silent; a
-# config without these keys and of another family has plain RMSNorm
-# before each sub-layer and none after, a selection bias on the router
-# and, with a residual of several streams, xing4_0's epsilon and clamp
-FAMILY_DEFAULTS = {"gigachat3_5": {"norm_type": "ZeroCenteredGatedNorm",
+# the families, and what a family's code does where its config.json
+# may be silent; a config without these keys has plain RMSNorm before
+# each sub-layer and none after, a selection bias on the router and,
+# with a residual of several streams, xing4_0's epsilon and clamp.
+# ``block`` ``retention``: every layer a DecoderBlock (else a
+# HybridBlock), read from this table alone and never from a config
+FAMILY_DEFAULTS = {"brumby": {"block": "retention"},
+                   "gigachat3_5": {"norm_type": "ZeroCenteredGatedNorm",
                                    "layernorm_type": "pre_post"},
+                   "kimi_k2": {}, "xing4_0": {},
                    "Motif": {"hc_eps": 1e-6, "mhc_h_res_clamp_min": -30,
                              "mhc_h_res_clamp_max": 30,
                              "selection_bias": False}}
@@ -472,7 +402,7 @@ def family_config(config: Mapping[str, Any]) -> dict:
 
 def _setting(config, key):
     return config.get(key, FAMILY_DEFAULTS.get(
-        config.get("model_type"), {}).get(key))
+        config.get("model_type", "brumby"), {}).get(key))
 
 
 def _gated(config) -> bool:
@@ -500,8 +430,10 @@ def block_norm(module, name, x, config):
 
 
 def _rounded(x, dtype):
-    """``x`` at the model's precision, kept float32 (see
-    ``DecoderBlock``: the rounding XLA may not drop)."""
+    """``x`` at the model's precision, kept float32 for the state's
+    arithmetic: ``reduce_precision`` is the rounding that XLA may not
+    drop (a float32 -> bfloat16 -> float32 pair of converts it may, as
+    excess precision)."""
     info = jnp.finfo(dtype)
     return jax.lax.reduce_precision(x, info.nexp, info.nmant)
 
@@ -990,45 +922,94 @@ class HybridBlock(nn.Module):
 
 
 class HybridLM(nn.Module):
-    """Causal language model of :class:`HybridBlock` layers, with
-    :class:`RetentionLM`'s contract (``apply``, ``method="hidden"``,
-    ``method="head"``). Its state holds two kinds side by side: a
-    delta-rule layer's fixed recurrent state and a latent layer's
-    cache, whose capacity ``lm_init_state`` is given; and ``experts``,
-    what the expert layers served since the state was empty: ``pairs``
-    ``(expert layers, experts held)`` and ``dropped``, and where the
-    held share takes the one-pass combine (``experts.one_pass_combine``)
-    ``combine_rows``, the rows that pass read back.
+    """Causal language model: an embedding, the config's layers
+    (``_blocks``: :class:`DecoderBlock` where the family's ``block`` is
+    ``retention``, else :class:`HybridBlock`), a final norm and a head.
 
-    With ``hc_mult`` in the config (``model_type`` ``xing4_0``) the
-    residual between the layers is that many streams a token: the
-    embedding is copied into each before the first layer and they are
-    summed after the last (of each row's last real token alone, unless
-    ``every``), so what ``hidden`` returns, and ``hidden_in_groups``
-    with it, has the one shape."""
+    ``apply(params, ids, lengths, state) -> (logits_last, state)``:
+    ``ids`` ``(B, T)`` are the next ``T`` tokens of each sequence,
+    ``lengths`` ``(B,)`` how many of them are real (padding follows
+    them and leaves the state alone); ``state`` is
+    ``lm_init_state(config, B, capacity)`` or what an earlier call
+    returned. ``logits_last`` ``(B, vocab)`` float32 are the logits
+    after each row's last real token of this call (undefined for a row
+    with none). ``method="hidden"`` stops before the head,
+    ``method="head"`` is the final norm and the head alone, so a prompt
+    fed in several calls pays for the head once.
+
+    The state holds ``pos``, the tokens each row has absorbed;
+    ``layers``, each layer's own: a retention or delta-rule layer's
+    fixed recurrent state (float32), a latent layer's cache of
+    ``capacity`` positions, a window layer's ring; and where some layer
+    is sparse ``experts``, what the expert layers served since the
+    state was empty: ``pairs`` ``(expert layers, experts held)`` and
+    ``dropped``. ``counts`` reads from a state what the stage reports.
+
+    With ``hc_mult`` in the config the residual between the layers is
+    that many streams a token: the embedding is copied into each before
+    the first layer and they are summed after the last (of each row's
+    last real token alone, unless ``every``), so what ``hidden``
+    returns, and ``hidden_in_groups`` with it, has the one shape."""
 
     config: Any
 
+    GROUP_TOKENS = 4096
+
     @staticmethod
     def _blocks(config):
-        return [HybridBlock(config, i, name=f"layers_{i}")
+        family = FAMILY_DEFAULTS.get(config.get("model_type", "brumby"), {})
+        block = (DecoderBlock if family.get("block") == "retention"
+                 else HybridBlock)
+        return [block(config, i, name=f"layers_{i}")
                 for i in range(config["num_hidden_layers"])]
 
     @staticmethod
     def init_state(config, batch, capacity=0):
-        from mmlspark_tpu.parallel.experts import one_pass_combine
-
         blocks = HybridLM._blocks(config)
-        sparse = sum(block.sparse() for block in blocks)
-        _, count, routed = experts_held(config)
         state = {"pos": jnp.zeros((batch,), jnp.int32),
                  "layers": [block.init_state(batch, capacity)
-                            for block in blocks],
-                 "experts": {"pairs": jnp.zeros((sparse, count), jnp.int32),
-                             "dropped": jnp.zeros((), jnp.int32)}}
-        if one_pass_combine(count, routed):
-            state["experts"]["combine_rows"] = jnp.zeros((), jnp.int32)
+                            for block in blocks]}
+        sparse = sum(block.sparse() for block in blocks)
+        if sparse:
+            _, count, _ = experts_held(config)
+            state["experts"] = {
+                "pairs": jnp.zeros((sparse, count), jnp.int32),
+                "dropped": jnp.zeros((), jnp.int32)}
         return state
+
+    @staticmethod
+    def counts(config, state):
+        """What a state says its rows went through, counted on the
+        device: with expert layers, what they served (``expert_pairs``,
+        one row: a count an expert layer and held expert;
+        ``dropped_pairs``); with caches, each row's ``cache_positions``
+        (the positions it has filled, summed over the layers that
+        cache; a window layer's ring is no such cache) and
+        ``cache_capacity`` (the same at capacity); with window layers,
+        each row's ``window_positions`` (``latent.window_read``, summed
+        over them); with a residual of several streams, each row's
+        ``hc_sublayer_tokens``: the tokens it has absorbed, prompt and
+        generated, times the sub-layers they went through that path
+        around."""
+        from mmlspark_tpu.parallel import latent
+
+        out, pos = {}, state["pos"]
+        if "experts" in state:
+            out.update(expert_pairs=state["experts"]["pairs"].reshape(1, -1),
+                       dropped_pairs=state["experts"]["dropped"])
+        caches = [layer["c"].shape[1] for layer in state["layers"]
+                  if "c" in layer]
+        if caches:
+            out.update(cache_positions=len(caches) * pos,
+                       cache_capacity=jnp.full_like(pos, sum(caches)))
+        rings = [layer["ring"]["c"].shape[1] for layer in state["layers"]
+                 if "ring" in layer]
+        if rings:
+            out["window_positions"] = sum(
+                latent.window_read(pos, width) for width in rings)
+        if "hc_mult" in config:
+            out["hc_sublayer_tokens"] = 2 * len(state["layers"]) * pos
+        return out
 
     @staticmethod
     def row_groups(rows, t):
@@ -1102,8 +1083,6 @@ class HybridLM(nn.Module):
             out = jnp.zeros((rows, hidden), jnp.float32)
         return jax.lax.fori_loop(first, stop, body, (state, out))[::-1]
 
-    GROUP_TOKENS = 4096
-
     def setup(self):
         c = self.config
         self.embedding = self.param(
@@ -1115,6 +1094,8 @@ class HybridLM(nn.Module):
         self.lm_head = Linear(c["vocab_size"], lm_dtype(c))
 
     def hidden(self, ids, lengths, state, every=False):
+        """Residual after the last layer at each row's last real token
+        ``(B, hidden)``, or with ``every`` at all ``T`` positions."""
         with jax.named_scope("lm.embed"):
             h = jnp.take(self.embedding, ids, axis=0).astype(jnp.float32)
         streams = "hc_mult" in self.config
@@ -1122,14 +1103,14 @@ class HybridLM(nn.Module):
             h = (h,) * self.config["hc_mult"]
         positions = state["pos"][:, None] + jnp.arange(ids.shape[1])
         layers, pairs = [], []
-        dropped = state["experts"]["dropped"]
+        experts = dict(state.get("experts", {}))
         for block, layer_state in zip(self.layers, state["layers"]):
             h, layer_state, served = block(h, positions, lengths,
                                            layer_state)
             layers.append(layer_state)
             if served is not None:
                 pairs.append(served[0])
-                dropped = dropped + served[1]
+                experts["dropped"] = experts["dropped"] + served[1]
         if not every:
             with jax.named_scope("lm.last"):
                 last = jnp.clip(lengths - 1, 0, ids.shape[1] - 1)
@@ -1142,14 +1123,10 @@ class HybridLM(nn.Module):
         if streams:
             with jax.named_scope("lm.hc"):
                 h = sum(h)
-        served = dict(state["experts"], dropped=dropped)
         if pairs:
-            served["pairs"] = served["pairs"] + jnp.stack(pairs)
-        if "combine_rows" in served:     # every expert layer took ids.size
-            served["combine_rows"] = served["combine_rows"] + (
-                ids.size * self.config["num_experts_per_tok"] * len(pairs))
-        return h, {"pos": state["pos"] + lengths, "layers": layers,
-                   "experts": served}
+            experts["pairs"] = experts["pairs"] + jnp.stack(pairs)
+        state = {"pos": state["pos"] + lengths, "layers": layers}
+        return h, dict(state, experts=experts) if experts else state
 
     def head(self, h):
         with jax.named_scope("lm.head"):
@@ -1159,7 +1136,3 @@ class HybridLM(nn.Module):
     def __call__(self, ids, lengths, state, every=False):
         h, state = self.hidden(ids, lengths, state, every)
         return self.head(h), state
-
-
-LM_MODELS = {"brumby": RetentionLM, "gigachat3_5": HybridLM,
-             "kimi_k2": HybridLM, "xing4_0": HybridLM, "Motif": HybridLM}

@@ -3,21 +3,15 @@ in, a column of greedy completions (and their log-probabilities) out.
 
 Parity: SynapseML's ``HuggingFaceCausalLM`` (a ``Transformer`` from a
 prompt column to a completion column, batched generation behind it).
-The model is the one ``modelConfig["model_type"]`` names in
-``backbones.LM_MODELS``: :class:`~mmlspark_tpu.dl.backbones.RetentionLM`
-(``brumby``, and a config without the key), whose layers keep a
-recurrent state and no cache, or
-:class:`~mmlspark_tpu.dl.backbones.HybridLM` (``gigachat3_5``), whose
-delta-rule layers keep a recurrent state and whose latent-attention
-layers a cache of one compressed entry a position, over sparse experts
-of which this chip holds a share; ``kimi_k2`` is the same class with
-latent attention in every layer, so its whole per-sequence state is
-cache, ``xing4_0`` that layer again inside a residual path of
-``hc_mult`` streams a token (``parallel/hyper.py``), and ``Motif``
-grouped differential attention on the latent cache in that path, its
-window layers holding a ring of the window's slots in place of a cache.
-A device batch is sized by the bytes of both: the state (rings
-included), and the cache at the longest prompt plus ``maxNewTokens``.
+The model is :class:`~mmlspark_tpu.dl.backbones.HybridLM` built from
+``modelConfig``, the model's ``config.json`` as a dict, whose
+``model_type`` names the family (``dl/backbones.py`` documents each
+family's keys). The stage uses its contract alone: ``init_state``, the
+``hidden``/``head`` methods, ``hidden_in_groups`` and its groups of
+rows, and ``counts``, what a state says its rows went through. A device
+batch is sized by the bytes of the state (``lm_state_bytes``): its
+fixed part, and the part that grows with the longest prompt plus
+``maxNewTokens``.
 
 One ``transform()``: the ragged prompts are sorted by length and cut
 into device batches (``ShardedScorer.length_batches``: the row ladder
@@ -71,7 +65,7 @@ def lm_prefill_program(module, prefill_chunk: int, new_tokens: int):
     import jax
     import jax.numpy as jnp
 
-    from mmlspark_tpu.dl.backbones import lm_hidden, lm_init_state
+    from mmlspark_tpu.dl.backbones import HybridLM, lm_init_state
 
     config = module.config
 
@@ -85,7 +79,8 @@ def lm_prefill_program(module, prefill_chunk: int, new_tokens: int):
             state, last = carry
             chunk_ids, start = xs
             real = jnp.clip(lengths - start, 0, chunk)
-            h, state = lm_hidden(module, params, chunk_ids, real, state)
+            h, state = HybridLM.hidden_in_groups(module, params, chunk_ids,
+                                                 real, state)
             with jax.named_scope("lm.last"):
                 last = jnp.where((real > 0)[:, None], h, last)
             return (state, last), None
@@ -99,34 +94,11 @@ def lm_prefill_program(module, prefill_chunk: int, new_tokens: int):
     return lm_prefill
 
 
-def window_read(pos, window: int):
-    """Cached positions a window layer's attention has read for a row
-    that has absorbed ``pos`` tokens, prompt and generated: position
-    ``p`` reads the ``min(p + 1, window)`` most recent, its own
-    included."""
-    import jax.numpy as jnp
-
-    return jnp.where(pos <= window, pos * (pos + 1) // 2,
-                     window * (window + 1) // 2 + (pos - window) * window)
-
-
 def lm_generate_program(module, new_tokens: int, with_logits: bool):
     """``lm_generate(params, last, state)``: ``new_tokens`` greedy
     tokens a row in one scan, the state its carry: ``({"tokens",
-    "logprobs"[, "logits"]}, state)``; with expert layers, also what
-    they served since the state was empty, counted on the device:
-    ``expert_pairs`` (one row: a count an expert layer and held expert)
-    and ``dropped_pairs``, and ``combine_rows`` where the experts
-    combine in one pass (the rows that pass read back); with caches,
-    each row's ``cache_positions``
-    (the positions it has filled, summed over the layers that cache;
-    a window layer's ring is no such cache) and ``cache_capacity``
-    (the same at capacity); with window layers, each row's
-    ``window_positions`` (``window_read``, summed over them); with a
-    residual of
-    several streams (``hc_mult``), each row's ``hc_sublayer_tokens``:
-    the tokens it has absorbed, prompt and generated, times the
-    sub-layers they went through that path around."""
+    "logprobs"[, "logits"]} and the model's ``counts`` of the final
+    state, state)``."""
     import jax
     import jax.numpy as jnp
 
@@ -154,26 +126,7 @@ def lm_generate_program(module, new_tokens: int, with_logits: bool):
         outs = {k: jnp.concatenate([jnp.moveaxis(v, 0, 1),
                                     final[k][:, None]], axis=1)
                 for k, v in outs.items()}
-        if "experts" in state:
-            outs.update(
-                expert_pairs=state["experts"]["pairs"].reshape(1, -1),
-                dropped_pairs=state["experts"]["dropped"])
-            if "combine_rows" in state["experts"]:
-                outs["combine_rows"] = state["experts"]["combine_rows"]
-        caches = [layer["c"].shape[1] for layer in state["layers"]
-                  if "c" in layer]
-        if caches:
-            outs.update(
-                cache_positions=len(caches) * state["pos"],
-                cache_capacity=jnp.full_like(state["pos"], sum(caches)))
-        rings = [layer["ring"]["c"].shape[1] for layer in state["layers"]
-                 if "ring" in layer]
-        if rings:
-            outs["window_positions"] = sum(
-                window_read(state["pos"], width) for width in rings)
-        if "hc_mult" in module.config:
-            outs.update(
-                hc_sublayer_tokens=2 * len(state["layers"]) * state["pos"])
+        outs.update(module.counts(module.config, state))
         # the state goes out again so that the donated buffers have an
         # output to alias: the scan then updates them in place, and a
         # second copy of the state (which would not fit) is never made
@@ -184,32 +137,8 @@ def lm_generate_program(module, new_tokens: int, with_logits: bool):
 
 class CausalLM(Transformer, HasInputCol, HasOutputCol):
     modelConfig = Param("modelConfig", "the model's config.json as a dict; "
-                        "model_type names the model (backbones.LM_MODELS): "
-                        "brumby, also taken where the key is absent "
-                        "(hidden_size, num_attention_heads, "
-                        "num_key_value_heads, head_dim, intermediate_size, "
-                        "vocab_size, num_hidden_layers, rms_norm_eps, "
-                        "rope_theta, torch_dtype), or gigachat3_5 or "
-                        "kimi_k2 (the keys of the model's config.json: "
-                        "for kimi_k2 hidden_size, num_hidden_layers, "
-                        "first_k_dense_replace, intermediate_size, "
-                        "moe_intermediate_size, n_routed_experts, "
-                        "n_shared_experts, num_experts_per_tok, "
-                        "routed_scaling_factor, q_lora_rank, kv_lora_rank, "
-                        "qk_nope_head_dim, qk_rope_head_dim, v_head_dim, "
-                        "num_attention_heads, rope_theta, rope_scaling, "
-                        "rms_norm_eps, vocab_size, torch_dtype; with "
-                        "experts_held and router_experts where this chip "
-                        "holds a share of the experts), or xing4_0 "
-                        "(kimi_k2's keys and the residual path's: hc_mult "
-                        "streams a token, hc_sinkhorn_iters, hc_eps, "
-                        "mhc_h_res_clamp_min, mhc_h_res_clamp_max), or "
-                        "Motif (its own config.json's keys, read through "
-                        "backbones.FAMILY_KEYS: attention_cls gdla, "
-                        "num_noise_heads, sliding_window and its pattern, "
-                        "hidden_act poly_norm, mhc_*; published_layers "
-                        "where a cut keeps some of the published layers)",
-                        is_complex=True)
+                        "model_type names its family, whose keys "
+                        "dl/backbones.py documents", is_complex=True)
     maxNewTokens = Param("maxNewTokens", "tokens generated a row (greedy, "
                          "no early stop)", to_int, gt(0), default=32)
     batchSize = Param("batchSize", "rows a device batch; unset, the "
@@ -324,22 +253,24 @@ class CausalLM(Transformer, HasInputCol, HasOutputCol):
         return programs[key]
 
     def _prefill_visits(self, lengths: np.ndarray, length: int):
-        """``[visits, run, fused]`` of one ``lm_prefill`` over these rows'
+        """``[visits, run]`` of one ``lm_prefill`` over these rows'
         lengths (the device batch's, zero rows included) at a length
         rung: a visit is a group of rows in a step
-        (``backbones.lm_hidden_visits``); those run are the ones inside
-        the bounds of the model's group loop; ``fused`` counts a run
-        visit once for each layer whose prefill runs the latent prefill
-        kernel (``backbones.lm_fused_prefill_layers``)."""
-        from mmlspark_tpu.dl.backbones import (lm_fused_prefill_layers,
-                                               lm_hidden_visits)
+        (``HybridLM.row_groups``: a step of one group is one visit,
+        run); those run are the ones inside the bounds of the group
+        loop (``HybridLM.active_groups``)."""
+        from mmlspark_tpu.dl.backbones import HybridLM
 
         chunk, steps = prefill_steps(self.get("prefillChunk"), length)
-        visits = np.sum([lm_hidden_visits(
-            self._module, np.clip(lengths - start, 0, chunk), chunk)
-            for start in np.arange(steps) * chunk], axis=0)
-        return np.append(visits, visits[1] * lm_fused_prefill_layers(
-            self._module, chunk))
+        groups = HybridLM.row_groups(len(lengths), chunk)
+        if groups == 1:
+            return np.array([steps, steps])
+        run = 0
+        for start in np.arange(steps) * chunk:
+            first, stop = HybridLM.active_groups(
+                np.clip(lengths - start, 0, chunk), groups)
+            run += int(stop - first)
+        return np.array([groups * steps, run])
 
     def _generate(self, params, batch):
         """What the engine calls a device batch with: two programs,
@@ -412,33 +343,23 @@ class CausalLM(Transformer, HasInputCol, HasOutputCol):
             visits = sum((self._prefill_visits(
                 np.pad(b["lengths"], (0, scorer.padded_rows(len(index))
                                       - len(index))), b["ids"].shape[1])
-                for index, b in batches), np.zeros(3, int))
+                for index, b in batches), np.zeros(2, int))
             root.counts.update(
                 new_tokens=int(new * len(prompts)),
                 state_bytes=int(held["state"]),
                 cache_bytes=int(held["cache"]), length_rung=int(rung),
                 prefill_visits=int(visits[0]),
-                prefill_visits_run=int(visits[1]),
-                fused_prefill_visits=int(visits[2]))
-            served = [scored for _, scored in outputs
-                      if "expert_pairs" in scored]
-            if served:
-                pairs = sum(s["expert_pairs"] for s in served)
-                root.counts.update(
-                    expert_pairs=int(pairs.sum()),
-                    expert_pairs_max=int(pairs.max()),
-                    dropped_pairs=int(sum(s["dropped_pairs"]
-                                          for s in served)),
-                    combine_rows=int(sum(s.get("combine_rows", 0)
-                                         for s in served)))
-            for key in ("cache_positions", "cache_capacity",
-                        "hc_sublayer_tokens", "window_positions"):
-                if outputs and key in outputs[0][1]:
-                    root.counts[key] = int(sum(
-                        scored[key].sum() for _, scored in outputs))
-            if "hc_mult" in self._module.config:
-                root.counts["hc_streams"] = int(
-                    self._module.config["hc_mult"])
+                prefill_visits_run=int(visits[1]))
+            # what the model counted on the device (HybridLM.counts),
+            # summed over the batches
+            counted = [key for key in (outputs[0][1] if outputs else ())
+                       if key not in names]
+            for key in counted:
+                root.counts[key] = int(sum(np.sum(scored[key])
+                                           for _, scored in outputs))
+            if "expert_pairs" in counted:
+                root.counts["expert_pairs_max"] = int(sum(
+                    scored["expert_pairs"] for _, scored in outputs).max())
         return out
 
     # -- persistence (as DeepModel: leaves in order) -------------------

@@ -482,6 +482,17 @@ def ring_write(ring, c, r, pos, lengths):
     return {"c": put(ring["c"], c), "r": put(ring["r"], r)}
 
 
+def window_read(pos, window: int):
+    """Cached positions a window layer's attention has read for a row
+    that has absorbed ``pos`` tokens, prompt and generated: position
+    ``p`` reads the ``min(p + 1, window)`` most recent, its own
+    included."""
+    import jax.numpy as jnp
+
+    return jnp.where(pos <= window, pos * (pos + 1) // 2,
+                     window * (window + 1) // 2 + (pos - window) * window)
+
+
 def window_prefill(q_n, q_r, ring, c, r, w_uk, w_uv, pos, lengths, *,
                    scale: float, dtype):
     """A stretch under a window of ``window`` positions (the ring's

@@ -13,8 +13,7 @@ import pytest
 
 from mmlspark_tpu.core.dataframe import DataFrame
 from mmlspark_tpu.core.logging_utils import SINK
-from mmlspark_tpu.dl.backbones import (HybridLM, lm_hidden, lm_init_state,
-                                       lm_module)
+from mmlspark_tpu.dl.backbones import HybridLM, lm_init_state, lm_module
 
 ROWS, T, GROUPS = 8, 8, 4
 # a step's real tokens a row, ascending as ``length_batches`` hands them
@@ -95,9 +94,7 @@ def check(loops, config, order, ended):
     hidden rows equal to the bit. A row with no token in the step has
     no hidden row to compare: the loop over all groups returns what it
     made of the padding, the bounded one may leave zeros, and
-    ``lm_prefill`` keeps the row's last either way. ``combine_rows``
-    (where the config has it) counts the visits each loop runs: a
-    group's tokens x ``top_k`` x expert layers a visit."""
+    ``lm_prefill`` keeps the row's last either way."""
     whole, bounded = loops
     started, real = arrange(order, ended)
     ids = np.random.default_rng(5).integers(
@@ -106,12 +103,6 @@ def check(loops, config, order, ended):
     want_h, want = whole(ids[1], real, state)
     got_h, got = bounded(ids[1], real, state)
     first, stop = HybridLM.active_groups(real, GROUPS)
-    if "combine_rows" in state["experts"]:
-        visit = (ROWS // GROUPS * T * config["num_experts_per_tok"]
-                 * state["experts"]["pairs"].shape[0])
-        for after, visits in ((got, stop - first), (want, GROUPS)):
-            assert int(after["experts"].pop("combine_rows")) == int(
-                state["experts"]["combine_rows"]) + visits * visit
     assert (jax.tree_util.tree_structure(got)
             == jax.tree_util.tree_structure(want))
     for a, b in zip(jax.tree_util.tree_leaves(got),
@@ -163,11 +154,12 @@ def check_stage(stage, prompts, monkeypatch):
 
 def check_one_group(config, params):
     """A decode step, and any step of ``GROUP_TOKENS`` tokens or fewer:
-    ``lm_hidden`` is ``module.apply`` and traces no loop over groups."""
+    ``hidden_in_groups`` is ``module.apply`` and traces no loop over
+    groups."""
     module = lm_module(config)
     args = (jnp.zeros((4, 1), jnp.int32), jnp.ones((4,), jnp.int32),
             lm_init_state(config, 4, 16))
-    assert str(jax.make_jaxpr(
-        lambda *a: lm_hidden(module, params, *a))(*args)) == str(
+    assert str(jax.make_jaxpr(lambda *a: HybridLM.hidden_in_groups(
+        module, params, *a))(*args)) == str(
             jax.make_jaxpr(lambda *a: module.apply(
                 params, *a, method="hidden"))(*args))

@@ -1,13 +1,17 @@
-"""``CausalLM`` over ``RetentionLM`` at a small size on the CPU, seeded
+"""``CausalLM`` over ``HybridLM`` read as ``brumby`` (every layer a
+``DecoderBlock``, power retention) at a small size on the CPU, seeded
 weights, against the plain reference (``benchmark/reference/brumby.py``,
-imported through the benchmark's own loader so there is one copy)."""
+imported through the benchmark's own loader so there is one copy); and
+the root's counts of every family's tiny config."""
 
 import numpy as np
 import pytest
 
-from benchmark.lookup import load_module
+from benchmark.lookup import load_json, load_module
 from mmlspark_tpu.core.dataframe import DataFrame
 from mmlspark_tpu.core.logging_utils import SINK
+from tests.dl import group_loop
+from tests.dl.root_counts import ROOT_COUNTS, STAGE_COUNTS
 
 CFG = dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
            head_dim=16, intermediate_size=128, vocab_size=512,
@@ -156,6 +160,7 @@ def test_save_load_spans_and_counts(params, tmp_path):
     assert stack["rows"] == 5 and stack["prompt_tokens"] == sum(LENGTHS)
     assert stack["padded_tokens"] == 5 * 128  # every batch on the 128 rung
     counts = record["counts"]
+    assert set(counts) == ROOT_COUNTS["tiny_brumby"]
     assert counts["new_tokens"] == 5 * NEW and counts["length_rung"] == 128
     # 4 rows x 2 layers x 2 kv heads x 9 rows of phi x 16 x (16 + 1) x 4 B
     assert counts["state_bytes"] == 4 * 2 * 2 * 9 * 16 * 17 * 4 + 4 * 4
@@ -173,7 +178,8 @@ def test_save_load_spans_and_counts(params, tmp_path):
 
 
 def test_the_prefill_has_no_loop_with_traced_bounds(params):
-    """``RetentionLM`` has no group loop: its ``lm_prefill`` is the scan
+    """A prefill step of 4 rows x 128 tokens is one group of rows
+    (``HybridLM.GROUP_TOKENS`` 4,096): the ``lm_prefill`` is the scan
     over the chunks and nothing in it is a ``while`` (what a
     ``fori_loop`` over the groups that have a token left would be)."""
     import jax
@@ -248,3 +254,65 @@ def test_a_bfloat16_model_places_its_weights_in_bfloat16(params):
                   - np.asarray(base.col("logprobs"))).max()
     assert 0 < diff < 0.5
     del jax
+
+
+def test_the_bounded_group_loop_cuts_and_pastes_the_retention_state(
+        params):
+    """The retention layers through ``hidden_in_groups`` (``GROUP_TOKENS``
+    16: 4 groups of 2 rows a step, some of them ended) against the loop
+    over all groups, state and hidden rows to the bit; and against the
+    plain call of all rows at once, to float32's rounding."""
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.dl.backbones import lm_init_state, lm_module
+
+    loops = group_loop.compile_loops(CFG, params)
+    group_loop.check(loops, CFG, "ascending", "some")
+    started, real = group_loop.arrange("shuffled", "some")
+    ids = jnp.asarray(np.random.default_rng(5).integers(
+        0, CFG["vocab_size"], (2, group_loop.ROWS, group_loop.T)), jnp.int32)
+    plain = jax.jit(lambda *a: lm_module(CFG).apply(
+        params, *a, method="hidden"))
+    state = lm_init_state(CFG, group_loop.ROWS, 3 * group_loop.T)
+    _, want = plain(ids[0], started, state)
+    want_h, want = plain(ids[1], real, want)
+    _, got = loops[1](ids[0], started, state)
+    got_h, got = loops[1](ids[1], real, got)
+    assert "experts" not in got
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        b = np.asarray(b)
+        assert np.abs(np.asarray(a) - b).max() <= 1e-5 * np.abs(b).max()
+    got_h, want_h = np.asarray(got_h)[real > 0], np.asarray(want_h)[real > 0]
+    assert np.abs(got_h - want_h).max() <= 1e-5 * np.abs(want_h).max()
+
+
+@pytest.mark.parametrize("name", sorted(ROOT_COUNTS))
+def test_the_root_counts_each_familys_keys(name):
+    """What the root sums of the model's counts (``HybridLM.counts`` of
+    an empty state of the family's tiny rehearsal config) is exactly
+    the family's root keys less the stage's own: each family's test
+    file holds a transform's root to the whole set, and its values."""
+    from mmlspark_tpu.dl.backbones import HybridLM, lm_init_state, lm_module
+
+    file = load_json("rehearsal", "configs", name + ".json")
+    config = lm_module({k: file[k] for k in file["model_keys"]}).config
+    counts = HybridLM.counts(config, lm_init_state(config, 2, 8))
+    assert set(counts) == (ROOT_COUNTS[name] - STAGE_COUNTS
+                           - {"expert_pairs_max"})
+
+
+def test_a_configs_own_block_key_is_not_read():
+    """A layer's kind is the family table's alone: a ``block`` key in a
+    config (no published config.json has one) changes no layer."""
+    from mmlspark_tpu.dl.backbones import (DecoderBlock, HybridBlock,
+                                           HybridLM, lm_module)
+
+    file = load_json("rehearsal", "configs", "tiny_kimi.json")
+    kimi = {k: file[k] for k in file["model_keys"]}
+    for config, block, other in ((CFG, DecoderBlock, "hybrid"),
+                                 (kimi, HybridBlock, "retention")):
+        for given in (config, dict(config, block=other)):
+            layers = HybridLM._blocks(lm_module(given).config)
+            assert {type(layer) for layer in layers} == {block}

@@ -11,6 +11,7 @@ from benchmark.lookup import load_module
 from mmlspark_tpu.core.dataframe import DataFrame
 from mmlspark_tpu.core.logging_utils import SINK
 from tests.dl import group_loop
+from tests.dl.root_counts import ROOT_COUNTS
 
 # layer 0: delta rule over the dense SwiGLU; 1: latent attention; 2, 3:
 # delta rule; 1-3 over the experts 4..7 of 16. swiglu_limit 0.1 so that
@@ -242,6 +243,7 @@ def test_save_load_spans_and_counts(params, tmp_path):
     record = [r for r in SINK.events[before:]
               if r.get("className") == "CausalLM"][-1]
     counts = record["counts"]
+    assert set(counts) == ROOT_COUNTS["tiny_gigachat"]
     assert counts["new_tokens"] == 5 * NEW and counts["length_rung"] == 128
     # 4 rows: three delta-rule layers of 4 heads x 16 x 16 float32 and a
     # tail of 3 x 128 channels, and the 4-byte position; the experts'

@@ -16,6 +16,7 @@ from mmlspark_tpu.core.dataframe import DataFrame
 from mmlspark_tpu.core.logging_utils import SINK
 from tests.dl import group_loop
 from tests.dl.plain_block import PlainLM
+from tests.dl.root_counts import ROOT_COUNTS
 
 # three layers: 0 over the dense SwiGLU, 1 and 2 over all 8 experts
 CFG = dict(
@@ -260,10 +261,10 @@ def test_spans_counts_and_scopes(params):
     _stage(params).transform(DataFrame({"prompt": col}))
     counts = [r for r in SINK.events[before:]
               if r.get("className") == "CausalLM"][-1]["counts"]
+    assert set(counts) == ROOT_COUNTS["tiny_xing"]
     # every token a row absorbed, prompt and NEW - 1 generated, round
     # both sub-layers of the three layers
     through = sum(LENGTHS) + len(LENGTHS) * (NEW - 1)
-    assert counts["hc_streams"] == 4
     assert counts["hc_sublayer_tokens"] == 2 * 3 * through
     assert counts["cache_positions"] == 3 * through
     assert counts["dropped_pairs"] == 0
@@ -276,42 +277,6 @@ def test_spans_counts_and_scopes(params):
         assert f"layers_1/{scope}" in text, scope
     assert "lm.mla/lm.hc" not in text and "lm.moe/lm.hc" not in text
     assert "lm.hc/mixer_hc/lm.mla" not in text
-
-
-@pytest.mark.parametrize("name,builder", [("tiny_xing", "xing4_0"),
-                                          ("tiny_kimi", "kimi_k2")])
-def test_combine_rows_are_the_rows_the_one_pass_reads_back(name, builder):
-    """On the root, ``tokens x top_k x expert layers`` over what the
-    expert layers took in (4 rows at the length rung, then ``NEW - 1``
-    decode steps) where every expert is held (``tiny_xing``: 8 of 8),
-    0 where a share is and the tile loop combines (``tiny_kimi``: 6 of
-    24)."""
-    from mmlspark_tpu.dl.causal_lm import CausalLM
-    from mmlspark_tpu.parallel.experts import one_pass_combine
-
-    file = load_json("rehearsal", "configs", name + ".json")
-    config = {k: file[k] for k in file["model_keys"]}
-    weights = load_module("builders", builder).make_weights(5, config)
-    rng = np.random.default_rng(4)
-    col = np.empty(4, dtype=object)
-    for i, n in enumerate([5, 17, 9, 30]):
-        col[i] = rng.integers(0, config["vocab_size"], n).astype(np.int32)
-    before = len(SINK.events)
-    CausalLM(inputCol="prompt", outputCol="completion", modelConfig=config,
-             maxNewTokens=NEW, batchSize=4, prefillChunk=8,
-             maxLength=64).set_weights(weights).transform(
-                 DataFrame({"prompt": col}))
-    counts = [r for r in SINK.events[before:]
-              if r.get("className") == "CausalLM"][-1]["counts"]
-    count = config.get("experts_held", (0, config["n_routed_experts"]))
-    held = one_pass_combine(count[1] - count[0], config.get(
-        "router_experts", config["n_routed_experts"]))
-    assert held == (name == "tiny_xing")
-    sparse = config["num_hidden_layers"] - config["first_k_dense_replace"]
-    tokens = 4 * (counts["length_rung"] + NEW - 1)
-    assert counts["combine_rows"] == (
-        tokens * config["num_experts_per_tok"] * sparse if held else 0)
-    assert counts["dropped_pairs"] == 0
 
 
 def test_a_block_of_one_stream_is_the_plain_block(params):

@@ -13,6 +13,8 @@ from benchmark.lookup import load_module
 from mmlspark_tpu.core.dataframe import DataFrame
 from mmlspark_tpu.core.logging_utils import SINK
 from tests.dl import group_loop
+from tests.dl.kernel_calls import prefill_kernel_calls
+from tests.dl.root_counts import ROOT_COUNTS
 
 # three layers: 0 over the dense SwiGLU, 1 and 2 over the experts
 # 6..11 of 24 (a count that is no power of two)
@@ -247,28 +249,30 @@ def test_the_prefill_kernel_in_the_stage_equals_the_scan(params,
     Pallas kernel, interpreted, in the form the rule gives this size (the
     rule's bound lowered to it: 8 queries a step, blocks of 16 positions,
     all 4 key heads a grid step), and the decode kernel: the scan's
-    tokens and log-probabilities, and each run visit counted once for
-    each of the three latent layers."""
+    tokens and log-probabilities; the stage's ``lm_prefill`` calls the
+    kernel once for each of the three latent layers, and the scan's
+    none."""
     from mmlspark_tpu.parallel import latent
 
     col = _prompts()
-    base = _stage(params).transform(DataFrame({"prompt": col}))
+    scan = _stage(params)
+    base = scan.transform(DataFrame({"prompt": col}))
+    assert prefill_kernel_calls(scan._program("lm_prefill"), params, 4,
+                                128) == 0
     monkeypatch.setattr(latent, "use_pallas", lambda: True)
     monkeypatch.setattr(latent, "PREFILL_KERNEL_MIN_ROWS", 8)
     assert latent.prefill_form(8, 1, 4, 128 + NEW) == (16, 8, 4)
     for name in ("_decode_pallas", "_prefill_pallas"):
         monkeypatch.setattr(latent, name, lambda *a, _real=getattr(
             latent, name): _real(*a[:-1], True))
-    before = len(SINK.events)
-    out = _stage(params).transform(DataFrame({"prompt": col}))
+    stage = _stage(params)
+    out = stage.transform(DataFrame({"prompt": col}))
     assert np.array_equal(np.asarray(out.col("completion")),
                           np.asarray(base.col("completion")))
     assert np.abs(np.asarray(out.col("logprobs"))
                   - np.asarray(base.col("logprobs"))).max() < 2e-5
-    counts = [r for r in SINK.events[before:]
-              if r.get("className") == "CausalLM"][-1]["counts"]
-    assert counts["fused_prefill_visits"] == 3 * counts[
-        "prefill_visits_run"] > 0
+    assert prefill_kernel_calls(stage._program("lm_prefill"), params, 4,
+                                128) == 3
 
 
 def test_the_shares_add_up_to_the_uncut_layer(reference):
@@ -356,6 +360,7 @@ def test_save_load_spans_and_counts(params, tmp_path):
     record = [r for r in SINK.events[before:]
               if r.get("className") == "CausalLM"][-1]
     counts = record["counts"]
+    assert set(counts) == ROOT_COUNTS["tiny_kimi"]
     assert counts["new_tokens"] == 5 * NEW and counts["length_rung"] == 128
     # no recurrent state: 4 rows' positions and the experts' counters
     # (2 layers x 6 held, and the dropped pairs)

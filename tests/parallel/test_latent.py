@@ -359,18 +359,23 @@ def test_the_rule_takes_the_kernel_at_motifs_shape_alone(monkeypatch):
     and one key head a grid step; the ungrouped shapes take blocks of
     twice their queries and eight key heads a grid step."""
     from benchmark.lookup import load_json
-    from mmlspark_tpu.dl.backbones import lm_fused_prefill_layers, lm_module
-
-    def fused(name):
-        file = load_json("configs", name + ".json")
-        module = lm_module({k: file[k] for k in file["model_keys"]})
-        return lm_fused_prefill_layers(module, file["prefillChunk"])
+    from tests.dl.kernel_calls import layer_kernel_calls
 
     names = ("motif_3_beta", "kimi_k2_6", "xing4_0_29b_a4b",
              "gigachat3_5_432b", "brumby_14b")
-    assert [fused(n) for n in names] == [0] * 5
+    cells = []
+    for name in names:
+        file = load_json("configs", name + ".json")
+        cells.append(({k: file[k] for k in file["model_keys"]},
+                      file["prefillChunk"]))
+
+    def fused():
+        # each layer's prefill step at the cell's widths and chunk
+        return [layer_kernel_calls(config, chunk) for config, chunk in cells]
+
+    assert fused() == [0] * 5
     monkeypatch.setattr(L, "use_pallas", lambda: True)
-    assert [fused(n) for n in names] == [2, 5, 6, 1, 0]
+    assert fused() == [2, 5, 6, 1, 0]
     assert L.prefill_kernel(1024, 80, 16)
     for t, heads in ((128, 64), (256, 32)):
         assert L.prefill_kernel(t, heads, heads)

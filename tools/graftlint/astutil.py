@@ -26,11 +26,12 @@ def dotted(node: ast.AST) -> Optional[str]:
 class ImportMap:
     """Alias -> canonical dotted path, from every import statement in
     the module (function-local imports included — the codebase imports
-    jax lazily almost everywhere)."""
+    jax lazily almost everywhere); ``nodes`` are the module's, in
+    ``ast.walk`` order."""
 
-    def __init__(self, tree: ast.AST):
+    def __init__(self, nodes: Sequence[ast.AST]):
         self.aliases: Dict[str, str] = {}
-        for n in ast.walk(tree):
+        for n in nodes:
             if isinstance(n, ast.Import):
                 for a in n.names:
                     if a.asname:
@@ -59,9 +60,9 @@ class ImportMap:
         return self.resolve(dotted(node))
 
 
-def build_parent_map(tree: ast.AST) -> Dict[ast.AST, ast.AST]:
+def build_parent_map(nodes: Sequence[ast.AST]) -> Dict[ast.AST, ast.AST]:
     parents: Dict[ast.AST, ast.AST] = {}
-    for node in ast.walk(tree):
+    for node in nodes:
         for child in ast.iter_child_nodes(node):
             parents[child] = node
     return parents
@@ -109,9 +110,11 @@ def is_tracing_wrapper(resolved: Optional[str]) -> bool:
     return _is_jit_name(resolved) or _is_shard_map_name(resolved)
 
 
-def _defs_by_name(tree: ast.AST) -> Dict[str, List[ast.AST]]:
+def defs_by_name(nodes: Sequence[ast.AST]) -> Dict[str, List[ast.AST]]:
+    """Function definitions by name, of a file's ``nodes``
+    (``ParsedFile.nodes``)."""
     out: Dict[str, List[ast.AST]] = {}
-    for n in ast.walk(tree):
+    for n in nodes:
         if isinstance(n, (ast.FunctionDef, ast.AsyncFunctionDef)):
             out.setdefault(n.name, []).append(n)
     return out
@@ -135,16 +138,17 @@ def _function_targets(arg: ast.AST, imports: ImportMap,
     return []
 
 
-def collect_traced_functions(tree: ast.AST,
-                             imports: ImportMap) -> Set[ast.AST]:
+def collect_traced_functions(nodes: Sequence[ast.AST], imports: ImportMap,
+                             defs: Dict[str, List[ast.AST]]
+                             ) -> Set[ast.AST]:
     """Function nodes whose bodies run under jax tracing: decorated with
     jit/pmap (directly or via ``partial(jax.jit, ...)``), or passed —
     possibly through ``functools.partial`` — to ``jax.jit``/``pmap``/
     ``shard_map``. Purely lexical: dynamically-built callables
-    (``jax.jit(make_fn())``) are out of reach and skipped."""
-    defs = _defs_by_name(tree)
+    (``jax.jit(make_fn())``) are out of reach and skipped. ``nodes``
+    and ``defs`` are the file's (``ParsedFile.nodes``, ``.defs``)."""
     marked: Set[ast.AST] = set()
-    for fn in ast.walk(tree):
+    for fn in nodes:
         if isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
             for dec in fn.decorator_list:
                 if isinstance(dec, ast.Call):
@@ -157,13 +161,38 @@ def collect_traced_functions(tree: ast.AST,
                         marked.add(fn)
                 elif is_tracing_wrapper(imports.resolve_node(dec)):
                     marked.add(fn)
-    for call in ast.walk(tree):
+    for call in nodes:
         if isinstance(call, ast.Call):
             f = imports.resolve_node(call.func)
             if is_tracing_wrapper(f) and call.args:
                 marked.update(
                     _function_targets(call.args[0], imports, defs))
     return marked
+
+
+def collect_jitted_names(nodes: Sequence[ast.AST], imports: ImportMap,
+                         traced: Set[ast.AST]) -> Set[str]:
+    """Names bound to jitted callables: ``step = jax.jit(f)`` targets
+    plus functions decorated with jit/pmap (of ``traced``, the file's
+    ``collect_traced_functions``)."""
+    names: Set[str] = set()
+    for node in nodes:
+        if isinstance(node, ast.Assign) and isinstance(node.value,
+                                                       ast.Call):
+            resolved = imports.resolve_node(node.value.func) or ""
+            if resolved in ("jax.jit", "jax.pmap"):
+                for t in node.targets:
+                    if isinstance(t, ast.Name):
+                        names.add(t.id)
+    for fn in traced:
+        if isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
+            for dec in fn.decorator_list:
+                r = (imports.resolve_node(
+                        dec.func if isinstance(dec, ast.Call) else dec)
+                     or "")
+                if r in ("jax.jit", "jax.pmap"):
+                    names.add(fn.name)
+    return names
 
 
 # --- host-callback escape hatches -----------------------------------------
@@ -184,13 +213,15 @@ def is_callback_primitive(resolved: Optional[str]) -> bool:
                         "debug.callback", "debug.print")
 
 
-def collect_callback_functions(tree: ast.AST,
-                               imports: ImportMap) -> Set[ast.AST]:
+def collect_callback_functions(nodes: Sequence[ast.AST],
+                               imports: ImportMap,
+                               defs: Dict[str, List[ast.AST]]
+                               ) -> Set[ast.AST]:
     """Function nodes passed to a callback primitive: their bodies are
-    host code by design, exempt from tracer-hygiene checks."""
-    defs = _defs_by_name(tree)
+    host code by design, exempt from tracer-hygiene checks. ``nodes``
+    and ``defs`` are the file's (``ParsedFile.nodes``, ``.defs``)."""
     out: Set[ast.AST] = set()
-    for call in ast.walk(tree):
+    for call in nodes:
         if not isinstance(call, ast.Call):
             continue
         if not is_callback_primitive(imports.resolve_node(call.func)):

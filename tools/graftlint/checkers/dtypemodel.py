@@ -3,11 +3,11 @@
 The four numeric-precision checkers all need the same facts about a
 file: which function bodies run under jax tracing, which are host
 callbacks, which names are bound to jitted callables, and what dtype
-(if any) a call expression casts its operand to. Building those facts
-is the expensive part — ``collect_traced_functions`` and the dataflow
-``Analysis`` runs walk the whole tree — so the model is memoized on the
-``ParsedFile`` instance and shared across the checkers; each rule then
-layers its own taint sources on the common ``Analysis`` cache.
+(if any) a call expression casts its operand to. The first three are
+the ``ParsedFile``'s own (made once a lint run, shared with GL002 and
+GL006-GL008); the model is memoized on the ``ParsedFile`` instance and
+shared across the checkers, and each rule layers its own taint sources
+on the common ``Analysis`` cache.
 
 Generalizes the GL007 dtype helpers: where GL007 cares only about
 float64/int64/sub-32 evidence, the model resolves the full dtype
@@ -19,10 +19,9 @@ from __future__ import annotations
 
 import ast
 import struct
-from typing import Dict, List, Optional, Set, Tuple
+from typing import Dict, List, Optional, Tuple
 
-from tools.graftlint.astutil import (collect_callback_functions,
-                                     collect_traced_functions, dotted)
+from tools.graftlint.astutil import dotted
 from tools.graftlint.core import ParsedFile
 from tools.graftlint.dataflow import Analysis, Tokens
 
@@ -56,10 +55,9 @@ class DtypeModel:
 
     def __init__(self, pf: ParsedFile):
         self.pf = pf
-        self.traced = collect_traced_functions(pf.tree, pf.imports)
-        self.callback_fns = collect_callback_functions(pf.tree,
-                                                       pf.imports)
-        self.jitted_names = _jitted_names(pf)
+        self.traced = pf.traced
+        self.callback_fns = pf.callback_fns
+        self.jitted_names = pf.jitted_names
         self._analyses: Dict[Tuple[int, str], Analysis] = {}
 
     # -- analysis cache -----------------------------------------------------
@@ -177,28 +175,3 @@ def significant_digits(text: str) -> int:
     mantissa = text.split("e")[0].split("E")[0]
     digits = "".join(c for c in mantissa if c.isdigit()).lstrip("0")
     return len(digits)
-
-
-# --- jit-boundary discovery (shared with GL016) -----------------------------
-
-def _jitted_names(pf: ParsedFile) -> Set[str]:
-    """Names bound to jitted callables: ``step = jax.jit(f)`` targets
-    plus functions decorated with jit/pmap."""
-    names: Set[str] = set()
-    for node in ast.walk(pf.tree):
-        if isinstance(node, ast.Assign) and isinstance(node.value,
-                                                       ast.Call):
-            resolved = pf.imports.resolve_node(node.value.func) or ""
-            if resolved in ("jax.jit", "jax.pmap"):
-                for t in node.targets:
-                    if isinstance(t, ast.Name):
-                        names.add(t.id)
-    for fn in collect_traced_functions(pf.tree, pf.imports):
-        if isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
-            for dec in fn.decorator_list:
-                r = (pf.imports.resolve_node(
-                        dec.func if isinstance(dec, ast.Call) else dec)
-                     or "")
-                if r in ("jax.jit", "jax.pmap"):
-                    names.add(fn.name)
-    return names

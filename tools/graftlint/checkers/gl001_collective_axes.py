@@ -58,7 +58,7 @@ class CollectiveAxisChecker(Checker):
         axis_by_name = {**declared, **local_consts}
         valid = set(axis_by_name.values())
         out: List[Finding] = []
-        for call in ast.walk(pf.tree):
+        for call in pf.nodes:
             if not isinstance(call, ast.Call):
                 continue
             resolved = pf.imports.resolve_node(call.func) or ""

@@ -22,9 +22,7 @@ from __future__ import annotations
 import ast
 from typing import List, Optional, Set
 
-from tools.graftlint.astutil import (collect_callback_functions,
-                                     collect_traced_functions, dotted,
-                                     is_callback_primitive,
+from tools.graftlint.astutil import (dotted, is_callback_primitive,
                                      walk_skipping)
 from tools.graftlint.core import Checker, Finding, ParsedFile, Project
 
@@ -49,10 +47,10 @@ class TracerHygieneChecker(Checker):
 
     def check_file(self, pf: ParsedFile,
                    project: Project) -> List[Finding]:
-        traced = collect_traced_functions(pf.tree, pf.imports)
+        traced = pf.traced
         if not traced:
             return []
-        callback_fns = collect_callback_functions(pf.tree, pf.imports)
+        callback_fns = pf.callback_fns
         out: List[Finding] = []
         seen: Set[int] = set()   # dedupe nodes reachable from 2 roots
         for fn in traced:

@@ -37,9 +37,9 @@ class RecompilationChecker(Checker):
     def check_file(self, pf: ParsedFile,
                    project: Project) -> List[Finding]:
         out: List[Finding] = []
-        defs = {n.name: n for n in ast.walk(pf.tree)
+        defs = {n.name: n for n in pf.nodes
                 if isinstance(n, (ast.FunctionDef, ast.AsyncFunctionDef))}
-        for node in ast.walk(pf.tree):
+        for node in pf.nodes:
             if isinstance(node, ast.Call):
                 out.extend(self._check_static_args_call(pf, node, defs))
                 out.extend(self._check_fstring_cache_call(pf, node))

@@ -67,7 +67,7 @@ class RegistryDriftChecker(Checker):
         for pf in project.files:
             if pf is faults_pf:
                 continue   # the harness's own docs/examples
-            for call in ast.walk(pf.tree):
+            for call in pf.nodes:
                 if not isinstance(call, ast.Call):
                     continue
                 fname = (dotted(call.func) or "").split(".")[-1]
@@ -220,7 +220,7 @@ class RegistryDriftChecker(Checker):
 
 
 def _known_points(pf: ParsedFile) -> Optional[Dict[str, int]]:
-    for stmt in ast.walk(pf.tree):
+    for stmt in pf.nodes:
         if not isinstance(stmt, (ast.Assign, ast.AnnAssign)):
             continue
         targets = stmt.targets if isinstance(stmt, ast.Assign) \
@@ -242,7 +242,7 @@ def _known_points(pf: ParsedFile) -> Optional[Dict[str, int]]:
 def _registered_vars(pf: ParsedFile) -> Dict[str, int]:
     """Literal first arguments of register(...) calls in core/env.py."""
     out: Dict[str, int] = {}
-    for call in ast.walk(pf.tree):
+    for call in pf.nodes:
         if not isinstance(call, ast.Call):
             continue
         if (dotted(call.func) or "").split(".")[-1] != "register":
@@ -259,7 +259,7 @@ def _env_references(pf: ParsedFile):
     """Yield (name, line, col, is_raw) for every MMLSPARK_TPU_* literal
     used as an env read/write in this file. ``is_raw`` marks direct
     os.environ access (vs the typed core/env.py helpers)."""
-    for node in ast.walk(pf.tree):
+    for node in pf.nodes:
         if isinstance(node, ast.Call):
             fname = dotted(node.func) or ""
             last = fname.split(".")[-1]

@@ -49,7 +49,7 @@ class DeterminismChecker(Checker):
                    project: Project) -> List[Finding]:
         out: List[Finding] = []
         in_kernel_code = bool(set(pf.rel.split("/")) & _KERNEL_DIRS)
-        for node in ast.walk(pf.tree):
+        for node in pf.nodes:
             if not isinstance(node, ast.Call):
                 continue
             resolved = pf.imports.resolve_node(node.func) or ""

@@ -31,7 +31,7 @@ from __future__ import annotations
 import ast
 from typing import Dict, List, Optional, Set, Tuple
 
-from tools.graftlint.astutil import (collect_traced_functions, dotted)
+from tools.graftlint.astutil import dotted
 from tools.graftlint.checkers.gl001_collective_axes import (
     COLLECTIVES, _is_collective_namespace)
 from tools.graftlint.core import Checker, Finding, ParsedFile, Project
@@ -65,10 +65,10 @@ class CollectiveDivergenceChecker(Checker):
 
     def check_file(self, pf: ParsedFile,
                    project: Project) -> List[Finding]:
-        traced = collect_traced_functions(pf.tree, pf.imports)
+        traced = pf.traced
         traced_ctx = functions_in_traced_context(pf.tree, traced)
         out: List[Finding] = []
-        for fn in ast.walk(pf.tree):
+        for fn in pf.nodes:
             if not isinstance(fn, (ast.FunctionDef,
                                    ast.AsyncFunctionDef)):
                 continue

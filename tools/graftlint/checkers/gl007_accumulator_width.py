@@ -39,7 +39,7 @@ from __future__ import annotations
 import ast
 from typing import Dict, List, Optional, Set
 
-from tools.graftlint.astutil import (collect_traced_functions, dotted)
+from tools.graftlint.astutil import dotted
 from tools.graftlint.core import Checker, Finding, ParsedFile, Project
 from tools.graftlint.dataflow import (Analysis, ExprTokens, Tokens,
                                       own_body_walk)
@@ -58,9 +58,9 @@ class AccumulatorWidthChecker(Checker):
 
     def check_file(self, pf: ParsedFile,
                    project: Project) -> List[Finding]:
-        jit_callables = _jitted_names(pf)
+        jit_callables = pf.jitted_names
         out: List[Finding] = []
-        for fn in ast.walk(pf.tree):
+        for fn in pf.nodes:
             if not isinstance(fn, (ast.FunctionDef,
                                    ast.AsyncFunctionDef)):
                 continue
@@ -360,31 +360,6 @@ def _has_int64(pf: ParsedFile, expr: ast.AST) -> bool:
         if (isinstance(n, ast.Constant) and n.value == "int64"):
             return True
     return False
-
-
-# --- jit-boundary discovery -------------------------------------------------
-
-def _jitted_names(pf: ParsedFile) -> Set[str]:
-    """Names bound to jitted callables: ``step = jax.jit(f)`` targets
-    plus functions decorated with jit/pmap."""
-    names: Set[str] = set()
-    for node in ast.walk(pf.tree):
-        if isinstance(node, ast.Assign) and isinstance(node.value,
-                                                       ast.Call):
-            resolved = pf.imports.resolve_node(node.value.func) or ""
-            if resolved in ("jax.jit", "jax.pmap"):
-                for t in node.targets:
-                    if isinstance(t, ast.Name):
-                        names.add(t.id)
-    for fn in collect_traced_functions(pf.tree, pf.imports):
-        if isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
-            for dec in fn.decorator_list:
-                r = (pf.imports.resolve_node(
-                        dec.func if isinstance(dec, ast.Call) else dec)
-                     or "")
-                if r in ("jax.jit", "jax.pmap"):
-                    names.add(fn.name)
-    return names
 
 
 def _enclosing_stmt(pf: ParsedFile, node: ast.AST,

@@ -30,9 +30,7 @@ from __future__ import annotations
 import ast
 from typing import Dict, List, Optional, Set, Tuple
 
-from tools.graftlint.astutil import (collect_callback_functions,
-                                     collect_traced_functions, dotted,
-                                     module_str_constants)
+from tools.graftlint.astutil import dotted, module_str_constants
 from tools.graftlint.checkers.gl001_collective_axes import (
     COLLECTIVES, _axis_argument, _declared_axes,
     _is_collective_namespace)
@@ -81,11 +79,10 @@ class CrossFunctionChecker(Checker):
         out: List[Finding] = []
         reported: Set[Tuple[int, int]] = set()
         for pf in project.files:
-            traced = collect_traced_functions(pf.tree, pf.imports)
+            traced = pf.traced
             if not traced:
                 continue
-            callback_fns = collect_callback_functions(pf.tree,
-                                                      pf.imports)
+            callback_fns = pf.callback_fns
             own_traced = {id(f) for f in traced}
             for root in traced:
                 if root in callback_fns:
@@ -117,9 +114,9 @@ class CrossFunctionChecker(Checker):
         visited.add(id(helper.fn))
         if helper.pf.rel.endswith(_SKIP_MODULE_SUFFIXES):
             return []
-        if helper.fn in _traced_fns_cached(helper.pf):
+        if helper.fn in helper.pf.traced:
             return []   # GL001/GL002 already own it
-        if helper.fn in _callback_fns_cached(helper.pf):
+        if helper.fn in helper.pf.callback_fns:
             return []   # host code by design
         bindings = _bind_str_args(caller_pf, call_site, helper.fn)
         # interprocedural tracer propagation: a helper parameter
@@ -240,32 +237,6 @@ class CrossFunctionChecker(Checker):
             hint="the helper executes under tracing; move the host "
                  "code out of the call chain or route it through "
                  "jax.pure_callback (see core/jax_compat.py)")
-
-
-# --- per-file caches --------------------------------------------------------
-
-_TRACED_CACHE: Dict[int, set] = {}
-_CALLBACK_CACHE: Dict[int, set] = {}
-
-
-def _traced_fns_cached(pf: ParsedFile) -> set:
-    hit = _TRACED_CACHE.get(id(pf))
-    if hit is None:
-        hit = collect_traced_functions(pf.tree, pf.imports)
-        _TRACED_CACHE[id(pf)] = hit
-        if len(_TRACED_CACHE) > 4096:
-            _TRACED_CACHE.clear()
-    return hit
-
-
-def _callback_fns_cached(pf: ParsedFile) -> set:
-    hit = _CALLBACK_CACHE.get(id(pf))
-    if hit is None:
-        hit = collect_callback_functions(pf.tree, pf.imports)
-        _CALLBACK_CACHE[id(pf)] = hit
-        if len(_CALLBACK_CACHE) > 4096:
-            _CALLBACK_CACHE.clear()
-    return hit
 
 
 # --- project indexing -------------------------------------------------------

@@ -62,7 +62,7 @@ class WeakTypeChecker(Checker):
                 seen.add(id(node))
                 out.extend(self._check_traced_node(pf, model, node))
         if pf.rel.startswith(_KERNEL_PREFIXES):
-            for node in ast.walk(pf.tree):
+            for node in pf.nodes:
                 if isinstance(node, ast.Call) and id(node) not in seen:
                     seen.add(id(node))
                     f = self._check_ctor(pf, model, node,

@@ -59,7 +59,7 @@ class ParityNarrowingChecker(Checker):
                    project: Project) -> List[Finding]:
         model = dtype_model(pf)
         out: List[Finding] = []
-        for fn in ast.walk(pf.tree):
+        for fn in pf.nodes:
             if not isinstance(fn, (ast.FunctionDef,
                                    ast.AsyncFunctionDef)):
                 continue

@@ -53,7 +53,7 @@ class LowPrecAccumulationChecker(Checker):
                    project: Project) -> List[Finding]:
         model = dtype_model(pf)
         out: List[Finding] = []
-        for fn in ast.walk(pf.tree):
+        for fn in pf.nodes:
             if not isinstance(fn, (ast.FunctionDef,
                                    ast.AsyncFunctionDef)):
                 continue
@@ -142,7 +142,7 @@ class LowPrecAccumulationChecker(Checker):
 
     def _check_seam(self, pf, model: DtypeModel) -> List[Finding]:
         out: List[Finding] = []
-        for node in ast.walk(pf.tree):
+        for node in pf.nodes:
             if not isinstance(node, ast.Call):
                 continue
             if model.cast_dtype(node) != "bfloat16":

@@ -55,7 +55,7 @@ class HostWidthDriftChecker(Checker):
         model = dtype_model(pf)
         helpers = _f64_helpers(pf, model)
         out: List[Finding] = []
-        for fn in ast.walk(pf.tree):
+        for fn in pf.nodes:
             if not isinstance(fn, (ast.FunctionDef,
                                    ast.AsyncFunctionDef)):
                 continue
@@ -108,7 +108,7 @@ class HostWidthDriftChecker(Checker):
     def _check_callback_operands(self, pf,
                                  model: DtypeModel) -> List[Finding]:
         out: List[Finding] = []
-        for call in ast.walk(pf.tree):
+        for call in pf.nodes:
             if not isinstance(call, ast.Call):
                 continue
             if not is_callback_primitive(
@@ -139,7 +139,7 @@ class HostWidthDriftChecker(Checker):
 def _f64_helpers(pf, model: DtypeModel) -> Set[str]:
     """Local function names whose returns carry np.float64 evidence."""
     names: Set[str] = set()
-    for fn in ast.walk(pf.tree):
+    for fn in pf.nodes:
         if not isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
             continue
         for node in own_body_walk(fn):

@@ -148,7 +148,7 @@ def build_class_models(pf, imports=None) -> List[ClassModel]:
     """One :class:`ClassModel` per top-level class in the file."""
     imports = imports if imports is not None else pf.imports
     out: List[ClassModel] = []
-    for node in ast.walk(pf.tree):
+    for node in pf.nodes:
         if not isinstance(node, ast.ClassDef):
             continue
         model = ClassModel(node=node)

@@ -6,10 +6,14 @@ import ast
 import hashlib
 import json
 from dataclasses import dataclass, field
+from functools import cached_property
 from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Set
 
-from tools.graftlint.astutil import ImportMap, build_parent_map
+from tools.graftlint.astutil import (ImportMap, build_parent_map,
+                                     collect_callback_functions,
+                                     collect_jitted_names,
+                                     collect_traced_functions, defs_by_name)
 
 SEVERITIES = ("error", "warning")
 
@@ -42,12 +46,39 @@ class Finding:
 
 @dataclass
 class ParsedFile:
+    """One parsed file and the analyses its rules share, each made once
+    a lint run: ``nodes`` (the tree in ``ast.walk`` order, walked once),
+    ``parents``, ``imports``, and on first use ``defs``, ``traced``,
+    ``callback_fns`` and ``jitted_names``."""
+
     path: Path                          # absolute
     rel: str                            # repo-root-relative, posix
     tree: ast.Module
     lines: List[str]
+    nodes: List[ast.AST]
     imports: ImportMap
     parents: Dict[ast.AST, ast.AST] = field(default_factory=dict)
+
+    @cached_property
+    def defs(self) -> Dict[str, List[ast.AST]]:
+        return defs_by_name(self.nodes)
+
+    @cached_property
+    def traced(self) -> Set[ast.AST]:
+        """Function nodes whose bodies run under jax tracing."""
+        return collect_traced_functions(self.nodes, self.imports,
+                                        self.defs)
+
+    @cached_property
+    def callback_fns(self) -> Set[ast.AST]:
+        """Function nodes passed to a host-callback primitive."""
+        return collect_callback_functions(self.nodes, self.imports,
+                                          self.defs)
+
+    @cached_property
+    def jitted_names(self) -> Set[str]:
+        """Names bound to jitted callables."""
+        return collect_jitted_names(self.nodes, self.imports, self.traced)
 
     def line_text(self, line: int) -> str:
         if 1 <= line <= len(self.lines):
@@ -89,10 +120,11 @@ class Project:
                 hint="fix the syntax error; graftlint checks nothing "
                      "else in an unparseable file"))
             return
+        nodes = list(ast.walk(tree))
         pf = ParsedFile(path=path, rel=rel, tree=tree,
-                        lines=source.splitlines(),
-                        imports=ImportMap(tree))
-        pf.parents = build_parent_map(tree)
+                        lines=source.splitlines(), nodes=nodes,
+                        imports=ImportMap(nodes),
+                        parents=build_parent_map(nodes))
         self.files.append(pf)
 
     def file_ending_with(self, suffix: str) -> Optional[ParsedFile]:

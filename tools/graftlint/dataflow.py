@@ -48,6 +48,17 @@ class CFG:
         self.entry: List[int] = []
         first = self._seq(body, frontier=None, loops=[])
         del first  # fall-off-the-end exits need no modelling
+        self.by_id: Dict[int, ast.stmt] = {id(p): p for p in self.points}
+        self.preds: Dict[int, List[int]] = {id(p): [] for p in self.points}
+        for src, dsts in self.succ.items():
+            for d in dsts:
+                self.preds[d].append(src)
+        # the walrus targets a point's header binds, in evaluation order
+        self.walrus: Dict[int, List[ast.NamedExpr]] = {
+            id(p): [n for e in _header_exprs(p) for n in ast.walk(e)
+                    if isinstance(n, ast.NamedExpr)
+                    and isinstance(n.target, ast.Name)]
+            for p in self.points}
 
     # frontier: list of point ids with an edge to the next statement;
     # None means "function entry" (recorded in self.entry instead)
@@ -205,6 +216,17 @@ class ExprTokens:
         return out
 
 
+def cfg_of(fn: ast.AST) -> CFG:
+    """``fn``'s CFG, built once a lint run and kept on the node, so that
+    every analysis of ``fn`` (each rule's, each taint kind's) shares
+    it."""
+    cfg = getattr(fn, "_graftlint_cfg", None)
+    if cfg is None:
+        cfg = CFG([] if isinstance(fn, ast.Lambda) else list(fn.body))
+        fn._graftlint_cfg = cfg
+    return cfg
+
+
 # --- the forward may-analysis ----------------------------------------------
 
 class Analysis:
@@ -220,19 +242,11 @@ class Analysis:
     def __init__(self, fn: ast.AST,
                  eval_expr: Callable[[Optional[ast.AST], Env], Tokens],
                  seed: Optional[Env] = None):
-        body = [] if isinstance(fn, ast.Lambda) else list(fn.body)
-        self.cfg = CFG(body)
+        self.cfg = cfg_of(fn)
         self.eval_expr = eval_expr
         self.seed: Env = dict(seed or {})
         self._in: Dict[int, Env] = {}
         self._out: Dict[int, Env] = {}
-        self._by_id: Dict[int, ast.stmt] = {id(p): p
-                                            for p in self.cfg.points}
-        self._preds: Dict[int, List[int]] = {id(p): []
-                                             for p in self.cfg.points}
-        for src, dsts in self.cfg.succ.items():
-            for d in dsts:
-                self._preds[d].append(src)
         self.run()
 
     def run(self) -> None:
@@ -244,11 +258,11 @@ class Analysis:
             nid = work.popleft()
             in_work.discard(nid)
             env: Env = dict(self.seed) if nid in entry else {}
-            for p in self._preds[nid]:
+            for p in self.cfg.preds[nid]:
                 for k, v in self._out.get(p, {}).items():
                     env[k] = env.get(k, EMPTY) | v
             self._in[nid] = env
-            out = self._transfer(self._by_id[nid], env)
+            out = self._transfer(self.cfg.by_id[nid], env)
             if out != self._out.get(nid):
                 self._out[nid] = out
                 for s in self.cfg.succ[nid]:
@@ -263,8 +277,8 @@ class Analysis:
 
     def _transfer(self, stmt: ast.stmt, env: Env) -> Env:
         env = dict(env)
-        for e in _header_exprs(stmt):
-            self._bind_walrus(e, env)
+        for n in self.cfg.walrus[id(stmt)]:
+            env[n.target.id] = self.eval_expr(n.value, env)
         if isinstance(stmt, ast.Assign):
             toks = self.eval_expr(stmt.value, env)
             for t in stmt.targets:
@@ -298,15 +312,6 @@ class Analysis:
                 if isinstance(t, ast.Name):
                     env.pop(t.id, None)
         return env
-
-    def _bind_walrus(self, expr: Optional[ast.AST], env: Env) -> None:
-        if expr is None:
-            return
-        for n in ast.walk(expr):
-            if (isinstance(n, ast.NamedExpr)
-                    and isinstance(n.target, ast.Name)):
-                env[n.target.id] = self.eval_expr(n.value, env)
-
 
 def _header_exprs(stmt: ast.stmt) -> List[ast.expr]:
     if isinstance(stmt, (ast.If, ast.While)):
