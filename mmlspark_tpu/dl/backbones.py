@@ -42,6 +42,16 @@ the key is ``brumby``'s), and a family reads these keys:
   ``sliding_window`` and its pattern (window layers on a ring),
   ``hidden_act`` ``poly_norm``, ``mhc_*``; ``published_layers`` where a
   cut keeps some of the published layers.
+- ``mimo_v2``: its own ``config.json``'s keys, read through
+  ``FAMILY_KEYS``: every layer grouped-query attention over a plain
+  key/value cache (``parallel/kv.py``), full or over a window of
+  ``sliding_window`` by ``hybrid_layer_pattern`` (1: window), each kind
+  with its own key heads and rotary base (``swa_*``), the first
+  ``partial_rotary_factor`` of a head's dims rotated, a sink logit a
+  head where ``add_swa_attention_sink_bias`` (or ``_full_``) says so,
+  the values scaled by ``attention_value_scale``; a dense SwiGLU or
+  sparse experts by ``moe_layer_freq``; ``published_layers`` where a
+  cut keeps some of the published layers.
 """
 
 from __future__ import annotations
@@ -362,14 +372,16 @@ def _centred(module, name, width):
 # each sub-layer and none after, a selection bias on the router and,
 # with a residual of several streams, xing4_0's epsilon and clamp.
 # ``block`` ``retention``: every layer a DecoderBlock (else a
-# HybridBlock), read from this table alone and never from a config
+# HybridBlock), and ``attention`` ``kv``: every layer's mixer a KVMixer,
+# each read from this table alone and never from a config
 FAMILY_DEFAULTS = {"brumby": {"block": "retention"},
                    "gigachat3_5": {"norm_type": "ZeroCenteredGatedNorm",
                                    "layernorm_type": "pre_post"},
                    "kimi_k2": {}, "xing4_0": {},
                    "Motif": {"hc_eps": 1e-6, "mhc_h_res_clamp_min": -30,
                              "mhc_h_res_clamp_max": 30,
-                             "selection_bias": False}}
+                             "selection_bias": False},
+                   "mimo_v2": {"attention": "kv"}}
 
 
 def _motif_keys(c):
@@ -384,19 +396,28 @@ def _motif_keys(c):
             "hc_sinkhorn_iters": c["mhc_sinkhorn_iters"]}
 
 
+def _mimo_keys(c):
+    # routed_scaling_factor null: the chosen weights as normalised;
+    # n_shared_experts null: no shared expert
+    return {"rms_norm_eps": c["layernorm_epsilon"],
+            "routed_scaling_factor": 1.0, "n_shared_experts": 0}
+
+
 # the keys this module's code reads, from a family's config.json where
 # it names them otherwise (Motif: no qk_nope_head_dim, head_dim the
 # query's and key's whole width)
-FAMILY_KEYS = {"Motif": _motif_keys}
+FAMILY_KEYS = {"Motif": _motif_keys, "mimo_v2": _mimo_keys}
 
 
 def family_config(config: Mapping[str, Any]) -> dict:
     """``config`` with the keys ``FAMILY_KEYS`` derives for its family
-    where it lacks them; another family's config as it is."""
+    where it lacks them or holds null; another family's config as it
+    is."""
     c = dict(config)
     derive = FAMILY_KEYS.get(c.get("model_type"))
     for key, value in (derive(c) if derive else {}).items():
-        c.setdefault(key, value)
+        if c.get(key) is None:
+            c[key] = value
     return c
 
 
@@ -592,7 +613,8 @@ class LatentMixer(nn.Module):
             self, x, positions, heads)
         pos = positions[:, 0]
         with jax.named_scope("lm.mla.write"):
-            cache = latent.cache_write(state, c_kv, k_r, pos, lengths)
+            cache = latent.write_entries(state, {"c": c_kv, "r": k_r},
+                                         pos, lengths)
         if t == 1:
             with jax.named_scope("lm.mla.decode"):
                 o = latent.latent_decode(
@@ -627,7 +649,7 @@ class DiffLatentMixer(nn.Module):
     With ``window`` the layer sees the ``sliding_window`` most recent
     positions alone, its own included, and its state is a ring of that
     many slots (``{"ring": {"c", "r"}}``, position ``p`` in slot ``p mod
-    window``; ``latent.ring_write``, ``latent.window_prefill``); a
+    window``; ``latent.ring_entries``, ``latent.window_prefill``); a
     decode step reads the ring with the cache's decode, through which
     the slots' order does not matter. The core and the ring's write
     stand under ``lm.mla.window``; a full layer's prefill attention
@@ -672,17 +694,20 @@ class DiffLatentMixer(nn.Module):
                 ring = state["ring"]
                 width = ring["c"].shape[1]
                 if t == 1:
-                    ring = latent.ring_write(ring, c_kv, k_r, pos, lengths)
+                    ring = latent.ring_entries(
+                        ring, {"c": c_kv, "r": k_r}, pos, lengths)
                     o = decode(ring, jnp.minimum(pos, width - 1))
                 else:
                     o = latent.window_prefill(
                         q_n, q_r, ring, c_kv, k_r, w_uk, w_uv, pos, lengths,
                         scale=scale, dtype=dtype)
-                    ring = latent.ring_write(ring, c_kv, k_r, pos, lengths)
+                    ring = latent.ring_entries(
+                        ring, {"c": c_kv, "r": k_r}, pos, lengths)
                 state = {"ring": ring}
         else:
             with jax.named_scope("lm.mla.write"):
-                state = latent.cache_write(state, c_kv, k_r, pos, lengths)
+                state = latent.write_entries(state, {"c": c_kv, "r": k_r},
+                                             pos, lengths)
             if t == 1:
                 with jax.named_scope("lm.mla.decode"):
                     o = decode(state, pos)
@@ -701,6 +726,103 @@ class DiffLatentMixer(nn.Module):
                 o = o * jax.nn.sigmoid(Linear(signal * d_v, dtype,
                                               name="g_proj")(x))
         return Linear(x.shape[-1], dtype, name="o_proj")(o), state
+
+
+def kv_widths(config, window: bool):
+    """``(query heads, key heads, head_dim, rotated dims, v_head_dim,
+    rotary base)`` of a :class:`KVMixer`: a window layer's from the
+    ``swa_`` keys where the config has them."""
+    def get(key):
+        return config.get("swa_" + key, config[key]) if window \
+            else config[key]
+
+    d = get("head_dim")
+    return (get("num_attention_heads"), get("num_key_value_heads"), d,
+            int(config["partial_rotary_factor"] * d), get("v_head_dim"),
+            get("rope_theta"))
+
+
+class KVMixer(nn.Module):
+    """Grouped-query attention over a plain key/value cache
+    (``parallel/kv.py``): one fused projection to the queries, keys and
+    values, the first ``partial_rotary_factor`` of each query and key
+    head's dims rotated ("rotate_half" over them, the rest passed as
+    they are), ``heads / key heads`` consecutive query heads a key head,
+    the softmax scaled by ``head_dim ** -0.5``, a learned sink logit a
+    head in the denominator where the config adds one to the layer's
+    kind, the values scaled by ``attention_value_scale``, then
+    ``W_o``. Each kind has its own widths (``kv_widths``).
+
+    State: a full layer's cache ``{"k_r", "k_n", "v"}`` of ``capacity``
+    positions in the model's dtype; with ``window``, ``{"ring": ...}``
+    the same of ``sliding_window`` slots, the layer seeing the
+    ``sliding_window`` most recent positions alone, its own included
+    (``latent.ring_entries``, ``kv.window_prefill``; a decode step reads
+    the ring with the cache's decode). The whole mixer stands under
+    ``lm.kv.full`` or ``lm.kv.window``."""
+
+    config: Any
+    window: bool = False
+
+    @staticmethod
+    def init_state(config, batch, capacity=0, window=False):
+        from mmlspark_tpu.parallel import kv
+
+        _, key_heads, d, rope, d_v, _ = kv_widths(config, window)
+        cache = kv.init_cache(batch, config["sliding_window"] if window
+                              else capacity, key_heads, rope, d - rope, d_v,
+                              lm_dtype(config))
+        return {"ring": cache} if window else cache
+
+    @nn.compact
+    def __call__(self, x, positions, lengths, state):
+        from mmlspark_tpu.parallel import kv, latent
+
+        c = self.config
+        heads, key_heads, d, rope, d_v, theta = kv_widths(c, self.window)
+        dtype, (b, t, _) = lm_dtype(c), x.shape
+        kind = "swa" if self.window else "full"
+        with jax.named_scope(f"lm.kv.{'window' if self.window else 'full'}"):
+            qkv = Linear(heads * d + key_heads * (d + d_v), dtype,
+                         name="qkv_proj")(x)
+            q, k, v = jnp.split(qkv, [heads * d, (heads + key_heads) * d],
+                                axis=-1)
+
+            def turned(a, n):
+                a = a.reshape(b, t, n, d)
+                return jnp.concatenate([rotary(a[..., :rope], positions,
+                                               theta), a[..., rope:]],
+                                       axis=-1)
+
+            q, k = turned(q, heads), turned(k, key_heads)
+            new = kv.entries(k, v.reshape(b, t, key_heads, d_v), rope)
+            sink = (self.param("sink", nn.initializers.zeros, (heads,),
+                               jnp.float32)
+                    if c.get(f"add_{kind}_attention_sink_bias") else None)
+            pos, scale = positions[:, 0], d ** -0.5
+            if self.window:
+                ring = state["ring"]
+                if t == 1:
+                    ring = latent.ring_entries(ring, new, pos, lengths)
+                    last = jnp.minimum(pos, ring["v"].shape[1] - 1)
+                    o = kv.kv_decode(q[:, 0], ring, last, scale=scale,
+                                     dtype=dtype, sink=sink)[:, None]
+                else:
+                    o = kv.window_prefill(q, ring, new, pos, lengths,
+                                          scale=scale, dtype=dtype, sink=sink)
+                    ring = latent.ring_entries(ring, new, pos, lengths)
+                state = {"ring": ring}
+            else:
+                state = latent.write_entries(state, new, pos, lengths)
+                if t == 1:
+                    o = kv.kv_decode(q[:, 0], state, pos, scale=scale,
+                                     dtype=dtype, sink=sink)[:, None]
+                else:
+                    o = kv.kv_prefill(q, state, pos, lengths, scale=scale,
+                                      dtype=dtype)
+            o = o.reshape(b, t, heads * d_v) * c.get("attention_value_scale",
+                                                     1.0)
+            return Linear(x.shape[-1], dtype, name="o_proj")(o), state
 
 
 def _poly_norm(module, config, prefix="", stack=()):
@@ -741,8 +863,8 @@ class DenseFeedForward(nn.Module):
 class ExpertFeedForward(nn.Module):
     """Sparse experts (``parallel/experts.py``): the router scores all
     published experts, the experts held here serve their pairs, the
-    shared expert every token. Also returns ``(pairs a held expert,
-    dropped pairs)``."""
+    shared expert (where ``n_shared_experts`` is not 0) every token.
+    Also returns ``(pairs a held expert, dropped pairs)``."""
 
     config: Any
 
@@ -773,13 +895,15 @@ class ExpertFeedForward(nn.Module):
             tile=experts.tile_rows(tokens.shape[0],
                                    c["num_experts_per_tok"], routed),
             poly=_poly_norm(self, c, "experts_", (count,)))
-        with jax.named_scope("lm.moe.shared"):
-            ws = w * c.get("n_shared_experts", 1)
-            y = y + experts.swiglu(
-                tokens, self.param("shared_gate", shape, (h, ws), dtype),
-                self.param("shared_up", shape, (h, ws), dtype),
-                self.param("shared_down", shape, (ws, h), dtype),
-                dtype=dtype, limit=limit, poly=_poly_norm(self, c, "shared_"))
+        ws = w * c.get("n_shared_experts", 1)
+        if ws:
+            with jax.named_scope("lm.moe.shared"):
+                y = y + experts.swiglu(
+                    tokens, self.param("shared_gate", shape, (h, ws), dtype),
+                    self.param("shared_up", shape, (h, ws), dtype),
+                    self.param("shared_down", shape, (ws, h), dtype),
+                    dtype=dtype, limit=limit,
+                    poly=_poly_norm(self, c, "shared_"))
         return y.reshape(x.shape), (pairs, dropped)
 
 
@@ -831,36 +955,62 @@ class HybridBlock(nn.Module):
     :class:`HyperConnection`'s: the sub-layer reads a mix of the
     streams and its output is written back into each beside a mix of
     them all (``parallel/hyper.py``), under the scope ``lm.hc``, which
-    stands outside the sub-layer's own."""
+    stands outside the sub-layer's own.
+
+    Where the family's ``attention`` is ``kv`` every mixer is a
+    :class:`KVMixer` (scope ``lm.kv``), full or over a window by
+    ``hybrid_layer_pattern``, and ``moe_layer_freq`` (a list) says which
+    layers are sparse; both by the published index the layer stands
+    for (``published_layers``)."""
 
     config: Any
     index: int
+
+    def published(self) -> int:
+        """The published index the layer stands for (``published_layers``,
+        a cut's; its own where absent)."""
+        c = self.config
+        return c.get("published_layers",
+                     range(c["num_hidden_layers"]))[self.index]
+
+    def kv(self) -> bool:
+        family = FAMILY_DEFAULTS.get(self.config.get("model_type"), {})
+        return family.get("attention") == "kv"
 
     def latent(self) -> bool:
         listed = self.config.get("full_attention_layers")
         return listed is None or self.index in tuple(listed)
 
     def sparse(self) -> bool:
+        freq = self.config.get("moe_layer_freq")
+        if isinstance(freq, (list, tuple)):
+            return bool(freq[self.published()])
         return self.index >= self.config["first_k_dense_replace"]
 
     def window(self) -> bool:
-        """A layer of window attention (``use_sliding_window``): by the
-        published index the layer stands for (``published_layers``, a
-        cut's; its own where absent), from ``max_window_layers`` on
-        every layer but each ``sliding_window_period``-th ("interleave":
-        published index + 1 a multiple of the period is full)."""
+        """A layer of window attention: where the config has
+        ``hybrid_layer_pattern``, its 1 at the published index;
+        elsewhere where ``use_sliding_window`` says so, from
+        ``max_window_layers`` on every layer but each
+        ``sliding_window_period``-th ("interleave": published index + 1 a
+        multiple of the period is full)."""
         c = self.config
+        pattern = c.get("hybrid_layer_pattern")
+        if pattern is not None:
+            return pattern[self.published()] == 1
         if not c.get("use_sliding_window"):
             return False
-        index = c.get("published_layers",
-                      range(c["num_hidden_layers"]))[self.index]
+        index = self.published()
         return (index >= c["max_window_layers"]
                 and (index + 1) % c["sliding_window_period"] != 0)
 
     def mixer(self):
         """The mixer: the delta rule, latent attention, or where the
         config says ``attention_cls`` ``gdla`` its grouped differential
-        form, over a window where ``window()``."""
+        form, over a window where ``window()``; where the family's
+        ``attention`` is ``kv``, a :class:`KVMixer`."""
+        if self.kv():
+            return functools.partial(KVMixer, window=self.window())
         if not self.latent():
             return DeltaMixer
         if self.config.get("attention_cls") != "gdla":
@@ -870,6 +1020,9 @@ class HybridBlock(nn.Module):
     def init_state(self, batch, capacity):
         """The mixer's empty state: a window layer's ring, else the
         cache or the recurrent state."""
+        if self.kv():
+            return KVMixer.init_state(self.config, batch, capacity,
+                                      self.window())
         if self.window():
             return DiffLatentMixer.ring(self.config, batch)
         return (LatentMixer if self.latent() else DeltaMixer).init_state(
@@ -909,8 +1062,8 @@ class HybridBlock(nn.Module):
                 lambda s: jnp.clip(s, -bound, bound), h)
 
         h, state = around(
-            h, "lm.mla" if self.latent() else "lm.gdn", "mixer",
-            self.mixer(), positions, lengths, state)
+            h, "lm.kv" if self.kv() else "lm.mla" if self.latent()
+            else "lm.gdn", "mixer", self.mixer(), positions, lengths, state)
         scope = "lm.moe" if self.sparse() else "lm.mlp"
         with jax.named_scope(scope):
             valid = (jnp.arange(positions.shape[1])[None, :]
@@ -939,11 +1092,12 @@ class HybridLM(nn.Module):
 
     The state holds ``pos``, the tokens each row has absorbed;
     ``layers``, each layer's own: a retention or delta-rule layer's
-    fixed recurrent state (float32), a latent layer's cache of
-    ``capacity`` positions, a window layer's ring; and where some layer
-    is sparse ``experts``, what the expert layers served since the
-    state was empty: ``pairs`` ``(expert layers, experts held)`` and
-    ``dropped``. ``counts`` reads from a state what the stage reports.
+    fixed recurrent state (float32), a latent or key/value layer's
+    cache of ``capacity`` positions, a window layer's ring; and where
+    some layer is sparse ``experts``, what the expert layers served
+    since the state was empty: ``pairs`` ``(expert layers, experts
+    held)`` and ``dropped``. ``counts`` reads from a state what the
+    stage reports.
 
     With ``hc_mult`` in the config the residual between the layers is
     that many streams a token: the embedding is copied into each before
@@ -982,28 +1136,28 @@ class HybridLM(nn.Module):
         """What a state says its rows went through, counted on the
         device: with expert layers, what they served (``expert_pairs``,
         one row: a count an expert layer and held expert;
-        ``dropped_pairs``); with caches, each row's ``cache_positions``
-        (the positions it has filled, summed over the layers that
-        cache; a window layer's ring is no such cache) and
-        ``cache_capacity`` (the same at capacity); with window layers,
-        each row's ``window_positions`` (``latent.window_read``, summed
-        over them); with a residual of several streams, each row's
-        ``hc_sublayer_tokens``: the tokens it has absorbed, prompt and
-        generated, times the sub-layers they went through that path
-        around."""
+        ``dropped_pairs``); with caches (latent or key/value), each
+        row's ``cache_positions`` (the positions it has filled, summed
+        over the layers that cache; a window layer's ring is no such
+        cache) and ``cache_capacity`` (the same at capacity); with
+        window layers, each row's ``window_positions``
+        (``latent.window_read``, summed over them); with a residual of
+        several streams, each row's ``hc_sublayer_tokens``: the tokens
+        it has absorbed, prompt and generated, times the sub-layers they
+        went through that path around."""
         from mmlspark_tpu.parallel import latent
 
         out, pos = {}, state["pos"]
         if "experts" in state:
             out.update(expert_pairs=state["experts"]["pairs"].reshape(1, -1),
                        dropped_pairs=state["experts"]["dropped"])
-        caches = [layer["c"].shape[1] for layer in state["layers"]
-                  if "c" in layer]
+        caches = [(layer["c"] if "c" in layer else layer["v"]).shape[1]
+                  for layer in state["layers"] if "c" in layer or "v" in layer]
         if caches:
             out.update(cache_positions=len(caches) * pos,
                        cache_capacity=jnp.full_like(pos, sum(caches)))
-        rings = [layer["ring"]["c"].shape[1] for layer in state["layers"]
-                 if "ring" in layer]
+        rings = [next(iter(layer["ring"].values())).shape[1]
+                 for layer in state["layers"] if "ring" in layer]
         if rings:
             out["window_positions"] = sum(
                 latent.window_read(pos, width) for width in rings)

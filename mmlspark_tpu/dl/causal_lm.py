@@ -61,7 +61,9 @@ def lm_prefill_program(module, prefill_chunk: int, new_tokens: int):
     """``lm_prefill(params, ids, lengths) -> (hidden after each row's
     last prompt token, state)``: the prompt absorbed ``prefill_chunk``
     tokens a row at a time, the state the scan's carry. A cache in it
-    has room for the batch's length rung and ``new_tokens`` more."""
+    has room for the batch's length rung (or its whole steps, where the
+    chunk does not divide the rung: a step's stretch is written whole)
+    and ``new_tokens`` more."""
     import jax
     import jax.numpy as jnp
 
@@ -85,7 +87,7 @@ def lm_prefill_program(module, prefill_chunk: int, new_tokens: int):
                 last = jnp.where((real > 0)[:, None], h, last)
             return (state, last), None
 
-        first = (lm_init_state(config, rows, length + new_tokens),
+        first = (lm_init_state(config, rows, steps * chunk + new_tokens),
                  jnp.zeros((rows, config["hidden_size"]), jnp.float32))
         (state, last), _ = jax.lax.scan(
             step, first, (ids, jnp.arange(steps) * chunk))

@@ -43,7 +43,7 @@ from __future__ import annotations
 import functools
 from typing import Optional
 
-from mmlspark_tpu.parallel.retention import use_pallas
+from mmlspark_tpu.parallel.mosaic import use_pallas
 
 HEAD_BLOCK = 16      # value heads a launch of the decode kernel's grid
 LANES = 128

@@ -33,7 +33,7 @@ two forms of one formula:
 
 A window layer (``window_prefill``) sees only the ``window`` most
 recent positions and keeps them in a ring of ``window`` slots, position
-``p`` in slot ``p mod window`` (``ring_write``): a stretch's queries are
+``p`` in slot ``p mod window`` (``ring_entries``): a stretch's queries are
 cut into blocks of ``window`` and each reads two blocks of keys, the
 ring or the stretch's block before and its own; a decode step reads
 the ring through ``latent_decode`` as a cache of ``window`` positions
@@ -41,7 +41,7 @@ the ring through ``latent_decode`` as a cache of ``window`` positions
 rotated when it was written).
 
 The cache is ``{"c": (B, capacity, rank), "r": (B, capacity, rope)}``
-in the model's dtype. ``cache_write`` puts a stretch's entries at each
+in the model's dtype. ``write_entries`` puts a stretch's entries at each
 row's own position (one token a row: one scatter); a padded position
 writes nothing, and every softmax masks the positions a row has not
 filled, scores and values both, so what lies beyond a row's fill is
@@ -57,7 +57,7 @@ from typing import Mapping, Optional
 
 import numpy as np
 
-from mmlspark_tpu.parallel.retention import use_pallas
+from mmlspark_tpu.parallel.mosaic import operand, use_pallas
 
 KV_BLOCK = 128       # cached positions expanded a step of the scan
 DECODE_ROWS = 8      # rows a grid step of the decode kernel
@@ -141,19 +141,19 @@ def init_cache(batch: int, capacity: int, rank: int, rope: int, dtype):
             "r": jnp.zeros((batch, capacity, rope), dtype)}
 
 
-def cache_write(cache, c, r, pos, lengths):
-    """``c``, ``r``: ``(B, T, ...)`` entries of a stretch whose first
-    position is ``pos`` ``(B,)``; the first ``lengths`` of each row are
-    real and are written, the rest leave the cache as it was. One
-    token a row is one scatter (a row without a real token writes
-    beyond the capacity, which drops it); a longer stretch is a slice a
-    row."""
+def write_entries(cache, new, pos, lengths):
+    """Each array of ``new`` (``(B, T, ...)``, a stretch from ``pos``)
+    into the array of ``cache`` of its name, at each row's own
+    positions: the first ``lengths`` of a row are written, the rest
+    leave the cache as it was. One token a row is one scatter (a row
+    without a real token writes beyond the capacity, which drops it); a
+    longer stretch is a slice a row."""
     import jax
     import jax.numpy as jnp
 
     from mmlspark_tpu.parallel.shard_rules import placement_cast
 
-    b, t = c.shape[:2]
+    b, t = next(iter(new.values())).shape[:2]
 
     def put_token(old, new):
         at = jnp.where(lengths > 0, pos, old.shape[1])
@@ -170,20 +170,7 @@ def cache_write(cache, c, r, pos, lengths):
             o, n, s, axis=0))(old, jnp.where(real, new, was), pos)
 
     put = put_token if t == 1 else put_stretch
-    return {"c": put(cache["c"], c), "r": put(cache["r"], r)}
-
-
-def _operand(x, dtype):
-    """``x`` as an operand of a product in ``dtype``, kept float32 (a
-    pair of converts XLA may drop as excess precision)."""
-    import jax
-    import jax.numpy as jnp
-
-    x = x.astype(jnp.float32)
-    if jnp.dtype(dtype) == jnp.float32:
-        return x
-    info = jnp.finfo(dtype)
-    return jax.lax.reduce_precision(x, info.nexp, info.nmant)
+    return {name: put(cache[name], entries) for name, entries in new.items()}
 
 
 def prefill_kernel(t: int, heads: int, key_heads: int) -> bool:
@@ -211,7 +198,7 @@ def latent_prefill(q_n, q_r, cache, w_uk, w_uv, pos, lengths, *,
                    scale: float, dtype, pallas: Optional[bool] = None,
                    interpret: bool = False):
     """``q_n``: ``(B, T, heads, nope)``; ``q_r``: ``(B, T, heads, rope)``
-    rotated; ``cache`` already holds the stretch (``cache_write``);
+    rotated; ``cache`` already holds the stretch (``write_entries``);
     ``w_uk``: ``(rank, heads, nope)``; ``w_uv``: ``(rank, heads, d_v)``,
     or of fewer key heads over which the query heads are grouped
     (``attention._block_attend``); ``pos``: ``(B,)`` the stretch's first
@@ -229,7 +216,7 @@ def latent_prefill(q_n, q_r, cache, w_uk, w_uv, pos, lengths, *,
         return _prefill_pallas(q_n, q_r, cache, w_uk, w_uv, pos, lengths,
                                scale, dtype, interpret)
     expand = _expander(w_uk, w_uv, dtype)
-    q = _operand(jnp.concatenate([q_n, q_r], axis=-1), dtype)
+    q = operand(jnp.concatenate([q_n, q_r], axis=-1), dtype)
     return blockwise_attention(
         q, cache["c"], cache["r"], block_size=KV_BLOCK, causal=True,
         scale=scale, q_positions=pos[:, None] + jnp.arange(t),
@@ -254,8 +241,8 @@ def _expander(w_uk, w_uv, dtype):
         k_n, v = kv[..., :w_uk.shape[-1]], kv[..., w_uk.shape[-1]:]
         k_r = jnp.broadcast_to(r.astype(jnp.float32)[:, :, None, :],
                                r.shape[:2] + (heads, r.shape[-1]))
-        return (_operand(jnp.concatenate([k_n, k_r], axis=-1), dtype),
-                _operand(v, dtype))
+        return (operand(jnp.concatenate([k_n, k_r], axis=-1), dtype),
+                operand(v, dtype))
 
     return expand
 
@@ -454,21 +441,22 @@ def ring_slots(pos, window: int):
     return last - (last - jnp.arange(window)[None, :]) % window
 
 
-def ring_write(ring, c, r, pos, lengths):
-    """A stretch's entries ``c``, ``r`` ``(B, T, ...)`` from ``pos``
-    into a ring of ``window`` slots (``{"c": (B, window, rank), "r":
-    (B, window, rope)}``): position ``p`` goes to slot ``p mod window``,
-    so of a stretch longer than the ring its last ``window`` real
-    positions stay. One token a row is one scatter (``cache_write`` at
-    the slot); a longer stretch a gather, a slot, of the stretch's
-    latest real entry of the slot's class where it has one."""
+def ring_entries(ring, new, pos, lengths):
+    """Each array of ``new`` (``(B, T, ...)``, a stretch from ``pos``)
+    into the ring's array of its name, ``window`` slots: position ``p``
+    goes to slot ``p mod window``, so of a stretch longer than the ring
+    its last ``window`` real positions stay. One token a row is one
+    scatter (``write_entries`` at the slot); a longer stretch a gather,
+    a slot, of the stretch's latest real entry of the slot's class where
+    it has one."""
     import jax.numpy as jnp
 
     from mmlspark_tpu.parallel.shard_rules import placement_cast
 
-    t, window = c.shape[1], ring["c"].shape[1]
+    t = next(iter(new.values())).shape[1]
+    window = next(iter(ring.values())).shape[1]
     if t == 1:
-        return cache_write(ring, c, r, pos % window, lengths)
+        return write_entries(ring, new, pos % window, lengths)
     end = (pos + lengths - 1)[:, None]                 # last real position
     index = (lengths[:, None] - 1
              - (end - jnp.arange(window)[None, :]) % window)
@@ -479,7 +467,7 @@ def ring_write(ring, c, r, pos, lengths):
         return jnp.where((index >= 0)[..., None],
                          placement_cast(got, old.dtype), old)
 
-    return {"c": put(ring["c"], c), "r": put(ring["r"], r)}
+    return {name: put(ring[name], entries) for name, entries in new.items()}
 
 
 def window_read(pos, window: int):
@@ -497,7 +485,7 @@ def window_prefill(q_n, q_r, ring, c, r, w_uk, w_uv, pos, lengths, *,
                    scale: float, dtype):
     """A stretch under a window of ``window`` positions (the ring's
     slots), each query's own included: the stretch's queries against
-    the ring as it was before the stretch (``ring_write`` comes after)
+    the ring as it was before the stretch (``ring_entries`` comes after)
     and the stretch's own entries ``c``, ``r`` ``(B, T, ...)``. The
     queries are cut into blocks of ``window``; block ``i`` reads two
     blocks of keys, the ring (``i`` = 0) or the stretch's block ``i -
@@ -525,7 +513,7 @@ def window_prefill(q_n, q_r, ring, c, r, w_uk, w_uv, pos, lengths, *,
             return y.reshape((b, blocks, window) + y.shape[2:])
         return jnp.concatenate([cut(x[:, :span]), cut(x[:, window:])], axis=2)
 
-    q = padded(_operand(jnp.concatenate([q_n, q_r], axis=-1), dtype))
+    q = padded(operand(jnp.concatenate([q_n, q_r], axis=-1), dtype))
     q = q.reshape(b, blocks, window, groups, heads // groups, -1)
     k, v = _expander(w_uk, w_uv, dtype)(
         *(jnp.concatenate([ring[name], padded(placement_cast(
@@ -542,7 +530,7 @@ def window_prefill(q_n, q_r, ring, c, r, w_uk, w_uv, pos, lengths, *,
     scores = jnp.einsum("bnqgjd,bnkgd->bngjqk", q, band(k)) * scale
     p = jax.nn.softmax(jnp.where(seen[:, :, None, None], scores, -1e30),
                        axis=-1)
-    o = jnp.einsum("bngjqk,bnkgd->bnqgjd", _operand(p, dtype), band(v))
+    o = jnp.einsum("bngjqk,bnkgd->bnqgjd", operand(p, dtype), band(v))
     return o.reshape(b, span, heads, -1)[:, :t]
 
 

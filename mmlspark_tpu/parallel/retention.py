@@ -49,6 +49,8 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from mmlspark_tpu.parallel.mosaic import use_pallas
+
 EPS = 1e-6
 State = Dict[str, "jax.Array"]  # noqa: F821
 
@@ -67,13 +69,6 @@ def init_state(batch: int, kv_heads: int, d: int) -> State:
 
     return {k: jnp.zeros(shape, jnp.float32)
             for k, shape in state_shapes(batch, kv_heads, d).items()}
-
-
-def use_pallas() -> bool:
-    """The Mosaic kernels on the TPU backend, ``jax.numpy`` elsewhere."""
-    import jax
-
-    return jax.default_backend() == "tpu"
 
 
 @functools.lru_cache(maxsize=None)

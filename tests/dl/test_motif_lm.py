@@ -193,7 +193,7 @@ def test_a_ring_written_at_p_not_p_mod_window_fails_the_comparison(
     their position, so that past the window they fall off the ring."""
     from mmlspark_tpu.parallel import latent
 
-    def at_p(ring, c, r, pos, lengths):
+    def at_p(ring, new, pos, lengths):
         slot = jnp.arange(ring["c"].shape[1])[None, :] - pos[:, None]
         ok = (slot >= 0) & (slot < lengths[:, None])
 
@@ -202,9 +202,9 @@ def test_a_ring_written_at_p_not_p_mod_window_fails_the_comparison(
                 new, jnp.clip(slot, 0, new.shape[1] - 1)[..., None], axis=1)
             return jnp.where(ok[..., None], got.astype(old.dtype), old)
 
-        return {"c": put(ring["c"], c), "r": put(ring["r"], r)}
+        return {name: put(ring[name], x) for name, x in new.items()}
 
-    monkeypatch.setattr(latent, "ring_write", at_p)
+    monkeypatch.setattr(latent, "ring_entries", at_p)
     col = _prompts()[[4]]
     out = _stage(params, logitsCol="logits").transform(
         DataFrame({"prompt": col}))

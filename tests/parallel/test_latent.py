@@ -52,14 +52,16 @@ def test_prefill_in_stretches_then_absorbed_decode_equal_the_expanded_form():
     cache = L.init_cache(B, 40, RANK, ROPE, jnp.float32)
     for lo, hi in zip(cut, cut[1:]):
         pos, n = jnp.full((B,), lo), jnp.full((B,), hi - lo)
-        cache = L.cache_write(cache, c[:, lo:hi], r[:, lo:hi], pos, n)
+        cache = L.write_entries(cache, {"c": c[:, lo:hi], "r": r[:, lo:hi]},
+                                pos, n)
         got = L.latent_prefill(q_n[:, lo:hi], q_r[:, lo:hi], cache, w_uk,
                                w_uv, pos, n, scale=SCALE, dtype=jnp.float32)
         assert np.abs(np.asarray(got) - want[:, lo:hi]).max() < TOL
     for i in range(cut[-1], t):
         pos = jnp.full((B,), i)
-        cache = L.cache_write(cache, c[:, i:i + 1], r[:, i:i + 1], pos,
-                              jnp.ones((B,), jnp.int32))
+        cache = L.write_entries(cache, {"c": c[:, i:i + 1],
+                                        "r": r[:, i:i + 1]}, pos,
+                                jnp.ones((B,), jnp.int32))
         got = L.latent_decode(q_n[:, i], q_r[:, i], cache, w_uk, w_uv, pos,
                               scale=SCALE, dtype=jnp.float32)
         assert np.abs(np.asarray(got) - want[:, i]).max() < TOL
@@ -72,8 +74,8 @@ def test_a_ragged_batch_writes_and_attends_at_each_rows_own_position():
     q_n, q_r, c, r, w_uk, w_uv = _inputs(17, seed=1)
     cache = L.init_cache(B, 24, RANK, ROPE, jnp.float32)
     before = jnp.array([5, 11])
-    cache = L.cache_write(cache, c[:, :11], r[:, :11], jnp.zeros((B,), int),
-                          before)
+    cache = L.write_entries(cache, {"c": c[:, :11], "r": r[:, :11]},
+                            jnp.zeros((B,), int), before)
     junk = {k: v.at[:, 12:].set(7.0) for k, v in cache.items()}
     junk = {k: v.at[0, 5:].set(7.0) for k, v in junk.items()}
     real = jnp.array([3, 6])
@@ -84,7 +86,8 @@ def test_a_ragged_batch_writes_and_attends_at_each_rows_own_position():
     qr = np.stack([q_r[0, 5:11], q_r[1, 11:17]])
     out = {}
     for name, held in (("clean", cache), ("junk", junk)):
-        written = L.cache_write(held, c_new, r_new, before, real)
+        written = L.write_entries(held, {"c": c_new, "r": r_new}, before,
+                                  real)
         out[name] = np.asarray(L.latent_prefill(
             qn, qr, written, w_uk, w_uv, before, real, scale=SCALE,
             dtype=jnp.float32))
@@ -203,12 +206,13 @@ def test_the_one_scatter_write_equals_a_slice_a_row(rows):
     pos[0], pos[1] = 0, capacity - 1
     real = np.ones(rows, np.int32)
     real[2] = 0                                  # a padded row writes nothing
-    got = L.cache_write(cache, c, r, jnp.asarray(pos), jnp.asarray(real))
+    got = L.write_entries(cache, {"c": c, "r": r}, jnp.asarray(pos),
+                          jnp.asarray(real))
     # the write of a stretch (a slice a row), given the token and a
     # padded second position: what a one-token write was before
-    two = L.cache_write(
+    two = L.write_entries(
         {k: jnp.pad(v, ((0, 0), (0, 1), (0, 0))) for k, v in cache.items()},
-        np.concatenate([c, c], 1), np.concatenate([r, r], 1),
+        {"c": np.concatenate([c, c], 1), "r": np.concatenate([r, r], 1)},
         jnp.asarray(pos), jnp.asarray(real))
     want = {k: np.array(v) for k, v in cache.items()}
     for b in range(rows):

@@ -770,3 +770,71 @@ def test_latent_prefill_kernel_lowers_to_mosaic(rows, t, heads, key_heads,
         jax.ShapeDtypeStruct((rows,), jnp.int32),
         jax.ShapeDtypeStruct((rows,), jnp.int32))
     assert "tpu_custom_call" in txt and "latent_prefill" in txt
+
+
+@pytest.mark.parametrize(
+    "rows,length,key_heads,sink",
+    [(16, 33280, 4, False), (16, 128, 8, True), (2, 33280, 4, False)],
+    ids=["mimo_v2_5.full", "mimo_v2_5.ring", "two rows"])
+def test_kv_decode_kernel_lowers_to_mosaic(rows, length, key_heads, sink):
+    """The key/value decode at MiMo's widths (64 query heads of 192, 64
+    of them rotated, values of 128, bfloat16): a full layer's cache of 4
+    key heads at the cell's capacity, a window layer's ring of 128 slots
+    and 8 key heads with its sink logits, and the check's two rows: the
+    scalar-prefetched positions in the block index, the queries on their
+    key heads' lanes, the sink's start and each head's own lanes at the
+    end go through Mosaic."""
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.parallel import kv
+
+    bf = jnp.bfloat16
+
+    def decode(q, k_r, k_n, v, pos, logits):
+        return kv.kv_decode(q, {"k_r": k_r, "k_n": k_n, "v": v}, pos,
+                            scale=192 ** -0.5, dtype=bf,
+                            sink=logits if sink else None, pallas=True)
+
+    txt = _lower_tpu(
+        decode, jax.ShapeDtypeStruct((rows, 64, 192), jnp.float32),
+        jax.ShapeDtypeStruct((rows, length, key_heads * 64), bf),
+        jax.ShapeDtypeStruct((rows, length, key_heads * 128), bf),
+        jax.ShapeDtypeStruct((rows, length, key_heads * 128), bf),
+        jax.ShapeDtypeStruct((rows,), jnp.int32),
+        jax.ShapeDtypeStruct((64,), jnp.float32))
+    assert "tpu_custom_call" in txt and "kv_decode" in txt
+
+
+@pytest.mark.parametrize("rows,t,capacity", [(4, 1024, 33280),
+                                             (2, 128, 1024)],
+                         ids=["mimo_v2_5.long_decode", "a short step"])
+def test_kv_prefill_kernel_lowers_to_mosaic(rows, t, capacity):
+    """A full layer's prefill kernel at MiMo's widths (64 query heads
+    over 4 key heads, 192 = 64 rotated + 128 a key, values of 128,
+    bfloat16), two key heads a grid step: a group of the cell's step of
+    1,024 queries against its cache, and a short step: the three
+    scalar-prefetched vectors in the block index, the rotated parts on
+    their key heads' lanes and each head's lanes of the block go through
+    Mosaic."""
+    import jax
+    import jax.numpy as jnp
+
+    from mmlspark_tpu.parallel import kv
+
+    bf = jnp.bfloat16
+    assert kv.prefill_form(t, 4, 64, 128, 128, capacity)[2] == 2
+
+    def prefill(q, k_r, k_n, v, pos, lengths):
+        return kv.kv_prefill(q, {"k_r": k_r, "k_n": k_n, "v": v}, pos,
+                             lengths, scale=192 ** -0.5, dtype=bf,
+                             pallas=True)
+
+    txt = _lower_tpu(
+        prefill, jax.ShapeDtypeStruct((rows, t, 64, 192), bf),
+        jax.ShapeDtypeStruct((rows, capacity, 256), bf),
+        jax.ShapeDtypeStruct((rows, capacity, 512), bf),
+        jax.ShapeDtypeStruct((rows, capacity, 512), bf),
+        jax.ShapeDtypeStruct((rows,), jnp.int32),
+        jax.ShapeDtypeStruct((rows,), jnp.int32))
+    assert "tpu_custom_call" in txt and "kv_prefill" in txt

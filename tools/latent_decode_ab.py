@@ -139,10 +139,13 @@ def _decode_ab(args, rehearsal):
                 q_n, q_r, cache, w_uk, w_uv, pos, scale=0.1, dtype=bf,
                 pallas=pallas, interpret=rehearsal and pallas)
 
+        def scatter(cache, c, r, pos, lengths):
+            return latent.write_entries(cache, {"c": c, "r": r}, pos, lengths)
+
         def old_write(cache, c, r, pos, lengths):       # the slice a row
-            return latent.cache_write(
-                cache, jnp.concatenate([c, c], 1), jnp.concatenate([r, r], 1),
-                pos, lengths)
+            return latent.write_entries(
+                cache, {"c": jnp.concatenate([c, c], 1),
+                        "r": jnp.concatenate([r, r], 1)}, pos, lengths)
 
         def per_step(decode, write):
             long, out = timed(scan_of(decode, write, args.steps), cache, pos0)
@@ -173,7 +176,7 @@ def _decode_ab(args, rehearsal):
                 "floor_s": floor,
                 "max_abs_diff_from_twin": float(jnp.abs(got - twin).max()),
                 "twin_scale": float(jnp.abs(twin).max())})
-        for name, write in (("scatter", latent.cache_write),
+        for name, write in (("scatter", scatter),
                             ("slices", old_write)):
             s, _ = per_step(None, write)
             lines.append({"shape": shape, "variant": "write " + name,
